@@ -1,0 +1,390 @@
+"""Chip smoke: the README user program, once, on the accelerator.
+
+    python chip_smoke.py
+
+drives the AutoML main path in ONE process (a chip has one owner) through the
+entry points a user calls, at the width the repo benches:
+
+  A  dense sweep      bench.dense_workflow — 1,000,000 x 28 RealNN ->
+                      transmogrify -> sanity_check -> 3-fold CV over 4 LR +
+                      RF(20 trees, depth 6) + GBT(20 rounds, depth 3) ->
+                      Workflow.train() -> evaluate() -> score()
+  B  transmogrify     bench.transmog_workflow — 100,000 rows of text /
+                      picklist / map / real columns, RawFeatureFilter on, LR
+                      selector -> train -> score -> save -> load -> re-score
+  C  serve            save() of the phase-A model -> start_server(bundle,
+                      port=0) in this process -> POST /v1/score, GET /metrics,
+                      GET /healthz
+
+and fails (non-zero exit, phase named) unless every phase ran on the chip, on
+the compiled path, complete: see the ``require`` calls.  The row counts sit on
+the large side of every size-gated accelerator branch (mesh >= 262,144 rows,
+train-start prefetch >= 100,000 rows, bf16 matrix storage >= 64M elements,
+device hash-count assembly >= 4M elements).
+
+It refuses to run unless ``jax.devices()[0].platform == "tpu"`` and never
+sets the platform itself.  The last stdout line is one JSON object,
+``{"ok": true, "device": {...}, ..., "claim": null}`` — printed only when
+every check passed.  No number it prints is a benchmark result.
+
+    JAX_PLATFORMS=cpu python chip_smoke.py --cpu-reference [--rows-a N --rows-b M]
+
+is the separately named CPU correctness run that produced CPU_REFERENCE below
+(and, at small row counts, a quick way to debug the script).  It prints no
+result line.
+"""
+
+import argparse
+import contextlib
+import json
+import math
+import os
+import shutil
+import sys
+import tempfile
+import time
+import urllib.request
+
+ROWS_A = 1_000_000
+ROWS_B = 100_000
+
+# What `JAX_PLATFORMS=cpu python chip_smoke.py --cpu-reference` printed at
+# ROWS_A / ROWS_B with the seeds baked into bench.make_data /
+# bench.make_transmog_columns (this sandbox, jax 0.9.0, XLA:CPU, f32 + host
+# paths; PR 21).  The chip must land inside AUROC_BAND of these and may log
+# no failure event the CPU run did not.
+CPU_REFERENCE = {
+    "A": {"auroc": 0.8142, "winner": "OpLogisticRegression",
+          "failure_events": []},
+    "B": {"auroc": 0.8419, "failure_events": []},
+}
+# bf16 feature storage and bf16 histogram contractions move a train-set AuROC
+# in the third decimal; a family that silently degraded moves it in the second
+AUROC_BAND = 0.01
+
+BAD_ACTIONS = ("skipped", "demoted", "degraded", "fallback", "swallowed",
+               "outage")
+FAMILIES = ("OpLogisticRegression", "OpRandomForestClassifier",
+            "OpGBTClassifier")
+
+
+class SmokeFailure(Exception):
+    pass
+
+
+def require(cond, phase, what):
+    if not cond:
+        raise SmokeFailure(f"phase {phase}: {what}")
+
+
+def say(msg):
+    print(msg, flush=True)
+
+
+@contextlib.contextmanager
+def phase(name, report):
+    """Wall + compile counters of one phase, printed and kept in the report."""
+    from transmogrifai_tpu.profiling import compile_stats
+    c0, t0 = compile_stats(), time.time()
+    rec = report.setdefault(name, {})
+    say(f"--- phase {name} ---")
+    yield rec
+    c1 = compile_stats()
+    rec["wall_s"] = round(time.time() - t0, 1)
+    rec["compile"] = {k: round(c1[k] - c0[k], 1) for k in c1}
+    say(f"phase {name}: wall {rec['wall_s']} s, compile {rec['compile']}")
+
+
+def bad_events(model):
+    return sorted({(e.action, e.point or e.stage)
+                   for e in model.failure_log.events
+                   if e.action in BAD_ACTIONS})
+
+
+def check_failure_log(model, name, rec):
+    events = bad_events(model)
+    rec["failure_events"] = [list(e) for e in events]
+    allowed = {tuple(e) for e in CPU_REFERENCE[name]["failure_events"]}
+    extra = [e for e in events if e not in allowed]
+    require(not extra, name,
+            f"failure log holds events the CPU run does not: {extra}; "
+            f"full log: {model.failure_log.to_json()}")
+
+
+def check_auroc(auroc, name, rec, reference):
+    rec["auroc"] = round(float(auroc), 4)
+    require(math.isfinite(auroc), name, f"AuROC is {auroc}")
+    if reference:
+        return
+    want = CPU_REFERENCE[name]["auroc"]
+    require(abs(auroc - want) <= AUROC_BAND, name,
+            f"AuROC {auroc:.4f} outside {want} +- {AUROC_BAND} (CPU "
+            "reference)")
+
+
+def check_memory(name, rec):
+    from transmogrifai_tpu.parallel.memory import memory_aux
+    aux = memory_aux()
+    rec["memory"] = {"shrink_level": aux["shrink_level"],
+                     "device_budget_bytes": aux["device_budget_bytes"],
+                     "plan": aux["plan"]}
+    require(aux["shrink_level"] == 0, name,
+            f"memory governor shrank the sweep: {aux}")
+
+
+def predictions(scored, pred_name):
+    import numpy as np
+    vals = scored[pred_name].values
+    return (np.asarray(vals["prediction"]),
+            np.asarray(vals["probability"], dtype=np.float64))
+
+
+def phase_a(rows, report, reference):
+    """Dense sweep; returns (model, batch, pred_name) for phase C."""
+    import numpy as np
+
+    import bench
+    from transmogrifai_tpu.evaluators import Evaluators
+    from transmogrifai_tpu.telemetry import REGISTRY
+
+    with phase("A", report) as rec:
+        wf, batch, selector, _ = bench.dense_workflow(rows)
+        model = wf.train()
+        auroc = model.evaluate(Evaluators.BinaryClassification.auROC(),
+                               batch=batch)["AuROC"]
+        pred_name = next(f.name for f in model.result_features)
+        pred, prob = predictions(model.score(), pred_name)
+
+        fam, _ = bench.family_cv_metrics(model, selector)
+        rec.update(rows=rows, family_cv_metrics=fam,
+                   winner=model.selected_model.summary.best_model_name,
+                   mesh_devices=REGISTRY.snapshot()["gauges"].get(
+                       "mesh.devices", 0))
+        say(f"A: winner {rec['winner']}, CV metrics {fam}, "
+            f"mesh devices {rec['mesh_devices']}")
+        for name in FAMILIES:
+            require(name in fam and math.isfinite(fam[name]), "A",
+                    f"family {name} has no finite CV metric: {fam}")
+        require(pred.shape == (rows,) and np.isfinite(prob).all(), "A",
+                f"score() gave shape {pred.shape}, finite "
+                f"{bool(np.isfinite(prob).all())}")
+        check_failure_log(model, "A", rec)
+        check_auroc(auroc, "A", rec, reference)
+        check_memory("A", rec)
+    return model, batch, pred_name
+
+
+def phase_b(rows, report, reference, tmp):
+    import numpy as np
+
+    import bench
+    from transmogrifai_tpu.evaluators import Evaluators
+    from transmogrifai_tpu.workflow import WorkflowModel
+
+    with phase("B", report) as rec:
+        wf, batch, _ = bench.transmog_workflow(rows)
+        model = wf.train()
+        auroc = model.evaluate(Evaluators.BinaryClassification.auROC(),
+                               batch=batch)["AuROC"]
+        pred_name = next(f.name for f in model.result_features)
+        pred, prob = predictions(model.score(), pred_name)
+        require(pred.shape == (rows,) and np.isfinite(prob).all(), "B",
+                f"score() gave shape {pred.shape}, finite "
+                f"{bool(np.isfinite(prob).all())}")
+
+        bundle = os.path.join(tmp, "transmog-model")
+        model.save(bundle)
+        loaded = WorkflowModel.load(bundle)
+        pred2, prob2 = predictions(
+            loaded.set_input_batch(batch).score(), pred_name)
+        drift = float(np.abs(prob - prob2).max())
+        rec.update(rows=rows, loaded_vs_memory_max_abs=drift,
+                   feature_vector_width=int(np.asarray(
+                       model.selected_model.best_model.fitted["coef"]
+                   ).shape[0]))
+        say(f"B: width {rec['feature_vector_width']}, loaded-vs-memory "
+            f"max |dp| {drift:.2e}")
+        require(np.array_equal(pred, pred2) and drift <= 1e-6, "B",
+                f"loaded model disagrees with the in-memory one: "
+                f"{int((pred != pred2).sum())} labels, max |dp| {drift}")
+        check_failure_log(model, "B", rec)
+        check_auroc(auroc, "B", rec, reference)
+        check_memory("B", rec)
+
+
+def metric_value(text, name):
+    """Value of the un-labelled sample of family ``name`` in /metrics text."""
+    for line in text.splitlines():
+        if line.startswith(f"transmogrifai_serving_{name} "):
+            return float(line.split(" # ")[0].split()[-1])
+    raise SmokeFailure(f"phase C: /metrics has no {name}")
+
+
+def phase_c(model, batch, pred_name, report, tmp):
+    import numpy as np
+
+    from transmogrifai_tpu.serving.server import start_server
+    from transmogrifai_tpu.telemetry import REGISTRY
+
+    with phase("C", report) as rec:
+        bundle = os.path.join(tmp, "dense-model")
+        model.save(bundle)
+        server, _ = start_server(bundle, port=0)
+        try:
+            url = f"http://127.0.0.1:{server.port}"
+
+            def call(path, body=None):
+                req = urllib.request.Request(
+                    url + path,
+                    data=None if body is None else json.dumps(body).encode(),
+                    headers={"Content-Type": "application/json"})
+                with urllib.request.urlopen(req, timeout=120) as r:
+                    return r.status, r.read().decode()
+
+            # reference on a small input: the same rows through the
+            # in-process compiled score path of the model that was saved
+            n = 37
+            names = [f"f{i}" for i in range(28)]
+            cols = {c: np.asarray(batch[c].values[:n]) for c in names}
+            records = [{c: float(cols[c][i]) for c in names}
+                       for i in range(n)]
+            from transmogrifai_tpu.serving.engine import records_to_batch
+            want = predictions(model.score(batch=records_to_batch(
+                model.raw_features, records)), pred_name)[1][:, 1]
+
+            got = []
+            status, body = call("/v1/score", records[0])
+            require(status == 200, "C", f"single POST -> {status}")
+            got.append(json.loads(body)["result"])
+            for lo, hi in ((1, 2), (2, 9), (9, n)):
+                status, body = call("/v1/score", records[lo:hi])
+                require(status == 200, "C", f"list POST -> {status}")
+                got.extend(json.loads(body)["results"])
+            served = np.asarray(
+                [r[pred_name]["probability_1"] for r in got], np.float64)
+            drift = float(np.abs(served - want).max())
+            require(served.shape == (n,) and np.isfinite(served).all()
+                    and drift <= 1e-5, "C",
+                    f"served probabilities: shape {served.shape}, max |dp| "
+                    f"vs model.score() {drift}")
+
+            metrics = call("/metrics")[1]
+            health = json.loads(call("/healthz")[1])
+            stats = server.engine.stats()
+            counters = REGISTRY.counters()
+            rec.update(
+                requests=4, rows=n, served_vs_score_max_abs=drift,
+                health=health["health"],
+                aot_executables=stats["aot_executables"],
+                compiled_path_active=stats["compiled_path_active"],
+                serving={k: metric_value(metrics, k) for k in (
+                    "fallback_batches_total", "online_traces_total",
+                    "breaker_demoted_batches_total")},
+                aot={k: counters.get(k, 0) for k in (
+                    "aot_registry.installs", "aot_registry.call_fallbacks",
+                    "aot_registry.install_failures", "aot.fallback")})
+            say(f"C: {rec['aot_executables']} AOT executables, health "
+                f"{rec['health']}, serving {rec['serving']}, aot "
+                f"{rec['aot']}, max |dp| {drift:.2e}")
+            require(rec["aot_executables"] > 0
+                    and rec["aot"]["aot_registry.installs"] > 0, "C",
+                    "bundle loaded with no AOT executables")
+            require(not any(rec["aot"][k] for k in (
+                "aot_registry.call_fallbacks",
+                "aot_registry.install_failures", "aot.fallback")), "C",
+                f"an AOT executable gave way to JIT: {rec['aot']}")
+            require(not any(rec["serving"].values()), "C",
+                    f"serving left the compiled path: {rec['serving']}")
+            require(rec["health"] == "SERVING"
+                    and rec["compiled_path_active"], "C",
+                    f"health {health}")
+        finally:
+            server.drain_and_close(timeout_s=30.0)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cpu-reference", action="store_true",
+                    help="run the same phases on the CPU backend and print "
+                         "the reference values; prints no result line")
+    ap.add_argument("--rows-a", type=int, default=ROWS_A,
+                    help="phase A rows (only with --cpu-reference)")
+    ap.add_argument("--rows-b", type=int, default=ROWS_B,
+                    help="phase B rows (only with --cpu-reference)")
+    args = ap.parse_args(argv)
+    if not args.cpu_reference and (args.rows_a, args.rows_b) != (ROWS_A,
+                                                                 ROWS_B):
+        ap.error("--rows-a/--rows-b need --cpu-reference: the chip run has "
+                 "one size")
+
+    import jax
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    want = "cpu" if args.cpu_reference else "tpu"
+    if dev.platform != want:
+        sys.stderr.write(
+            f"chip_smoke: needs platform {want!r}, jax found {device}; "
+            "nothing was run\n")
+        return 2
+
+    # the package decides the compile-cache directory at import
+    from importlib import metadata
+
+    import transmogrifai_tpu  # noqa: F401
+    from transmogrifai_tpu.native import fallback_reasons
+    versions = {}
+    for dist in ("jax", "jaxlib", "libtpu"):
+        try:
+            versions[dist] = metadata.version(dist)
+        except metadata.PackageNotFoundError:
+            versions[dist] = None
+    say(f"device {device}; {versions}; compile cache "
+        f"{jax.config.jax_compilation_cache_dir}")
+    python_paths = fallback_reasons()
+    say(f"native modules on the Python path: {python_paths or 'none'}")
+
+    report = {"device": device, "versions": versions,
+              "compile_cache_dir": jax.config.jax_compilation_cache_dir}
+    tmp = tempfile.mkdtemp(prefix="chip-smoke-")
+    t0 = time.time()
+    try:
+        require(not python_paths, "start",
+                f"native modules fell back to Python: {python_paths}")
+        model, batch, pred_name = phase_a(args.rows_a, report,
+                                          args.cpu_reference)
+        phase_b(args.rows_b, report, args.cpu_reference, tmp)
+        phase_c(model, batch, pred_name, report, tmp)
+    except SmokeFailure as e:
+        sys.stderr.write(f"chip_smoke FAILED — {e}\n")
+        return 1
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+    report["wall_s"] = round(time.time() - t0, 1)
+    report["compile_cache_dir_at_exit"] = \
+        jax.config.jax_compilation_cache_dir
+
+    if args.cpu_reference:
+        say("CPU_REFERENCE " + json.dumps({
+            k: {"auroc": report[k]["auroc"],
+                "failure_events": report[k]["failure_events"],
+                **({"winner": report[k]["winner"]} if k == "A" else {})}
+            for k in ("A", "B")}))
+        say("CPU reference run complete: " + json.dumps(report))
+        return 0
+    out_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "chip_smoke_report.json"), "w") as fh:
+        json.dump(report, fh, indent=1)
+    print(json.dumps({"ok": True, "device": device,
+                      "phases": {k: {"wall_s": report[k]["wall_s"],
+                                     "compile": report[k]["compile"]}
+                                 for k in ("A", "B", "C")},
+                      "wall_s": report["wall_s"], "claim": None}),
+          flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -18,9 +18,9 @@ Usage::
     python scripts/bench_compare.py fresh.log --tolerance 0.25 \
         --report bench_compare_report.json
 
-The fresh input may be the bench's raw stdout (the last JSON line is the
-aggregate record), the aggregate record itself, or a standing-format
-document (``{"runs": [...]}`` — newest run is used).
+The fresh input may be the bench's raw stdout (one ``"cell"``-tagged record
+per line), a single record, or a standing-format document
+(``{"runs": [...]}`` — newest run is used).
 """
 
 from __future__ import annotations
@@ -75,6 +75,19 @@ def load_workloads(path: str) -> Dict[str, Dict[str, Any]]:
     try:
         doc = json.loads(text)
     except ValueError:
+        # bench stdout: one full record per cell (tagged "cell"), then a
+        # compact aggregate that carries values only
+        cells = {}
+        for line in text.splitlines():
+            if line.startswith("{"):
+                try:
+                    rec = json.loads(line)
+                except ValueError:
+                    continue
+                if isinstance(rec, dict) and "cell" in rec:
+                    cells[rec["cell"]] = rec
+        if cells:
+            return cells
         doc = last_json_line(text)
     if not isinstance(doc, dict):
         raise SystemExit(f"no JSON record found in {path!r}")

@@ -2,7 +2,7 @@
 (full X copy per group + per-row python assembly, the round-2 implementation).
 
 Usage: python scripts/bench_loco.py [rows] [cols] [groups]
-Prints one JSON line; VERDICT round-2 item 4 asks >=10x at 100k x 512.
+Prints one JSON line naming the platform it ran on.
 """
 
 import json

@@ -1,9 +1,9 @@
 """Closed-loop chaos harness for the device-runtime supervisor (train side).
 
 The serving control plane has ``chaos_slo.py``; this is the same discipline
-for the OUTAGE_r5 failure modes on the training path.  It injects, via the
+for device-runtime outages on the training path.  It injects, via the
 ``supervisor.*`` injection points and the probe chaos preludes, the faults
-that outage actually produced — a native init hang, a SIGTERM-ignoring hung
+such an outage produces — a native init hang, a SIGTERM-ignoring hung
 process, a dead probe child, a stalled host→device chunk, and a mid-sweep
 device loss — and asserts the supervision contract:
 

@@ -12,8 +12,9 @@ real local processes, that multi-process training is loss-proof —
   checkpoints — is detected, the survivors abort via the posted group
   abort / preemption guard, the launcher relaunches at world size 1, the
   resumed sweep replays the checkpoint, and the winner is IDENTICAL;
-* the loss writes the standardized outage record (the OUTAGE_r5.json
-  schema) and ZERO worker processes survive the harness.
+* the loss writes the standardized outage record
+  (``supervisor.OUTAGE_RECORD_KEYS``) and ZERO worker processes survive the
+  harness.
 
 Usage:
     python scripts/ci_hostgroup_smoke.py run OUT_DIR       # launch groups
@@ -187,11 +188,8 @@ def validate(out_dir):
 
 
 def _outage_schema_ok(rec):
-    if not isinstance(rec, dict):
-        return False
-    with open(os.path.join(_REPO, "OUTAGE_r5.json")) as fh:
-        ref = json.load(fh)
-    return set(rec) == set(ref)
+    from transmogrifai_tpu.parallel.supervisor import OUTAGE_RECORD_KEYS
+    return isinstance(rec, dict) and set(rec) == set(OUTAGE_RECORD_KEYS)
 
 
 if __name__ == "__main__":

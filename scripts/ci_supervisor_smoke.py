@@ -1,16 +1,16 @@
 """CI smoke for the device-runtime supervisor (ISSUE 11): prove, in one
-process, that the OUTAGE_r5 failure mode is hang-proof now —
+process, that a native init hang cannot stall a job —
 
 * an injected init hang (probe child that never returns) resolves to a
   TYPED ``outage`` verdict within the timeout+grace watchdog deadline,
   instead of stalling the job until the CI-level timeout shoots it;
-* a SIGTERM-ignoring hung child — the exact process shape plain SIGTERM
-  could not kill during the round-5 outage — is reclaimed by the SIGKILL
+* a SIGTERM-ignoring hung child — the process shape plain SIGTERM cannot
+  kill — is reclaimed by the SIGKILL
   escalation, and ZERO hung processes survive the run;
 * a healthy probe still reads ``available`` with a device inventory (the
   verdict machinery distinguishes, it doesn't just always say outage);
-* the standardized outage record (the OUTAGE_r5.json schema, written by
-  code) lands as a CI artifact next to this smoke record.
+* the standardized outage record (``supervisor.OUTAGE_RECORD_KEYS``)
+  lands as a CI artifact next to this smoke record.
 
 Usage:
     python scripts/ci_supervisor_smoke.py run OUT_DIR       # probe + record
@@ -115,7 +115,7 @@ def validate(out_dir):
     assert hl["deviceCount"] >= 1 and hl["devices"], hl
     assert hl["latencyS"] > 0, hl
 
-    # the outage-record artifact exists and is schema-exact OUTAGE_r5 shape
+    # the outage-record artifact exists and carries exactly the stable keys
     assert record["outage_record"], record
     with open(os.path.join(out_dir, record["outage_record"])) as fh:
         rec = json.load(fh)

@@ -1,5 +1,6 @@
 """Run the dense bench at HIGGS scale points (4M / 8M / 11M — the
-BASELINE.json north star) and record a committed artifact.
+BASELINE.json north star) and write an artifact (git-ignored
+``chiprun_out/`` by default).
 
 Each size runs twice in fresh processes: the first pays any XLA compiles for
 the new shapes ("cold"), the second measures the steady state ("warm").
@@ -10,10 +11,10 @@ DEFAULT PATH (ISSUE 10): the combined full grid runs IN ONE PROCESS with
 mesh sharding forced on (TRANSMOGRIFAI_TPU_MESH=1) and chunked host→device
 streaming, so the dataset is bounded by aggregate HBM across the mesh and
 transfer staging is O(TRANSMOGRIFAI_DEVICE_CHUNK_BYTES) — the regime that
-used to hard-fault a single worker (BENCH_11M_ATTEMPTS_r4.json).
+used to kill a single worker.
 
 FALLBACK (--subprocess-ladder): the retired PER-FAMILY subprocess isolation
-(VERDICT r4 next #3) — each candidate family's CV grid in a fresh process
+— each candidate family's CV grid in a fresh process
 over identical data with an automated budget/cache retry ladder, scalar CV
 metrics merged into one full-grid record.  Kept for single-device hardware
 or post-mortems, no longer the default.
@@ -49,17 +50,15 @@ _LADDER = [
 
 
 def _run_bench(n, extra_env, timeout_s=3600):
-    env = {**os.environ, "BENCH_WORKLOAD": "dense", "BENCH_ROWS": str(n),
-           # cold/warm semantics rely on exactly ONE process per run: a
-           # silent in-bench subprocess retry would report a crashed "warm"
-           # run as rc=0 measured cold
-           "BENCH_NO_RETRY": "1", **extra_env}
+    # cold/warm semantics rely on exactly ONE process per run, which is what
+    # `bench.py --cell` is: the dense cell in a process that owns the chip
+    env = {**os.environ, "BENCH_ROWS": str(n), **extra_env}
     # supervised child: SIGTERM→SIGKILL escalation reclaims a bench whose
-    # native init hung (plain subprocess timeout leaves the hang alive —
-    # the OUTAGE_r5 / BENCH_11M_ATTEMPTS_r4 failure mode); rc=124 keeps
-    # the ladder's historical timeout convention
+    # native init hung (plain subprocess timeout leaves the hang alive);
+    # rc=124 keeps the ladder's historical timeout convention
     from transmogrifai_tpu.parallel.supervisor import run_supervised
-    r = run_supervised([sys.executable, os.path.join(ROOT, "bench.py")],
+    r = run_supervised([sys.executable, os.path.join(ROOT, "bench.py"),
+                        "--cell", "dense"],
                        timeout_s=timeout_s, grace_s=30.0, env=env, cwd=ROOT)
     rec = {"rc": r.rc, "proc_wall_s": round(r.wall_s, 1)}
     if r.escalated:
@@ -135,7 +134,9 @@ def main():
     use_ladder = "--subprocess-ladder" in argv
     if use_ladder:
         argv.remove("--subprocess-ladder")
-    out_path = argv[0] if argv else os.path.join(ROOT, "BENCH_11M.json")
+    out_path = argv[0] if argv else os.path.join(ROOT, "chiprun_out",
+                                                 "scale_bench.json")
+    os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
     sizes = ([int(float(a)) for a in argv[1:]]
              or [4_000_000, 8_000_000, 11_000_000])
     out = {"workload": "dense HIGGS-difficulty (bench.py run_dense)",
@@ -152,8 +153,8 @@ def main():
             extra = {}
             if use_ladder:
                 if n >= 8_000_000:
-                    # cumulative HBM residency is what hard-faults the
-                    # worker at 10M+ (VERDICT r3 #2): shrink the
+                    # cumulative HBM residency is what kills the worker at
+                    # 10M+: shrink the
                     # host→device transfer cache so stale raw-column copies
                     # evict, and lower the tree histogram budget below the
                     # near-capacity trigger

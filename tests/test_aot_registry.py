@@ -38,7 +38,7 @@ def registry(tmp_path):
                  (aot_registry.REGISTRY_ENV, "TRANSMOGRIFAI_COMPILE_CACHE")}
     aot_registry.reset_for_tests()
     root = str(tmp_path / "registry")
-    aot_registry.configure(root=root, manage_compile_cache=False)
+    aot_registry.configure(root=root)
     yield root
     aot_registry.reset_for_tests()
     for k, v in saved_env.items():
@@ -170,7 +170,7 @@ class TestRaces:
             "import sys\n"
             "from transmogrifai_tpu import aot_registry as R\n"
             "root, key = sys.argv[1], sys.argv[2]\n"
-            "R.configure(root=root, manage_compile_cache=False)\n"
+            "R.configure(root=root)\n"
             "payload = bytes(range(256)) * 256\n"
             "ok = R.publish(key, payload, {'kind': 'grid'})\n"
             "assert R.lookup(key) == payload\n"
@@ -318,7 +318,7 @@ class TestGridSeam:
     def test_shared_load_memoizes(self, registry):
         f = jax.jit(lambda x: x * 4.0)
         x = np.arange(3, dtype=np.float32)
-        rec = pickle.loads(aot_registry.serialize_fresh(lambda: f.lower(x)))
+        rec = pickle.loads(aot_registry.serialize_fresh(f, (x,)))
         n0 = aot_registry.loaded_count()
         a = aot_registry.shared_load("digest-tenant", rec)
         shared = _counter("shared_hits")
@@ -339,8 +339,6 @@ class TestCacheWarmPublish:
         serializes with its fusion symbols missing — publish must detect
         the cache hit and re-compile once with the cache disabled rather
         than silently skipping (or worse, publishing garbage)."""
-        from jax.experimental.serialize_executable import \
-            deserialize_and_load
         cache_dir = tmp_path / "xla-cache"
         saved = (jax.config.jax_compilation_cache_dir,
                  jax.config.jax_enable_compilation_cache,
@@ -367,13 +365,13 @@ class TestCacheWarmPublish:
             # LOAD, whose serialization is garbage (the PR-9 hazard)
             jax.clear_caches()
             recomp0 = _counter("recompiles_for_publish")
-            rec = aot_registry.serialize_fresh(lambda: f.lower(x))
+            rec = aot_registry.serialize_fresh(f, (x,))
             assert _counter("recompiles_for_publish") == recomp0 + 1
             assert rec is not None  # NOT silently skipped
             assert aot_registry.payload_roundtrips(rec)
-            obj = pickle.loads(rec)
-            fn = deserialize_and_load(obj["payload"], obj["inTree"],
-                                      obj["outTree"])
+            # a cache-LOADED executable deserializes fine on jax 0.9 and
+            # fails only here, at its first call
+            fn = aot_registry.load_executable(pickle.loads(rec))
             np.testing.assert_array_equal(np.asarray(fn(x)), expect)
         finally:
             jax.config.update("jax_compilation_cache_dir", saved[0])

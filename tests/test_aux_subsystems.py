@@ -375,7 +375,7 @@ def test_rff_drops_shifted_map_keys_individually():
 class TestInsightsDepth:
     """Reference-depth ModelInsights (≙ ModelInsights.scala:74-392): RFF
     distributions, per-group Cramér's V, descaled contributions, training
-    echo — the round-3 VERDICT golden check."""
+    echo — the golden check."""
 
     @pytest.fixture(scope="class")
     def deep_model(self):

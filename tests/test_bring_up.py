@@ -1,0 +1,212 @@
+"""What can be pinned about chip bring-up without a chip (ISSUE 21):
+
+* AOT executables load over the devices they were compiled for, so a host
+  that shows several devices runs them instead of quietly giving way to JIT;
+* the persistent compile cache stays where the environment put it — through
+  package import, a runner train with a checkpoint location, a registry
+  ``configure`` and the environments built for pool / host-group children —
+  and is one fixed in-checkout directory when the environment says nothing;
+* ``chip_smoke.py`` refuses to run off the accelerator;
+* the serving pool gives worker *k* chip *k* and refuses more workers than
+  chips."""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from test_aux_subsystems import make_records, train_small_model  # noqa: E402
+
+from transmogrifai_tpu.parallel import supervisor as sup  # noqa: E402
+from transmogrifai_tpu.serving.engine import records_to_batch  # noqa: E402
+from transmogrifai_tpu.serving.pool import ServingPool  # noqa: E402
+from transmogrifai_tpu.telemetry import REGISTRY  # noqa: E402
+from transmogrifai_tpu.workflow import WorkflowModel  # noqa: E402
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _counter(name):
+    return REGISTRY.snapshot()["counters"].get(name, 0)
+
+
+def test_aot_load_runs_on_a_multi_device_host(tmp_path):
+    """save -> load -> score with more than one device visible: every
+    shipped executable installs and is CALLED — zero fallbacks to JIT."""
+    assert len(jax.devices()) > 1, "conftest forces several host devices"
+    model = train_small_model(make_records(120))[0].train()
+    bundle = str(tmp_path / "model")
+    model.save(bundle)
+    before = {k: _counter(k) for k in (
+        "aot_registry.installs", "aot_registry.call_fallbacks",
+        "aot_registry.install_failures", "aot.fallback")}
+    loaded = WorkflowModel.load(bundle)
+    assert loaded.aot_executables > 0
+    assert _counter("aot_registry.installs") > before["aot_registry.installs"]
+    records = [{"x1": 0.4, "x2": 3.0, "cat": "a"}] * 4   # a ladder size
+    pred = next(f.name for f in loaded.result_features)
+
+    def score(m):
+        scored = m.score(batch=records_to_batch(m.raw_features, records))
+        return np.asarray(scored[pred].values["probability"])
+
+    np.testing.assert_array_equal(score(loaded), score(model))
+    for k in ("aot_registry.call_fallbacks", "aot_registry.install_failures",
+              "aot.fallback"):
+        assert _counter(k) == before[k], k
+    events = [e for e in loaded.failure_log.events
+              if e.action in ("degraded", "fallback")] \
+        if getattr(loaded, "failure_log", None) else []
+    assert events == []
+
+
+_CACHE_CHILD = r"""
+import json, os, sys
+sys.path.insert(0, os.path.join(sys.argv[1], "tests"))
+import jax
+import transmogrifai_tpu
+seen = {"import": jax.config.jax_compilation_cache_dir}
+
+from test_aux_subsystems import make_records, train_small_model
+from transmogrifai_tpu.params import OpParams
+from transmogrifai_tpu.runner import OpWorkflowRunner, RunType
+out = sys.argv[2]
+if sys.argv[3] == "train":
+    wf, _ = train_small_model(make_records(120))
+    OpWorkflowRunner(wf).run(RunType.TRAIN, OpParams(
+        model_location=os.path.join(out, "model"),
+        checkpoint_location=os.path.join(out, "ckpt")))
+seen["runner_train"] = jax.config.jax_compilation_cache_dir
+
+from transmogrifai_tpu import aot_registry
+aot_registry.configure(root=os.path.join(out, "registry"))
+seen["registry_configure"] = jax.config.jax_compilation_cache_dir
+
+from transmogrifai_tpu.serving.pool import ServingPool
+pool = ServingPool(os.path.join(out, "model"), workers=1,
+                   run_dir=os.path.join(out, "pool"))
+pool._device_env = pool._resolve_device_env()
+env = pool._worker_env(pool.slots[0])
+seen["pool_child_env"] = {k: env.get(k) for k in (
+    "JAX_COMPILATION_CACHE_DIR", "TRANSMOGRIFAI_COMPILE_CACHE")}
+seen["default"] = transmogrifai_tpu.DEFAULT_COMPILE_CACHE_DIR
+seen["entries_under_ckpt"] = [
+    os.path.join(d, f) for d, _, fs in os.walk(out) for f in fs
+    if "compile-cache" in d]
+print(json.dumps(seen))
+"""
+
+
+def _run_cache_child(tmp_path, cache_env, train):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR",
+                        "TRANSMOGRIFAI_COMPILE_CACHE",
+                        "TRANSMOGRIFAI_COMPILATION_CACHE",
+                        "TRANSMOGRIFAI_AOT_REGISTRY")}
+    env.update(cache_env)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    p = subprocess.run([sys.executable, "-c", _CACHE_CHILD, REPO,
+                        str(tmp_path), "train" if train else "no-train"],
+                       env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_stays_where_the_environment_put_it(tmp_path):
+    placed = str(tmp_path / "placed-cache")
+    seen = _run_cache_child(tmp_path / "run",
+                            {"JAX_COMPILATION_CACHE_DIR": placed}, train=True)
+    for step in ("import", "runner_train", "registry_configure"):
+        assert seen[step] == placed, (step, seen[step])
+    assert seen["pool_child_env"]["JAX_COMPILATION_CACHE_DIR"] == placed
+    assert seen["pool_child_env"]["TRANSMOGRIFAI_COMPILE_CACHE"] is None
+    assert seen["entries_under_ckpt"] == []
+    assert os.listdir(placed), "the train's programs were cached there"
+
+
+def test_compile_cache_defaults_to_one_fixed_directory_in_the_checkout(
+        tmp_path):
+    # (the runner train is exercised in the placed-cache test above; here
+    # only what import, configure and the child env decide)
+    seen = _run_cache_child(tmp_path / "run", {}, train=False)
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert seen["default"] == fixed
+    for step in ("import", "runner_train", "registry_configure"):
+        assert seen[step] == fixed, (step, seen[step])
+    assert seen["pool_child_env"] == {"JAX_COMPILATION_CACHE_DIR": None,
+                                      "TRANSMOGRIFAI_COMPILE_CACHE": None}
+    assert seen["entries_under_ckpt"] == []
+    # git ignores it
+    with open(os.path.join(REPO, ".gitignore")) as fh:
+        assert ".jax_cache/" in fh.read().split()
+
+
+def test_chip_smoke_refuses_to_run_off_the_accelerator(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       env=env, cwd=str(tmp_path), capture_output=True,
+                       text=True, timeout=120)
+    assert p.returncode != 0
+    assert "nothing was run" in p.stderr
+    # no result line, no phase started
+    assert not [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
+    assert "phase" not in p.stdout
+
+
+def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
+    """The driver also runs the script with nothing else of the repo next
+    to it: it must fail there, whatever the platform."""
+    import shutil
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), str(tmp_path))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    p = subprocess.run([sys.executable, "chip_smoke.py", "--cpu-reference",
+                        "--rows-a", "1000", "--rows-b", "1000"],
+                       env=env, cwd=str(tmp_path), capture_output=True,
+                       text=True, timeout=120)
+    assert p.returncode != 0
+    assert not [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
+
+
+class TestPoolDevices:
+    def _pool(self, tmp_path, workers):
+        return ServingPool(str(tmp_path / "model"), workers=workers,
+                           run_dir=str(tmp_path / "pool"))
+
+    def _probe(self, monkeypatch, **verdict):
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(
+            sup, "probe_devices",
+            lambda **kw: sup.ProbeVerdict(**verdict))
+
+    def test_worker_k_gets_chip_k_with_the_platform_pinned(
+            self, tmp_path, monkeypatch):
+        self._probe(monkeypatch, status=sup.AVAILABLE, platform="tpu",
+                    device_kind="TPU v5 lite", device_count=4)
+        pool = self._pool(tmp_path, 3)
+        pool._device_env = pool._resolve_device_env()
+        envs = [pool._worker_env(s) for s in pool.slots]
+        assert [e["TPU_VISIBLE_DEVICES"] for e in envs] == ["0", "1", "2"]
+        assert {e["JAX_PLATFORMS"] for e in envs} == {"tpu"}
+
+    def test_more_workers_than_chips_is_refused(self, tmp_path, monkeypatch):
+        self._probe(monkeypatch, status=sup.AVAILABLE, platform="tpu",
+                    device_kind="TPU v5 lite", device_count=1)
+        with pytest.raises(ValueError, match="one worker per chip"):
+            self._pool(tmp_path, 2).start()
+
+    def test_outage_probe_fails_the_pool(self, tmp_path, monkeypatch):
+        self._probe(monkeypatch, status=sup.OUTAGE, cause="hang")
+        with pytest.raises(RuntimeError, match="outage"):
+            self._pool(tmp_path, 1).start()
+
+    def test_cpu_pin_in_the_environment_needs_no_probe(self, tmp_path,
+                                                       monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        monkeypatch.setattr(sup, "probe_devices", lambda **kw: 1 / 0)
+        pool = self._pool(tmp_path, 2)
+        assert pool._resolve_device_env() == [{}, {}]

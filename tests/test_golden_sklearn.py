@@ -161,7 +161,7 @@ def test_chi2_sf_matches_scipy_across_regimes():
 
 
 def test_tree_feature_importances_match_sklearn_direction():
-    """Gain-based importances (VERDICT r3 #5): on planted-signal data the
+    """Gain-based importances: on planted-signal data the
     top features by accumulated impurity gain must match sklearn's
     gain-based feature_importances_ — and the planted noise features must
     rank at the bottom in both."""
@@ -205,7 +205,7 @@ def test_tree_feature_importances_match_sklearn_direction():
 
 
 def test_family_cv_quality_within_tolerance_of_sklearn():
-    """Per-family CV quality pin (VERDICT r3 #7): the batched (fold x grid)
+    """Per-family CV quality pin: the batched (fold x grid)
     RF/GBT fitters must land within tolerance of sklearn's CV AuPR on the
     same folds — a silently-degraded tree fitter fails here even when LR
     wins the selection."""

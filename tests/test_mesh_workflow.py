@@ -1,4 +1,4 @@
-"""Full-workflow mesh parity IN CI (VERDICT r4 next #4): the production path
+"""Full-workflow mesh parity IN CI: the production path
 — RawFeatureFilter + transmogrify over mixed raw types + SanityChecker + CV
 selector + compiled score() — trained with the mesh ON and OFF must agree on
 dropped features, winning model, and probabilities.  This covers the

@@ -74,7 +74,7 @@ class TestRunSupervised:
 
     @pytest.mark.slow
     def test_sigterm_ignoring_child_reclaimed_by_sigkill(self):
-        """The OUTAGE_r5 failure mode: plain SIGTERM does not kill the hung
+        """The native-hang failure mode: plain SIGTERM does not kill the hung
         process — only the SIGKILL escalation reclaims it, within the
         timeout+grace watchdog budget."""
         code = sup.CHAOS_PRELUDES["hang_ignore_sigterm"]
@@ -160,7 +160,7 @@ class TestProbe:
 # --------------------------------------------------------------------------
 
 class TestOutageRecord:
-    def test_schema_matches_outage_r5(self, tmp_path):
+    def test_schema_is_the_stable_key_set(self, tmp_path):
         attempts = [{"wall_s": 150.0, "result": "hang", "from": "13:04",
                      "to": "13:06", "every_s": 45}]
         path = str(tmp_path / "OUTAGE_test.json")
@@ -168,11 +168,9 @@ class TestOutageRecord:
                                 timeline=sup.outage_timeline(attempts),
                                 mitigations=["m1"], will_update="u")
         rec = json.loads(open(path).read())
-        ref = json.loads(open(os.path.join(REPO, "OUTAGE_r5.json")).read())
-        assert set(rec) == set(ref)          # key-for-key the r5 shape
         assert set(rec) == set(sup.OUTAGE_RECORD_KEYS)
         tl = rec["timeline_utc"][0]
-        assert set(tl) == set(ref["timeline_utc"][0])
+        assert set(tl) == {"from", "to", "every_s", "result"}
         assert tl["result"] == "hang"
 
     def test_maybe_write_uses_env_dir(self, tmp_path, monkeypatch):

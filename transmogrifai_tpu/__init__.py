@@ -14,40 +14,39 @@ import os as _os
 # every run after the first pay execution cost only (the TPU analog of the
 # JVM/Spark warm-start the reference relies on).
 #
-# TRANSMOGRIFAI_COMPILE_CACHE=<dir> pins the cache root explicitly (scoped
-# per backend platform underneath) and caches EVERY program, so a warm
-# process reports ~0 new compiles; =0 disables the cache outright.  Unset,
-# the legacy default applies: /tmp/transmogrifai_tpu_jax_cache_<plat> with a
-# 0.1s floor, opt out with TRANSMOGRIFAI_COMPILATION_CACHE=0.
+# The directory is decided HERE, once, and no other code path re-points it
+# (the cache path is part of jax's cache key, so a directory that moves
+# never hits):
+#   1. JAX_COMPILATION_CACHE_DIR set — jax reads it itself; the operator
+#      placed the cache and children inherit the variable.
+#   2. TRANSMOGRIFAI_COMPILE_CACHE=<dir> — <dir>/<JAX_PLATFORMS or default>,
+#      every program cached (0 s floor); also opts in to fit-row padding and
+#      background pre-tracing (tuning._fit_padding_enabled, aot.pretrace_-
+#      enabled), with or without (1).
+#   3. neither — DEFAULT_COMPILE_CACHE_DIR, one fixed git-ignored directory
+#      in the checkout, 0.1 s floor.
+# TRANSMOGRIFAI_COMPILE_CACHE=0 / TRANSMOGRIFAI_COMPILATION_CACHE=0 leave
+# jax's cache configuration untouched.
+DEFAULT_COMPILE_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".jax_cache")
+
 _cc = _os.environ.get("TRANSMOGRIFAI_COMPILE_CACHE")
 if _cc != "0" and (_cc or _os.environ.get(
         "TRANSMOGRIFAI_COMPILATION_CACHE", "1") != "0"):
-    try:
-        import jax as _jax
+    import jax as _jax
 
-        # Scope the cache per backend platform: CPU AOT entries carry host
-        # machine-feature assumptions, and a cache populated by an
-        # accelerator-process's host compiler must not be loaded by a pure
-        # CPU process (xla cpu_aot_loader rejects them with SIGILL warnings).
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         _plat = ((_os.environ.get("JAX_PLATFORMS") or "default")
                  .split(",")[0].strip() or "default")
-        if _cc:
-            _jax.config.update("jax_compilation_cache_dir",
-                               _os.path.join(_cc, _plat))
-            _jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-        else:
-            _jax.config.update(
-                "jax_compilation_cache_dir",
-                _os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                                f"/tmp/transmogrifai_tpu_jax_cache_{_plat}"))
-            # cache even small programs: a warm train run launches ~90
-            # distinct executables and re-compiling the sub-second ones
-            # still costs multiple seconds of wall per run
-            _jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.1)
-    except Exception:  # pragma: no cover — cache is best-effort
-        pass
+        _jax.config.update(
+            "jax_compilation_cache_dir",
+            _os.path.join(_cc, _plat) if _cc else DEFAULT_COMPILE_CACHE_DIR)
+    # cache even small programs: a warm train run launches ~90 distinct
+    # executables and re-compiling the sub-second ones still costs multiple
+    # seconds of wall per run
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                       0.0 if _cc else 0.1)
 
 # compile-vs-execute counters (profiling.compile_stats) ride jax.monitoring's
 # process-global listeners; registering costs nothing until a compile fires

@@ -143,56 +143,61 @@ def _export_bundle_inner(model, bundle_dir: str) -> int:
     max_batch = int(os.environ.get("TRANSMOGRIFAI_AOT_LADDER_MAX",
                                    _DEFAULT_LADDER_MAX))
     sizes = ladder_sizes(max_batch)
-    with span("workflow.aot_export", sizes=sizes):
+    from . import aot_registry
+    from .compiled import suppress_trace_count
+    # every trace in here — the ladder warm-up AND a rebuild's re-trace —
+    # stays off the global trace_count() books: a save() running
+    # concurrently with a serving engine (lifecycle retrain+promote) must not
+    # land export traces inside the engine's online-trace window.  And the
+    # persistent compile cache is suspended throughout: what the warm-up
+    # compiles is what gets serialized, and only an executable BUILT here
+    # serializes into something that runs (aot_registry.fresh_record)
+    with span("workflow.aot_export", sizes=sizes), suppress_trace_count(), \
+            aot_registry.persistent_cache_suspended():
         # warm: score a synthetic record at every ladder size so the program
         # table holds exactly the serve-shaped entries (same monoid-zero
-        # record ScoringEngine warms with).  These traces stay off the
-        # global trace_count() books: a save() running concurrently with a
-        # serving engine (lifecycle retrain+promote) must not land export
-        # warmup traces inside the engine's online-trace window
-        from .compiled import suppress_trace_count
+        # record ScoringEngine warms with)
         before = set(program._jitted)
-        with suppress_trace_count():
+        for size in sizes:
+            try:
+                batch = records_to_batch(model.raw_features, [{}] * size)
+                model.score(batch=batch)
+            except Exception as e:  # noqa: BLE001 — skip unwarmable sizes
+                record_failure("workflow.save", "swallowed", e,
+                               point="checkpoint.aot",
+                               detail=f"AOT warm at batch size {size}")
+        # nnz-ladder warm (ISSUE 19): a sparse (hashed-text) frontier
+        # column's flat-component shape is its nnz CAPACITY — the
+        # monoid-zero records above only exercise the floor rung
+        # (nnz=0 → cap 1024, which already serves every real batch with
+        # ≤1024 entries).  Synthetic token records push the program
+        # across higher nnz rungs so those serve with zero compiles
+        # too.  Densities are tokens/record
+        # (TRANSMOGRIFAI_AOT_NNZ_LADDER, comma-separated, "" disables);
+        # models without text features skip — same records, same avals,
+        # no new table entries.
+        from .types import is_text_kind
+        text_feats = [f for f in model.raw_features
+                      if f.kind is not None and is_text_kind(f.kind)]
+        densities = []
+        for tok in os.environ.get("TRANSMOGRIFAI_AOT_NNZ_LADDER",
+                                  "32").split(","):
+            with contextlib.suppress(ValueError):
+                if int(tok) > 0:
+                    densities.append(int(tok))
+        for k_tok in densities if text_feats else []:
+            text = " ".join(f"tok{j}" for j in range(k_tok))
             for size in sizes:
                 try:
-                    batch = records_to_batch(model.raw_features, [{}] * size)
+                    recs = [{f.name: text for f in text_feats}
+                            for _ in range(size)]
+                    batch = records_to_batch(model.raw_features, recs)
                     model.score(batch=batch)
-                except Exception as e:  # noqa: BLE001 — skip unwarmable sizes
+                except Exception as e:  # noqa: BLE001
                     record_failure("workflow.save", "swallowed", e,
                                    point="checkpoint.aot",
-                                   detail=f"AOT warm at batch size {size}")
-            # nnz-ladder warm (ISSUE 19): a sparse (hashed-text) frontier
-            # column's flat-component shape is its nnz CAPACITY — the
-            # monoid-zero records above only exercise the floor rung
-            # (nnz=0 → cap 1024, which already serves every real batch with
-            # ≤1024 entries).  Synthetic token records push the program
-            # across higher nnz rungs so those serve with zero compiles
-            # too.  Densities are tokens/record
-            # (TRANSMOGRIFAI_AOT_NNZ_LADDER, comma-separated, "" disables);
-            # models without text features skip — same records, same avals,
-            # no new table entries.
-            from .types import is_text_kind
-            text_feats = [f for f in model.raw_features
-                          if f.kind is not None and is_text_kind(f.kind)]
-            densities = []
-            for tok in os.environ.get("TRANSMOGRIFAI_AOT_NNZ_LADDER",
-                                      "32").split(","):
-                with contextlib.suppress(ValueError):
-                    if int(tok) > 0:
-                        densities.append(int(tok))
-            for k_tok in densities if text_feats else []:
-                text = " ".join(f"tok{j}" for j in range(k_tok))
-                for size in sizes:
-                    try:
-                        recs = [{f.name: text for f in text_feats}
-                                for _ in range(size)]
-                        batch = records_to_batch(model.raw_features, recs)
-                        model.score(batch=batch)
-                    except Exception as e:  # noqa: BLE001
-                        record_failure("workflow.save", "swallowed", e,
-                                       point="checkpoint.aot",
-                                       detail=f"AOT nnz warm at batch size "
-                                              f"{size} x {k_tok} tokens")
+                                   detail=f"AOT nnz warm at batch size "
+                                          f"{size} x {k_tok} tokens")
         keys = [k for k in program._jitted
                 if k in program._input_specs
                 and (k in before or k[2] in sizes)]
@@ -204,71 +209,51 @@ def _export_bundle_inner(model, bundle_dir: str) -> int:
         os.makedirs(out_dir, exist_ok=True)
         index: List[Dict[str, Any]] = []
         written = 0
-        # the export compiles must BYPASS the persistent compilation cache:
-        # an executable jax re-loaded from the disk cache serializes with
-        # its jitted fusion symbols missing ("Symbols not found" at
-        # deserialize) — only a fresh backend compile round-trips
         pretrace_drain()
         # registry publish rides the same export loop: every executable the
         # bundle ships also lands in the fleet registry under its
         # family x rung key, so pool workers / tenants / CI on OTHER
         # bundles of the same content install instead of compiling
-        from . import aot_registry
         family = (aot_registry.model_family_digest(bundle_dir)
                   if aot_registry.registry_enabled() else None)
-        prev_cache = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        try:
-            for i, key in enumerate(sorted(keys,
-                                           key=lambda k: (k[2], k[0]))):
-                # aval variants (ISSUE 19): a key that saw more than one
-                # input signature (sparse nnz rungs) exports one record per
-                # signature; single-variant keys export the legacy record —
-                # byte-compatible with pre-variant bundles
-                variants = program._input_spec_variants.get(key) or {}
-                if len(variants) > 1:
-                    jobs = sorted(variants.items())
-                else:
-                    jobs = [(None, None)]
-                for j, (sig, specs) in enumerate(jobs):
-                    try:
-                        rec = _serialize_key(program, key, specs=specs,
-                                             sig=sig)
-                        if not aot_registry.payload_roundtrips(rec):
-                            # the executable came out of the persistent
-                            # compile cache (its payload deserializes to
-                            # "Symbols not found") — re-lower + re-compile
-                            # once with every cache layer suspended so the
-                            # bundle ships an installable build instead of
-                            # silently skipping
-                            _count("aot_registry.recompiles_for_publish")
-                            with aot_registry.fresh_compile_env():
-                                rec = _serialize_key(program, key,
-                                                     specs=specs, sig=sig)
-                            if not aot_registry.payload_roundtrips(rec):
-                                raise RuntimeError(
-                                    "payload does not deserialize even "
-                                    "after a cache-suspended rebuild")
-                    except Exception as e:  # noqa: BLE001 — best effort
-                        record_failure("workflow.save", "swallowed", e,
-                                       point="checkpoint.aot",
-                                       detail=f"AOT serialize "
-                                              f"rows={key[2]}")
-                        continue
-                    fname = (f"seg-{i:03d}.aotx" if sig is None
-                             else f"seg-{i:03d}-v{j:02d}.aotx")
-                    with open(os.path.join(out_dir, fname), "wb") as f:
-                        f.write(rec)
-                    ent = {"file": fname, **_key_json(key)}
-                    if sig is not None:
-                        ent["argSig"] = sig
-                    index.append(ent)
-                    written += 1
-                    if family:
-                        aot_registry.publish_score(family, key, program,
-                                                   rec, specs=specs)
-        finally:
-            jax.config.update("jax_enable_compilation_cache", prev_cache)
+        for i, key in enumerate(sorted(keys,
+                                       key=lambda k: (k[2], k[0]))):
+            # aval variants (ISSUE 19): a key that saw more than one
+            # input signature (sparse nnz rungs) exports one record per
+            # signature; single-variant keys export the legacy record —
+            # byte-compatible with pre-variant bundles
+            variants = program._input_spec_variants.get(key) or {}
+            if len(variants) > 1:
+                jobs = sorted(variants.items())
+            else:
+                jobs = [(None, None)]
+            for j, (sig, specs) in enumerate(jobs):
+                try:
+                    # a key first dispatched BEFORE this export may hold
+                    # a cache-loaded executable — fresh_record rebuilds it
+                    rec = aot_registry.fresh_record(
+                        program._jitted[key][0],
+                        lambda: _serialize_key(program, key, specs=specs,
+                                               sig=sig),
+                        maybe_loaded=key in program._cache_loaded)
+                except Exception as e:  # noqa: BLE001 — best effort
+                    record_failure("workflow.save", "swallowed", e,
+                                   point="checkpoint.aot",
+                                   detail=f"AOT serialize "
+                                          f"rows={key[2]}")
+                    continue
+                fname = (f"seg-{i:03d}.aotx" if sig is None
+                         else f"seg-{i:03d}-v{j:02d}.aotx")
+                with open(os.path.join(out_dir, fname), "wb") as f:
+                    f.write(rec)
+                ent = {"file": fname, **_key_json(key)}
+                if sig is not None:
+                    ent["argSig"] = sig
+                index.append(ent)
+                written += 1
+                if family:
+                    aot_registry.publish_score(family, key, program,
+                                               rec, specs=specs)
         if family:
             program.registry_family = family
         if not written:
@@ -294,6 +279,8 @@ def _serialize_key(program, key: Tuple, specs: Any = None,
     (tagged ``argSig``); single-variant keys stay byte-compatible with
     pre-variant bundles."""
     from jax.experimental.serialize_executable import serialize
+
+    from . import aot_registry
     jitted, canon_out = program._jitted[key]
     if specs is None:
         specs = program._input_specs[key]
@@ -306,6 +293,7 @@ def _serialize_key(program, key: Tuple, specs: Any = None,
         "payload": payload,
         "inTree": in_tree,
         "outTree": out_tree,
+        "deviceIds": aot_registry.executable_device_ids(compiled),
     }
     if sig is not None:
         rec["argSig"] = sig

@@ -1,9 +1,10 @@
 """Fleet-wide content-addressed compiled-program registry: cold ≈ warm.
 
 Serving cold-start is solved (aot.py ships executables inside each bundle),
-but every OTHER first run still pays the compile wall in full: a cold 4M-row
-train is 463s vs 86s warm (BENCH_11M), a fresh process runs ~107 XLA
-compiles (BENCH_STANDING), and every pool worker, tenant activation,
+but every OTHER first run still pays the compile wall in full: a fresh
+process runs ~100 XLA compiles for the six-candidate dense sweep (count from
+a CPU run; compile TIME on the chip is in PERF.md), and every pool worker,
+tenant activation,
 hostgroup rank, and lifecycle retrain re-derives the same executables.  The
 programs themselves are already canonicalized — fit-shape ladder rungs,
 positional pytree names at the jit boundary — so their identities are
@@ -43,14 +44,13 @@ Three seams feed and drain it:
   payload digest (``shared_load``), so two tenants serving the same
   family x rung share ONE loaded executable and its device memory.
 
-The registry also *manages* the persistent XLA compile cache: when no
-explicit ``TRANSMOGRIFAI_COMPILE_CACHE`` is pinned, configuring a registry
-root points jax's cache at ``<root>/compile-cache`` — shipping the registry
-directory to a fresh machine (or restoring it from CI's ``actions/cache``)
-makes EVERY train compile a disk hit, not just the grid programs.  Both
-stores are size-capped: ``enforce_budget`` / ``gc_compile_cache`` run
-LRU-by-atime eviction under a byte budget, stale-ABI entries first, with
-``evicted`` FailureLog notes.
+The registry never moves the persistent XLA compile cache: that directory
+is decided once at package import (``JAX_COMPILATION_CACHE_DIR``, else
+``TRANSMOGRIFAI_COMPILE_CACHE``, else the fixed in-checkout default — see
+``transmogrifai_tpu/__init__.py``) and children inherit it through the
+environment.  Both stores are size-capped: ``enforce_budget`` /
+``gc_compile_cache`` run LRU-by-atime eviction under a byte budget,
+stale-ABI entries first, with ``evicted`` FailureLog notes.
 
 Opt out with ``--no-registry`` / ``registryParams`` /
 ``TRANSMOGRIFAI_AOT_REGISTRY=0``.
@@ -91,7 +91,6 @@ _STATE: Dict[str, Any] = {
     "cap_bytes": None,
     "keep_min": None,
     "cache_cap_bytes": None,
-    "managed_cache": None,  # compile-cache dir this module pinned, if any
 }
 
 # process-wide loaded-executable table: payload/key digest -> deserialized
@@ -158,14 +157,11 @@ def registry_root() -> Optional[str]:
 def configure(root: Optional[str] = None, enabled: Optional[bool] = None,
               cap_bytes: Optional[int] = None,
               keep_min: Optional[int] = None,
-              cache_cap_bytes: Optional[int] = None,
-              manage_compile_cache: bool = True) -> None:
+              cache_cap_bytes: Optional[int] = None) -> None:
     """Apply registryParams / CLI flags.  Exports the root into the process
     environment so spawned children (serving pool workers, hostgroup ranks,
     supervised probes) install from the same registry without their own
-    plumbing.  Unless a compile cache is already pinned, also parks the
-    persistent XLA compile cache under ``<root>/compile-cache`` — the
-    registry directory then carries BOTH stores fleet-wide."""
+    plumbing."""
     with _LOCK:
         if enabled is not None:
             _STATE["enabled"] = bool(enabled)
@@ -180,22 +176,6 @@ def configure(root: Optional[str] = None, enabled: Optional[bool] = None,
             os.environ[REGISTRY_ENV] = str(root)
     if enabled is False:
         os.environ[REGISTRY_ENV] = "0"
-        return
-    if root and manage_compile_cache and \
-            not os.environ.get("TRANSMOGRIFAI_COMPILE_CACHE"):
-        from .profiling import set_compile_cache_dir
-        cache_dir = os.path.join(str(root), "compile-cache")
-        if set_compile_cache_dir(cache_dir):
-            with _LOCK:
-                _STATE["managed_cache"] = cache_dir
-            # children must see the SAME cache (env wins over their own
-            # defaulting) — and gets them the fleet-warm entries
-            os.environ["TRANSMOGRIFAI_COMPILE_CACHE"] = cache_dir
-
-
-def managed_compile_cache() -> Optional[str]:
-    with _LOCK:
-        return _STATE["managed_cache"]
 
 
 def _cap_bytes() -> int:
@@ -236,8 +216,7 @@ def reset_for_tests() -> None:
         _PUBLISHED.clear()
         _DYN_KWARGS.clear()
         _STATE.update(enabled=True, root=None, cap_bytes=None,
-                      keep_min=None, cache_cap_bytes=None,
-                      managed_cache=None)
+                      keep_min=None, cache_cap_bytes=None)
 
 
 # -- keys --------------------------------------------------------------------
@@ -469,6 +448,32 @@ def lookup(key: str, root: Optional[str] = None) -> Optional[bytes]:
         return None
 
 
+def executable_device_ids(compiled) -> List[int]:
+    """Ids of the devices ``compiled`` (a ``jax.stages.Compiled``) runs on —
+    stored beside every serialized payload so the load side can hand
+    ``deserialize_and_load`` the same execution devices."""
+    return [int(d.id) for d in compiled.runtime_executable().local_devices()]
+
+
+def load_executable(payload_rec: Dict[str, Any]) -> Any:
+    """``deserialize_and_load`` over the devices the executable was compiled
+    for.  Left to its default it loads over EVERY device of the backend, and
+    a single-device executable then fails its first call on any host that
+    shows more than one ("Expected args ... to have N shards").  A recorded
+    id this process does not have raises KeyError — an install failure the
+    callers already degrade on."""
+    import jax
+    from jax.experimental.serialize_executable import deserialize_and_load
+    ids = payload_rec.get("deviceIds")
+    devices = None
+    if ids is not None:
+        by_id = {d.id: d for d in jax.devices()}
+        devices = [by_id[i] for i in ids]
+    return deserialize_and_load(payload_rec["payload"], payload_rec["inTree"],
+                                payload_rec["outTree"],
+                                execution_devices=devices)
+
+
 def shared_load(digest: str, payload_rec: Dict[str, Any]) -> Any:
     """Deserialize ``payload_rec`` (serialize_executable triple) memoized on
     ``digest`` — the cross-tenant seam: every caller installing the same
@@ -478,9 +483,7 @@ def shared_load(digest: str, payload_rec: Dict[str, Any]) -> Any:
         if fn is not None:
             _count("aot_registry.shared_hits")
             return fn
-    from jax.experimental.serialize_executable import deserialize_and_load
-    fn = deserialize_and_load(payload_rec["payload"], payload_rec["inTree"],
-                              payload_rec["outTree"])
+    fn = load_executable(payload_rec)
     with _LOCK:
         # a racing loader may have beaten us — prefer the incumbent so
         # everyone converges on one object
@@ -529,24 +532,20 @@ def _reset_jax_compile_cache() -> None:
     """Drop jax's memoized compilation-cache object so the next compile
     re-reads ``jax_compilation_cache_dir``.  jax captures the cache object
     on first use; config updates alone are silently ignored after that."""
-    with contextlib.suppress(Exception):
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 @contextlib.contextmanager
-def fresh_compile_env():
-    """Suspend EVERY compile-caching layer so ``lower().compile()`` inside
-    the block is a real backend build: the persistent cache dir is unset,
-    jax's memoized cache object dropped, and the in-memory jit/compilation
-    memos cleared (they would otherwise hand the same cache-loaded
-    executable straight back).  Later dispatches re-trace — acceptable for
-    the rare cache-warm-but-registry-cold publish path this guards."""
+def persistent_cache_suspended():
+    """Every compile inside the block is a real backend build: the
+    persistent cache dir is unset and jax's memoized cache object dropped
+    (process-wide — other threads compile uncached meanwhile).  The
+    directory is restored on exit, never re-pointed."""
     import jax
     prev = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", None)
     _reset_jax_compile_cache()
-    jax.clear_caches()
     try:
         yield
     finally:
@@ -554,62 +553,92 @@ def fresh_compile_env():
         _reset_jax_compile_cache()
 
 
+@contextlib.contextmanager
+def fresh_compile_env(jitted):
+    """Make ``jitted.lower(...).compile()`` inside the block a real backend
+    build: the persistent cache is suspended and ``jitted``'s own in-memory
+    traces/executables are cleared (they would otherwise hand the same
+    cache-loaded executable straight back; its later dispatches re-trace
+    once)."""
+    with persistent_cache_suspended():
+        jitted.clear_cache()
+        yield
+
+
 def payload_roundtrips(rec: bytes) -> bool:
-    """Ground-truth publishability check: deserialize the payload.  An
-    executable jax re-loaded from the PERSISTENT COMPILE CACHE serializes
-    without its fusion object code and fails exactly here ("Symbols not
-    found") — the PR-9 hazard.  Every detection scheme based on cache-hit
-    counters has a blind spot (the hit may predate serialization, e.g.
-    during export warm-up scoring), so publishers validate the artifact
-    itself."""
+    """Cheap publishability check: the payload deserializes over the devices
+    it was compiled for.  It does NOT prove the executable runs — see
+    :func:`fresh_record` for the hazard only provenance can rule out."""
     try:
-        from jax.experimental.serialize_executable import \
-            deserialize_and_load
-        obj = pickle.loads(rec)
-        deserialize_and_load(obj["payload"], obj["inTree"], obj["outTree"])
+        load_executable(pickle.loads(rec))
         return True
     except Exception:  # noqa: BLE001
         return False
 
 
-def serialize_fresh(lower_fn, label: str = "") -> Optional[bytes]:
-    """``lower_fn() -> Lowered``; returns serialized executable bytes whose
-    payload round-trips through ``deserialize_and_load``.
+def fresh_record(jitted, build, maybe_loaded: bool = False) -> bytes:
+    """``build() -> bytes`` lowers ``jitted``, compiles and serializes one
+    executable record; returns a record that came from a FRESH backend
+    compile.
 
-    A cache-warm process must not silently publish garbage OR silently
-    skip publishing: we compile once normally, validate the payload by
-    deserializing it, and on failure re-lower + re-compile once under
-    :func:`fresh_compile_env` so the published payload is always a fresh
-    backend build."""
+    An executable jax LOADED from the persistent compile cache must not be
+    serialized: on jax 0.9 it serializes and deserializes without complaint
+    and its first CALL then fails (XLA:CPU: "Function ..._fusion not
+    found"), asynchronously, past every except.  jax keeps no provenance on
+    an executable and memoizes it for later ``lower().compile()`` calls, so
+    the callers keep it: ``maybe_loaded`` says the dispatch that first
+    compiled this program took a cache hit (``profiling.thread_cache_hits``
+    before/after), and the same bracket around ``build()`` catches a hit
+    taken right here.  Either way — or when the payload does not even
+    deserialize — ``build`` runs once more under :func:`fresh_compile_env`:
+    a cache-warm process neither ships garbage nor silently skips shipping.
+    Shared by the registry publish path and the bundle export loop."""
+    from .profiling import thread_cache_hits
+    if not maybe_loaded:
+        hits = thread_cache_hits()
+        rec = build()
+        if thread_cache_hits() == hits and payload_roundtrips(rec):
+            return rec
+    _count("aot_registry.recompiles_for_publish")
+    with fresh_compile_env(jitted):
+        rec = build()
+    if not payload_roundtrips(rec):
+        raise RuntimeError("payload does not deserialize even after a "
+                           "cache-suspended rebuild")
+    return rec
+
+
+def serialize_fresh(fn, args: tuple = (), kwargs: Optional[Dict] = None,
+                    label: str = "", maybe_loaded: bool = False
+                    ) -> Optional[bytes]:
+    """The registry record (serialized executable + pytrees + execution
+    devices) of a fresh build of jitted ``fn`` at ``fn.lower(*args,
+    **kwargs)`` (see :func:`fresh_record`), or None with a ``swallowed``
+    note — publish is strictly optional."""
     from .resilience import record_failure
     from jax.experimental.serialize_executable import serialize
 
-    def _attempt() -> bytes:
-        compiled = lower_fn().compile()
+    def _build() -> bytes:
+        compiled = fn.lower(*args, **(kwargs or {})).compile()
         payload, in_tree, out_tree = serialize(compiled)
         buf = io.BytesIO()
         pickle.dump({"payload": payload, "inTree": in_tree,
                      "outTree": out_tree,
+                     "deviceIds": executable_device_ids(compiled),
                      "dynKwargs": _dynamic_kwarg_names(in_tree)},
                     buf, protocol=4)
         return buf.getvalue()
     try:
-        with contextlib.suppress(Exception):
-            rec = _attempt()
-            if payload_roundtrips(rec):
-                return rec
-        _count("aot_registry.recompiles_for_publish")
-        with fresh_compile_env():
-            rec = _attempt()
-        return rec if payload_roundtrips(rec) else None
+        return fresh_record(fn, _build, maybe_loaded)
     except Exception as e:  # noqa: BLE001 — publish is strictly optional
         record_failure("aot_registry", "swallowed", e,
                        point="aot_registry.serialize", detail=label)
         return None
 
 
-def _queue_publish(key: str, label: str, lower_fn,
-                   meta: Optional[Dict[str, Any]] = None) -> None:
+def _queue_publish(key: str, label: str, fn, args: tuple, kwargs: Dict,
+                   meta: Optional[Dict[str, Any]] = None,
+                   maybe_loaded: bool = False) -> None:
     """Serialize + publish on the background pre-trace thread: the publish
     compile never lands inside a foreground fit/score wall, and
     ``aot.pretrace_drain`` (which export_bundle already calls before
@@ -623,7 +652,7 @@ def _queue_publish(key: str, label: str, lower_fn,
         if os.path.isdir(entry_dir(key) or "/nonexistent"):
             _count("aot_registry.publish_dedup")
             return
-        rec = serialize_fresh(lower_fn, label)
+        rec = serialize_fresh(fn, args, kwargs, label, maybe_loaded)
         if rec is not None:
             publish(key, rec, meta)
     from .aot import pretrace_submit
@@ -710,10 +739,12 @@ def grid_call(label: str, fn, args: tuple, *,
                            fallback="JIT recompile")
             _count("aot_registry.call_fallbacks")
             _drop_loaded(key)
+    from .profiling import thread_cache_hits
+    hits = thread_cache_hits()
     out = fn(*args, **statics)
-    _queue_publish(key, label,
-                   lambda: fn.lower(*args, **statics),
-                   {"kind": "grid", "family": label, "rung": int(rung)})
+    _queue_publish(key, label, fn, args, statics,
+                   {"kind": "grid", "family": label, "rung": int(rung)},
+                   maybe_loaded=thread_cache_hits() != hits)
     return out
 
 
@@ -746,7 +777,7 @@ def grid_compile(label: str, fn, args: tuple, *,
             return
         except Exception:  # noqa: BLE001 — fall through to the compile
             _count("aot_registry.install_failures")
-    rec = serialize_fresh(lambda: fn.lower(*args, **statics), label)
+    rec = serialize_fresh(fn, args, statics, label)
     if rec is not None:
         with _LOCK:
             _PUBLISHED.add(key)
@@ -839,7 +870,7 @@ def registry_bytes(root: Optional[str] = None) -> int:
         return 0
     total = 0
     for dirpath, dirnames, filenames in os.walk(root):
-        # the managed compile cache is accounted separately
+        # a compile cache parked under the root is accounted separately
         if os.path.basename(dirpath) == "compile-cache":
             dirnames[:] = []
             continue
@@ -950,14 +981,8 @@ def gc_compile_cache(cache_dir: Optional[str] = None,
     entry just recompiles.  Returns the number of files removed."""
     from .resilience import record_failure
     if cache_dir is None:
-        cache_dir = os.environ.get("TRANSMOGRIFAI_COMPILE_CACHE") or \
-            managed_compile_cache()
-        if not cache_dir or cache_dir == "0":
-            try:
-                import jax
-                cache_dir = jax.config.jax_compilation_cache_dir
-            except Exception:  # noqa: BLE001
-                cache_dir = None
+        import jax
+        cache_dir = jax.config.jax_compilation_cache_dir
     if not cache_dir or not os.path.isdir(cache_dir):
         return 0
     cap = _cache_cap_bytes() if cap_bytes is None else int(cap_bytes)

@@ -46,10 +46,9 @@ def mxu_dtype_for(platform: str):
 
 
 def _mxu_dtype():
-    """Default histogram dtype from the process-global backend.  NOT cached:
-    the backend can change mid-process (dryrun_multichip switches from the
-    real chip to a virtual CPU mesh).  Computations pinned to an explicit
-    mesh should instead pass ``hist_dtype=mxu_dtype_for(<mesh platform>)``."""
+    """Default histogram dtype from the process-global backend.
+    Computations pinned to an explicit mesh should instead pass
+    ``hist_dtype=mxu_dtype_for(<mesh platform>)``."""
     return mxu_dtype_for(jax.default_backend())
 
 
@@ -60,8 +59,8 @@ def _mxu_dtype():
 # (weakref(X), {max_bins: (splits, B)}) keyed by id(X): every tree family in
 # a CV grid shares ONE binned matrix per (matrix, max_bins) instead of each
 # building its own — at 11M rows a duplicate B is ~0.3 GB of HBM and a full
-# binning pass, and cumulative residency is what hard-faults the worker
-# (VERDICT r3 #2).  Entries drop when the feature matrix is collected.
+# binning pass, and cumulative residency is what exhausts HBM.  Entries drop
+# when the feature matrix is collected.
 _SHARED_BINS: Dict[int, Any] = {}
 
 # id(X) → (weakref(X), n_real) for zero-weight-padded matrices: the sweep's

@@ -2,21 +2,33 @@
 
 The reference's ingestion/runtime layer is JVM code running on Spark
 executors; this framework's equivalent native layer lives here.  Modules are
-compiled on first use with ``g++`` (no pip/network), cached next to the
-package, and every consumer has a pure-Python fallback — absence of a
-toolchain degrades performance, never correctness.
+compiled on first use with ``g++`` (no pip/network) into a git-ignored
+``_build/`` next to the package, and every consumer has a pure-Python
+fallback — absence of a toolchain degrades performance, never correctness.
+
+A binary is only ever reused when it was built from exactly the source now
+on disk: its file name carries a digest of the ``.cpp`` bytes and of the
+interpreter/numpy ABI it was compiled against, so a copied tree cannot run a
+``.so`` that git never saw the source of.  A module that could not be built
+or imported says why through :func:`fallback_reasons` (and a warning), so a
+caller that requires the native path — ``chip_smoke.py`` — can fail on it.
 """
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import os
 import subprocess
 import sys
 import sysconfig
-from typing import Any, Optional
+import warnings
+from typing import Any, Dict, Optional
+
+MODULES = ("fastcsv", "fasttok", "locofmt", "mapprof", "textprof")
 
 _CACHE: dict = {}
+_REASONS: Dict[str, str] = {}
 
 
 def _build_dir() -> str:
@@ -31,44 +43,68 @@ def _source_path(name: str) -> str:
     return os.path.join(os.path.dirname(pkg_root), "native", f"{name}.cpp")
 
 
-def _compile(name: str) -> Optional[str]:
-    src = _source_path(name)
-    if not os.path.exists(src):
-        return None
-    so = os.path.join(_build_dir(), f"_{name}.so")
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(src):
-        return so
+def _compile(name: str) -> str:
+    """Path of ``_<name>.so`` built from the current source; raises with the
+    compiler's own words when it cannot be built."""
     import numpy as np
+    src = _source_path(name)
+    with open(src, "rb") as fh:
+        digest = hashlib.sha256(fh.read())
+    digest.update(f"{sys.version}|{np.__version__}".encode())
+    so = os.path.join(_build_dir(), f"_{name}.{digest.hexdigest()[:16]}.so")
+    if os.path.exists(so):
+        return so
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = [
         os.environ.get("CXX", "g++"), "-O2", "-std=c++17", "-shared", "-fPIC",
         f"-I{sysconfig.get_paths()['include']}",
         f"-I{np.get_include()}",
-        src, "-o", so,
+        src, "-o", tmp,
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except Exception:  # pragma: no cover — toolchain-dependent
-        return None
+        os.replace(tmp, so)     # concurrent builders converge on one file
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"{cmd[0]} failed (rc={e.returncode}): "
+            f"{e.stderr.decode(errors='replace')[-800:]}") from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     return so
 
 
 def load(name: str) -> Optional[Any]:
     """Import native module ``_<name>``, compiling it if needed.  Returns the
-    module or None (callers fall back to pure Python).  Disable with
-    TRANSMOGRIFAI_NATIVE=0."""
+    module or None (callers fall back to pure Python, and
+    :func:`fallback_reasons` says why).  Disable with TRANSMOGRIFAI_NATIVE=0."""
     if name in _CACHE:
         return _CACHE[name]
     mod = None
-    if os.environ.get("TRANSMOGRIFAI_NATIVE", "1") != "0":
+    if os.environ.get("TRANSMOGRIFAI_NATIVE", "1") == "0":
+        _REASONS[name] = "disabled by TRANSMOGRIFAI_NATIVE=0"
+    else:
         try:
-            so = _compile(name)
-            if so is not None:
-                spec = importlib.util.spec_from_file_location(f"_{name}", so)
-                if spec and spec.loader:
-                    mod = importlib.util.module_from_spec(spec)
-                    sys.modules[f"_{name}"] = mod
-                    spec.loader.exec_module(mod)
-        except Exception:  # pragma: no cover — best-effort native path
+            spec = importlib.util.spec_from_file_location(
+                f"_{name}", _compile(name))
+            mod = importlib.util.module_from_spec(spec)
+            sys.modules[f"_{name}"] = mod
+            spec.loader.exec_module(mod)
+        except Exception as e:  # noqa: BLE001 — toolchain-dependent; the
+            # Python path is exact, so degrade — but never silently
             mod = None
+            sys.modules.pop(f"_{name}", None)
+            _REASONS[name] = f"{type(e).__name__}: {e}"
+            warnings.warn(f"native module {name!r} unavailable, using the "
+                          f"pure-Python path: {_REASONS[name]}",
+                          RuntimeWarning, stacklevel=2)
     _CACHE[name] = mod
     return mod
+
+
+def fallback_reasons() -> Dict[str, str]:
+    """Loads every module in :data:`MODULES`; returns ``{name: reason}`` for
+    those running on the pure-Python path (empty when all are native)."""
+    for name in MODULES:
+        load(name)
+    return {n: _REASONS[n] for n in MODULES if _CACHE.get(n) is None}

@@ -2,8 +2,8 @@
 
 Serving has been fully observable since PR 5 (/metrics with exemplars,
 distributed tracing), but a running *train* exposed nothing until it
-finished or died: BENCH_11M_ATTEMPTS_r4 and OUTAGE_r5 were reconstructed
-after the fact from per-rank heartbeat files and partial logs.  This module
+finished or died: a failed scale run had to be reconstructed after the
+fact from per-rank heartbeat files and partial logs.  This module
 is the train-side control plane (ROADMAP item 3):
 
 * ``ProgressBoard`` — a lock-free snapshot object the sweep's *existing*

@@ -198,7 +198,7 @@ def sharded_gbt_round(mesh: Mesh, *, task: str = "classification",
 
 
 # --------------------------------------------------------------------------
-# full sharded training step (used by __graft_entry__.dryrun_multichip)
+# full sharded training step
 # --------------------------------------------------------------------------
 
 def sharded_train_step(mesh: Mesh, n_iter: int = 8):

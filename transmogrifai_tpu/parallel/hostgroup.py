@@ -5,17 +5,15 @@ lost executors are detected by driver heartbeats and their tasks re-run
 elsewhere.  This module is that story for the jax_graft port: N ranked
 worker *processes* (one per host; in CI, N local processes over the
 multi-process CPU backend) under one supervising launcher, with host loss a
-recoverable, observable event instead of a silent collective hang
-(OUTAGE_r5's failure family at cross-host scope).
+recoverable, observable event instead of a silent collective hang.
 
 Four cooperating pieces:
 
 * ``launch_hosts(cmd, n)`` — the launcher.  Spawns ``cmd`` once per rank
   under the ``run_supervised`` conventions (per-rank log/ready files in a
   run dir, ``start_new_session`` process groups, SIGTERM→grace→SIGKILL
-  drain, zero orphans), pre-flighted by the subprocess device probe so an
-  OUTAGE_r5-class native hang becomes a typed verdict before any rank
-  exists.  Ranks find each other through ``TRANSMOGRIFAI_HOSTGROUP_*`` env
+  drain, zero orphans), pre-flighted by the subprocess device probe so a
+  native init hang becomes a typed verdict before any rank exists.  Ranks find each other through ``TRANSMOGRIFAI_HOSTGROUP_*`` env
   vars (rank, world size, run dir, coordinator address, generation).
 
 * rank-side init — ``maybe_init_hostgroup()`` is the one call worker code
@@ -29,7 +27,7 @@ Four cooperating pieces:
   AVAILABLE/DEGRADED/OUTAGE state machine to host granularity
   (``hostgroup.alive``/``hostgroup.state`` gauges, ``host_lost``/
   ``host_recovered`` failure-log actions, outage records through the
-  shared OUTAGE_r5-schema writer).  ``barrier_sync(name, timeout_s)`` is
+  supervisor's shared writer).  ``barrier_sync(name, timeout_s)`` is
   the deadline-guarded rendezvous: a rank that never arrives surfaces as a
   typed :class:`HostLostError` on every survivor within the deadline — no
   Python-level collective can hang silently.  (Native collectives already
@@ -282,7 +280,7 @@ class HostLiveness:
     device to host granularity.  ``tick()`` is the synchronous unit (fully
     fake-clock testable); transitions land as ``host_lost`` /
     ``host_recovered`` failure-log actions, ``hostgroup.alive`` /
-    ``hostgroup.state`` gauges, and an OUTAGE_r5-schema record per loss."""
+    ``hostgroup.state`` gauges, and a standard outage record per loss."""
 
     def __init__(self, run_dir: str, world: int, *,
                  timeout_s: Optional[float] = None, generation: int = 0,
@@ -726,7 +724,7 @@ def launch_hosts(cmd: Sequence[str], hosts: int, *,
     trace context per rank so all spans share the launcher's trace id),
     wait for the per-rank ready files under ``boot_timeout``, then monitor
     child liveness (process exit + heartbeat staleness).  On a loss: post
-    the group abort, write the OUTAGE_r5-schema record, drain survivors
+    the group abort, write the standard outage record, drain survivors
     under SIGTERM→SIGKILL, and — budget permitting — relaunch at the
     shrunken world size with ``generation+1`` so ranks resume their sweep
     checkpoints.  Returns when a generation completes cleanly (every rank
@@ -748,8 +746,10 @@ def launch_hosts(cmd: Sequence[str], hosts: int, *,
                              wall_s=0.0)
 
     # pre-flight: the PR-11 subprocess probe — a wedged accelerator runtime
-    # (the OUTAGE_r5 native hang) becomes a typed verdict BEFORE any rank
-    # exists, instead of N ranks hanging in init
+    # (a native init hang) becomes a typed verdict BEFORE any rank exists,
+    # instead of N ranks hanging in init.  Run from a launcher that already
+    # owns the chip, the probe child cannot have it and reports an outage
+    # (platform pinned) or the CPU — the launcher must stay off the backend.
     if preflight is None:
         preflight = supervisor_enabled()
     if preflight:
@@ -772,22 +772,26 @@ def launch_hosts(cmd: Sequence[str], hosts: int, *,
 
     parent_ctx = current_trace_context() or TraceContext.new()
     base_env = dict(os.environ)
+    if preflight and verdict.platform:
+        # ranks get the platform the probe found, pinned: a rank that cannot
+        # have it (the launcher, or a sibling rank on this host, owns the
+        # chip) then fails in init instead of training on the CPU
+        base_env.setdefault("JAX_PLATFORMS", verdict.platform)
     if env:
         base_env.update({str(k): str(v) for k, v in env.items()})
     # children must resolve the package wherever the launcher did
     base_env["PYTHONPATH"] = _repo_root() + (
         os.pathsep + base_env["PYTHONPATH"]
         if base_env.get("PYTHONPATH") else "")
-    # every rank shares the launcher's compiled-program registry (and its
-    # managed compile cache): rank 0's publishes warm ranks 1..N-1, and a
-    # relaunch after a lost host resumes without re-paying compiles
-    from ..aot_registry import managed_compile_cache, registry_root
+    # every rank shares the launcher's compiled-program registry: rank 0's
+    # publishes warm ranks 1..N-1, and a relaunch after a lost host resumes
+    # without re-paying compiles.  The compile-cache variables
+    # (JAX_COMPILATION_CACHE_DIR / TRANSMOGRIFAI_COMPILE_CACHE) are inherited
+    # as they stand.
+    from ..aot_registry import registry_root
     _reg = registry_root()
     if _reg:
         base_env.setdefault("TRANSMOGRIFAI_AOT_REGISTRY", _reg)
-    _cache = managed_compile_cache()
-    if _cache:
-        base_env.setdefault("TRANSMOGRIFAI_COMPILE_CACHE", _cache)
 
     # training control plane: when an obs port is configured the launcher
     # keeps the base port for the merged rank panel and deals each child
@@ -961,7 +965,7 @@ def _supervise_generation(procs: Dict[int, subprocess.Popen], run_dir: str,
                 event("hostgroup.booted", generation=generation,
                       world=world)
             elif now >= boot_deadline and not losses:
-                # the OUTAGE_r5 shape at group scope: rank(s) wedged before
+                # the init-hang shape at group scope: rank(s) wedged before
                 # ready — reclaim them (SIGTERM→SIGKILL) and call it a loss
                 for rank in range(world):
                     if rank not in ready and procs[rank].poll() is None:

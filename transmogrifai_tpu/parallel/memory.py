@@ -1,10 +1,9 @@
 """Memory governance for the training/streaming paths (ISSUE 15).
 
-Every 11M-row attempt in ``BENCH_11M_ATTEMPTS_r4.json`` died the same way:
-a TPU worker hard-faulted inside ``batched_device_put`` and a human
-re-launched with a smaller hand-picked budget ("budget4/cache256M" →
-"budget2/cache128M").  This module makes the runtime walk that ladder
-itself, in four pieces:
+Scale runs used to die the same way: the device ran out of memory inside
+``batched_device_put`` and a human re-launched with a smaller hand-picked
+budget.  This module makes the runtime walk that ladder itself, in four
+pieces:
 
 * **Budget discovery** — per-device capacity from
   ``TRANSMOGRIFAI_DEVICE_MEM_BYTES`` (operator override / ``memoryParams``

@@ -83,8 +83,8 @@ def init_distributed(coordinator_address: Optional[str] = None,
     single-host machines can hard-abort the process, so without a coordinator
     and without cluster env vars this is a clean no-op.
 
-    ``timeout_s`` runs the init under a watchdog: the round-5 outage showed
-    it can HANG in native code with no error raised (OUTAGE_r5.json), and a
+    ``timeout_s`` runs the init under a watchdog: it can HANG in native
+    code with no error raised, and a
     hang must surface as ``WatchdogTimeout`` — raised for an explicit
     coordinator request, recorded in the failure log and degraded to
     single-host for auto-detection.
@@ -102,8 +102,7 @@ def init_distributed(coordinator_address: Optional[str] = None,
     the failure log.
     """
     from ..telemetry import REGISTRY, span
-    already = getattr(jax.distributed, "is_initialized", None)
-    if already is not None and already():
+    if jax.distributed.is_initialized():
         REGISTRY.gauge("multihost.initialized").set(1)
         REGISTRY.gauge("multihost.process_count").set(jax.process_count())
         return jax.process_count() > 1

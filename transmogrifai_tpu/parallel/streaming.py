@@ -3,7 +3,7 @@
 The one-shot ``jax.device_put(X, data_sharding(mesh, 2))`` stages the whole
 host matrix at once: at 11M × 1596 f32 that is a ~70GB transient on top of
 the resident copy, which is exactly the cumulative-HBM/host-RSS pressure
-that hard-faulted single workers (BENCH_11M_ATTEMPTS_r4).  This module
+that kills a single worker.  This module
 assembles each device's row shard from bounded host slices instead:
 
   * at most two chunk-sized host staging buffers are alive at any moment
@@ -38,8 +38,8 @@ _DEFAULT_CHUNK_BYTES = 256 * 1024 * 1024
 
 
 def _put_chunk(buf, dev, seq: int):
-    """One supervised chunk transfer.  A hung host→device link (the
-    OUTAGE_r5 failure family) surfaces as a typed ``TransferStallError``
+    """One supervised chunk transfer.  A hung host→device link surfaces as
+    a typed ``TransferStallError``
     within the TRANSMOGRIFAI_CHUNK_DEADLINE_S budget instead of blocking
     the stream forever; ``supervisor.chunk_stall`` is the chaos-injection
     point, keyed by a monotone per-process chunk sequence so a sticky

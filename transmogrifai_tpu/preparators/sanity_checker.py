@@ -53,7 +53,7 @@ def _col_stats(X: jnp.ndarray, y: jnp.ndarray, spearman: bool = False):
     OpStatistics.scala:71).  With ``spearman=True`` the rank transform
     (argsort + tie-averaged positions) happens INSIDE the same program
     (≙ SanityChecker.scala:535-640 Spearman option) — one executable, one
-    dispatch, no second stats pass (VERDICT r4 next #6).
+    dispatch, no second stats pass.
 
     Jitted so the centred intermediates fuse into the reductions instead of
     materializing eagerly (an eager pass holds 2-3 full [N, D] temporaries —

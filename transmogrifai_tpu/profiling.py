@@ -51,21 +51,23 @@ _COMPILE_INSTALL_LOCK = threading.Lock()
 _COMPILE_STATS = {"compile_s": 0.0, "backend_compiles": 0,
                   "cache_hits": 0, "cache_misses": 0}
 _COMPILE_LISTENERS_INSTALLED = [False]
+# cache hits seen by THIS thread: jax fires the event synchronously on the
+# compiling thread, so a before/after read brackets exactly the compiles a
+# call made (aot_registry uses it to tell a cache-LOADED executable from one
+# built here)
+_THREAD_CACHE_HITS = threading.local()
 
 
 def install_compile_listeners() -> bool:
     """Register the jax.monitoring listeners feeding ``compile_stats``.
-    Idempotent and safe without jax (returns False).  Called from package
-    import; also from the accessors so a bare ``import profiling`` works.
+    Idempotent.  Called from package import; also from the accessors so a
+    bare ``import profiling`` works.
     Registration is double-checked under an install lock: jax.monitoring has
     no dedup, so two racing callers registering the same listeners would
     double-count every compile second from then on."""
     if _COMPILE_LISTENERS_INSTALLED[0]:
         return True
-    try:
-        from jax import monitoring
-    except Exception:  # pragma: no cover — jax-less host
-        return False
+    from jax import monitoring
 
     def _on_duration(event: str, duration: float, **kw) -> None:
         if event == _COMPILE_DURATION_EVENT:
@@ -77,6 +79,7 @@ def install_compile_listeners() -> bool:
         if event == _CACHE_HIT_EVENT:
             with _COMPILE_LOCK:
                 _COMPILE_STATS["cache_hits"] += 1
+            _THREAD_CACHE_HITS.n = thread_cache_hits() + 1
         elif event == _CACHE_MISS_EVENT:
             with _COMPILE_LOCK:
                 _COMPILE_STATS["cache_misses"] += 1
@@ -90,15 +93,14 @@ def install_compile_listeners() -> bool:
     return True
 
 
+def thread_cache_hits() -> int:
+    """Persistent-compile-cache hits the calling thread has taken so far."""
+    return getattr(_THREAD_CACHE_HITS, "n", 0)
+
+
 def compile_stats() -> Dict[str, float]:
     install_compile_listeners()
     return dict(_COMPILE_STATS)
-
-
-def reset_compile_stats() -> None:
-    install_compile_listeners()
-    for k in _COMPILE_STATS:
-        _COMPILE_STATS[k] = 0.0 if k == "compile_s" else 0
 
 
 def compile_seconds() -> float:
@@ -112,35 +114,10 @@ def new_compile_count() -> int:
     its small backend_compile_duration is retrieval, not compilation);
     without one every backend compile is a fresh build."""
     install_compile_listeners()
-    try:
-        import jax
-        if jax.config.jax_compilation_cache_dir:
-            return int(_COMPILE_STATS["cache_misses"])
-    except Exception:  # pragma: no cover
-        pass
+    import jax
+    if jax.config.jax_compilation_cache_dir:
+        return int(_COMPILE_STATS["cache_misses"])
     return int(_COMPILE_STATS["backend_compiles"])
-
-
-def set_compile_cache_dir(path: str, min_compile_time_secs: float = 0.0
-                          ) -> bool:
-    """Point jax's persistent compilation cache at ``path`` (created on
-    first write by jax).  ``min_compile_time_secs=0`` caches every program —
-    a warm process then reports ~0 ``new_compile_count()``.  The path is
-    scoped per backend platform (same hazard as the import-time default: CPU
-    AOT entries carry host machine-feature assumptions)."""
-    try:
-        import os
-
-        import jax
-        plat = ((os.environ.get("JAX_PLATFORMS") or "default")
-                .split(",")[0].strip() or "default")
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(path, plat))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_time_secs))
-        return True
-    except Exception:  # pragma: no cover — cache is best-effort
-        return False
 
 
 # -- selector racing accounting (ISSUE 4) -----------------------------------
@@ -164,7 +141,7 @@ def reset_racing_stats() -> None:
         RACING_STATS[k] = 0
 
 
-# -- XLA program cost registry (VERDICT r4 next #5) -------------------------
+# -- XLA program cost registry -----------------------------------------------
 # When TRANSMOGRIFAI_COST_ANALYSIS=1, the dominant compiled programs record
 # their XLA cost analysis (flops / bytes accessed) here, once per program
 # name; bench.py turns them into achieved-FLOP/s roofline fields.
@@ -184,7 +161,7 @@ def record_program_cost(name: str, jitted_fn, args=(), kwargs=None) -> None:
     Only the cheap ``lower()`` trace happens here (a Lowered holds shapes,
     not argument buffers); the compile()+cost_analysis() pass is deferred to
     ``flush_program_costs`` so enabling TRANSMOGRIFAI_COST_ANALYSIS=1 does
-    not add analysis time inside a caller's timed wall (ADVICE r5)."""
+    not add analysis time inside a caller's timed wall."""
     if (not cost_analysis_enabled() or name in PROGRAM_COSTS
             or name in _PENDING_COSTS):
         return

@@ -250,7 +250,9 @@ class OpWorkflowRunner:
             else contextlib.nullcontext()
         # opt-in heartbeat supervision for the whole run: background
         # re-probes feed the device-runtime breaker + AVAILABLE/DEGRADED/
-        # OUTAGE gauges while the run is in flight
+        # OUTAGE gauges while the run is in flight.  The probe is a fresh
+        # child; on an accelerator this process owns the chip, so the child
+        # reports outage/cpu for as long as the run holds it (see Heartbeat)
         hb = None
         try:
             hb_interval = float(os.environ.get("TRANSMOGRIFAI_HEARTBEAT_S",
@@ -416,22 +418,13 @@ class OpWorkflowRunner:
             # default the compiled-program registry next to the sweep state:
             # the checkpoint dir outlives /tmp, so every re-train (and every
             # pool worker / lifecycle retrain pointed at the same location)
-            # installs executables instead of compiling.  configure() also
-            # parks the persistent XLA compile cache under the registry root
-            # (<registry>/compile-cache), so the pre-registry cache
-            # defaulting below only fires when the registry is disabled
+            # installs executables instead of compiling.  The persistent XLA
+            # compile cache stays where package import put it.
             from .aot_registry import configure as configure_registry
             from .aot_registry import registry_allowed, registry_root
             if registry_allowed() and registry_root() is None:
                 configure_registry(root=os.path.join(
                     params.checkpoint_location, "registry"))
-            if not os.environ.get("TRANSMOGRIFAI_COMPILE_CACHE"):
-                # registry off: keep the old behavior — park the XLA
-                # compile cache beside the sweep state so every re-train
-                # of this app pays execution cost only
-                from .profiling import set_compile_cache_dir
-                set_compile_cache_dir(os.path.join(
-                    params.checkpoint_location, "compile-cache"))
         try:
             with timer.phase("train"):
                 model = self.workflow.train(resume_from=resume_from)

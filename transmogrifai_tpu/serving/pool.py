@@ -438,6 +438,7 @@ class ServingPool:
             "tenantMaxActive": tenant_max_active,
             "tenantMemoryBudgetBytes": tenant_memory_budget_bytes}
         self.slots = [self._make_slot(i) for i in range(self.workers)]
+        self._device_env: List[Dict[str, str]] = []  # per worker, by start()
         self._supervisor: Optional[threading.Thread] = None
 
     # -- spawning ----------------------------------------------------------
@@ -450,6 +451,52 @@ class ServingPool:
                            os.path.join(self.run_dir,
                                         f"worker-{worker_id}.log"))
 
+    def _resolve_device_env(self) -> List[Dict[str, str]]:
+        """Where each worker computes, as child-environment entries.  The
+        pool parent stays off the backend (a chip belongs to one process),
+        so it asks the supervisor's subprocess probe what a fresh process
+        gets.  On an accelerator worker *k* owns chip *k*, with the platform
+        pinned so a worker that cannot have its chip fails at boot instead
+        of serving from the CPU; more workers than chips is refused.  With
+        ``JAX_PLATFORMS=cpu`` in the environment the operator chose the CPU
+        backend and the workers just inherit it."""
+        pinned = (os.environ.get("JAX_PLATFORMS") or "").split(",")[0].strip()
+        if pinned == "cpu":
+            return [{} for _ in self.slots]
+        from ..parallel.supervisor import (OUTAGE, probe_devices,
+                                           single_chip_env)
+        verdict = probe_devices(key="serving-pool")
+        if verdict.status == OUTAGE:
+            raise RuntimeError(
+                f"serving pool: device probe says outage ({verdict.cause}) "
+                "— is this process (or another) holding the accelerator?")
+        if verdict.platform == "cpu":
+            return [{"JAX_PLATFORMS": "cpu"} for _ in self.slots]
+        if self.workers > verdict.device_count:
+            raise ValueError(
+                f"serving pool: {self.workers} workers but this host shows "
+                f"{verdict.device_count} {verdict.platform} device(s); a "
+                "chip belongs to one process, so the pool serves one worker "
+                "per chip")
+        return [{"JAX_PLATFORMS": verdict.platform, **single_chip_env(k)}
+                for k in range(self.workers)]
+
+    def _worker_env(self, slot: _WorkerSlot) -> Dict[str, str]:
+        env = dict(os.environ)
+        root = os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__))))
+        env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+        # every worker installs from the pool's compiled-program registry:
+        # N-worker boot then costs at most the ONE compile the first
+        # publisher paid, not N re-derivations (aot_registry.py).  The
+        # compile-cache variables are inherited as they stand.
+        from ..aot_registry import registry_root
+        reg = registry_root()
+        if reg:
+            env.setdefault("TRANSMOGRIFAI_AOT_REGISTRY", reg)
+        env.update(self._device_env[slot.worker_id])
+        return env
+
     def _spawn(self, slot: _WorkerSlot) -> None:
         ready_path = os.path.join(self.run_dir,
                                   f"worker-{slot.worker_id}.ready.json")
@@ -457,20 +504,7 @@ class ServingPool:
             os.unlink(ready_path)
         slot.ready = None
         slot.probe_failures = 0
-        env = dict(os.environ)
-        root = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
-        # every worker installs from the pool's compiled-program registry:
-        # N-worker boot then costs at most the ONE compile the first
-        # publisher paid, not N re-derivations (aot_registry.py)
-        from ..aot_registry import managed_compile_cache, registry_root
-        reg = registry_root()
-        if reg:
-            env.setdefault("TRANSMOGRIFAI_AOT_REGISTRY", reg)
-        cache = managed_compile_cache()
-        if cache:
-            env.setdefault("TRANSMOGRIFAI_COMPILE_CACHE", cache)
+        env = self._worker_env(slot)
         # seed the worker's root span from the pool's ambient trace so
         # worker-side spans land on the same trace_id as the spawner
         from ..telemetry import TRACEPARENT_ENV, current_trace_context
@@ -524,6 +558,7 @@ class ServingPool:
         """Spawn every worker, wait until all are ready, start the
         supervisor thread.  Raises (after killing stragglers) if any
         worker fails to boot."""
+        self._device_env = self._resolve_device_env()
         deadline = time.monotonic() + self.worker_boot_timeout_s
         try:
             for slot in self.slots:

@@ -1128,6 +1128,14 @@ def run_serve_scaleout(launch: "Launch"):
             run_dir=os.path.join(out_dir, f"pool-{n_workers}-{ctype[-8:]}"))
         try:
             pool.start()
+            # where the workers say they compute, not where the launcher's
+            # probe expected them to (the pool already refused a mismatch
+            # with what IT resolved)
+            devices = [w["device"] for w in pool.status()["workerList"]]
+            if any(d["platform"] != launch.platform for d in devices):
+                raise CellFailed(
+                    f"serve_scaleout: pool workers report {devices}, the "
+                    f"launcher probed {launch.platform}")
             # one warm round-trip per worker-count so the first timed
             # request doesn't pay connection setup
             storm_points = []
@@ -1141,7 +1149,7 @@ def run_serve_scaleout(launch: "Launch"):
         best = (max(within, key=lambda p: p["rows_per_s"]) if within
                 else max(storm_points, key=lambda p: p["rows_per_s"]))
         return {"best": best, "slo_met": bool(within),
-                "sweep": storm_points}
+                "sweep": storm_points, "worker_devices": devices}
 
     try:
         json_single = measure(1, json_body, "application/json")

@@ -27,11 +27,10 @@ sets the platform itself.  The last stdout line is one JSON object,
 ``{"ok": true, "device": {...}, ..., "claim": null}`` — printed only when
 every check passed.  No number it prints is a benchmark result.
 
-    JAX_PLATFORMS=cpu python chip_smoke.py --cpu-reference [--rows-a N --rows-b M]
+    JAX_PLATFORMS=cpu python chip_smoke.py --cpu-reference
 
-is the separately named CPU correctness run that produced CPU_REFERENCE below
-(and, at small row counts, a quick way to debug the script).  It prints no
-result line.
+is the separately named CPU correctness run that produced CPU_REFERENCE below.
+It prints no result line.
 """
 
 import argparse
@@ -57,6 +56,7 @@ CPU_REFERENCE = {
     "A": {"auroc": 0.8142, "winner": "OpLogisticRegression",
           "failure_events": []},
     "B": {"auroc": 0.8419, "failure_events": []},
+    "C": {"failure_events": []},
 }
 # bf16 feature storage and bf16 histogram contractions move a train-set AuROC
 # in the third decimal; a family that silently degraded moves it in the second
@@ -83,32 +83,41 @@ def say(msg):
 
 @contextlib.contextmanager
 def phase(name, report):
-    """Wall + compile counters of one phase, printed and kept in the report."""
+    """One phase: its wall and compile counters, printed and kept in the
+    report, and its own ambient failure log.  ``train()`` installs a log of
+    its own (``model.failure_log``); everything else — ``score()``,
+    ``evaluate()``, ``save()``, ``load()``, the serving engine — records
+    into whatever log is ambient, which is this one."""
     from transmogrifai_tpu.profiling import compile_stats
+    from transmogrifai_tpu.resilience import FailureLog, use_failure_log
     c0, t0 = compile_stats(), time.time()
     rec = report.setdefault(name, {})
     say(f"--- phase {name} ---")
-    yield rec
+    with use_failure_log(FailureLog()) as log:
+        yield rec, log
     c1 = compile_stats()
     rec["wall_s"] = round(time.time() - t0, 1)
     rec["compile"] = {k: round(c1[k] - c0[k], 1) for k in c1}
     say(f"phase {name}: wall {rec['wall_s']} s, compile {rec['compile']}")
 
 
-def bad_events(model):
+def bad_events(*logs):
     return sorted({(e.action, e.point or e.stage)
-                   for e in model.failure_log.events
+                   for log in logs for e in log.events
                    if e.action in BAD_ACTIONS})
 
 
-def check_failure_log(model, name, rec):
-    events = bad_events(model)
+def check_failure_logs(name, rec, *logs):
+    """No log of this phase may hold an event the CPU run does not: a
+    family skipped, a fused program demoted to eager stages, an AOT
+    executable that gave way to JIT, an export that was swallowed."""
+    events = bad_events(*logs)
     rec["failure_events"] = [list(e) for e in events]
     allowed = {tuple(e) for e in CPU_REFERENCE[name]["failure_events"]}
     extra = [e for e in events if e not in allowed]
     require(not extra, name,
             f"failure log holds events the CPU run does not: {extra}; "
-            f"full log: {model.failure_log.to_json()}")
+            f"full logs: {[log.to_json() for log in logs]}")
 
 
 def check_auroc(auroc, name, rec, reference):
@@ -122,14 +131,58 @@ def check_auroc(auroc, name, rec, reference):
             "reference)")
 
 
-def check_memory(name, rec):
-    from transmogrifai_tpu.parallel.memory import memory_aux
+def check_memory(name, rec, plan_before):
+    """Shrink level 0 so far, and the plan this phase's sweep made — None
+    when it made none (only the mesh-sharded sweep plans)."""
+    from transmogrifai_tpu.parallel.memory import last_plan, memory_aux
     aux = memory_aux()
+    plan = last_plan()
     rec["memory"] = {"shrink_level": aux["shrink_level"],
+                     "shrinks_total": aux["shrinks_total"],
                      "device_budget_bytes": aux["device_budget_bytes"],
-                     "plan": aux["plan"]}
-    require(aux["shrink_level"] == 0, name,
+                     "plan": (plan.to_json() if plan is not None
+                              and plan is not plan_before else None)}
+    require(aux["shrink_level"] == 0 and not aux["shrinks_total"], name,
             f"memory governor shrank the sweep: {aux}")
+
+
+def check_aot_counters(name, rec):
+    """No AOT executable, installed from a bundle or from the registry, has
+    failed to install or given way to JIT so far in this process."""
+    from transmogrifai_tpu.telemetry import REGISTRY
+    counters = REGISTRY.counters()
+    rec["aot"] = {k: counters.get(k, 0) for k in (
+        "aot_registry.installs", "aot_registry.call_fallbacks",
+        "aot_registry.install_failures", "aot.fallback")}
+    require(rec["aot"]["aot_registry.installs"] > 0, name,
+            "no AOT executable was installed")
+    require(not any(v for k, v in rec["aot"].items()
+                    if k != "aot_registry.installs"), name,
+            f"an AOT executable gave way to JIT: {rec['aot']}")
+
+
+def check_bundle_aot(name, rec, bundle, installed):
+    """Every rung of the serving ladder was exported, and every exported
+    executable installed: a warm or serialize failure swallowed for one rung
+    would otherwise pass as long as some other executable loaded."""
+    import glob
+
+    from transmogrifai_tpu import aot
+    metas = glob.glob(os.path.join(bundle, aot.AOT_DIR_PREFIX + "*",
+                                   aot.AOT_META_NAME))
+    require(len(metas) == 1, name, f"bundle holds AOT indexes {metas}")
+    with open(metas[0]) as fh:
+        exported = json.load(fh)["executables"]
+    rows = sorted({e["rows"] for e in exported})
+    rec["aot_exported"], rec["aot_installed"] = len(exported), installed
+    rec["aot_rows"] = rows
+    ladder = aot.ladder_sizes(int(os.environ.get(
+        "TRANSMOGRIFAI_AOT_LADDER_MAX", aot._DEFAULT_LADDER_MAX)))
+    missing = [r for r in ladder if r not in rows]
+    require(not missing, name,
+            f"ladder rungs {missing} were not exported (bundle has {rows})")
+    require(installed == len(exported), name,
+            f"{len(exported)} executables exported, {installed} installed")
 
 
 def predictions(scored, pred_name):
@@ -145,9 +198,11 @@ def phase_a(rows, report, reference):
 
     import bench
     from transmogrifai_tpu.evaluators import Evaluators
+    from transmogrifai_tpu.parallel.memory import last_plan
     from transmogrifai_tpu.telemetry import REGISTRY
 
-    with phase("A", report) as rec:
+    with phase("A", report) as (rec, log):
+        plan_before = last_plan()
         wf, batch, selector, _ = bench.dense_workflow(rows)
         model = wf.train()
         auroc = model.evaluate(Evaluators.BinaryClassification.auROC(),
@@ -168,9 +223,9 @@ def phase_a(rows, report, reference):
         require(pred.shape == (rows,) and np.isfinite(prob).all(), "A",
                 f"score() gave shape {pred.shape}, finite "
                 f"{bool(np.isfinite(prob).all())}")
-        check_failure_log(model, "A", rec)
+        check_failure_logs("A", rec, model.failure_log, log)
         check_auroc(auroc, "A", rec, reference)
-        check_memory("A", rec)
+        check_memory("A", rec, plan_before)
     return model, batch, pred_name
 
 
@@ -179,9 +234,11 @@ def phase_b(rows, report, reference, tmp):
 
     import bench
     from transmogrifai_tpu.evaluators import Evaluators
+    from transmogrifai_tpu.parallel.memory import last_plan
     from transmogrifai_tpu.workflow import WorkflowModel
 
-    with phase("B", report) as rec:
+    with phase("B", report) as (rec, log):
+        plan_before = last_plan()
         wf, batch, _ = bench.transmog_workflow(rows)
         model = wf.train()
         auroc = model.evaluate(Evaluators.BinaryClassification.auROC(),
@@ -202,14 +259,18 @@ def phase_b(rows, report, reference, tmp):
                    feature_vector_width=int(np.asarray(
                        model.selected_model.best_model.fitted["coef"]
                    ).shape[0]))
-        say(f"B: width {rec['feature_vector_width']}, loaded-vs-memory "
-            f"max |dp| {drift:.2e}")
+        check_bundle_aot("B", rec, bundle, loaded.aot_executables)
+        check_aot_counters("B", rec)
+        say(f"B: width {rec['feature_vector_width']}, "
+            f"{rec['aot_exported']} AOT executables exported, "
+            f"{rec['aot_installed']} installed, aot {rec['aot']}, "
+            f"loaded-vs-memory max |dp| {drift:.2e}")
         require(np.array_equal(pred, pred2) and drift <= 1e-6, "B",
                 f"loaded model disagrees with the in-memory one: "
                 f"{int((pred != pred2).sum())} labels, max |dp| {drift}")
-        check_failure_log(model, "B", rec)
+        check_failure_logs("B", rec, model.failure_log, log)
         check_auroc(auroc, "B", rec, reference)
-        check_memory("B", rec)
+        check_memory("B", rec, plan_before)
 
 
 def metric_value(text, name):
@@ -224,9 +285,8 @@ def phase_c(model, batch, pred_name, report, tmp):
     import numpy as np
 
     from transmogrifai_tpu.serving.server import start_server
-    from transmogrifai_tpu.telemetry import REGISTRY
 
-    with phase("C", report) as rec:
+    with phase("C", report) as (rec, log):
         bundle = os.path.join(tmp, "dense-model")
         model.save(bundle)
         server, _ = start_server(bundle, port=0)
@@ -271,7 +331,6 @@ def phase_c(model, batch, pred_name, report, tmp):
             metrics = call("/metrics")[1]
             health = json.loads(call("/healthz")[1])
             stats = server.engine.stats()
-            counters = REGISTRY.counters()
             rec.update(
                 requests=4, rows=n, served_vs_score_max_abs=drift,
                 health=health["health"],
@@ -279,20 +338,13 @@ def phase_c(model, batch, pred_name, report, tmp):
                 compiled_path_active=stats["compiled_path_active"],
                 serving={k: metric_value(metrics, k) for k in (
                     "fallback_batches_total", "online_traces_total",
-                    "breaker_demoted_batches_total")},
-                aot={k: counters.get(k, 0) for k in (
-                    "aot_registry.installs", "aot_registry.call_fallbacks",
-                    "aot_registry.install_failures", "aot.fallback")})
-            say(f"C: {rec['aot_executables']} AOT executables, health "
-                f"{rec['health']}, serving {rec['serving']}, aot "
-                f"{rec['aot']}, max |dp| {drift:.2e}")
-            require(rec["aot_executables"] > 0
-                    and rec["aot"]["aot_registry.installs"] > 0, "C",
-                    "bundle loaded with no AOT executables")
-            require(not any(rec["aot"][k] for k in (
-                "aot_registry.call_fallbacks",
-                "aot_registry.install_failures", "aot.fallback")), "C",
-                f"an AOT executable gave way to JIT: {rec['aot']}")
+                    "breaker_demoted_batches_total")})
+            check_bundle_aot("C", rec, bundle, rec["aot_executables"])
+            check_aot_counters("C", rec)
+            say(f"C: {rec['aot_exported']} AOT executables exported, "
+                f"{rec['aot_installed']} installed, health {rec['health']}, "
+                f"serving {rec['serving']}, aot {rec['aot']}, max |dp| "
+                f"{drift:.2e}")
             require(not any(rec["serving"].values()), "C",
                     f"serving left the compiled path: {rec['serving']}")
             require(rec["health"] == "SERVING"
@@ -300,6 +352,7 @@ def phase_c(model, batch, pred_name, report, tmp):
                     f"health {health}")
         finally:
             server.drain_and_close(timeout_s=30.0)
+        check_failure_logs("C", rec, log)
 
 
 def main(argv=None):
@@ -307,15 +360,7 @@ def main(argv=None):
     ap.add_argument("--cpu-reference", action="store_true",
                     help="run the same phases on the CPU backend and print "
                          "the reference values; prints no result line")
-    ap.add_argument("--rows-a", type=int, default=ROWS_A,
-                    help="phase A rows (only with --cpu-reference)")
-    ap.add_argument("--rows-b", type=int, default=ROWS_B,
-                    help="phase B rows (only with --cpu-reference)")
     args = ap.parse_args(argv)
-    if not args.cpu_reference and (args.rows_a, args.rows_b) != (ROWS_A,
-                                                                 ROWS_B):
-        ap.error("--rows-a/--rows-b need --cpu-reference: the chip run has "
-                 "one size")
 
     import jax
     dev = jax.devices()[0]
@@ -351,10 +396,15 @@ def main(argv=None):
     try:
         require(not python_paths, "start",
                 f"native modules fell back to Python: {python_paths}")
-        model, batch, pred_name = phase_a(args.rows_a, report,
-                                          args.cpu_reference)
-        phase_b(args.rows_b, report, args.cpu_reference, tmp)
+        model, batch, pred_name = phase_a(ROWS_A, report, args.cpu_reference)
+        phase_b(ROWS_B, report, args.cpu_reference, tmp)
         phase_c(model, batch, pred_name, report, tmp)
+        # whatever was recorded outside every phase's log (between phases,
+        # or by a background thread that outlived its phase)
+        from transmogrifai_tpu.resilience import DEFAULT_LOG
+        require(not bad_events(DEFAULT_LOG), "end",
+                f"the process-default failure log holds "
+                f"{DEFAULT_LOG.to_json()}")
     except SmokeFailure as e:
         sys.stderr.write(f"chip_smoke FAILED — {e}\n")
         return 1
@@ -366,10 +416,10 @@ def main(argv=None):
 
     if args.cpu_reference:
         say("CPU_REFERENCE " + json.dumps({
-            k: {"auroc": report[k]["auroc"],
-                "failure_events": report[k]["failure_events"],
+            k: {"failure_events": report[k]["failure_events"],
+                **({"auroc": report[k]["auroc"]} if k != "C" else {}),
                 **({"winner": report[k]["winner"]} if k == "A" else {})}
-            for k in ("A", "B")}))
+            for k in ("A", "B", "C")}))
         say("CPU reference run complete: " + json.dumps(report))
         return 0
     out_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),

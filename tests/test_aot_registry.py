@@ -381,6 +381,48 @@ class TestCacheWarmPublish:
             aot_registry._reset_jax_compile_cache()
 
 
+    def test_overlapping_suspensions_restore_the_cache_dir(self):
+        """save() suspends the persistent cache on the caller's thread while
+        a publish job may suspend it on the pre-trace thread: however the
+        blocks interleave, the directory is away while any is open and back,
+        unchanged, when the last one exits."""
+        saved = jax.config.jax_compilation_cache_dir
+        assert saved, "conftest/package import place a cache directory"
+        seen_set = []
+        errors = []
+        start = threading.Barrier(6)
+
+        def worker(i):
+            try:
+                start.wait(timeout=30)
+                for _ in range(40):
+                    with aot_registry.persistent_cache_suspended():
+                        if jax.config.jax_compilation_cache_dir is not None:
+                            seen_set.append(i)
+                        time.sleep(0.0005)   # hold it open: blocks overlap
+                        if i % 2:   # nest, as export -> publish can
+                            with aot_registry.persistent_cache_suspended():
+                                pass
+            except Exception as e:  # noqa: BLE001 — reported below
+                errors.append(e)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=worker, args=(i,))
+                       for i in range(6)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert errors == []
+        assert seen_set == []   # never restored under an open block
+        assert jax.config.jax_compilation_cache_dir == saved
+
+
 # ---------------------------------------------------------------------------
 # size-capped GC: registry entries + persistent compile cache
 # ---------------------------------------------------------------------------

@@ -7,8 +7,9 @@
   ``configure`` and the environments built for pool / host-group children —
   and is one fixed in-checkout directory when the environment says nothing;
 * ``chip_smoke.py`` refuses to run off the accelerator;
-* the serving pool gives worker *k* chip *k* and refuses more workers than
-  chips."""
+* the serving pool gives worker *k* chip *k*, refuses more workers than
+  chips, refuses a CPU fallback on a host that has an accelerator, and
+  checks where each worker says it computes."""
 
 import json
 import os
@@ -23,6 +24,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from test_aux_subsystems import make_records, train_small_model  # noqa: E402
 
 from transmogrifai_tpu.parallel import supervisor as sup  # noqa: E402
+from transmogrifai_tpu.profiling import compile_stats  # noqa: E402
 from transmogrifai_tpu.serving.engine import records_to_batch  # noqa: E402
 from transmogrifai_tpu.serving.pool import ServingPool  # noqa: E402
 from transmogrifai_tpu.telemetry import REGISTRY  # noqa: E402
@@ -63,6 +65,48 @@ def test_aot_load_runs_on_a_multi_device_host(tmp_path):
               if e.action in ("degraded", "fallback")] \
         if getattr(loaded, "failure_log", None) else []
     assert events == []
+
+
+def test_export_rebuilds_a_cache_loaded_program_before_serializing(tmp_path):
+    """XLA:CPU: an executable jax LOADED from the persistent compile cache
+    serializes into a payload that fails at its first call.  A program first
+    dispatched before ``save()`` may be one, so the export builds it again."""
+    from transmogrifai_tpu import aot_registry
+    assert not aot_registry.cache_loads_reserialize()    # this is the CPU
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs)
+    records = make_records(120)
+    try:
+        jax.config.update("jax_compilation_cache_dir",
+                          str(tmp_path / "xla-cache"))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        aot_registry._reset_jax_compile_cache()
+        model = train_small_model(records)[0].train()
+        pred = next(f.name for f in model.result_features)
+        batch = records_to_batch(model.raw_features, records[:37])
+
+        def score(m):
+            return np.asarray(m.score(batch=batch)[pred].values["probability"])
+        want = score(model)          # compiles the 37-row program: disk cache
+        # fresh-process simulation: in-memory executables gone, disk entries
+        # not — the next dispatch of the 37-row program is a cache LOAD
+        jax.clear_caches()
+        hits = compile_stats()["cache_hits"]
+        np.testing.assert_array_equal(score(model), want)
+        assert compile_stats()["cache_hits"] > hits, \
+            "precondition: the pre-save dispatch was a cache load"
+        bundle = str(tmp_path / "model")
+        model.save(bundle)
+        fallbacks = _counter("aot.fallback")
+        loaded = WorkflowModel.load(bundle)
+        assert loaded.aot_executables > 0
+        np.testing.assert_array_equal(score(loaded), want)   # CALLS it
+        assert _counter("aot.fallback") == fallbacks
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          saved[1])
+        aot_registry._reset_jax_compile_cache()
 
 
 _CACHE_CHILD = r"""
@@ -164,8 +208,7 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     import shutil
     shutil.copy(os.path.join(REPO, "chip_smoke.py"), str(tmp_path))
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
-    p = subprocess.run([sys.executable, "chip_smoke.py", "--cpu-reference",
-                        "--rows-a", "1000", "--rows-b", "1000"],
+    p = subprocess.run([sys.executable, "chip_smoke.py", "--cpu-reference"],
                        env=env, cwd=str(tmp_path), capture_output=True,
                        text=True, timeout=120)
     assert p.returncode != 0
@@ -210,3 +253,60 @@ class TestPoolDevices:
         monkeypatch.setattr(sup, "probe_devices", lambda **kw: 1 / 0)
         pool = self._pool(tmp_path, 2)
         assert pool._resolve_device_env() == [{}, {}]
+
+    def test_cpu_fallback_on_an_accelerator_host_fails_the_pool(
+            self, tmp_path, monkeypatch):
+        """Unpinned, a probe child that cannot have the chip (this process
+        or another holds it) continues on the CPU; on a host that has an
+        accelerator the pool must refuse, not pin its workers to the CPU."""
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(sup, "accelerator_expected", lambda: True)
+        asked = {}
+
+        def probe(**kw):
+            asked.update(kw)
+            return sup.ProbeVerdict(
+                status=sup.DEGRADED if kw["expect_accelerator"]
+                else sup.AVAILABLE, platform="cpu", device_count=1,
+                cause="accelerator expected but probe resolved cpu")
+        monkeypatch.setattr(sup, "probe_devices", probe)
+        with pytest.raises(RuntimeError, match="degraded"):
+            self._pool(tmp_path, 1).start()
+        assert asked["expect_accelerator"] is True
+
+    def test_a_worker_on_another_platform_fails_the_pool(self, tmp_path,
+                                                         monkeypatch):
+        """The worker writes where it computes into its ready file; the pool
+        compares that with what it resolved."""
+        import threading
+        import time
+        self._probe(monkeypatch, status=sup.AVAILABLE, platform="tpu",
+                    device_kind="TPU v5 lite", device_count=1)
+        pool = self._pool(tmp_path, 1)
+        pool._device_env = pool._resolve_device_env()
+        slot = pool.slots[0]
+        ready = os.path.join(pool.run_dir, "worker-0.ready.json")
+        with open(ready, "w") as fh:
+            json.dump({"workerId": "0", "pid": 1, "port": 1, "adminPort": 2,
+                       "device": {"platform": "cpu", "kind": "cpu",
+                                  "count": 1}}, fh)
+        with pytest.raises(RuntimeError, match="serves from 'cpu'"):
+            pool._wait_ready(slot, time.monotonic() + 5)
+
+
+class TestAcceleratorExpected:
+    def test_a_pinned_platform_decides(self, monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        assert sup.accelerator_expected() is True
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        assert sup.accelerator_expected() is False
+
+    def test_unpinned_it_is_the_device_nodes(self, monkeypatch):
+        import glob
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setattr(glob, "glob", lambda pat: [])
+        assert sup.accelerator_expected() is False
+        monkeypatch.setattr(
+            glob, "glob",
+            lambda pat: ["/dev/vfio/0"] if pat.startswith("/dev/vfio") else [])
+        assert sup.accelerator_expected() is True

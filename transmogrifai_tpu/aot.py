@@ -148,12 +148,15 @@ def _export_bundle_inner(model, bundle_dir: str) -> int:
     # every trace in here — the ladder warm-up AND a rebuild's re-trace —
     # stays off the global trace_count() books: a save() running
     # concurrently with a serving engine (lifecycle retrain+promote) must not
-    # land export traces inside the engine's online-trace window.  And the
-    # persistent compile cache is suspended throughout: what the warm-up
-    # compiles is what gets serialized, and only an executable BUILT here
-    # serializes into something that runs (aot_registry.fresh_record)
+    # land export traces inside the engine's online-trace window.  Where
+    # only an executable BUILT in this process serializes into something
+    # that runs (XLA:CPU, aot_registry.cache_loads_reserialize) the
+    # persistent compile cache is suspended throughout, so what the warm-up
+    # compiles is a real build
+    fresh_only = not aot_registry.cache_loads_reserialize()
     with span("workflow.aot_export", sizes=sizes), suppress_trace_count(), \
-            aot_registry.persistent_cache_suspended():
+            (aot_registry.persistent_cache_suspended() if fresh_only
+             else contextlib.nullcontext()):
         # warm: score a synthetic record at every ladder size so the program
         # table holds exactly the serve-shaped entries (same monoid-zero
         # record ScoringEngine warms with)
@@ -229,13 +232,12 @@ def _export_bundle_inner(model, bundle_dir: str) -> int:
                 jobs = [(None, None)]
             for j, (sig, specs) in enumerate(jobs):
                 try:
-                    # a key first dispatched BEFORE this export may hold
-                    # a cache-loaded executable — fresh_record rebuilds it
-                    rec = aot_registry.fresh_record(
-                        program._jitted[key][0],
-                        lambda: _serialize_key(program, key, specs=specs,
-                                               sig=sig),
-                        maybe_loaded=key in program._cache_loaded)
+                    if fresh_only and key in before and j == 0:
+                        # first dispatched BEFORE this export: its memoized
+                        # executable may be a cache load — drop it, the
+                        # lower().compile() below builds it again
+                        program._jitted[key][0].clear_cache()
+                    rec = _serialize_key(program, key, specs=specs, sig=sig)
                 except Exception as e:  # noqa: BLE001 — best effort
                     record_failure("workflow.save", "swallowed", e,
                                    point="checkpoint.aot",

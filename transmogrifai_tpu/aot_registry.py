@@ -526,7 +526,19 @@ def _dynamic_kwarg_names(in_tree: Any) -> List[str]:
     return []
 
 
-# -- fresh serialization (satellite: cache-loaded executables) ---------------
+# -- fresh serialization (cache-loaded executables) ---------------------------
+
+def cache_loads_reserialize() -> bool:
+    """Does an executable jax LOADED from the persistent compile cache
+    serialize into something that runs?  On XLA:TPU yes (PR 21 chip run:
+    five kinds of program and the save -> serve flow, every compile a cache
+    load, zero fallbacks).  On XLA:CPU with jax 0.9 no: the payload
+    serializes, deserializes without complaint, and fails at its first CALL
+    ("Function ..._fusion not found"), asynchronously, past every except —
+    there only an executable BUILT in this process may be serialized."""
+    import jax
+    return jax.default_backend() != "cpu"
+
 
 def _reset_jax_compile_cache() -> None:
     """Drop jax's memoized compilation-cache object so the next compile
@@ -536,39 +548,42 @@ def _reset_jax_compile_cache() -> None:
     compilation_cache.reset_cache()
 
 
+# the suspension is process-wide (a jax config value), entered from save()
+# on the caller's thread and from publish jobs on the pre-trace thread: the
+# outermost entry takes the directory away and the last exit puts it back
+_SUSPEND_LOCK = threading.Lock()
+_SUSPENDED = {"depth": 0, "dir": None}
+
+
 @contextlib.contextmanager
 def persistent_cache_suspended():
     """Every compile inside the block is a real backend build: the
     persistent cache dir is unset and jax's memoized cache object dropped
-    (process-wide — other threads compile uncached meanwhile).  The
-    directory is restored on exit, never re-pointed."""
+    (process-wide — other threads compile uncached meanwhile).  Blocks may
+    nest and overlap across threads; the directory comes back, unchanged,
+    when the last one exits."""
     import jax
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    _reset_jax_compile_cache()
+    with _SUSPEND_LOCK:
+        if _SUSPENDED["depth"] == 0:
+            _SUSPENDED["dir"] = jax.config.jax_compilation_cache_dir
+            jax.config.update("jax_compilation_cache_dir", None)
+            _reset_jax_compile_cache()
+        _SUSPENDED["depth"] += 1
     try:
         yield
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        _reset_jax_compile_cache()
-
-
-@contextlib.contextmanager
-def fresh_compile_env(jitted):
-    """Make ``jitted.lower(...).compile()`` inside the block a real backend
-    build: the persistent cache is suspended and ``jitted``'s own in-memory
-    traces/executables are cleared (they would otherwise hand the same
-    cache-loaded executable straight back; its later dispatches re-trace
-    once)."""
-    with persistent_cache_suspended():
-        jitted.clear_cache()
-        yield
+        with _SUSPEND_LOCK:
+            _SUSPENDED["depth"] -= 1
+            if _SUSPENDED["depth"] == 0:
+                jax.config.update("jax_compilation_cache_dir",
+                                  _SUSPENDED["dir"])
+                _reset_jax_compile_cache()
 
 
 def payload_roundtrips(rec: bytes) -> bool:
     """Cheap publishability check: the payload deserializes over the devices
     it was compiled for.  It does NOT prove the executable runs — see
-    :func:`fresh_record` for the hazard only provenance can rule out."""
+    :func:`cache_loads_reserialize`."""
     try:
         load_executable(pickle.loads(rec))
         return True
@@ -576,45 +591,24 @@ def payload_roundtrips(rec: bytes) -> bool:
         return False
 
 
-def fresh_record(jitted, build, maybe_loaded: bool = False) -> bytes:
-    """``build() -> bytes`` lowers ``jitted``, compiles and serializes one
-    executable record; returns a record that came from a FRESH backend
-    compile.
-
-    An executable jax LOADED from the persistent compile cache must not be
-    serialized: on jax 0.9 it serializes and deserializes without complaint
-    and its first CALL then fails (XLA:CPU: "Function ..._fusion not
-    found"), asynchronously, past every except.  jax keeps no provenance on
-    an executable and memoizes it for later ``lower().compile()`` calls, so
-    the callers keep it: ``maybe_loaded`` says the dispatch that first
-    compiled this program took a cache hit (``profiling.thread_cache_hits``
-    before/after), and the same bracket around ``build()`` catches a hit
-    taken right here.  Either way — or when the payload does not even
-    deserialize — ``build`` runs once more under :func:`fresh_compile_env`:
-    a cache-warm process neither ships garbage nor silently skips shipping.
-    Shared by the registry publish path and the bundle export loop."""
-    from .profiling import thread_cache_hits
-    if not maybe_loaded:
-        hits = thread_cache_hits()
-        rec = build()
-        if thread_cache_hits() == hits and payload_roundtrips(rec):
-            return rec
-    _count("aot_registry.recompiles_for_publish")
-    with fresh_compile_env(jitted):
-        rec = build()
-    if not payload_roundtrips(rec):
-        raise RuntimeError("payload does not deserialize even after a "
-                           "cache-suspended rebuild")
-    return rec
-
-
 def serialize_fresh(fn, args: tuple = (), kwargs: Optional[Dict] = None,
                     label: str = "", maybe_loaded: bool = False
                     ) -> Optional[bytes]:
     """The registry record (serialized executable + pytrees + execution
-    devices) of a fresh build of jitted ``fn`` at ``fn.lower(*args,
-    **kwargs)`` (see :func:`fresh_record`), or None with a ``swallowed``
-    note — publish is strictly optional."""
+    devices) of jitted ``fn`` at ``fn.lower(*args, **kwargs)``, or None with
+    a ``swallowed`` note — publish is strictly optional.
+
+    Where a cache-loaded executable must not be serialized
+    (:func:`cache_loads_reserialize`) the record comes from a build made in
+    this process.  jax keeps no provenance on an executable and hands the
+    memoized one back to ``lower().compile()``, so the caller says whether
+    the dispatch that first compiled the program took a cache hit
+    (``maybe_loaded``; ``profiling.thread_cache_hits`` before/after), and
+    the same bracket here catches a hit taken by this compile.  Either way
+    the program is compiled once more with the persistent cache suspended
+    and ``fn``'s own memo cleared (its later dispatches re-trace once): a
+    cache-warm process neither ships garbage nor silently skips shipping."""
+    from .profiling import thread_cache_hits
     from .resilience import record_failure
     from jax.experimental.serialize_executable import serialize
 
@@ -629,7 +623,20 @@ def serialize_fresh(fn, args: tuple = (), kwargs: Optional[Dict] = None,
                     buf, protocol=4)
         return buf.getvalue()
     try:
-        return fresh_record(fn, _build, maybe_loaded)
+        fresh_only = not cache_loads_reserialize()
+        rebuild = fresh_only and maybe_loaded
+        if not rebuild:
+            hits = thread_cache_hits()
+            rec = _build()
+            rebuild = fresh_only and thread_cache_hits() != hits
+        if rebuild:
+            _count("aot_registry.recompiles_for_publish")
+            with persistent_cache_suspended():
+                fn.clear_cache()
+                rec = _build()
+        if not payload_roundtrips(rec):
+            raise RuntimeError("payload does not deserialize")
+        return rec
     except Exception as e:  # noqa: BLE001 — publish is strictly optional
         record_failure("aot_registry", "swallowed", e,
                        point="aot_registry.serialize", detail=label)
@@ -640,9 +647,7 @@ def _queue_publish(key: str, label: str, fn, args: tuple, kwargs: Dict,
                    meta: Optional[Dict[str, Any]] = None,
                    maybe_loaded: bool = False) -> None:
     """Serialize + publish on the background pre-trace thread: the publish
-    compile never lands inside a foreground fit/score wall, and
-    ``aot.pretrace_drain`` (which export_bundle already calls before
-    toggling the cache flag) serializes us against save-time exports."""
+    compile never lands inside a foreground fit/score wall."""
     with _LOCK:
         if key in _PUBLISHED:
             return

@@ -139,10 +139,6 @@ class ScoreProgram:
         # deserialized pre-compiled executable rather than a jit wrapper
         self._input_specs: Dict[Tuple, Any] = {}
         self._aot_installed: Set[Tuple] = set()
-        # keys whose jit entry took a persistent-compile-cache HIT on some
-        # dispatch: its executable may be a cache load, which export must
-        # rebuild before serializing (aot_registry.fresh_record)
-        self._cache_loaded: Set[Tuple] = set()
         # aval-variant seam (ISSUE 19): the program-table key carries only
         # (stage uids, keep_intermediate, rows) — sparse frontier columns
         # add an nnz-capacity degree of freedom the key cannot see.  Every
@@ -444,18 +440,14 @@ class ScoreProgram:
                     REGISTRY.counter("aot.fallback").inc()
                     self._aot_variants.pop((key, sig), None)
         jitted, canon_out_map = self._jitted[key]
-        from .profiling import (cost_analysis_enabled, record_program_cost,
-                                thread_cache_hits)
+        from .profiling import cost_analysis_enabled, record_program_cost
         if cost_analysis_enabled():
             record_program_cost("fused_transform", jitted, (arrays,))
         try:
             # chaos hook: an injected fault here exercises the eager-segment
             # demotion below, the same path a device dispatch failure takes
             maybe_inject("compiled.segment", key=run[0].uid)
-            hits = thread_cache_hits()
             out_c = jitted(arrays)
-            if thread_cache_hits() != hits:
-                self._cache_loaded.add(key)
             out = {n: out_c[c] for n, c in canon_out_map.items()}
         except _StageTraceError:
             self._jitted.pop(key, None)

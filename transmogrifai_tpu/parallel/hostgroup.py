@@ -65,8 +65,8 @@ from ..resilience import record_failure
 from ..telemetry import (REGISTRY, TRACEPARENT_ENV, TraceContext,
                          current_trace_context, event, span)
 from .supervisor import (AVAILABLE, DEGRADED, OUTAGE, _STATE_CODES,
-                         maybe_write_outage_record, probe_devices,
-                         supervisor_enabled)
+                         accelerator_expected, maybe_write_outage_record,
+                         probe_devices, supervisor_enabled)
 
 # -- the rank-side contract: env vars the launcher exports ------------------
 ENV_RANK = "TRANSMOGRIFAI_HOSTGROUP_RANK"
@@ -749,13 +749,16 @@ def launch_hosts(cmd: Sequence[str], hosts: int, *,
     # (a native init hang) becomes a typed verdict BEFORE any rank exists,
     # instead of N ranks hanging in init.  Run from a launcher that already
     # owns the chip, the probe child cannot have it and reports an outage
-    # (platform pinned) or the CPU — the launcher must stay off the backend.
+    # (platform pinned) or, unpinned, the CPU — degraded on a host that has
+    # an accelerator, and the launch aborts: the launcher must stay off the
+    # backend.
     if preflight is None:
         preflight = supervisor_enabled()
     if preflight:
-        verdict = probe_devices(key="hostgroup-preflight")
+        verdict = probe_devices(key="hostgroup-preflight",
+                                expect_accelerator=accelerator_expected())
         result.preflight = verdict.to_json()
-        if verdict.status == OUTAGE:
+        if not verdict.ok:
             result.reason = (f"preflight probe: {verdict.status} "
                              f"({verdict.cause})")
             maybe_write_outage_record(

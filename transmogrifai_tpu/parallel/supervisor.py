@@ -323,6 +323,24 @@ def single_chip_env(chip: int) -> Dict[str, str]:
             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
             "TPU_PROCESS_BOUNDS": "1,1,1"}
 
+
+def accelerator_expected() -> bool:
+    """Should a fresh process on this host get an accelerator?  Yes when
+    ``JAX_PLATFORMS`` pins one, or when the host shows an accelerator's
+    device nodes (what libtpu itself looks for: ``/dev/accel*``, or
+    ``/dev/vfio/<n>`` on v5e and later).  A launcher passes this as
+    ``expect_accelerator`` to its probe: with the platform unpinned, a child
+    that cannot have the chip — this process or another holds it —
+    continues on the CPU, and the probe must call that degraded instead of
+    handing the children a CPU pin.  An operator who wants the CPU on such
+    a host says ``JAX_PLATFORMS=cpu``."""
+    import glob
+    pinned = (os.environ.get("JAX_PLATFORMS") or "").split(",")[0].strip()
+    if pinned:
+        return pinned != "cpu"
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
 #: Chaos preludes prepended to the probe child — the injection surface the
 #: train-side chaos harness and CI smoke use to fake the init-hang failure
 #: modes in a real subprocess (``hang_ignore_sigterm`` is the mode plain

@@ -261,6 +261,8 @@ def worker_main(config_path: str) -> int:
     server on an ephemeral port, draining cleanly on SIGTERM."""
     import contextlib
 
+    import jax
+
     from ..checkpoint import preemption_guard, shutdown_requested
     from ..telemetry import TraceContext, Tracer, use_tracer
     from .overload import OverloadConfig
@@ -328,7 +330,12 @@ def worker_main(config_path: str) -> int:
         _atomic_write_json(
             os.path.join(cfg["runDir"], f"worker-{worker_id}.ready.json"),
             {"workerId": worker_id, "pid": os.getpid(),
-             "port": traffic.port, "adminPort": admin.port})
+             "port": traffic.port, "adminPort": admin.port,
+             # where this worker's engine computes — the pool checks it
+             # against what it resolved
+             "device": {"platform": jax.devices()[0].platform,
+                        "kind": jax.devices()[0].device_kind,
+                        "count": len(jax.devices())}})
         print(f"worker {worker_id} serving {served} on "
               f":{traffic.port} (admin :{admin.port})", flush=True)
         try:
@@ -439,6 +446,7 @@ class ServingPool:
             "tenantMemoryBudgetBytes": tenant_memory_budget_bytes}
         self.slots = [self._make_slot(i) for i in range(self.workers)]
         self._device_env: List[Dict[str, str]] = []  # per worker, by start()
+        self._platform: Optional[str] = None  # what every worker must report
         self._supervisor: Optional[threading.Thread] = None
 
     # -- spawning ----------------------------------------------------------
@@ -462,14 +470,19 @@ class ServingPool:
         backend and the workers just inherit it."""
         pinned = (os.environ.get("JAX_PLATFORMS") or "").split(",")[0].strip()
         if pinned == "cpu":
+            self._platform = "cpu"
             return [{} for _ in self.slots]
-        from ..parallel.supervisor import (OUTAGE, probe_devices,
-                                           single_chip_env)
-        verdict = probe_devices(key="serving-pool")
-        if verdict.status == OUTAGE:
+        from ..parallel.supervisor import (accelerator_expected,
+                                           probe_devices, single_chip_env)
+        verdict = probe_devices(key="serving-pool",
+                                expect_accelerator=accelerator_expected())
+        if not verdict.ok:
             raise RuntimeError(
-                f"serving pool: device probe says outage ({verdict.cause}) "
-                "— is this process (or another) holding the accelerator?")
+                f"serving pool: device probe says {verdict.status} "
+                f"({verdict.cause}) — is this process (or another) holding "
+                "the accelerator?  Set JAX_PLATFORMS=cpu to serve from the "
+                "CPU on purpose")
+        self._platform = verdict.platform
         if verdict.platform == "cpu":
             return [{"JAX_PLATFORMS": "cpu"} for _ in self.slots]
         if self.workers > verdict.device_count:
@@ -532,9 +545,16 @@ class ServingPool:
                 try:
                     with open(ready_path) as f:
                         slot.ready = json.load(f)
-                    return
                 except (OSError, ValueError):
                     pass  # mid-rename; retry
+            if slot.ready is not None:
+                got = slot.ready.get("device", {}).get("platform")
+                if got != self._platform:
+                    raise RuntimeError(
+                        f"worker {slot.worker_id} serves from {got!r}, the "
+                        f"pool resolved {self._platform!r} "
+                        f"(log: {slot.log_path})")
+                return
             if slot.proc is not None and slot.proc.poll() is not None:
                 raise RuntimeError(
                     f"worker {slot.worker_id} exited rc="
@@ -667,6 +687,7 @@ class ServingPool:
                   {"workerId": s.worker_id, "alive": s.alive,
                    "pid": (s.ready or {}).get("pid"),
                    "adminPort": (s.ready or {}).get("adminPort"),
+                   "device": (s.ready or {}).get("device"),
                    "restarts": s.restarts} for s in self.slots]}
         if self.model_root:
             st["modelRoot"] = self.model_root

@@ -23,9 +23,16 @@ train-start prefetch >= 100,000 rows, bf16 matrix storage >= 64M elements,
 device hash-count assembly >= 4M elements).
 
 It refuses to run unless ``jax.devices()[0].platform == "tpu"`` and never
-sets the platform itself.  The last stdout line is one JSON object,
-``{"ok": true, "device": {...}, ..., "claim": null}`` — printed only when
-every check passed.  No number it prints is a benchmark result.
+sets the platform itself.  The last stdout line is one JSON object with
+exactly these keys, the device as jax reports it:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+``"ok": true`` is printed only when every check passed; a phase that failed
+on the chip ends with ``"ok": false`` and exit code 1; off the accelerator
+nothing ran and no result is printed.  The line before it, ``summary {...,
+"claim": null}``, carries the per-phase walls and compile counters.  No
+number it prints is a benchmark result.
 
     JAX_PLATFORMS=cpu python chip_smoke.py --cpu-reference
 
@@ -79,6 +86,22 @@ def require(cond, phase, what):
 
 def say(msg):
     print(msg, flush=True)
+
+
+def jax_device():
+    """The device as jax reports it."""
+    import jax
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
+def result_line(ok, device):
+    """The last stdout line: exactly ``ok`` and ``device`` — the driver
+    refuses any other shape."""
+    return json.dumps({"ok": bool(ok), "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
 
 
 @contextlib.contextmanager
@@ -363,11 +386,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     import jax
-    dev = jax.devices()[0]
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices())}
+    device = jax_device()
     want = "cpu" if args.cpu_reference else "tpu"
-    if dev.platform != want:
+    if device["platform"] != want:
         sys.stderr.write(
             f"chip_smoke: needs platform {want!r}, jax found {device}; "
             "nothing was run\n")
@@ -407,6 +428,8 @@ def main(argv=None):
                 f"{DEFAULT_LOG.to_json()}")
     except SmokeFailure as e:
         sys.stderr.write(f"chip_smoke FAILED — {e}\n")
+        if not args.cpu_reference:
+            say(result_line(False, device))
         return 1
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
@@ -427,12 +450,12 @@ def main(argv=None):
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "chip_smoke_report.json"), "w") as fh:
         json.dump(report, fh, indent=1)
-    print(json.dumps({"ok": True, "device": device,
-                      "phases": {k: {"wall_s": report[k]["wall_s"],
-                                     "compile": report[k]["compile"]}
-                                 for k in ("A", "B", "C")},
-                      "wall_s": report["wall_s"], "claim": None}),
-          flush=True)
+    say("summary " + json.dumps({
+        "phases": {k: {"wall_s": report[k]["wall_s"],
+                       "compile": report[k]["compile"]}
+                   for k in ("A", "B", "C")},
+        "wall_s": report["wall_s"], "claim": None}))
+    say(result_line(True, device))
     return 0
 
 

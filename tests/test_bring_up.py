@@ -215,6 +215,49 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     assert not [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
 
 
+def test_chip_smoke_result_line_has_exactly_the_contract_keys(
+        monkeypatch, capsys, tmp_path):
+    """The driver refuses any last line but ``{"ok", "device": {"platform",
+    "kind", "count"}}``: the walls and ``"claim": null`` go on the summary
+    line before it, and a failed phase ends with ``"ok": false``."""
+    import json
+    monkeypatch.syspath_prepend(REPO)
+    import chip_smoke
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    for ok in (True, False):
+        assert json.loads(chip_smoke.result_line(ok, device)) == {
+            "ok": ok, "device": device}
+
+    # main() with the device faked and the phases stubbed out
+    def stub(name):
+        def run(*args):
+            report = next(a for a in args if isinstance(a, dict))
+            report[name] = {"wall_s": 0.0, "compile": {}}
+            return None, None, None
+        return run
+    # other tests of this process have left their drills in the default log
+    from transmogrifai_tpu import resilience
+    monkeypatch.setattr(resilience, "DEFAULT_LOG", resilience.FailureLog())
+    monkeypatch.setattr(chip_smoke, "jax_device", lambda: dict(device))
+    monkeypatch.setattr(chip_smoke, "__file__",
+                        str(tmp_path / "chip_smoke.py"))
+    for name in "abc":
+        monkeypatch.setattr(chip_smoke, f"phase_{name}", stub(name.upper()))
+    assert chip_smoke.main([]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    assert lines[-2].startswith("summary ")
+    assert json.loads(lines[-2][len("summary "):])["claim"] is None
+    assert (tmp_path / "chiprun_out" / "chip_smoke_report.json").exists()
+
+    def failing(*args):
+        raise chip_smoke.SmokeFailure("phase A: injected")
+    monkeypatch.setattr(chip_smoke, "phase_a", failing)
+    assert chip_smoke.main([]) == 1
+    out = capsys.readouterr().out.splitlines()
+    assert json.loads(out[-1]) == {"ok": False, "device": device}
+
+
 class TestPoolDevices:
     def _pool(self, tmp_path, workers):
         return ServingPool(str(tmp_path / "model"), workers=workers,

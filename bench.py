@@ -268,36 +268,6 @@ def peak_flops(device_kind: str) -> float:
             f"{sorted(PEAK_FLOPS)})") from None
 
 
-def _roofline_aux(selector_wall_s, on_accel):
-    """Achieved-FLOP/s diagnostic from the XLA cost
-    analyses the fit path recorded.  Program flops count ONE execution of
-    each recorded program (the batched grid fits run once per family;
-    per-round GBT programs are not counted), so `peak_fraction` is a floor
-    of true utilization — enough to tell compute-bound from link-bound."""
-    from transmogrifai_tpu.profiling import (PROGRAM_COSTS,
-                                             flush_program_costs)
-    # the fit path only stashed cheap lowerings during the timed wall; the
-    # compile-cache analysis passes run here, OUTSIDE any measured region
-    flush_program_costs()
-    if not PROGRAM_COSTS:
-        return {}
-    fit_flops = sum(c.get("flops") or 0.0 for n, c in PROGRAM_COSTS.items()
-                    if n.endswith("_fit"))
-    out = {"programs": {n: {k: round(v, 3) if isinstance(v, float) else v
-                            for k, v in c.items()}
-                        for n, c in PROGRAM_COSTS.items()}}
-    if fit_flops and selector_wall_s:
-        ach = fit_flops / selector_wall_s
-        out["fit_flops_counted"] = fit_flops
-        out["achieved_fit_gflops_per_s"] = round(ach / 1e9, 1)
-        if on_accel:
-            import jax
-            peak = peak_flops(jax.devices()[0].device_kind)
-            out["peak_flops_assumed"] = peak
-            out["peak_fraction_floor"] = round(ach / peak, 4)
-    return out
-
-
 def _baseline(key):
     try:
         with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -438,7 +408,6 @@ def run_dense(N: int, on_accel: bool, platform: str):
             "vs_baseline_8core_lpt": (round(lpt8 / wall, 3)
                                       if (lpt8 and at_ref) else None),
             **phases,
-            "roofline": _roofline_aux(phases.get("selector_s"), on_accel),
             "telemetry": _telemetry_aux(tracer),
             "memory": _memory_aux(),
             "registry": _registry_aux(),
@@ -518,7 +487,6 @@ def run_transmog(N: int, on_accel: bool, platform: str):
             "vs_baseline_8core_lpt": (round(lpt8 / wall, 3)
                                       if (lpt8 and at_ref) else None),
             **phases,
-            "roofline": _roofline_aux(phases.get("selector_s"), on_accel),
             "telemetry": _telemetry_aux(tracer),
             "memory": _memory_aux(),
             "registry": _registry_aux(),
@@ -562,12 +530,7 @@ def run_score(N: int, on_accel: bool, platform: str):
     model.score(batch=batch)
     cols2, _ = make_transmog_columns(N, seed=7)
     batch2 = ColumnBatch(cols2, N)
-    from transmogrifai_tpu.profiling import (PROGRAM_COSTS,
-                                             flush_program_costs,
-                                             host_link_bytes)
-    # resolve the warmup's stashed lowering BEFORE the timed region so the
-    # analysis pass cannot leak into the measured wall
-    flush_program_costs()
+    from transmogrifai_tpu.profiling import host_link_bytes
     link0 = host_link_bytes()
     t0 = time.time()
     scored = model.score(batch=batch2)
@@ -577,14 +540,6 @@ def run_score(N: int, on_accel: bool, platform: str):
     rows_per_s = round(N / wall)
     proxy = _baseline("score1m_rows_per_s")
     at_ref = on_accel and N == 1_000_000
-    roofline = {}
-    prog = PROGRAM_COSTS.get("fused_transform")
-    if prog and prog.get("flops"):
-        # end-to-end: the wall includes the host prologue, so this is the
-        # achieved rate of the WORKLOAD, not the program in isolation
-        roofline = {"fused_transform": prog,
-                    "achieved_gflops_per_s_end_to_end":
-                        round(prog["flops"] / wall / 1e9, 2)}
     return {
         "metric": f"WorkflowModel.score throughput (transmogrified width "
                   f"{fv_width}, {N} rows, warm, {platform})",
@@ -594,8 +549,7 @@ def run_score(N: int, on_accel: bool, platform: str):
                         if (proxy and at_ref) else 1.0),
         "aux": {"rows": N, "wall_s": round(wall, 2),
                 "feature_vector_width": fv_width, "platform": platform,
-                "host_link_mb": round((host_link_bytes() - link0) / 1e6, 1),
-                "roofline": roofline},
+                "host_link_mb": round((host_link_bytes() - link0) / 1e6, 1)},
     }
 
 
@@ -1541,9 +1495,6 @@ def run_in_this_process(kind: str, name: str, args) -> int:
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
-    # roofline diagnostics: the fit/transform paths record XLA cost analyses
-    # of their dominant programs (profiling.record_program_cost)
-    os.environ.setdefault("TRANSMOGRIFAI_COST_ANALYSIS", "1")
     if kind == "step":
         rec = STEPS[name](*args)
     else:

@@ -373,7 +373,10 @@ class TestCompileListenerIdempotence:
         """Count registrations instead of actually registering (the real
         listeners are already installed process-wide)."""
         from jax import monitoring
-        calls = {"duration": 0, "event": 0}
+        calls = {"duration": 0, "event": 0, "scalar": 0}
+        monkeypatch.setattr(
+            monitoring, "register_scalar_listener",
+            lambda fn: calls.__setitem__("scalar", calls["scalar"] + 1))
         monkeypatch.setattr(
             monitoring, "register_event_duration_secs_listener",
             lambda fn: calls.__setitem__("duration", calls["duration"] + 1))
@@ -388,7 +391,7 @@ class TestCompileListenerIdempotence:
     def test_double_install_registers_once(self, fake_monitoring):
         assert profiling.install_compile_listeners() is True
         assert profiling.install_compile_listeners() is True
-        assert fake_monitoring == {"duration": 1, "event": 1}
+        assert fake_monitoring == {"duration": 1, "event": 1, "scalar": 1}
 
     def test_concurrent_install_registers_once(self, fake_monitoring):
         barrier = threading.Barrier(8)
@@ -402,7 +405,7 @@ class TestCompileListenerIdempotence:
             t.start()
         for t in threads:
             t.join()
-        assert fake_monitoring == {"duration": 1, "event": 1}
+        assert fake_monitoring == {"duration": 1, "event": 1, "scalar": 1}
         assert profiling._COMPILE_LISTENERS_INSTALLED[0]
 
 

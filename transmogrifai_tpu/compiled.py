@@ -35,6 +35,7 @@ import numpy as np
 from .columns import Column, ColumnBatch
 from .resilience import maybe_inject, record_failure
 from .stages.base import Transformer
+from .telemetry import span
 
 _WIRE_SEP = "\x00"      # wire-entry names: "<uid>\x00<key>" — never a column
 
@@ -224,8 +225,9 @@ class ScoreProgram:
             try:
                 for i, (is_dev, stages) in enumerate(segments):
                     if not is_dev:
-                        for st in stages:
-                            b = st.transform_batch(b)
+                        with span("transform.host_stage", stages=len(stages)):
+                            for st in stages:
+                                b = st.transform_batch(b)
                         continue
                     later = [st for _, seg in segments[i + 1:] for st in seg]
                     b = self._apply_run(b, stages, later, keep_intermediate)
@@ -251,96 +253,13 @@ class ScoreProgram:
             needed.update(f.name for f in st.input_features)
         return [n for n in produced if n in needed]
 
-    def _apply_run(self, batch: ColumnBatch, run: List[Transformer],
-                   later: List[Transformer], keep_intermediate: bool
-                   ) -> ColumnBatch:
-        # staged = stages whose inputs are NOT all array-resident right now;
-        # their host prologue supplies wire arrays instead of columns
-        staged_fns: Dict[str, Any] = {}
-        wires: Dict[str, Any] = {}
-        for st in run:
-            if all(batch[f.name].is_device for f in st.input_features
-                   if f.name in batch):
-                continue
-            res = None
-            try:
-                res = st.transform_staged(batch)
-            except Exception as e:  # noqa: BLE001 — demotion signal
-                raise _StageTraceError(st.uid, e) from e
-            if res is None:
-                raise _StageTraceError(st.uid, TypeError(
-                    "stage has host inputs and no staged form"))
-            wire, fn = res
-            staged_fns[st.uid] = fn
-            for k, v in wire.items():
-                wires[st.uid + _WIRE_SEP + k] = v
-
-        key = (tuple(st.uid for st in run), keep_intermediate, len(batch))
-        frontier = sorted({f.name for st in run
-                           if st.uid not in staged_fns
-                           for f in st.input_features if f.name in batch})
-        # canonical positional names at the jit boundary: stage uids are
-        # process-global counters, so real column/wire names differ between
-        # otherwise identical workflows — with them as pytree keys every new
-        # process MISSES the persistent compilation cache and pays a full
-        # XLA recompile of the fused program
-        canon_in = {n: f"a{i}" for i, n in enumerate(
-            frontier + sorted(wires))}
-        # _partition simulates host-stage outputs by kind; validate against
-        # the actual columns and demote consumers of any misprediction (e.g.
-        # a numeric-kinded host stage that emitted an object array)
-        host_cols = [n for n in frontier if not batch[n].is_device]
-        if host_cols:
-            offender = next(st for st in run if st.uid not in staged_fns
-                            and any(f.name in host_cols
-                                    for f in st.input_features))
-            raise _StageTraceError(offender.uid, TypeError(
-                f"frontier columns {host_cols} are host-resident"))
-        out_names = self._wanted_outputs(run, later, keep_intermediate)
-        kinds = {n: batch[n].kind for n in frontier}
-        metas_in = {n: batch[n].meta for n in frontier}
-        n_rows_static = len(batch)
-
-        fresh = key not in self._jitted
-        if fresh:
-            metas_out: Dict[str, Any] = {}
-            fns_at_trace = dict(staged_fns)
-            inv_in = {c: n for n, c in canon_in.items()}
-            canon_out = {n: f"o{i}" for i, n in enumerate(out_names)}
-
-            def traced(arrays_c: Dict[str, Tuple[Any, Any]]):
-                if not getattr(_TRACE_LOCAL, "suppress", False):
-                    _TRACE_COUNT[0] += 1
-                arrays = {inv_in[c]: vm for c, vm in arrays_c.items()}
-                cols = {n: Column(kinds[n], v, m, meta=metas_in[n])
-                        for n, (v, m) in arrays.items()
-                        if _WIRE_SEP not in n}
-                b = ColumnBatch(dict(cols), n_rows_static)
-                for st in run:
-                    try:
-                        if st.uid in fns_at_trace:
-                            sub = {k.split(_WIRE_SEP, 1)[1]: v
-                                   for k, (v, _) in arrays.items()
-                                   if k.startswith(st.uid + _WIRE_SEP)}
-                            out_col = fns_at_trace[st.uid](sub)
-                            (f,) = st.output_features
-                            b = b.with_columns({f.name: out_col})
-                        else:
-                            b = st.transform_batch(b)
-                    except _StageTraceError:
-                        raise
-                    except Exception as e:  # noqa: BLE001 — demotion signal
-                        raise _StageTraceError(st.uid, e) from e
-                out = {}
-                for n in out_names:
-                    c = b[n]
-                    metas_out[n] = (c.meta, c.kind)
-                    out[canon_out[n]] = (c.values, c.mask)
-                return out
-
-            self._jitted[key] = (jax.jit(traced), canon_out)
-            self._metas[key] = metas_out
-
+    def _wire(self, batch: ColumnBatch, frontier: List[str],
+              wires: Dict[str, Any], canon_in: Dict[str, str], key: Tuple,
+              n_rows: int):
+        """The call's arguments as the fused program takes them: frontier
+        columns and wire arrays under their canonical names, float32 on the
+        bf16 wire, row-sharded over the mesh when there is one.  Returns
+        (arrays, their aval signature, the mesh or None)."""
         def _prep(v):
             # float32 columns ride the bf16 wire format to the device (see
             # columns.to_device_f32); other dtypes transfer as-is inside jit
@@ -385,12 +304,12 @@ class ScoreProgram:
         # executor row map, FitStagesUtil.scala:96).  Non-row wires (packed
         # token words, per-row+1 lens) stay replicated.
         from .parallel.mesh import data_sharding, maybe_data_mesh
-        mesh = maybe_data_mesh(n_rows_static)
+        mesh = maybe_data_mesh(n_rows)
         if mesh is not None:
             try:
                 def _shard(x):
                     if (x is not None and getattr(x, "ndim", 0) >= 1
-                            and x.shape[0] == n_rows_static):
+                            and x.shape[0] == n_rows):
                         return jax.device_put(x, data_sharding(mesh, x.ndim))
                     return x
                 arrays = {k: (_shard(v), _shard(m))
@@ -402,6 +321,106 @@ class ScoreProgram:
                 record_failure("compiled", "degraded", e,
                                point="compiled.shard",
                                fallback="unsharded program")
+        return arrays, sig, mesh
+
+    def _apply_run(self, batch: ColumnBatch, run: List[Transformer],
+                   later: List[Transformer], keep_intermediate: bool
+                   ) -> ColumnBatch:
+        # staged = stages whose inputs are NOT all array-resident right now;
+        # their host prologue supplies wire arrays instead of columns
+        staged_fns: Dict[str, Any] = {}
+        wires: Dict[str, Any] = {}
+        with span("transform.stage_wires", stages=len(run)):
+            for st in run:
+                if all(batch[f.name].is_device for f in st.input_features
+                       if f.name in batch):
+                    continue
+                res = None
+                try:
+                    res = st.transform_staged(batch)
+                except Exception as e:  # noqa: BLE001 — demotion signal
+                    raise _StageTraceError(st.uid, e) from e
+                if res is None:
+                    raise _StageTraceError(st.uid, TypeError(
+                        "stage has host inputs and no staged form"))
+                wire, fn = res
+                staged_fns[st.uid] = fn
+                for k, v in wire.items():
+                    wires[st.uid + _WIRE_SEP + k] = v
+
+        key = (tuple(st.uid for st in run), keep_intermediate, len(batch))
+        frontier = sorted({f.name for st in run
+                           if st.uid not in staged_fns
+                           for f in st.input_features if f.name in batch})
+        # canonical positional names at the jit boundary: stage uids are
+        # process-global counters, so real column/wire names differ between
+        # otherwise identical workflows — with them as pytree keys every new
+        # process MISSES the persistent compilation cache and pays a full
+        # XLA recompile of the fused program
+        canon_in = {n: f"a{i}" for i, n in enumerate(
+            frontier + sorted(wires))}
+        # _partition simulates host-stage outputs by kind; validate against
+        # the actual columns and demote consumers of any misprediction (e.g.
+        # a numeric-kinded host stage that emitted an object array)
+        host_cols = [n for n in frontier if not batch[n].is_device]
+        if host_cols:
+            offender = next(st for st in run if st.uid not in staged_fns
+                            and any(f.name in host_cols
+                                    for f in st.input_features))
+            raise _StageTraceError(offender.uid, TypeError(
+                f"frontier columns {host_cols} are host-resident"))
+        out_names = self._wanted_outputs(run, later, keep_intermediate)
+        kinds = {n: batch[n].kind for n in frontier}
+        metas_in = {n: batch[n].meta for n in frontier}
+        n_rows_static = len(batch)
+
+        fresh = key not in self._jitted
+        if fresh:
+            metas_out: Dict[str, Any] = {}
+            fns_at_trace = dict(staged_fns)
+            inv_in = {c: n for n, c in canon_in.items()}
+            canon_out = {n: f"o{i}" for i, n in enumerate(out_names)}
+
+            def traced(arrays_c: Dict[str, Tuple[Any, Any]]):
+                if not getattr(_TRACE_LOCAL, "suppress", False):
+                    _TRACE_COUNT[0] += 1
+                arrays = {inv_in[c]: vm for c, vm in arrays_c.items()}
+                cols = {n: Column(kinds[n], v, m, meta=metas_in[n])
+                        for n, (v, m) in arrays.items()
+                        if _WIRE_SEP not in n}
+                b = ColumnBatch(dict(cols), n_rows_static)
+                for st in run:
+                    try:
+                        # the stage's operations carry its class and output
+                        # kind in the device trace (a uid is a process
+                        # counter and would not repeat)
+                        with jax.named_scope(_stage_scope(st)):
+                            if st.uid in fns_at_trace:
+                                sub = {k.split(_WIRE_SEP, 1)[1]: v
+                                       for k, (v, _) in arrays.items()
+                                       if k.startswith(st.uid + _WIRE_SEP)}
+                                out_col = fns_at_trace[st.uid](sub)
+                                (f,) = st.output_features
+                                b = b.with_columns({f.name: out_col})
+                            else:
+                                b = st.transform_batch(b)
+                    except _StageTraceError:
+                        raise
+                    except Exception as e:  # noqa: BLE001 — demotion signal
+                        raise _StageTraceError(st.uid, e) from e
+                out = {}
+                for n in out_names:
+                    c = b[n]
+                    metas_out[n] = (c.meta, c.kind)
+                    out[canon_out[n]] = (c.values, c.mask)
+                return out
+
+            self._jitted[key] = (jax.jit(traced), canon_out)
+            self._metas[key] = metas_out
+
+        with span("transform.wire", arrays=len(frontier) + len(wires)):
+            arrays, sig, mesh = self._wire(batch, frontier, wires, canon_in,
+                                           key, n_rows_static)
         if (mesh is None and key not in self._aot_installed
                 and (key, sig) not in self._aot_variants
                 and (key, sig) not in self._registry_checked):
@@ -423,7 +442,8 @@ class ScoreProgram:
                 vfn, v_canon_out, v_metas = var
                 try:
                     maybe_inject("compiled.segment", key=run[0].uid)
-                    out_c = vfn(arrays)
+                    with span("transform.dispatch", installed=True):
+                        out_c = vfn(arrays)
                     out = {n: out_c[c] for n, c in v_canon_out.items()}
                     new_cols = {}
                     for n, (v, m) in out.items():
@@ -440,14 +460,17 @@ class ScoreProgram:
                     REGISTRY.counter("aot.fallback").inc()
                     self._aot_variants.pop((key, sig), None)
         jitted, canon_out_map = self._jitted[key]
-        from .profiling import cost_analysis_enabled, record_program_cost
-        if cost_analysis_enabled():
-            record_program_cost("fused_transform", jitted, (arrays,))
         try:
             # chaos hook: an injected fault here exercises the eager-segment
             # demotion below, the same path a device dispatch failure takes
             maybe_inject("compiled.segment", key=run[0].uid)
-            out_c = jitted(arrays)
+            # a fresh jax.jit(traced) pays trace, lowering, and a compile or
+            # a cache load before it dispatches; a warm entry or an installed
+            # executable dispatches only
+            with span("transform.first_call"
+                      if fresh and key not in self._aot_installed
+                      else "transform.dispatch"):
+                out_c = jitted(arrays)
             out = {n: out_c[c] for n, c in canon_out_map.items()}
         except _StageTraceError:
             self._jitted.pop(key, None)
@@ -486,6 +509,13 @@ class ScoreProgram:
             meta, kind = metas_out[n]
             new_cols[n] = Column(kind, v, m, meta=meta)
         return batch.with_columns(new_cols)
+
+
+def _stage_scope(st: Transformer) -> str:
+    """``transform.<stage class>.<output kind>``: the ``jax.named_scope`` of
+    a stage's operations inside the fused program."""
+    out = st.output_features[0].kind if st.output_features else None
+    return f"transform.{type(st).__name__}.{getattr(out, '__name__', out)}"
 
 
 def _kind_arrayish(kind) -> bool:

@@ -21,6 +21,7 @@ import zlib
 
 from .columns import Column, ColumnBatch
 from .features import Feature
+from .telemetry import span
 from .types import is_map_kind, is_numeric_kind, is_text_kind
 
 
@@ -273,9 +274,10 @@ def _sharded_numeric_hist(mesh, arr, keep, lo, hi, bins: int) -> np.ndarray:
     if fn is None:
         @jax.jit
         def fn(i, m):
-            oh = (i[:, None] == jnp.arange(bins)[None, :]
-                  ).astype(jnp.float32)
-            return jnp.sum(oh * m.astype(jnp.float32)[:, None], axis=0)
+            with jax.named_scope("rff.distributions"):
+                oh = (i[:, None] == jnp.arange(bins)[None, :]
+                      ).astype(jnp.float32)
+                return jnp.sum(oh * m.astype(jnp.float32)[:, None], axis=0)
         _HIST_FNS[bins] = fn
     i = jax.device_put(jnp.asarray(idx), data_sharding(mesh, 1))
     m = jax.device_put(jnp.asarray(valid), data_sharding(mesh, 1))
@@ -512,7 +514,6 @@ class RawFeatureFilter:
         """≙ generateFilteredRaw:486: returns (clean batch, dropped features,
         results)."""
         results = RawFeatureFilterResults()
-        dists: Dict[str, List[FeatureDistribution]] = {}
         label_values: Optional[np.ndarray] = None
         label_name = next((f.name for f in raw_features if f.is_response), None)
         if label_name and label_name in batch:
@@ -523,6 +524,18 @@ class RawFeatureFilter:
             score_batch = self.score_reader.generate_batch(
                 [f for f in raw_features if not f.is_response])
 
+        with span("rff.distributions", features=len(raw_features)):
+            per_feature = self._distributions(batch, score_batch,
+                                              raw_features, results)
+        with span("rff.decide", features=len(per_feature)):
+            for f, fdists, sdists in per_feature:
+                self._decide(f, fdists, sdists, batch, label_values, results)
+            return self._clean(batch, raw_features, results)
+
+    def _distributions(self, batch, score_batch, raw_features, results):
+        """[(feature, its train distributions, its score distributions)] of
+        every predictor in ``batch``; both lists also go into ``results``."""
+        per_feature = []
         for f in raw_features:
             if f.name not in batch or f.is_response:
                 continue
@@ -536,65 +549,73 @@ class RawFeatureFilter:
                 ranges = merge_ranges(ranges, numeric_ranges(f, score_col))
             fdists = compute_distribution(f, batch[f.name], self.bins,
                                           self.text_bins, ranges=ranges)
-            dists[f.name] = fdists
             results.train_distributions.extend(fdists)
             sdists: List[FeatureDistribution] = []
             if score_col is not None:
                 sdists = compute_distribution(f, score_col, self.bins,
                                               self.text_bins, ranges=ranges)
                 results.score_distributions.extend(sdists)
-            if f.name in self.protected:
+            per_feature.append((f, fdists, sdists))
+        return per_feature
+
+    def _decide(self, f, fdists, sdists, batch, label_values, results
+                ) -> None:
+        """Record in ``results`` whether ``f`` (or some of its map keys) is
+        dropped, and why."""
+        if f.name in self.protected:
+            return
+
+        reasons: List[str] = []
+        # minimum fill rate (≙ minFill)
+        if all(d.fill_rate < self.min_fill_rate for d in fdists):
+            reasons.append(
+                f"fill rate {fdists[0].fill_rate:.4f} < minFillRate")
+        # null-label correlation (leakage through missingness)
+        if label_values is not None and len(np.unique(label_values)) > 1:
+            presence = _value_presence(batch[f.name]).astype(np.float64)
+            if presence.std() > 0:
+                corr = float(np.corrcoef(presence, label_values)[0, 1])
+                if np.isfinite(corr) and abs(corr) > self.max_correlation:
+                    reasons.append(
+                        f"null-label correlation {corr:.4f} > max")
+
+        # train-vs-score distribution shift, compared PER KEY for maps
+        # (≙ getFeaturesToExclude pairing distributions by (name, key));
+        # shifted map keys drop individually, the whole feature drops
+        # only when every key fails
+        sd_by_key = {d.key: d for d in sdists}
+        shifted_keys: List[str] = []
+        for d in fdists:
+            sd = sd_by_key.get(d.key)
+            if sd is None:
                 continue
+            kreasons = []
+            if d.relative_fill_rate(sd) > self.max_fill_difference:
+                kreasons.append("fill rate difference train/score too large")
+            if d.relative_fill_ratio(sd) > self.max_fill_ratio_diff:
+                kreasons.append("fill rate ratio train/score too large")
+            js = d.js_divergence(sd)
+            if js > self.max_js_divergence:
+                kreasons.append(f"JS divergence {js:.4f} > max")
+            if not kreasons:
+                continue
+            if d.key is None:
+                reasons.extend(kreasons)
+            else:
+                shifted_keys.append(d.key)
+                results.reasons[f"{f.name}[{d.key}]"] = kreasons
+        all_keys = [d.key for d in fdists if d.key is not None]
+        if shifted_keys:
+            results.dropped_map_keys[f.name] = shifted_keys
+            if len(shifted_keys) == len(all_keys):
+                reasons.append("every map key failed train/score checks")
+        if reasons:
+            results.dropped.append(f.name)
+            results.reasons[f.name] = reasons + \
+                results.reasons.get(f.name, [])
 
-            reasons: List[str] = []
-            # minimum fill rate (≙ minFill)
-            if all(d.fill_rate < self.min_fill_rate for d in fdists):
-                reasons.append(
-                    f"fill rate {fdists[0].fill_rate:.4f} < minFillRate")
-            # null-label correlation (leakage through missingness)
-            if label_values is not None and len(np.unique(label_values)) > 1:
-                presence = _value_presence(batch[f.name]).astype(np.float64)
-                if presence.std() > 0:
-                    corr = float(np.corrcoef(presence, label_values)[0, 1])
-                    if np.isfinite(corr) and abs(corr) > self.max_correlation:
-                        reasons.append(
-                            f"null-label correlation {corr:.4f} > max")
-
-            # train-vs-score distribution shift, compared PER KEY for maps
-            # (≙ getFeaturesToExclude pairing distributions by (name, key));
-            # shifted map keys drop individually, the whole feature drops
-            # only when every key fails
-            sd_by_key = {d.key: d for d in sdists}
-            shifted_keys: List[str] = []
-            for d in fdists:
-                sd = sd_by_key.get(d.key)
-                if sd is None:
-                    continue
-                kreasons = []
-                if d.relative_fill_rate(sd) > self.max_fill_difference:
-                    kreasons.append("fill rate difference train/score too large")
-                if d.relative_fill_ratio(sd) > self.max_fill_ratio_diff:
-                    kreasons.append("fill rate ratio train/score too large")
-                js = d.js_divergence(sd)
-                if js > self.max_js_divergence:
-                    kreasons.append(f"JS divergence {js:.4f} > max")
-                if not kreasons:
-                    continue
-                if d.key is None:
-                    reasons.extend(kreasons)
-                else:
-                    shifted_keys.append(d.key)
-                    results.reasons[f"{f.name}[{d.key}]"] = kreasons
-            all_keys = [d.key for d in fdists if d.key is not None]
-            if shifted_keys:
-                results.dropped_map_keys[f.name] = shifted_keys
-                if len(shifted_keys) == len(all_keys):
-                    reasons.append("every map key failed train/score checks")
-            if reasons:
-                results.dropped.append(f.name)
-                results.reasons[f.name] = reasons + \
-                    results.reasons.get(f.name, [])
-
+    def _clean(self, batch, raw_features, results):
+        """``batch`` without the dropped features and map keys."""
         dropped = set(results.dropped)
         dropped_features = [f for f in raw_features if f.name in dropped]
         clean = batch.drop(results.dropped)

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 
 @jax.jit
+@jax.named_scope("panel.auroc")
 def masked_auroc(y: jnp.ndarray, scores: jnp.ndarray, w: jnp.ndarray):
     """Weighted Mann-Whitney AUC with exact tie handling.  ``w`` is a 0/1 (or
     weighted) row mask; rows with w=0 are ignored."""
@@ -47,6 +48,7 @@ def masked_auroc(y: jnp.ndarray, scores: jnp.ndarray, w: jnp.ndarray):
 
 
 @jax.jit
+@jax.named_scope("panel.aupr")
 def masked_aupr(y: jnp.ndarray, scores: jnp.ndarray, w: jnp.ndarray):
     """Weighted area under the PR curve, MLlib-style (threshold-grouped,
     trapezoid over recall with a prepended (0, 1) point)."""

@@ -107,10 +107,7 @@ def _grouped_grid_fit(est, X, y, fold_weights, grids, *, loss: str,
             # miss → lower+compile into the persistent cache and publish
             grid_compile(label, g_fn, g_args, static_kwargs=g_statics)
             continue
-        from ..profiling import cost_analysis_enabled, record_program_cost
         res = grid_call(label, g_fn, g_args, static_kwargs=g_statics)
-        if cost_analysis_enabled() and not sparse:
-            record_program_cost(label, g_fn, g_args, g_statics)
         coef = np.asarray(res.coef)
         inter = np.asarray(res.intercept)
         n_it = np.asarray(res.n_iter)
@@ -144,6 +141,7 @@ def _binary_outputs(margin: np.ndarray) -> Dict[str, np.ndarray]:
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "full", "family"))
+@jax.named_scope("score.linear")
 def _linear_device_scores(Xd, coef, intercept, *, kind: str, full: bool,
                           family: str = "gaussian"):
     """One fused program for the whole device-score chain — the eager
@@ -155,6 +153,7 @@ def _linear_device_scores(Xd, coef, intercept, *, kind: str, full: bool,
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "full", "family"))
+@jax.named_scope("score.linear")
 def _scores_from_linear(lin, intercept, *, kind: str, full: bool,
                         family: str = "gaussian"):
     """Score-chain tail given the linear predictor ``lin = X @ coef`` — the

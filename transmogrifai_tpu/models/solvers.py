@@ -18,6 +18,7 @@ be trained as one ``vmap``'d XLA program.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Callable, NamedTuple, Optional, Tuple
 
@@ -109,6 +110,7 @@ class FitResult(NamedTuple):
     objective: jnp.ndarray  # final objective value
 
 
+@jax.named_scope("linear.lipschitz")
 def _spectral_norm_sq_weighted(X: jnp.ndarray, wn: jnp.ndarray,
                                mean: jnp.ndarray, scale: jnp.ndarray,
                                iters: int = 16) -> jnp.ndarray:
@@ -140,6 +142,7 @@ def _loss_target(loss: str, y: jnp.ndarray, n_classes: int) -> jnp.ndarray:
     return y.astype(jnp.float32)
 
 
+@jax.named_scope("linear.fista")
 def _fista_loop(xs_mv: Callable, xs_tmv: Callable, target: jnp.ndarray,
                 w: jnp.ndarray, l2: jnp.ndarray, l1: jnp.ndarray, *,
                 loss: str, d: int, n_classes: int, fit_intercept: bool,
@@ -256,7 +259,10 @@ def fista_fit(X: jnp.ndarray, y: jnp.ndarray, sample_weight: jnp.ndarray,
 
     ``l2``/``l1`` may be traced scalars → vmap over a regularisation grid.
     ``sigma_sq`` (λ_max of the weighted Gram) may be shared across grid
-    lanes; computed here when absent.
+    lanes; computed here when absent.  A fit that computes its own is a
+    single fit, not a grid lane — the winner's refit, a candidate fitted
+    alone — and its operations carry the scope ``linear.refit`` in the
+    device trace.
     """
     n, d = X.shape
     C = n_classes
@@ -282,11 +288,13 @@ def fista_fit(X: jnp.ndarray, y: jnp.ndarray, sample_weight: jnp.ndarray,
 
     # step size from Lipschitz bound: c * sigma_max(Xs_w)^2 (+ l2)
     wn = w / jnp.sum(w)
-    if sigma_sq is None:
-        sigma_sq = _spectral_norm_sq_weighted(X, wn, mu, sc)
-    return _fista_loop(xs_mv, xs_tmv, target, w, l2, l1, loss=loss, d=d,
-                       n_classes=C, fit_intercept=fit_intercept,
-                       max_iter=max_iter, tol=tol, sigma_sq=sigma_sq)
+    with (jax.named_scope("linear.refit") if sigma_sq is None
+          else contextlib.nullcontext()):
+        if sigma_sq is None:
+            sigma_sq = _spectral_norm_sq_weighted(X, wn, mu, sc)
+        return _fista_loop(xs_mv, xs_tmv, target, w, l2, l1, loss=loss, d=d,
+                           n_classes=C, fit_intercept=fit_intercept,
+                           max_iter=max_iter, tol=tol, sigma_sq=sigma_sq)
 
 
 @functools.partial(jax.jit, static_argnames=("fit_intercept",))
@@ -483,6 +491,7 @@ def _sp_col_scale(values, indices, row_ids, wn, n_cols):
     return jnp.sqrt(jnp.maximum(var, 1e-12))
 
 
+@jax.named_scope("linear.lipschitz")
 def _sp_spectral_norm_sq(values, indices, row_ids, wn, scale,
                          n_rows: int, n_cols: int,
                          iters: int = 16) -> jnp.ndarray:

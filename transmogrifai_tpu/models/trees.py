@@ -781,9 +781,6 @@ def fit_forest(X: np.ndarray, y: np.ndarray, *, task: str, n_classes: int,
                 jnp.float32(min_instances), jnp.float32(min_gain),
                 jnp.float32(1.0))
     trees = fitter(*fit_args)
-    from ..profiling import cost_analysis_enabled, record_program_cost
-    if cost_analysis_enabled():
-        record_program_cost("forest_fit", fitter, fit_args)
     return {"kind": "forest", "task": task, "n_classes": n_classes,
             "max_depth": max_depth,
             "feature": np.asarray(trees.feature),
@@ -1214,9 +1211,6 @@ class _ForestEstimatorBase(PredictorEstimator):
                 continue
             trees = grid_call("trees.forest_grid_fit", fitter, grid_args,
                               sig_statics=f_statics)
-            from ..profiling import cost_analysis_enabled, record_program_cost
-            if cost_analysis_enabled():
-                record_program_cost("forest_grid_fit", fitter, grid_args)
             # keep the tree arrays device-resident: candidates slice views of
             # the [Kt, ...] stacks; they only cross the host link if a model
             # is serialized or scored on host data
@@ -1373,9 +1367,6 @@ class _GBTEstimatorBase(PredictorEstimator):
                 continue
             margins, rounds = grid_call("trees.gbt_grid_fit", fit_all,
                                         gbt_args, sig_statics=g_statics)
-            from ..profiling import cost_analysis_enabled, record_program_cost
-            if cost_analysis_enabled():
-                record_program_cost("gbt_grid_fit", fit_all, gbt_args)
             # device-resident [Kc, R, T] stacks; sliced per candidate below
             feature = jnp.swapaxes(rounds.feature, 0, 1)
             threshold = jnp.swapaxes(rounds.threshold, 0, 1)

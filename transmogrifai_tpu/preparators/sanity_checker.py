@@ -21,6 +21,7 @@ import numpy as np
 
 from ..columns import Column, ColumnBatch
 from ..stages.base import Estimator, TransformerModel
+from ..telemetry import span
 from ..types import OPVector, RealNN
 from ..vector_meta import VectorMeta
 
@@ -47,6 +48,7 @@ def _label_corr(Xf: jnp.ndarray, yf: jnp.ndarray) -> jnp.ndarray:
 
 
 @partial(jax.jit, static_argnames=("spearman",))
+@jax.named_scope("sanity.col_stats")
 def _col_stats(X: jnp.ndarray, y: jnp.ndarray, spearman: bool = False):
     """Single fused pass: per-column count/mean/var/min/max + corr with the
     label (≙ Statistics.colStats + computeCorrelationsWithLabel,
@@ -81,8 +83,9 @@ def _col_stats_with_contingency(X, y, union_idx, y_classes, spearman=False):
     The contingency always contracts RAW indicator values; only the label
     correlation switches to ranks under ``spearman``."""
     mean, var, mn, mx, corr = _col_stats(X, y, spearman=spearman)
-    yoh = (y[:, None] == y_classes[None, :]).astype(jnp.float32)
-    cont = yoh.T @ X[:, union_idx].astype(jnp.float32)
+    with jax.named_scope("sanity.contingency"):
+        yoh = (y[:, None] == y_classes[None, :]).astype(jnp.float32)
+        cont = yoh.T @ X[:, union_idx].astype(jnp.float32)
     return jnp.stack([mean, var, mn, mx, corr]), cont
 
 
@@ -196,164 +199,173 @@ class SanityChecker(Estimator):
         return f"{self.input_features[1].name}_sanityChecked_{self.uid[-6:]}"
 
     def fit(self, batch: ColumnBatch) -> SanityCheckerModel:
-        import jax
+        with span("sanity.fit"):
+            return self._fit(batch)
 
-        label_f, vec_f = self.input_features
-        y = np.asarray(batch[label_f.name].values, dtype=np.float32)
-        vec = batch[vec_f.name]
-        vals = vec.values
-        # keep the matrix in its native residency — on real TPU hardware the
-        # host link is the bottleneck, so all stats run on device and only the
-        # [D]-sized results transfer (≙ colStats on executors)
-        Xd = (vals if isinstance(vals, jax.Array)
-              else jnp.asarray(np.asarray(vals, np.float32)))
-        if Xd.dtype not in (jnp.float32, jnp.bfloat16):
-            # bf16 feature-matrix storage passes through untouched — the
-            # jitted stats force f32 accumulation internally
-            Xd = Xd.astype(jnp.float32)
-        n, d = Xd.shape
-        meta = vec.meta or VectorMeta(vec_f.name, [])
-        names = (meta.column_names() if meta.size == d
-                 else [f"f_{i}" for i in range(d)])
+    def _fit(self, batch: ColumnBatch) -> SanityCheckerModel:
+        with span("sanity.stage"):
+            label_f, vec_f = self.input_features
+            y = np.asarray(batch[label_f.name].values, dtype=np.float32)
+            vec = batch[vec_f.name]
+            vals = vec.values
+            # keep the matrix in its native residency — on real TPU hardware the
+            # host link is the bottleneck, so all stats run on device and only the
+            # [D]-sized results transfer (≙ colStats on executors)
+            Xd = (vals if isinstance(vals, jax.Array)
+                  else jnp.asarray(np.asarray(vals, np.float32)))
+            if Xd.dtype not in (jnp.float32, jnp.bfloat16):
+                # bf16 feature-matrix storage passes through untouched — the
+                # jitted stats force f32 accumulation internally
+                Xd = Xd.astype(jnp.float32)
+            n, d = Xd.shape
+            meta = vec.meta or VectorMeta(vec_f.name, [])
+            names = (meta.column_names() if meta.size == d
+                     else [f"f_{i}" for i in range(d)])
 
-        # sampling (≙ SanityChecker sample fraction:524)
-        frac = float(self.get("check_sample_fraction", 1.0))
-        limit = int(self.get("sample_upper_limit", DEFAULT_SAMPLE_UPPER_LIMIT))
-        if frac < 1.0 or n > limit:
-            m = min(int(n * frac) if frac < 1.0 else n, limit)
-            rng = np.random.default_rng(int(self.get("seed", 42)))
-            idx = rng.choice(n, size=m, replace=False)
-            Xs, ys_host = Xd[idx], y[idx]
-        else:
-            Xs, ys_host = Xd, y
-        from ..columns import to_device_f32
-        # exact bf16-when-lossless wire, weakref-cached: the selector's grid
-        # fits reuse the SAME label transfer
-        ys = to_device_f32(ys_host, exact=True)
-        # multi-device: row-shard the matrix over the mesh 'data' axis so the
-        # stats reductions run as ONE GSPMD program with psum collectives
-        # (≙ SanityChecker colStats on executors, SanityChecker.scala:575)
-        from ..parallel.mesh import data_sharding, maybe_data_mesh
-        mesh = maybe_data_mesh(int(Xs.shape[0]))
-        if mesh is not None:
-            Xs = jax.device_put(Xs, data_sharding(mesh, 2))
-            ys = jax.device_put(ys, data_sharding(mesh, 1))
+            # sampling (≙ SanityChecker sample fraction:524)
+            frac = float(self.get("check_sample_fraction", 1.0))
+            limit = int(self.get("sample_upper_limit", DEFAULT_SAMPLE_UPPER_LIMIT))
+            if frac < 1.0 or n > limit:
+                m = min(int(n * frac) if frac < 1.0 else n, limit)
+                rng = np.random.default_rng(int(self.get("seed", 42)))
+                idx = rng.choice(n, size=m, replace=False)
+                Xs, ys_host = Xd[idx], y[idx]
+            else:
+                Xs, ys_host = Xd, y
+            from ..columns import to_device_f32
+            # exact bf16-when-lossless wire, weakref-cached: the selector's grid
+            # fits reuse the SAME label transfer
+            ys = to_device_f32(ys_host, exact=True)
+            # multi-device: row-shard the matrix over the mesh 'data' axis so the
+            # stats reductions run as ONE GSPMD program with psum collectives
+            # (≙ SanityChecker colStats on executors, SanityChecker.scala:575)
+            from ..parallel.mesh import data_sharding, maybe_data_mesh
+            mesh = maybe_data_mesh(int(Xs.shape[0]))
+            if mesh is not None:
+                Xs = jax.device_put(Xs, data_sharding(mesh, 2))
+                ys = jax.device_put(ys, data_sharding(mesh, 1))
 
-        # Cramér's V + association rules per categorical indicator group
-        # (≙ categoricalTests): group = columns with an indicatorValue sharing
-        # (parentFeatureName, grouping)
-        groups: Dict[Tuple[str, Optional[str]], List[int]] = {}
-        if meta.size == d:
-            for c in meta.columns:
-                if c.indicator_value is not None:
-                    groups.setdefault((c.parent_feature_name, c.grouping), []
-                                      ).append(c.index)
-        y_classes = np.unique(ys_host)
-        cont_all = None
-        pos_of = {}
-        corr_type = self.get("correlation_type", DEFAULT_CORRELATION_TYPE)
-        union: List[int] = []
-        if len(y_classes) > 100:
-            # contingency tables need a CATEGORICAL label: a continuous
-            # (regression) response would one-hot into an [N, ~N] block;
-            # Cramér's V is meaningless there, so skip the tests entirely
-            groups = {}
-        if groups:
-            # ONE device contraction over the UNION of indicator columns
-            # covers every group's contingency — per-group gathers would pay
-            # a dispatch + stream sync each on high-latency links, and
-            # contracting all D columns would pull width-proportional bytes
-            # (≙ categoricalTests, batched)
-            union = sorted({i for idxs in groups.values() for i in idxs})
-            pos_of = {i: p for p, i in enumerate(union)}
-        spearman = corr_type == "spearman"
-        if groups:
-            # stats + contingency (+ rank transform under spearman) in ONE
-            # compiled program, TWO pulls.  Guard: groups only exist for
-            # categorical indicator columns, so the label one-hot [N, C]
-            # stays small — never build it for a continuous (regression)
-            # label with ~N distinct values
-            stacked, cont = _col_stats_with_contingency(
-                Xs, ys, jnp.asarray(union, jnp.int32),
-                jnp.asarray(y_classes, jnp.float32), spearman=spearman)
-            mean, var, mn, mx, corr_arr = np.asarray(stacked)
-            cont_all = np.asarray(cont)
-        else:
-            mean, var, mn, mx, corr = _col_stats(Xs, ys, spearman=spearman)
-            corr_arr = np.asarray(corr)
-            mean, var, mn, mx = (np.asarray(a) for a in (mean, var, mn, mx))
-        cramers: Dict[str, float] = {}
-        group_fail: Dict[int, List[str]] = {}
-        max_rule_conf = float(self.get("max_rule_confidence", 1.0))
-        min_rule_supp = float(self.get("min_required_rule_support", 1.0))
-        contingency_by_group: Dict[str, Dict] = {}
-        for (parent, grouping), idxs in groups.items():
-            contingency = cont_all[:, [pos_of[i] for i in idxs]]  # [C, k]
-            # full contingency panel: Cramér's V + chi2 + PMI/MI + rule
-            # confidences (≙ OpStatistics.contingencyStats:300; reference
-            # rows=choices so transpose)
-            from ..utils.stats import contingency_stats
-            cstats = contingency_stats(contingency.T)
-            v = cstats.cramers_v
-            gname = parent if grouping is None else f"{parent}({grouping})"
-            cramers[gname] = v
-            contingency_by_group[gname] = cstats.to_json()
-            reasons = []
-            if np.isfinite(v) and v > float(self.get("max_cramers_v", 1.0)):
-                reasons.append(f"CramersV {v:.4f} > max")
-            # association rule confidence (leakage): P(label=c | col=1)
-            conf = np.asarray(cstats.max_confidences)
-            supp = np.asarray(cstats.supports) * contingency.sum() / max(
-                len(ys_host), 1)
-            if max_rule_conf < 1.0 or min_rule_supp < 1.0:
-                bad = (conf >= max_rule_conf) & (supp >= min_rule_supp)
-                if bad.any():
-                    reasons.append("rule confidence leakage")
-            if reasons:
-                for i in idxs:
-                    group_fail.setdefault(i, []).extend(reasons)
+            # Cramér's V + association rules per categorical indicator group
+            # (≙ categoricalTests): group = columns with an indicatorValue sharing
+            # (parentFeatureName, grouping)
+            groups: Dict[Tuple[str, Optional[str]], List[int]] = {}
+            if meta.size == d:
+                for c in meta.columns:
+                    if c.indicator_value is not None:
+                        groups.setdefault((c.parent_feature_name, c.grouping), []
+                                          ).append(c.index)
+            y_classes = np.unique(ys_host)
+            cont_all = None
+            pos_of = {}
+            corr_type = self.get("correlation_type", DEFAULT_CORRELATION_TYPE)
+            union: List[int] = []
+            if len(y_classes) > 100:
+                # contingency tables need a CATEGORICAL label: a continuous
+                # (regression) response would one-hot into an [N, ~N] block;
+                # Cramér's V is meaningless there, so skip the tests entirely
+                groups = {}
+            if groups:
+                # ONE device contraction over the UNION of indicator columns
+                # covers every group's contingency — per-group gathers would pay
+                # a dispatch + stream sync each on high-latency links, and
+                # contracting all D columns would pull width-proportional bytes
+                # (≙ categoricalTests, batched)
+                union = sorted({i for idxs in groups.values() for i in idxs})
+                pos_of = {i: p for p, i in enumerate(union)}
+        # the span in which the host waits for the device: the dispatch
+        # returns at once and the pulls block until the program has run
+        with span("sanity.stats"):
+            spearman = corr_type == "spearman"
+            if groups:
+                # stats + contingency (+ rank transform under spearman) in ONE
+                # compiled program, TWO pulls.  Guard: groups only exist for
+                # categorical indicator columns, so the label one-hot [N, C]
+                # stays small — never build it for a continuous (regression)
+                # label with ~N distinct values
+                stacked, cont = _col_stats_with_contingency(
+                    Xs, ys, jnp.asarray(union, jnp.int32),
+                    jnp.asarray(y_classes, jnp.float32), spearman=spearman)
+                mean, var, mn, mx, corr_arr = np.asarray(stacked)
+                cont_all = np.asarray(cont)
+            else:
+                mean, var, mn, mx, corr = _col_stats(Xs, ys, spearman=spearman)
+                corr_arr = np.asarray(corr)
+                mean, var, mn, mx = (np.asarray(a) for a in (mean, var, mn, mx))
+        with span("sanity.contingency", groups=len(groups)):
+            cramers: Dict[str, float] = {}
+            group_fail: Dict[int, List[str]] = {}
+            max_rule_conf = float(self.get("max_rule_confidence", 1.0))
+            min_rule_supp = float(self.get("min_required_rule_support", 1.0))
+            contingency_by_group: Dict[str, Dict] = {}
+            for (parent, grouping), idxs in groups.items():
+                contingency = cont_all[:, [pos_of[i] for i in idxs]]  # [C, k]
+                # full contingency panel: Cramér's V + chi2 + PMI/MI + rule
+                # confidences (≙ OpStatistics.contingencyStats:300; reference
+                # rows=choices so transpose)
+                from ..utils.stats import contingency_stats
+                cstats = contingency_stats(contingency.T)
+                v = cstats.cramers_v
+                gname = parent if grouping is None else f"{parent}({grouping})"
+                cramers[gname] = v
+                contingency_by_group[gname] = cstats.to_json()
+                reasons = []
+                if np.isfinite(v) and v > float(self.get("max_cramers_v", 1.0)):
+                    reasons.append(f"CramersV {v:.4f} > max")
+                # association rule confidence (leakage): P(label=c | col=1)
+                conf = np.asarray(cstats.max_confidences)
+                supp = np.asarray(cstats.supports) * contingency.sum() / max(
+                    len(ys_host), 1)
+                if max_rule_conf < 1.0 or min_rule_supp < 1.0:
+                    bad = (conf >= max_rule_conf) & (supp >= min_rule_supp)
+                    if bad.any():
+                        reasons.append("rule confidence leakage")
+                if reasons:
+                    for i in idxs:
+                        group_fail.setdefault(i, []).extend(reasons)
 
-        # per-column drop rules
-        max_corr = float(self.get("max_correlation", DEFAULT_MAX_CORRELATION))
-        min_corr = float(self.get("min_correlation", DEFAULT_MIN_CORRELATION))
-        min_var = float(self.get("min_variance", DEFAULT_MIN_VARIANCE))
-        reasons_by_col: Dict[int, List[str]] = {i: list(r) for i, r in group_fail.items()}
-        for i in range(d):
-            c = abs(corr_arr[i])
-            if np.isfinite(c):
-                if c > max_corr:
+        with span("sanity.rules"):
+            # per-column drop rules
+            max_corr = float(self.get("max_correlation", DEFAULT_MAX_CORRELATION))
+            min_corr = float(self.get("min_correlation", DEFAULT_MIN_CORRELATION))
+            min_var = float(self.get("min_variance", DEFAULT_MIN_VARIANCE))
+            reasons_by_col: Dict[int, List[str]] = {i: list(r) for i, r in group_fail.items()}
+            for i in range(d):
+                c = abs(corr_arr[i])
+                if np.isfinite(c):
+                    if c > max_corr:
+                        reasons_by_col.setdefault(i, []).append(
+                            f"correlation {c:.4f} > maxCorrelation")
+                    elif c < min_corr:
+                        reasons_by_col.setdefault(i, []).append(
+                            f"correlation {c:.4f} < minCorrelation")
+                if var[i] < min_var:
                     reasons_by_col.setdefault(i, []).append(
-                        f"correlation {c:.4f} > maxCorrelation")
-                elif c < min_corr:
-                    reasons_by_col.setdefault(i, []).append(
-                        f"correlation {c:.4f} < minCorrelation")
-            if var[i] < min_var:
-                reasons_by_col.setdefault(i, []).append(
-                    f"variance {var[i]:.2e} < minVariance")
+                        f"variance {var[i]:.2e} < minVariance")
 
-        remove = bool(self.get("remove_bad_features", True))
-        drop_idx = sorted(reasons_by_col) if remove else []
-        keep = [i for i in range(d) if i not in set(drop_idx)]
-        if not keep:  # never drop everything
-            keep = list(range(d))
-            drop_idx = []
+            remove = bool(self.get("remove_bad_features", True))
+            drop_idx = sorted(reasons_by_col) if remove else []
+            keep = [i for i in range(d) if i not in set(drop_idx)]
+            if not keep:  # never drop everything
+                keep = list(range(d))
+                drop_idx = []
 
-        summary = SanityCheckerSummary(
-            correlation_type=corr_type, names=names,
-            correlations_with_label=[float(c) for c in corr_arr],
-            variances=[float(v) for v in var], means=[float(m) for m in mean],
-            mins=[float(v) for v in mn], maxs=[float(v) for v in mx],
-            cramers_v_by_group=cramers,
-            contingency_stats_by_group=contingency_by_group,
-            dropped=[names[i] for i in drop_idx],
-            drop_reasons={names[i]: r for i, r in reasons_by_col.items()},
-            sample_size=len(ys_host))
+        with span("sanity.summary"):
+            summary = SanityCheckerSummary(
+                correlation_type=corr_type, names=names,
+                correlations_with_label=[float(c) for c in corr_arr],
+                variances=[float(v) for v in var], means=[float(m) for m in mean],
+                mins=[float(v) for v in mn], maxs=[float(v) for v in mx],
+                cramers_v_by_group=cramers,
+                contingency_stats_by_group=contingency_by_group,
+                dropped=[names[i] for i in drop_idx],
+                drop_reasons={names[i]: r for i, r in reasons_by_col.items()},
+                sample_size=len(ys_host))
 
-        model = SanityCheckerModel(
-            fitted={"indices_to_keep": np.asarray(keep, dtype=np.int64)},
-            **self._params)
-        model.metadata["summary"] = summary.to_json()
-        if meta.size == d:  # full input lineage for ModelInsights
-            model.metadata["input_vector_meta"] = meta.to_json()
-        model.summary = summary
-        return self._finalize_model(model)
+            model = SanityCheckerModel(
+                fitted={"indices_to_keep": np.asarray(keep, dtype=np.int64)},
+                **self._params)
+            model.metadata["summary"] = summary.to_json()
+            if meta.size == d:  # full input lineage for ModelInsights
+                model.metadata["input_vector_meta"] = meta.to_json()
+            model.summary = summary
+            return self._finalize_model(model)

@@ -12,8 +12,6 @@ from __future__ import annotations
 
 import bisect
 import contextlib
-import json
-import os
 import threading
 import time
 from dataclasses import dataclass, field
@@ -42,9 +40,16 @@ def host_link_bytes() -> int:
 # "seconds spent inside XLA compilation" vs everything else, and let the
 # bench count NEW programs built this process (persistent-cache misses when
 # the cache is on, raw backend compiles otherwise).
+_TRACE_DURATION_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_DURATION_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE_DURATION_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+# the three steps a jit call pays before its first dispatch, by the event
+# jax times each with; the value is the step's name in ``program_stats``
+# rows (``<step>s``, ``<step>_s``) and in the ``jit.<step>`` trace events
+_JIT_STEPS = {_TRACE_DURATION_EVENT: "trace", _LOWER_DURATION_EVENT: "lower",
+              _COMPILE_DURATION_EVENT: "compile"}
 
 _COMPILE_LOCK = threading.Lock()
 _COMPILE_INSTALL_LOCK = threading.Lock()
@@ -56,12 +61,38 @@ _COMPILE_LISTENERS_INSTALLED = [False]
 # call made (aot_registry uses it to tell a cache-LOADED executable from one
 # built here)
 _THREAD_CACHE_HITS = threading.local()
+# per-program table behind ``program_stats()``: what each jitted function
+# (jax's ``fun_name``) cost this process to trace, lower and compile or load
+_PROGRAM_STATS: Dict[str, Dict[str, Any]] = {}
+# jit steps OPEN on this thread, outermost first: jax announces a step's
+# start (a scalar event) and its duration (at its end) on the thread that
+# runs it, so a step that ends while another is open ran INSIDE that one —
+# a jit traced inside another trace, an eager op compiled while tracing.
+# Its seconds are already in the outer step's, so only the outermost step
+# adds seconds to the table or becomes a trace event.
+_OPEN_JIT_STEPS = threading.local()
+
+
+def _program_name(kw: Dict[str, Any]) -> str:
+    """jax names a trace by the function (``traced``) and its lowering and
+    compile by the module (``jit(traced)``): one name, the module's."""
+    name = str(kw.get("fun_name", "?"))
+    return name if "(" in name else f"jit({name})"
+
+
+def _program_row(fun_name: str) -> Dict[str, Any]:
+    row = _PROGRAM_STATS.get(fun_name)
+    if row is None:
+        row = _PROGRAM_STATS[fun_name] = {
+            "traces": 0, "trace_s": 0.0, "lowers": 0, "lower_s": 0.0,
+            "compiles": 0, "compile_s": 0.0, "cache_hits": 0, "nested": 0}
+    return row
 
 
 def install_compile_listeners() -> bool:
-    """Register the jax.monitoring listeners feeding ``compile_stats``.
-    Idempotent.  Called from package import; also from the accessors so a
-    bare ``import profiling`` works.
+    """Register the jax.monitoring listeners feeding ``compile_stats`` and
+    ``program_stats``.  Idempotent.  Called from package import; also from
+    the accessors so a bare ``import profiling`` works.
     Registration is double-checked under an install lock: jax.monitoring has
     no dedup, so two racing callers registering the same listeners would
     double-count every compile second from then on."""
@@ -69,11 +100,47 @@ def install_compile_listeners() -> bool:
         return True
     from jax import monitoring
 
+    def _on_step_start(event: str, value: float, **kw) -> None:
+        if event in _JIT_STEPS:
+            stack = getattr(_OPEN_JIT_STEPS, "stack", None)
+            if stack is None:
+                stack = _OPEN_JIT_STEPS.stack = []
+            stack.append((event, _program_name(kw), thread_cache_hits()))
+
     def _on_duration(event: str, duration: float, **kw) -> None:
+        step = _JIT_STEPS.get(event)
+        if step is None:
+            return
+        seconds = float(duration)
         if event == _COMPILE_DURATION_EVENT:
             with _COMPILE_LOCK:
-                _COMPILE_STATS["compile_s"] += float(duration)
+                _COMPILE_STATS["compile_s"] += seconds
                 _COMPILE_STATS["backend_compiles"] += 1
+        stack = getattr(_OPEN_JIT_STEPS, "stack", None)
+        opened = stack.pop() if stack else None
+        if opened is not None and opened[0] != event:
+            # starts and ends out of step (a listener installed mid-call):
+            # forget what is open rather than nest under a stale entry
+            del stack[:]
+            opened = None
+        if stack:
+            with _COMPILE_LOCK:
+                _program_row(stack[0][1])["nested"] += 1
+            return
+        fun_name = _program_name(kw)
+        cache_hit = None
+        if step == "compile" and opened is not None:
+            cache_hit = thread_cache_hits() > opened[2]
+        with _COMPILE_LOCK:
+            row = _program_row(fun_name)
+            row[step + "s"] += 1
+            row[step + "_s"] += seconds
+            row["cache_hits"] += bool(cache_hit)
+        # telemetry imports this module, so the edge back stays out of
+        # module load; event() is a no-op without a tracer
+        from .telemetry import event as _event
+        _event("jit." + step, fun_name=fun_name, seconds=seconds,
+               cache_hit=cache_hit)
 
     def _on_event(event: str, **kw) -> None:
         if event == _CACHE_HIT_EVENT:
@@ -87,6 +154,7 @@ def install_compile_listeners() -> bool:
     with _COMPILE_INSTALL_LOCK:
         if _COMPILE_LISTENERS_INSTALLED[0]:
             return True
+        monitoring.register_scalar_listener(_on_step_start)
         monitoring.register_event_duration_secs_listener(_on_duration)
         monitoring.register_event_listener(_on_event)
         _COMPILE_LISTENERS_INSTALLED[0] = True
@@ -101,6 +169,21 @@ def thread_cache_hits() -> int:
 def compile_stats() -> Dict[str, float]:
     install_compile_listeners()
     return dict(_COMPILE_STATS)
+
+
+def program_stats() -> Dict[str, Dict[str, Any]]:
+    """{fun_name: traces, trace_s, lowers, lower_s, compiles, compile_s,
+    cache_hits, nested} since the process started: the jit steps jax timed,
+    by the function jax names them for (``jit(traced)``, ``_col_stats``).  A
+    compile that loaded from the persistent cache counts as a compile and as
+    a cache hit.  ``nested`` counts the steps that ran inside one of this
+    program's own (their seconds are in this row's already, and in no row of
+    their own), so the seconds of all rows add up to wall time spent in jit
+    machinery; ``compile_stats()`` keeps every backend compile, nested or
+    not.  Two snapshots bracket a stretch of work: subtract them."""
+    install_compile_listeners()
+    with _COMPILE_LOCK:
+        return {k: dict(v) for k, v in _PROGRAM_STATS.items()}
 
 
 def compile_seconds() -> float:
@@ -139,63 +222,6 @@ def racing_stats() -> Dict[str, int]:
 def reset_racing_stats() -> None:
     for k in RACING_STATS:
         RACING_STATS[k] = 0
-
-
-# -- XLA program cost registry -----------------------------------------------
-# When TRANSMOGRIFAI_COST_ANALYSIS=1, the dominant compiled programs record
-# their XLA cost analysis (flops / bytes accessed) here, once per program
-# name; bench.py turns them into achieved-FLOP/s roofline fields.
-PROGRAM_COSTS: Dict[str, Dict[str, Any]] = {}
-
-# name → jax Lowered, captured inline at near-zero cost and resolved to a
-# PROGRAM_COSTS entry by flush_program_costs() OUTSIDE any timed wall
-_PENDING_COSTS: Dict[str, Any] = {}
-
-
-def cost_analysis_enabled() -> bool:
-    return os.environ.get("TRANSMOGRIFAI_COST_ANALYSIS") == "1"
-
-
-def record_program_cost(name: str, jitted_fn, args=(), kwargs=None) -> None:
-    """Best-effort XLA cost analysis of ``jitted_fn`` at ``args``' shapes.
-    Only the cheap ``lower()`` trace happens here (a Lowered holds shapes,
-    not argument buffers); the compile()+cost_analysis() pass is deferred to
-    ``flush_program_costs`` so enabling TRANSMOGRIFAI_COST_ANALYSIS=1 does
-    not add analysis time inside a caller's timed wall."""
-    if (not cost_analysis_enabled() or name in PROGRAM_COSTS
-            or name in _PENDING_COSTS):
-        return
-    try:
-        _PENDING_COSTS[name] = jitted_fn.lower(*args, **(kwargs or {}))
-    except Exception:  # noqa: BLE001 — diagnostics must never break a fit
-        pass
-
-
-def flush_program_costs() -> None:
-    """Resolve pending lowerings into PROGRAM_COSTS entries.  The explicit
-    compile() hits the in-process/persistent compile cache (the caller
-    already executed the program), so the cost is one analysis pass, not a
-    recompile.  Call after the timed region ends."""
-    while _PENDING_COSTS:
-        name, lowered = _PENDING_COSTS.popitem()
-        if name in PROGRAM_COSTS:
-            continue
-        try:
-            ca = lowered.compile().cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            PROGRAM_COSTS[name] = {
-                "flops": float(ca.get("flops", 0.0)),
-                "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
-            }
-        except Exception:  # noqa: BLE001 — diagnostics only
-            pass
-
-
-def clear_program_costs() -> None:
-    """Reset both resolved and pending cost records (workload boundaries)."""
-    PROGRAM_COSTS.clear()
-    _PENDING_COSTS.clear()
 
 
 class LatencyHistogram:
@@ -351,11 +377,14 @@ def _device_memory() -> Dict[str, Optional[int]]:
 
 
 class PhaseTimer:
-    """Collects per-phase timings; nested phases are recorded flat."""
+    """Collects per-phase timings; nested phases are recorded flat.  Walls
+    are read from ``time.monotonic()``, the clock ``telemetry.Tracer`` stamps
+    its spans with: a phase and the ``phase.<name>`` span it opens agree, and
+    neither moves when the wall clock is stepped."""
 
     def __init__(self):
         self.phases: List[PhaseMetrics] = []
-        self._t0 = time.time()
+        self._t0 = time.monotonic()
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -363,7 +392,7 @@ class PhaseTimer:
         # stay out of module load.  span() is a no-op without a tracer.
         from .obsv import BOARD
         from .telemetry import span as _span
-        t0 = time.time()
+        t0 = time.monotonic()
         link0 = host_link_bytes()
         compile0 = compile_seconds()
         # training control plane: the phase boundary is the coarsest
@@ -375,25 +404,45 @@ class PhaseTimer:
         finally:
             mem = _device_memory()
             self.phases.append(PhaseMetrics(
-                name, time.time() - t0,
+                name, time.monotonic() - t0,
                 device_bytes_in_use=mem["bytes_in_use"],
                 peak_bytes_in_use=mem["peak_bytes_in_use"],
                 host_link_bytes=host_link_bytes() - link0,
                 compile_s=compile_seconds() - compile0))
             BOARD.publish(phase=f"{name}:done",
-                          phaseWallS=round(time.time() - t0, 3))
+                          phaseWallS=round(time.monotonic() - t0, 3))
 
     def app_metrics(self, tag: Optional[str] = None) -> AppMetrics:
-        return AppMetrics(tag, time.time() - self._t0, list(self.phases))
+        return AppMetrics(tag, time.monotonic() - self._t0, list(self.phases))
+
+
+# profiler_trace() contexts open right now: while there is one, every span
+# the ambient tracer opens is also written into the profiler's own trace
+_PROFILER_TRACES_OPEN = [0]
+
+
+def span_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named like the span being opened,
+    when a ``profiler_trace`` is open; ``None`` (and no jax import) when none
+    is.  ``Tracer.span`` enters it beside the span."""
+    if not _PROFILER_TRACES_OPEN[0]:
+        return None
+    import jax
+    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager
 def profiler_trace(log_dir: str):
     """Wrap a block in a jax.profiler trace (≙ the listener's event capture);
-    view with tensorboard or xprof."""
+    view with tensorboard or xprof.  Every span the ambient tracer opens
+    inside the block is also written as a ``TraceAnnotation`` of the same
+    name, so the program's spans lie in the ``.xplane.pb`` itself, on the
+    host plane over the device operations they caused."""
     import jax
     jax.profiler.start_trace(log_dir)
+    _PROFILER_TRACES_OPEN[0] += 1
     try:
         yield
     finally:
+        _PROFILER_TRACES_OPEN[0] -= 1
         jax.profiler.stop_trace()

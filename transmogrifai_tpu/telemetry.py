@@ -49,13 +49,14 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Union
 
 from .profiling import (LatencyHistogram, compile_stats, host_link_bytes,
-                        racing_stats)
+                        program_stats, racing_stats, span_annotation)
 
 __all__ = [
     "Span", "Tracer", "TraceContext", "TRACEPARENT_ENV", "use_tracer",
     "active_tracer", "span", "event", "current_span_id",
     "current_trace_context", "Counter", "Gauge", "MetricsRegistry",
-    "REGISTRY", "LatencyHistogram", "telemetry_summary",
+    "REGISTRY", "LatencyHistogram", "span_profile", "subtree",
+    "publish_train_profile", "telemetry_summary",
     "write_telemetry_summary", "render_trace_summary", "load_trace",
     "merge_traces",
 ]
@@ -184,6 +185,9 @@ def _proc_label(run_name: str, worker_id, rank) -> str:
     if rank is not None:
         label += f" [rank {rank}]"
     return label
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
 
 
 class Tracer:
@@ -319,7 +323,10 @@ class Tracer:
                              for l in (links or [])])
             self._stacks.setdefault(tid, []).append(sp)
         try:
-            yield sp
+            # inside profiling.profiler_trace the span also goes into the
+            # profiler's own trace, under its name
+            with span_annotation(name) or _NO_ANNOTATION:
+                yield sp
         except BaseException as e:
             sp.status = "error"
             sp.attrs.setdefault("error", f"{type(e).__name__}: {e}")
@@ -622,6 +629,9 @@ def _default_registry() -> MetricsRegistry:
     reg.gauge("compile.cache_hits", lambda: compile_stats()["cache_hits"])
     reg.gauge("compile.cache_misses",
               lambda: compile_stats()["cache_misses"])
+    # the same listener's per-program table (a dict: JSON exports carry it,
+    # the Prometheus text skips what is no number)
+    reg.gauge("compile.programs", program_stats)
     reg.gauge("racing.cv_fits_saved",
               lambda: racing_stats()["cv_fits_saved"])
     reg.gauge("racing.families_raced",
@@ -709,24 +719,108 @@ REGISTRY = _default_registry()
 # summaries + CLI rendering
 # --------------------------------------------------------------------------
 
+#: Names of the zero-duration events ``profiling``'s jit listener records
+#: under the span that caused a trace, a lowering, or a compile or cache load.
+JIT_EVENTS = ("jit.trace", "jit.lower", "jit.compile")
+
+
+def _clip(intervals, lo: float, hi: float):
+    return [(max(a, lo), min(b, hi)) for a, b in intervals
+            if min(b, hi) > max(a, lo)]
+
+
+def span_profile(spans: Iterable[Span]) -> Dict[str, Dict[str, Any]]:
+    """``{name: count, total_s, self_s, jit_s}`` over closed ``spans``.
+
+    ``total_s`` adds the durations of the spans of that name.  ``self_s`` is
+    a span's duration less the part of it its children cover: the children's
+    union, clipped to the span, children on other threads included.  Every
+    instant of a root span goes to exactly one span under it — the part of a
+    child that outlives its parent goes to nobody, and where two siblings
+    overlap (pool threads) the overlap is the one's that started first — so
+    the self seconds of a subtree add up to its root's duration.  ``jit_s``
+    is the seconds of the ``jit.*`` events recorded directly under spans of
+    that name: how much of their time went into tracing, lowering, and
+    compiling or loading programs."""
+    spans = [s for s in spans if s.end_s is not None]
+    ids = {s.span_id for s in spans}
+    children: Dict[Optional[str], List[Span]] = {}
+    for s in spans:
+        parent = s.parent_id if s.parent_id in ids else None
+        children.setdefault(parent, []).append(s)
+    table: Dict[str, Dict[str, Any]] = {}
+
+    def row(name: str) -> Dict[str, Any]:
+        return table.setdefault(name, {"count": 0, "total_s": 0.0,
+                                       "self_s": 0.0, "jit_s": 0.0})
+
+    # (span, the stretches of time that are this span's to give away)
+    todo = [(s, [(s.start_s, s.end_s)]) for s in children.get(None, [])]
+    while todo:
+        s, own = todo.pop()
+        r = row(s.name)
+        r["count"] += 1
+        r["total_s"] += s.duration_s
+        self_s = sum(b - a for a, b in own)
+        reach = float("-inf")       # how far the siblings so far have got
+        for c in sorted(children.get(s.span_id, []),
+                        key=lambda c: (c.start_s, c.end_s)):
+            if c.name in JIT_EVENTS:
+                r["jit_s"] += float(c.attrs.get("seconds") or 0.0)
+            # siblings come by start, so [c.start_s, reach) is taken already
+            theirs = _clip(own, max(c.start_s, reach), c.end_s)
+            reach = max(reach, c.end_s)
+            self_s -= sum(b - a for a, b in theirs)
+            todo.append((c, theirs))
+        r["self_s"] += self_s
+    return table
+
+
+def subtree(spans: Iterable[Span], root: Span) -> List[Span]:
+    """``root`` and the spans of ``spans`` that descend from it."""
+    spans = list(spans)
+    by_parent: Dict[Optional[str], List[Span]] = {}
+    for s in spans:
+        by_parent.setdefault(s.parent_id, []).append(s)
+    out, todo = [root], [root.span_id]
+    while todo:
+        kids = by_parent.get(todo.pop(), [])
+        out.extend(kids)
+        todo.extend(k.span_id for k in kids)
+    return out
+
+
+def publish_train_profile(root: Span) -> None:
+    """Set the gauge ``train.span_profile`` to the ``span_profile`` of the
+    closed ``workflow.train`` span ``root`` and what ran under it: what
+    ``telemetry.json``, ``GET /traces`` and the benchmark's readers read.
+    Called only when a tracer gave ``train`` a span."""
+    tracer = active_tracer()
+    if tracer is not None:
+        REGISTRY.gauge("train.span_profile").set(
+            span_profile(subtree(tracer.spans, root)))
+
+
 def telemetry_summary(tracer: Optional[Tracer] = None,
                       registry: Optional[MetricsRegistry] = None,
                       top_n: int = 15) -> Dict[str, Any]:
     """The ``telemetry.json`` payload: top slowest spans (with tree
-    context), span counts by name, and the full metrics snapshot.  Bundled
-    next to saved models and embedded in bench aux."""
+    context), per-name counts, total, self and jit seconds (``span_profile``)
+    and the full metrics snapshot.  Bundled next to saved models and
+    embedded in bench aux."""
     tracer = tracer if tracer is not None else active_tracer()
     registry = registry if registry is not None else REGISTRY
     out: Dict[str, Any] = {"metrics": registry.snapshot()}
     if tracer is not None:
         spans = tracer.spans
-        by_name: Dict[str, Dict[str, Any]] = {}
+        by_name: Dict[str, Dict[str, Any]] = {
+            name: {"count": r["count"], "totalS": round(r["total_s"], 6),
+                   "maxS": 0.0, "errors": 0,
+                   "selfS": round(r["self_s"], 6),
+                   "jitS": round(r["jit_s"], 6)}
+            for name, r in span_profile(spans).items()}
         for s in spans:
-            agg = by_name.setdefault(
-                s.name, {"count": 0, "totalS": 0.0, "maxS": 0.0,
-                         "errors": 0})
-            agg["count"] += 1
-            agg["totalS"] = round(agg["totalS"] + s.duration_s, 6)
+            agg = by_name[s.name]
             agg["maxS"] = round(max(agg["maxS"], s.duration_s), 6)
             agg["errors"] += int(s.status == "error")
         out["trace"] = {

@@ -31,6 +31,9 @@ from .types import Prediction
 
 MODEL_JSON = "op-model.json"
 PARAMS_NPZ = "params.npz"
+#: Rows below which ``train`` starts no transfers ahead of the fit: a tiny
+#: workflow would pay dispatch latency for nothing.
+PREFETCH_MIN_ROWS = 100_000
 
 
 class _WorkflowCore:
@@ -240,16 +243,18 @@ class Workflow(_WorkflowCore):
         from .resilience import FailureLog, record_failure, use_failure_log
         from .sanitizer import (audit_dag_purity, audit_stage_serialization,
                                 nan_guard)
-        from .telemetry import span
+        from .telemetry import publish_train_profile, span
 
         timer = PhaseTimer()
         flog = FailureLog()
         sweep_cp = None
         if resume_from is not None:
             sweep_cp = SweepCheckpoint(resume_from)
+        train_span = None       # stays None when no tracer is installed
         try:
             with span("workflow.train",
-                      resumed=bool(sweep_cp is not None and len(sweep_cp))), \
+                      resumed=bool(sweep_cp is not None and len(sweep_cp))
+                      ) as train_span, \
                     use_failure_log(flog), preemption_guard("train"), \
                     use_sweep_checkpoint(sweep_cp):
                 if sweep_cp is not None and len(sweep_cp):
@@ -262,6 +267,9 @@ class Workflow(_WorkflowCore):
         except TrainingPreempted as e:
             e.failure_log = flog
             raise
+        finally:
+            if train_span is not None:
+                publish_train_profile(train_span)
 
     def _train_guarded(self, timer, flog) -> "WorkflowModel":
         """Body of ``train`` — runs with the failure log, preemption guard
@@ -329,17 +337,21 @@ class Workflow(_WorkflowCore):
         (the TPU analog of the reference keeping row work on executors,
         SmartTextVectorizer.scala:80).  Large batches only: tiny workflows
         would pay dispatch latency for nothing."""
-        if len(batch) < 100_000:
+        if len(batch) < PREFETCH_MIN_ROWS:
             return
         import jax
 
         from .columns import to_device_f32
         from .ops.text import HashingVectorizer, SmartTextVectorizer
+        from .telemetry import span
         if jax.default_backend() == "cpu":
             return      # no slow link to hide
         try:
-            for st in dag_stages(compute_dag(self.result_features)):
-                if isinstance(st, (SmartTextVectorizer, HashingVectorizer)):
+            with span("prefetch.text_profiles"):
+                for st in dag_stages(compute_dag(self.result_features)):
+                    if not isinstance(st, (SmartTextVectorizer,
+                                           HashingVectorizer)):
+                        continue
                     num_hashes = int(st.get("num_hashes") or 0)
                     for f in st.input_features:
                         col = batch.get(f.name)
@@ -357,14 +369,15 @@ class Workflow(_WorkflowCore):
             # numeric raw columns + label: the weakref transfer cache makes
             # these THE copies every later consumer (frontier _prep,
             # vectorizer fits, selector y) reuses
-            for f in self.raw_features:
-                col = batch.get(f.name)
-                if col is None or col.is_host_object():
-                    continue
-                v = col.values
-                if (isinstance(v, np.ndarray)
-                        and v.dtype in (np.float32, np.float64)):
-                    to_device_f32(v, exact=f.is_response)
+            with span("prefetch.numeric"):
+                for f in self.raw_features:
+                    col = batch.get(f.name)
+                    if col is None or col.is_host_object():
+                        continue
+                    v = col.values
+                    if (isinstance(v, np.ndarray)
+                            and v.dtype in (np.float32, np.float64)):
+                        to_device_f32(v, exact=f.is_response)
         except Exception as e:  # noqa: BLE001 — prefetch must never break
             # train, but a dead prefetch means the host link no longer hides
             # behind RFF/fit work — observable, not invisible
@@ -387,6 +400,7 @@ class Workflow(_WorkflowCore):
         from .dag import prune_batch
         from .profiling import PhaseTimer
         from .selector import ModelSelector
+        from .telemetry import span
         timer = timer or PhaseTimer()
         fitted_dag = []
         # columns that outlive the DAG: raw inputs (label profile, re-scoring),
@@ -404,13 +418,14 @@ class Workflow(_WorkflowCore):
             from HBM before the selector's CV grid runs)."""
             if not pending:
                 return b
-            prog = ScoreProgram(
-                [[m] for m in pending],
-                [f.name for m in pending for f in m.output_features])
-            b = prog(b, keep_intermediate=True)
-            pending.clear()
-            pending_out.clear()
-            return prune_batch(b, remaining, keep)
+            with span("transform.apply", stages=len(pending)):
+                prog = ScoreProgram(
+                    [[m] for m in pending],
+                    [f.name for m in pending for f in m.output_features])
+                b = prog(b, keep_intermediate=True)
+                pending.clear()
+                pending_out.clear()
+                return prune_batch(b, remaining, keep)
 
         for i, layer in enumerate(dag):
             new_layer = []

@@ -115,6 +115,45 @@ class TestBundleRoundTrip:
         assert trace_count() == t0
 
 
+    def test_export_and_install_after_a_table_hit(self, tmp_path):
+        """A second train of the same content scores through the executable
+        the first left in the process-wide table; its save() still lowers
+        and builds what it ships, and the loaded bundle dispatches what it
+        installed, not the table's."""
+        from transmogrifai_tpu.compiled import trace_count
+        from transmogrifai_tpu.profiling import program_stats
+
+        def shared():
+            return {k: _counter("compiled.shared." + k)
+                    for k in ("hit", "miss", "bypass")}
+        first = train_small_model(make_records(120))[0].train()
+        _score_rows(first, SCORE_RECORDS)
+        second = train_small_model(make_records(120))[0].train()
+        before = shared()
+        want = _score_rows(second, SCORE_RECORDS)
+        after = shared()
+        assert after["hit"] > before["hit"]
+        assert (after["miss"], after["bypass"]) == (before["miss"],
+                                                    before["bypass"])
+        rows0 = dict(program_stats()["jit(traced)"])
+        path = str(tmp_path / "model")
+        second.save(path)
+        written = read_manifest(path)["aot"]["executables"]
+        rows1 = program_stats()["jit(traced)"]
+        assert written > 0
+        assert rows1["lowers"] - rows0["lowers"] >= written
+        assert rows1["compiles"] - rows0["compiles"] >= written
+        loaded = WorkflowModel.load(path)
+        assert loaded.aot_executables == written
+        before, traces = shared(), trace_count()
+        fallbacks = _counter("aot.fallback")
+        got = _score_rows(loaded, SCORE_RECORDS)
+        assert shared() == before and trace_count() == traces
+        assert _counter("aot.fallback") == fallbacks
+        for k in want:
+            np.testing.assert_array_equal(got[k], want[k])
+
+
 class TestFallbacks:
     def test_corrupt_artifact_is_caught_by_digest(self, trained, tmp_path):
         path = str(tmp_path / "model")

@@ -332,6 +332,46 @@ class TestGridSeam:
 # satellite: cache-warm processes still publish installable payloads (PR-9)
 # ---------------------------------------------------------------------------
 
+class TestScoreSeam:
+    def test_published_executable_installs_over_the_shared_table(
+            self, registry, tmp_path):
+        """save() publishes the scoring executables and leaves the same
+        programs in the process-wide table of compiled executables.  The
+        same model loaded from a bundle without AOT artifacts installs the
+        published one for its first score: the registry wins, the table is
+        not asked."""
+        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+        from test_aux_subsystems import make_records, train_small_model
+        from transmogrifai_tpu.compiled import trace_count
+        from transmogrifai_tpu.serving.engine import records_to_batch
+        from transmogrifai_tpu.workflow import WorkflowModel
+
+        def shared():
+            c = REGISTRY.snapshot()["counters"]
+            return {k: c.get("compiled.shared." + k, 0)
+                    for k in ("hit", "miss", "bypass")}
+
+        def score(m):
+            pred = next(f.name for f in m.result_features)
+            batch = records_to_batch(
+                m.raw_features, [{"x1": 0.4, "x2": 3.0, "cat": "a"}] * 4)
+            return np.asarray(m.score(batch=batch)[pred].values["probability"])
+        model = train_small_model(make_records(120))[0].train()
+        want = score(model)
+        bundle = str(tmp_path / "model")
+        model.save(bundle)
+        assert _counter("publishes") >= 1
+        model.save(str(tmp_path / "jit-only"), aot=False)   # same content
+        loaded = WorkflowModel.load(str(tmp_path / "jit-only"))
+        assert loaded.aot_executables == 0
+        assert loaded.score_program().registry_family
+        before, traces = shared(), trace_count()
+        np.testing.assert_array_equal(score(loaded), want)
+        assert loaded.score_program().aot_installed_count() >= 1
+        assert shared() == before and trace_count() == traces
+        assert _counter("install_failures") == 0
+
+
 class TestCacheWarmPublish:
     def test_cache_loaded_compile_republishes_fresh(self, registry,
                                                     tmp_path):

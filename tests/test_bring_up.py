@@ -23,6 +23,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from test_aux_subsystems import make_records, train_small_model  # noqa: E402
 
+from transmogrifai_tpu import compiled  # noqa: E402
 from transmogrifai_tpu.parallel import supervisor as sup  # noqa: E402
 from transmogrifai_tpu.profiling import compile_stats  # noqa: E402
 from transmogrifai_tpu.serving.engine import records_to_batch  # noqa: E402
@@ -67,7 +68,8 @@ def test_aot_load_runs_on_a_multi_device_host(tmp_path):
     assert events == []
 
 
-def test_export_rebuilds_a_cache_loaded_program_before_serializing(tmp_path):
+def test_export_rebuilds_a_cache_loaded_program_before_serializing(
+        tmp_path, monkeypatch):
     """XLA:CPU: an executable jax LOADED from the persistent compile cache
     serializes into a payload that fails at its first call.  A program first
     dispatched before ``save()`` may be one, so the export builds it again."""
@@ -88,9 +90,13 @@ def test_export_rebuilds_a_cache_loaded_program_before_serializing(tmp_path):
         def score(m):
             return np.asarray(m.score(batch=batch)[pred].values["probability"])
         want = score(model)          # compiles the 37-row program: disk cache
-        # fresh-process simulation: in-memory executables gone, disk entries
-        # not — the next dispatch of the 37-row program is a cache LOAD
+        # fresh-process simulation: in-memory executables gone (jit's, the
+        # score program's and the process-wide table's), disk entries not —
+        # the next dispatch of the 37-row program is a cache LOAD
         jax.clear_caches()
+        model._score_program = None
+        monkeypatch.setattr(compiled, "SHARED_EXECUTABLES",
+                            compiled._SharedExecutables(capacity=64))
         hits = compile_stats()["cache_hits"]
         np.testing.assert_array_equal(score(model), want)
         assert compile_stats()["cache_hits"] > hits, \

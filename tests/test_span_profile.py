@@ -237,6 +237,32 @@ def test_a_second_call_of_one_program_is_a_dispatch(narrow):
         "transform.dispatch")
 
 
+def test_a_second_train_traces_and_lowers_and_compiles_nothing(narrow):
+    """The process holds the fused programs of the first train
+    (``compiled.SHARED_EXECUTABLES``): a second user's train of the same
+    content pays ``jit.trace`` and ``jit.lower`` under its
+    ``transform.first_call`` spans and no ``jit.compile``."""
+    tracer, _ = traced_train(8)
+    first_calls = [s for s in tracer.spans
+                   if s.name == "transform.first_call"]
+    assert len(first_calls) >= 2
+    assert all(s.attrs["shared"] is True for s in first_calls)
+    under = [e for e in jit_events(tracer)
+             if e.parent_id in {s.span_id for s in first_calls}]
+    for sp in first_calls:
+        mine = [e.name for e in under if e.parent_id == sp.span_id]
+        assert mine == ["jit.trace", "jit.lower"], mine
+    assert {e.attrs["fun_name"] for e in under} == {"jit(traced)"}
+    keys = [s for s in tracer.spans if s.name == "transform.program_key"]
+    assert len(keys) == len(first_calls)
+    assert {s.parent_id for s in keys} == {s.span_id for s in first_calls}
+    # the first train of the module paid the compiles, unshared
+    paid = [s for s in narrow[0].spans if s.name == "transform.first_call"]
+    assert len(paid) == len(first_calls)
+    assert any(e.name == "jit.compile" for e in jit_events(narrow[0])
+               if e.parent_id in {s.span_id for s in paid})
+
+
 def test_span_count_does_not_grow_with_the_width_of_the_table(narrow):
     wide_tracer, wide_model = traced_train(192)
     narrow_tracer, narrow_model = narrow

@@ -15,6 +15,7 @@ import os
 import re
 import sys
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -353,8 +354,15 @@ class TestServerPropagation:
         assert back is not None and back.trace_id == "ab" * 16
         # the server's span is a CHILD: same trace, new span id
         assert back.span_id != "cd" * 8
-        req_spans = [s for s in tracer.spans
-                     if s.name == "serving.request"]
+        # the span closes after the reply is written: give the handler
+        # thread a moment under a loaded runner
+        deadline = time.monotonic() + 5.0
+        while True:
+            req_spans = [s for s in tracer.spans
+                         if s.name == "serving.request"]
+            if req_spans or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
         assert any(s.trace_id == "ab" * 16 for s in req_spans)
 
     def test_fresh_context_when_absent(self, traced_server):

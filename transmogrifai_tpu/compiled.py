@@ -25,7 +25,9 @@ of the program).
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import hashlib
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
@@ -35,7 +37,7 @@ import numpy as np
 from .columns import Column, ColumnBatch
 from .resilience import maybe_inject, record_failure
 from .stages.base import Transformer
-from .telemetry import span
+from .telemetry import REGISTRY, span
 
 _WIRE_SEP = "\x00"      # wire-entry names: "<uid>\x00<key>" — never a column
 
@@ -99,6 +101,99 @@ def _args_sig(arrays) -> Optional[str]:
         return None
 
 
+class _SharedExecutables:
+    """Bounded LRU of compiled executables (``jax.stages.Compiled``) by
+    program identity (:func:`_program_identity`), shared by every
+    ``ScoreProgram`` of the process.  Entries hold the executable only — no
+    stage, batch or column metadata stays alive through them."""
+
+    def __init__(self, capacity: int):
+        self.capacity = capacity
+        self._lock = threading.Lock()   # the serving engine scores on threads
+        self._entries: "collections.OrderedDict[str, Any]" = \
+            collections.OrderedDict()
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._entries)
+
+    def get(self, ident: str) -> Optional[Any]:
+        with self._lock:
+            exe = self._entries.get(ident)
+            if exe is not None:
+                self._entries.move_to_end(ident)
+            return exe
+
+    def put(self, ident: str, exe: Any) -> Any:
+        """Insert unless ``ident`` is held already (two threads that missed
+        together both compile; the first insert wins and both dispatch it).
+        Returns the executable held; evicts the least recently used past
+        ``capacity``."""
+        evicted = 0
+        with self._lock:
+            exe = self._entries.setdefault(ident, exe)
+            self._entries.move_to_end(ident)
+            while len(self._entries) > self.capacity:
+                self._entries.popitem(last=False)
+                evicted += 1
+        if evicted:
+            REGISTRY.counter("compiled.shared.evict").inc(evicted)
+        return exe
+
+
+# a train flushes three fused programs and a served model holds one per
+# segment and padded batch size (7 sizes by default): room for a few models'
+# worth, small enough that stale executables do not pile up on the device
+SHARED_EXECUTABLES = _SharedExecutables(capacity=64)
+
+
+def _program_identity(lowered) -> Optional[str]:
+    """Digest of everything that decides which executable is right for
+    ``lowered`` (a ``jax.stages.Lowered``), or None where that cannot be
+    shown.  The lowered module's text carries the computation, the input
+    avals, shardings and donation, and — as jax bakes closed-over values
+    into the module — every fitted value ``traced`` closes over, constants
+    printed in full; beside it go the platform, the ids of the devices it
+    is lowered for (a mesh's, in assignment order) and the argument and
+    result pytrees.  None when something outside the module would reach
+    the executable: constants hoisted into hidden call arguments
+    (``jax_use_simplified_jaxpr_constants``) or host callbacks."""
+    try:
+        low = lowered._lowering
+        if (low.const_args or low.compile_args["host_callbacks"]
+                or low.compile_args["keepalive"]):
+            return None
+        placement = (tuple(low._platforms),
+                     tuple(int(d.id) for d in low._device_list))
+    except (AttributeError, KeyError, TypeError):
+        return None     # another jax's internals: no proof, no sharing
+    h = hashlib.sha256(lowered.as_text().encode())
+    h.update(repr((placement, str(lowered.in_tree),
+                   str(lowered.out_tree))).encode())
+    return h.hexdigest()
+
+
+def _shared_executable(jitted, arrays) -> Tuple[Any, bool]:
+    """What the first call of a fresh fused program dispatches to, and
+    whether an earlier program of this process compiled it.  The stages are
+    traced and the module lowered either way (the trace is what fills this
+    program's output metadata; the module is the identity); only the
+    backend compile — or its persistent-cache load — is skipped on a hit."""
+    lowered = jitted.lower(arrays)
+    with span("transform.program_key"):
+        ident = _program_identity(lowered)
+    if ident is None:
+        # jit keeps the trace and the lowering just made; it compiles alone
+        REGISTRY.counter("compiled.shared.bypass").inc()
+        return jitted, False
+    exe = SHARED_EXECUTABLES.get(ident)
+    if exe is not None:
+        REGISTRY.counter("compiled.shared.hit").inc()
+        return exe, True
+    REGISTRY.counter("compiled.shared.miss").inc()
+    return SHARED_EXECUTABLES.put(ident, lowered.compile()), False
+
+
 class _StageTraceError(Exception):
     """Tracing failed inside a specific stage; carries the stage uid."""
 
@@ -114,9 +209,26 @@ class ScoreProgram:
     ``program = ScoreProgram(stages, result_names)`` then
     ``scored = program(batch)`` — equivalent to ``apply_dag`` but every
     maximal contiguous run of device-traceable (or staged) stages executes
-    as one jitted XLA program (host stages eager in between).  jax's jit
-    cache keys on the frontier shapes, so calls with a fixed schema compile
-    each segment exactly once.
+    as one jitted XLA program (host stages eager in between).
+
+    Where an executable lives.  ``_jitted[key]`` holds the ``jax.jit``
+    wrapper of a segment at a row count (``key`` = stage uids,
+    keep_intermediate, rows); the wrapper traces and lowers, and AOT export
+    lowers and clears it.  What a call dispatches to is held beside it, in
+    ``_executables``, per input-aval signature and mesh devices: the
+    ``jax.stages.Compiled`` of the lowered module, taken from the
+    process-wide ``SHARED_EXECUTABLES`` where an earlier ``ScoreProgram``
+    of this process — another train's, a reloaded model's — compiled the
+    same module, and compiled (or loaded from the persistent cache) and put
+    there otherwise.  That table's key (:func:`_program_identity`) is a
+    digest of the lowered module's text, which holds the computation, the
+    avals, shardings and every fitted value the stages close over, plus the
+    platform, the device ids and the argument and result pytrees; stage
+    uids and column names are not in it, and the column metadata of a call
+    always comes from this program's own trace.  An executable installed
+    from a bundle or the fleet registry replaces the wrapper and is
+    dispatched first.  So a fixed schema compiles each segment once a
+    process, and traces and lowers it once a ``ScoreProgram``.
     """
 
     def __init__(self, dag: Sequence, result_names: Sequence[str]):
@@ -135,6 +247,10 @@ class ScoreProgram:
         self._demoted: Set[str] = set()   # uids proven untraceable
         self._jitted: Dict[Tuple, Any] = {}
         self._metas: Dict[Tuple, Dict[str, Any]] = {}
+        # (key, input-aval signature, mesh device ids) -> what its calls
+        # dispatch to: a shared executable, or the jit wrapper itself where
+        # sharing was refused (class docstring)
+        self._executables: Dict[Tuple, Any] = {}
         # AOT seams (see aot.py): per-key input avals captured at first call
         # (what export lowers against), and keys whose entry is a
         # deserialized pre-compiled executable rather than a jit wrapper
@@ -180,6 +296,13 @@ class ScoreProgram:
 
     def aot_installed_count(self) -> int:
         return len(self._aot_installed) + len(self._aot_variants)
+
+    def _forget(self, key: Tuple) -> None:
+        """Drop ``key``'s jit wrapper, metadata and held executables."""
+        self._jitted.pop(key, None)
+        self._metas.pop(key, None)
+        for held in [k for k in self._executables if k[0] == key]:
+            del self._executables[held]
 
     # -- partition ----------------------------------------------------------
     def _partition(self, batch: ColumnBatch) -> List[Tuple[bool, List[Transformer]]]:
@@ -456,25 +579,36 @@ class ScoreProgram:
                     record_failure("compiled", "degraded", e,
                                    point="compiled.aot",
                                    fallback="JIT recompile")
-                    from .telemetry import REGISTRY
                     REGISTRY.counter("aot.fallback").inc()
                     self._aot_variants.pop((key, sig), None)
         jitted, canon_out_map = self._jitted[key]
+        # an installed executable wins over the shared table; arguments with
+        # no signature stay with jit, which keys on the avals itself
+        installed = key in self._aot_installed
+        held = (key, sig, None if mesh is None
+                else tuple(int(i) for i in mesh.device_ids.flat))
+        call = (jitted if installed or sig is None
+                else self._executables.get(held))
         try:
             # chaos hook: an injected fault here exercises the eager-segment
             # demotion below, the same path a device dispatch failure takes
             maybe_inject("compiled.segment", key=run[0].uid)
-            # a fresh jax.jit(traced) pays trace, lowering, and a compile or
-            # a cache load before it dispatches; a warm entry or an installed
+            # the first call of a program at these avals pays trace and
+            # lowering, and a compile or a cache load unless the process
+            # holds the executable already; a later call or an installed
             # executable dispatches only
-            with span("transform.first_call"
-                      if fresh and key not in self._aot_installed
-                      else "transform.dispatch"):
-                out_c = jitted(arrays)
+            first = call is None or (fresh and not installed)
+            with span("transform.first_call" if first
+                      else "transform.dispatch") as sp:
+                if call is None:
+                    call, shared = _shared_executable(jitted, arrays)
+                    self._executables[held] = call
+                    if sp is not None:
+                        sp.attrs["shared"] = shared
+                out_c = call(arrays)
             out = {n: out_c[c] for n, c in canon_out_map.items()}
         except _StageTraceError:
-            self._jitted.pop(key, None)
-            self._metas.pop(key, None)
+            self._forget(key)
             raise
         except Exception as e:  # noqa: BLE001
             if key in self._aot_installed:
@@ -484,11 +618,9 @@ class ScoreProgram:
                 record_failure("compiled", "degraded", e,
                                point="compiled.aot",
                                fallback="JIT recompile")
-                from .telemetry import REGISTRY
                 REGISTRY.counter("aot.fallback").inc()
                 self._aot_installed.discard(key)
-                self._jitted.pop(key, None)
-                self._metas.pop(key, None)
+                self._forget(key)
                 return self._apply_run(batch, run, later, keep_intermediate)
             # unexpected jit-boundary failure: never break scoring — run the
             # segment eagerly (≙ apply_dag) and stop attempting to compile
@@ -496,8 +628,7 @@ class ScoreProgram:
                            point="compiled.segment",
                            stages=[st.uid for st in run],
                            fallback="eager per-stage execution")
-            self._jitted.pop(key, None)
-            self._metas.pop(key, None)
+            self._forget(key)
             self._demoted.update(st.uid for st in run)
             b = batch
             for st in run:

@@ -376,6 +376,17 @@ def _device_memory() -> Dict[str, Optional[int]]:
         return {"bytes_in_use": None, "peak_bytes_in_use": None}
 
 
+def device_peak_bytes() -> List[Optional[int]]:
+    """``peak_bytes_in_use`` of every local device, in ``jax.local_devices()``
+    order; None where the backend reports no memory statistics (the CPU's)."""
+    try:
+        import jax
+        return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                for d in jax.local_devices()]
+    except Exception:
+        return []
+
+
 class PhaseTimer:
     """Collects per-phase timings; nested phases are recorded flat.  Walls
     are read from ``time.monotonic()``, the clock ``telemetry.Tracer`` stamps

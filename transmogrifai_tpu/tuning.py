@@ -1040,6 +1040,7 @@ class OpValidator:
             self.last_fit_shape = None
             self.last_mesh = None
         from .columns import to_device_f32
+        from .telemetry import REGISTRY, span
         # zero-weight row padding (mesh divisibility quantum, ladder rungs)
         # is exact only for families that declare it — one non-exact family
         # in the grid keeps the whole shared matrix unpadded
@@ -1054,160 +1055,192 @@ class OpValidator:
             # nnz rung (DeviceTable).  Global row_ids let GSPMD insert the
             # collectives; the segment-sum fitters tolerate the zero pads
             # exactly (value 0.0 addends at an in-range row).
-            mesh = self._maybe_mesh(N, pad=pad_exact_all)
-            self.last_mesh = mesh
-            if (mesh is None and not pad_exact_all
-                    and self._maybe_mesh(N, pad=True) is not None):
-                # honest degrade: the mesh WAS viable (pad-divisible) but a
-                # mixed grid (some family not weighted_pad_exact) pinned the
-                # matrix unpadded and indivisible — record it so bench aux
-                # and operators see single-device as a degrade, not a choice
-                record_failure(
-                    "sweep", "degraded",
-                    RuntimeError(
-                        f"N={N} indivisible and grid mixes non-pad-exact "
-                        f"families: sweep falls back to single device"),
-                    point="selector.mesh", fallback="single_device")
-                from .telemetry import REGISTRY as _REG
-                _REG.counter("selector.mesh_degraded").inc()
-            from .parallel import (data_axis_size, data_sharding,
-                                   pad_rows_for, stream_to_device)
-            from .parallel import memory as _mem
-            _plan_chunk = None   # preflight-chosen streaming chunk bytes
-            N_fit = N
-            if mesh is not None:
-                # multi-device: row-shard the matrix over the mesh 'data' axis
-                # and let GSPMD insert the collectives inside every batched
-                # fit/metric program (SURVEY §2.6 P1/P3 on the REAL path).
-                # Row count pads up to the device-divisible quantum — and,
-                # with the compile cache on, up to the fit-shape ladder rung —
-                # with zero-weight rows; one padded matrix serves every
-                # family (all are weighted_pad_exact whenever N_fit > N).
-                extent = data_axis_size(mesh)
-                N_fit = N + pad_rows_for(N, mesh)
-                if _fit_padding_enabled() and pad_exact_all:
-                    rung = _fit_pad_rows(N)
-                    N_fit = max(N_fit, -(-rung // extent) * extent)
-                if N_fit > N and not pad_exact_all:
-                    N_fit = N   # divisible N, mixed families: no ladder pad
-                if _mem.memory_governor_enabled():
-                    # preflight (ISSUE 15): estimate the padded-rung ×
-                    # dtype × grid-width × fold-panel footprint against the
-                    # per-device budget and choose chunk bytes (and grid
-                    # partitioning, read back by the fit bodies) BEFORE the
-                    # first transfer — the 11M-row regime stops discovering
-                    # OOM by dying in batched_device_put
-                    plan = _mem.plan_sweep_memory(
-                        rows=N_fit,
-                        cols=(int(X.shape[1])
-                              if is_sparse or getattr(X, "ndim", 1) == 2
-                              else 1),
-                        folds=len(fsplits),
-                        grid_width=max((len(c.grid) for c in candidates),
-                                       default=1),
-                        devices=int(mesh.devices.size),
-                        nnz=int(X.nnz) if is_sparse else None)
-                    _plan_chunk = plan.chunk_bytes
-                if is_sparse:
-                    # COO entries stream by nnz range under the same chunk
-                    # budget (DeviceTable dispatch inside stream_to_device);
-                    # empty pad rows own no entries, so the nnz-rung pads are
-                    # the only on-device synthesis
-                    X = stream_to_device(X, mesh, pad_to=N_fit,
-                                         chunk_bytes=_plan_chunk)
-                elif isinstance(X, jax.Array):
-                    # already device-resident (upstream DAG output): pad on
-                    # device, then lay out over the mesh in one shot
-                    Xj = X if X.dtype == jnp.float32 else X.astype(
-                        jnp.float32)
-                    if N_fit > N:
-                        Xj = jnp.pad(Xj, ((0, N_fit - N), (0, 0)))
-                    X = jax.device_put(Xj, data_sharding(mesh, 2))
-                else:
-                    # chunked host→device streaming: assemble each device's
-                    # row shard from bounded host slices so peak staging is
-                    # O(TRANSMOGRIFAI_DEVICE_CHUNK_BYTES), not O(dataset) —
-                    # the one-shot device_put staged the whole matrix
-                    X = stream_to_device(np.asarray(X, dtype=np.float32),
-                                         mesh, pad_to=N_fit,
-                                         chunk_bytes=_plan_chunk)
-                if N_fit > N and not is_sparse:
-                    # tree families quantile-bin over the true rows only —
-                    # keeps padded split points identical to unpadded ones
-                    # (sparse grids are linear-only: no binning to protect)
-                    from .models.trees import register_real_rows
-                    register_real_rows(X, N)
-            elif not isinstance(X, jax.Array) and not is_sparse:
-                # ONE host→device transfer shared by every candidate family —
-                # the host link is the scarce resource
-                X = to_device_f32(X)
-            is_dev = isinstance(X, jax.Array) or is_sparse
-            y_dev = None
-            if is_dev:
-                # exact wire (bf16 only when verified lossless), shared with
-                # every other consumer of the same label buffer
-                y_dev = (stream_to_device(y32, mesh, pad_to=N_fit,
-                                          chunk_bytes=_plan_chunk)
-                         if mesh is not None else
-                         to_device_f32(y32, exact=True))
-            X_host = None if is_dev else X   # lazy d2h only if a fallback needs it
-            va_slices = [va for _, va in fsplits]
-            va_masks_dev = []
-            assign = np.full(N_fit, _NO_FOLD, np.uint8)
-            if N_fit > N:
-                assign[N:] = _PAD_FOLD   # pad rows join NO fold, ever
-            for f, (_, va_idx) in enumerate(fsplits):
-                assign[va_idx] = f
-            # dense per-fold weight rows only materialize when a splitter
-            # may modify them (or the host path needs them below)
-            W_rows = []
-            neutral = splitter is None or (
-                type(splitter).validation_prepare_weights
-                is Splitter.validation_prepare_weights)
-            if not neutral or not (is_dev and len(fsplits) < _PAD_FOLD):
-                neutral = True
-                for f, (tr_idx, _) in enumerate(fsplits):
-                    w = np.zeros(N, np.float32)
-                    w[tr_idx] = 1.0
-                    if splitter is not None:
-                        w2 = splitter.validation_prepare_weights(y_all, w)
-                        neutral = neutral and w2 is w
-                        w = w2
-                    W_rows.append(w)
-            if is_dev and neutral and len(fsplits) < _PAD_FOLD:
-                # fold masks from ONE [N] uint8 assignment shipped over the
-                # link — 1 byte/row instead of (folds+1)×4 bytes/row of
-                # train + validation masks.  On the mesh the assignment is
-                # row-sharded first so the [F, N] masks materialize directly
-                # with the fit programs' expected sharding.
-                aj = jnp.asarray(assign)
+            # everything the sweep lays over the devices, under one span: the
+            # matrix, the label, the fold assignment and the masks
+            with span("selector.place") as place:
+                mesh = self._maybe_mesh(N, pad=pad_exact_all)
+                self.last_mesh = mesh
+                if (mesh is None and not pad_exact_all
+                        and self._maybe_mesh(N, pad=True) is not None):
+                    # honest degrade: the mesh WAS viable (pad-divisible) but a
+                    # mixed grid (some family not weighted_pad_exact) pinned
+                    # the matrix unpadded and indivisible — record it so bench
+                    # aux and operators see single-device as a degrade, not a
+                    # choice
+                    record_failure(
+                        "sweep", "degraded",
+                        RuntimeError(
+                            f"N={N} indivisible and grid mixes non-pad-exact "
+                            f"families: sweep falls back to single device"),
+                        point="selector.mesh", fallback="single_device")
+                    REGISTRY.counter("selector.mesh_degraded").inc()
+                from .parallel import (data_axis_size, data_sharding,
+                                       pad_rows_for, stream_to_device)
+                from .parallel import memory as _mem
+                _plan_chunk = None   # preflight-chosen streaming chunk bytes
+                N_fit = N
+                relayout_bytes = 0   # moved on the device to suit the mesh
                 if mesh is not None:
-                    aj = jax.device_put(aj, data_sharding(mesh, 1))
-                Wd, VAd = _fold_masks_from_assignment(aj, len(fsplits))
-                W = Wd
-                va_masks_dev = [VAd[f] for f in range(len(fsplits))]
-            else:
-                W = np.stack(W_rows)
+                    # multi-device: row-shard the matrix over the mesh 'data'
+                    # axis and let GSPMD insert the collectives inside every
+                    # batched fit/metric program (SURVEY §2.6 P1/P3 on the REAL
+                    # path). Row count pads up to the device-divisible quantum
+                    # — and, with the compile cache on, up to the fit-shape
+                    # ladder rung — with zero-weight rows; one padded matrix
+                    # serves every family (all are weighted_pad_exact whenever
+                    # N_fit > N).
+                    extent = data_axis_size(mesh)
+                    N_fit = N + pad_rows_for(N, mesh)
+                    if _fit_padding_enabled() and pad_exact_all:
+                        rung = _fit_pad_rows(N)
+                        N_fit = max(N_fit, -(-rung // extent) * extent)
+                    if N_fit > N and not pad_exact_all:
+                        N_fit = N   # divisible N, mixed families: no ladder pad
+                    if _mem.memory_governor_enabled():
+                        # preflight (ISSUE 15): estimate the padded-rung ×
+                        # dtype × grid-width × fold-panel footprint against the
+                        # per-device budget and choose chunk bytes (and grid
+                        # partitioning, read back by the fit bodies) BEFORE the
+                        # first transfer — the 11M-row regime stops discovering
+                        # OOM by dying in batched_device_put
+                        plan = _mem.plan_sweep_memory(
+                            rows=N_fit,
+                            cols=(int(X.shape[1])
+                                  if is_sparse or getattr(X, "ndim", 1) == 2
+                                  else 1),
+                            folds=len(fsplits),
+                            grid_width=max((len(c.grid) for c in candidates),
+                                           default=1),
+                            devices=int(mesh.devices.size),
+                            # a device-resident matrix stays as it is stored
+                            dtype_bytes=(int(X.dtype.itemsize)
+                                         if isinstance(X, jax.Array) else 4),
+                            nnz=int(X.nnz) if is_sparse else None)
+                        _plan_chunk = plan.chunk_bytes
+                    if is_sparse:
+                        # COO entries stream by nnz range under the same chunk
+                        # budget (DeviceTable dispatch inside
+                        # stream_to_device); empty pad rows own no entries, so
+                        # the nnz-rung pads are the only on-device synthesis
+                        X = stream_to_device(X, mesh, pad_to=N_fit,
+                                             chunk_bytes=_plan_chunk)
+                    elif isinstance(X, jax.Array):
+                        # already device-resident (the fused transform's
+                        # output): kept in the dtype it is stored in, as on
+                        # one device — a bfloat16 matrix stays bfloat16 and
+                        # the fit programs accumulate in float32; a float32
+                        # copy would double the bytes a chip holds and reads.
+                        # What a cast, a pad or a change of layout moves on
+                        # the device is counted (mesh.relayout_bytes)
+                        want = data_sharding(mesh, 2)
+                        Xj = X
+                        if X.dtype not in (jnp.float32, jnp.bfloat16):
+                            Xj = X.astype(jnp.float32)
+                        if N_fit > N:
+                            Xj = jnp.pad(Xj, ((0, N_fit - N), (0, 0)))
+                        if Xj is not X or not X.sharding.is_equivalent_to(
+                                want, X.ndim):
+                            relayout_bytes = int(Xj.nbytes)
+                        X = jax.device_put(Xj, want)
+                    else:
+                        # chunked host→device streaming: assemble each device's
+                        # row shard from bounded host slices so peak staging is
+                        # O(TRANSMOGRIFAI_DEVICE_CHUNK_BYTES), not O(dataset) —
+                        # the one-shot device_put staged the whole matrix
+                        X = stream_to_device(np.asarray(X, dtype=np.float32),
+                                             mesh, pad_to=N_fit,
+                                             chunk_bytes=_plan_chunk)
+                    if N_fit > N and not is_sparse:
+                        # tree families quantile-bin over the true rows only —
+                        # keeps padded split points identical to unpadded ones
+                        # (sparse grids are linear-only: no binning to protect)
+                        from .models.trees import register_real_rows
+                        register_real_rows(X, N)
+                elif not isinstance(X, jax.Array) and not is_sparse:
+                    # ONE host→device transfer shared by every candidate family
+                    # — the host link is the scarce resource
+                    X = to_device_f32(X)
+                is_dev = isinstance(X, jax.Array) or is_sparse
+                y_dev = None
                 if is_dev:
-                    for va_idx in va_slices:
-                        vm = np.zeros(N, np.float32)
-                        vm[va_idx] = 1.0
-                        if mesh is not None:
-                            # pad tail streams in as zeros — never validated
-                            vmj = stream_to_device(vm, mesh, pad_to=N_fit,
-                                                   chunk_bytes=_plan_chunk)
-                        else:
-                            vmj = to_device_f32(vm)  # 0/1 mask: bf16 exact
-                        va_masks_dev.append(vmj)
-                if mesh is not None:
-                    W = stream_to_device(W, mesh, row_axis=1, pad_to=N_fit,
-                                         chunk_bytes=_plan_chunk)
+                    # exact wire (bf16 only when verified lossless), shared
+                    # with every other consumer of the same label buffer
+                    y_dev = (stream_to_device(y32, mesh, pad_to=N_fit,
+                                              chunk_bytes=_plan_chunk)
+                             if mesh is not None else
+                             to_device_f32(y32, exact=True))
+                X_host = None if is_dev else X   # lazy d2h only if a fallback needs it
+                va_slices = [va for _, va in fsplits]
+                va_masks_dev = []
+                assign = np.full(N_fit, _NO_FOLD, np.uint8)
+                if N_fit > N:
+                    assign[N:] = _PAD_FOLD   # pad rows join NO fold, ever
+                for f, (_, va_idx) in enumerate(fsplits):
+                    assign[va_idx] = f
+                # dense per-fold weight rows only materialize when a splitter
+                # may modify them (or the host path needs them below)
+                W_rows = []
+                neutral = splitter is None or (
+                    type(splitter).validation_prepare_weights
+                    is Splitter.validation_prepare_weights)
+                if not neutral or not (is_dev and len(fsplits) < _PAD_FOLD):
+                    neutral = True
+                    for f, (tr_idx, _) in enumerate(fsplits):
+                        w = np.zeros(N, np.float32)
+                        w[tr_idx] = 1.0
+                        if splitter is not None:
+                            w2 = splitter.validation_prepare_weights(y_all, w)
+                            neutral = neutral and w2 is w
+                            w = w2
+                        W_rows.append(w)
+                if is_dev and neutral and len(fsplits) < _PAD_FOLD:
+                    # fold masks from ONE [N] uint8 assignment shipped over the
+                    # link — 1 byte/row instead of (folds+1)×4 bytes/row of
+                    # train + validation masks.  On the mesh the assignment is
+                    # row-sharded first so the [F, N] masks materialize
+                    # directly with the fit programs' expected sharding.
+                    aj = jnp.asarray(assign)
+                    if mesh is not None:
+                        aj = jax.device_put(aj, data_sharding(mesh, 1))
+                    Wd, VAd = _fold_masks_from_assignment(aj, len(fsplits))
+                    W = Wd
+                    va_masks_dev = [VAd[f] for f in range(len(fsplits))]
                 else:
-                    # one shared transfer; family fits see a no-op conversion.
-                    # exact=True: bf16 wire only when verified lossless (0/1
-                    # fold masks; balancer keep/drop weights) — custom
-                    # splitters may emit arbitrary weights, which go exact f32
-                    W = to_device_f32(W, exact=True)
+                    W = np.stack(W_rows)
+                    if is_dev:
+                        for va_idx in va_slices:
+                            vm = np.zeros(N, np.float32)
+                            vm[va_idx] = 1.0
+                            if mesh is not None:
+                                # pad tail streams in as zeros — never
+                                # validated
+                                vmj = stream_to_device(vm, mesh, pad_to=N_fit,
+                                                       chunk_bytes=_plan_chunk)
+                            else:
+                                vmj = to_device_f32(vm)  # 0/1 mask: bf16 exact
+                            va_masks_dev.append(vmj)
+                    if mesh is not None:
+                        W = stream_to_device(W, mesh, row_axis=1, pad_to=N_fit,
+                                             chunk_bytes=_plan_chunk)
+                    else:
+                        # one shared transfer; family fits see a no-op
+                        # conversion. exact=True: bf16 wire only when verified
+                        # lossless (0/1 fold masks; balancer keep/drop weights)
+                        # — custom splitters may emit arbitrary weights, which
+                        # go exact f32
+                        W = to_device_f32(W, exact=True)
+                n_dev = 1 if mesh is None else int(mesh.devices.size)
+                REGISTRY.gauge("mesh.devices").set(n_dev)
+                if mesh is not None:
+                    REGISTRY.counter("mesh.relayout_bytes").inc(relayout_bytes)
+                if place is not None:
+                    place.attrs.update(
+                        rows=int(N), pad_rows=int(N_fit - N), devices=n_dev,
+                        dtype=str(getattr(X, "dtype", "")),
+                        relayout_bytes=relayout_bytes,
+                        bytes_placed=sum(
+                            int(getattr(a, "nbytes", 0))
+                            for a in (X, y_dev, W, *va_masks_dev)))
             # fit-shape canonicalization (ISSUE 4 compile reuse): one shared
             # zero-weight-row-padded copy of (X, y) serves every pad-exact
             # family, so nearby row counts land on the same ladder rung and

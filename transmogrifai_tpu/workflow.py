@@ -239,11 +239,11 @@ class Workflow(_WorkflowCore):
         log) instead of dying mid-write."""
         from .checkpoint import (SweepCheckpoint, TrainingPreempted,
                                  preemption_guard, use_sweep_checkpoint)
-        from .profiling import PhaseTimer
+        from .profiling import PhaseTimer, device_peak_bytes
         from .resilience import FailureLog, record_failure, use_failure_log
         from .sanitizer import (audit_dag_purity, audit_stage_serialization,
                                 nan_guard)
-        from .telemetry import publish_train_profile, span
+        from .telemetry import REGISTRY, publish_train_profile, span
 
         timer = PhaseTimer()
         flog = FailureLog()
@@ -268,6 +268,9 @@ class Workflow(_WorkflowCore):
             e.failure_log = flog
             raise
         finally:
+            # what each device has held at most so far, fullest or not: on a
+            # mesh the shards' peaks differ where the work does
+            REGISTRY.gauge("mesh.device_peak_bytes").set(device_peak_bytes())
             if train_span is not None:
                 publish_train_profile(train_span)
 

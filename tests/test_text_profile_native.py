@@ -1,0 +1,284 @@
+"""The native text profile (native/textprof.cpp via ops/text_profile.py):
+one walk of a column's object array, read in place, gives what ``_py_scan``
+gives and — with a cap — what ``_py_intern`` gives, array for array; a
+batch's columns walked side by side give the same profiles in feature order;
+the counters say which of this happened."""
+
+import os
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+from transmogrifai_tpu import types as T
+from transmogrifai_tpu.columns import Column, ColumnBatch
+from transmogrifai_tpu.native import load
+from transmogrifai_tpu.ops import text_profile as tp
+from transmogrifai_tpu.telemetry import REGISTRY, Tracer, use_tracer
+
+pytestmark = pytest.mark.skipif(load("textprof") is None,
+                                reason="no native toolchain")
+
+SCAN_FIELDS = ("null", "empty", "lengths", "crc", "tok_lens", "tok_hash")
+COUNTERS = ("text_profile.scan", "text_profile.fused_intern",
+            "text_profile.intern.hit", "text_profile.intern.miss")
+
+
+def _column(values) -> np.ndarray:
+    arr = np.empty(len(values), dtype=object)
+    arr[:] = values
+    return arr
+
+
+def _ascii(n=400):
+    # 1, 7, 8, 9, 16, 17 and 40 bytes: every tail of the 8-byte CRC step
+    pool = ["x", "seven_b", "eight_by", "nine_byte", "sixteen bytes ok",
+            "seventeen bytes s", "a much longer value, with 40 bytes in it",
+            "it's", "a b c", "tab\tsep,comma;semi"]
+    return _column([pool[(i * i + i // 3) % len(pool)] + str(i % 23)
+                    for i in range(n)])
+
+
+CASES = {
+    "ascii": (_ascii(), 1),
+    "non_ascii_row": (_column(["plain", "Ünïcode tøken K", "plain", "É",
+                               "日本語 テキスト", "after"] * 20), 1),
+    "nones": (_column([None, "a", None, None, "b", "a", None] * 30), 1),
+    "empty_strings": (_column(["", "a", "", None, "b b", ""] * 30), 1),
+    "upper_case": (_column(["Mixed CASE Words", "mixed case words", "ABC",
+                            "abc", "AbC dEf"] * 30), 1),
+    "min_token_len_2": (_column(["a bb ccc d", "x", "yy", "it's a b",
+                                 "Ü bb c"] * 30), 2),
+    "all_null": (_column([None] * 50), 1),
+    "zero_rows": (np.empty(0, dtype=object), 1),
+}
+
+
+def _assert_scan_equal(prof, ref):
+    for f in SCAN_FIELDS:
+        a, b = getattr(prof, f), getattr(ref, f)
+        assert a.dtype == b.dtype and np.array_equal(a, b), f
+
+
+def _assert_interned_equal(iv, ref):
+    assert iv.uniq == ref.uniq
+    assert iv.counts.dtype == ref.counts.dtype == np.int64
+    assert iv.codes.dtype == ref.codes.dtype == np.int32
+    assert np.array_equal(iv.counts, ref.counts)
+    assert np.array_equal(iv.codes, ref.codes)
+    assert iv.frozen == ref.frozen and iv.cap == ref.cap
+
+
+def _counters():
+    c = REGISTRY.counters()
+    return {k: c.get(k, 0) for k in COUNTERS}
+
+
+def _moved(before):
+    return {k.split("text_profile.")[1]: v - before[k]
+            for k, v in _counters().items() if v != before[k]}
+
+
+@pytest.mark.parametrize("cap", [None, -1, 0, 3, 30])
+@pytest.mark.parametrize("case", list(CASES))
+def test_fused_pass_equals_python_scan_and_intern(case, cap):
+    arr, min_len = CASES[case]
+    prof = tp.scan_strings(arr, min_len, cap)
+    _assert_scan_equal(prof, tp._py_scan(arr, min_len))
+    assert prof._strings is arr
+    if cap is None:
+        assert prof._interned == {}
+        return
+    assert list(prof._interned) == [cap]
+    _assert_interned_equal(prof._interned[cap], tp._py_intern(arr, cap))
+    # the in-place intern a cache miss falls to: the same answer
+    _assert_interned_equal(tp._intern(arr, cap), tp._py_intern(arr, cap))
+
+
+def test_blocks_strides_and_what_the_walk_refuses():
+    native = load("textprof")
+    rng = np.random.default_rng(0)
+    pool = _column(["", None, "Ünï", "it's"]
+                   + [f"v{i:05d} w{i % 7}" for i in range(3000)])
+    # more rows than one block holds (65,536), and every other one of them
+    big = pool[rng.integers(0, len(pool), size=140_001)]
+    for arr in (big, big[::2]):
+        prof = tp.scan_strings(arr, 1, 30)
+        _assert_scan_equal(prof, tp._py_scan(arr))
+        _assert_interned_equal(prof._interned[30], tp._py_intern(arr, 30))
+        _assert_interned_equal(tp._intern(arr, -1), tp._py_intern(arr, -1))
+    assert not big[::2].flags.c_contiguous
+    with pytest.raises(TypeError):
+        native.profile(["a", "b"])                   # a list: not read
+    with pytest.raises(TypeError):
+        native.intern(np.zeros((2, 2), dtype=object))
+    with pytest.raises(TypeError):
+        native.profile(_column(["a", 3]))            # a value that is no str
+    assert not hasattr(native, "scan")               # the list walk is gone
+
+
+def _text_columns(n_cols=11, rows=5000):
+    rng = np.random.default_rng(1)
+    cols = []
+    for j in range(n_cols):
+        levels = _column([None, ""] + [f"c{j}_{i:x} t{i % 5}"
+                                      for i in range(3 + 17 * j)])
+        cols.append(Column(T.Text, levels[rng.integers(0, len(levels),
+                                                       size=rows)]))
+    return cols
+
+
+@pytest.mark.parametrize("cores,most", [(1, 4), (2, 4), (8, 4), (64, 16)])
+def test_pool_gives_the_same_profiles_in_feature_order(monkeypatch, cores,
+                                                       most):
+    """One worker is a plain loop; more walk side by side — in the last case
+    a thread a column, more than this machine has cores — with threads
+    switched every 10 us so that a lost update on a shared structure would
+    show."""
+    monkeypatch.setattr(os, "sched_getaffinity",
+                        lambda pid: set(range(cores)))
+    monkeypatch.setattr(tp, "_MAX_WORKERS", most)
+    cols = _text_columns()
+    pairs = [(c, 30 if j % 3 else None) for j, c in enumerate(cols)]
+    before = _counters()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        profs = list(tp.profile_columns(pairs))
+    finally:
+        sys.setswitchinterval(interval)
+    assert REGISTRY.gauge("text_profile.workers").value == min(
+        cores, most, len(cols))
+    capped = sum(cap is not None for _, cap in pairs)
+    assert _moved(before) == {"scan": len(cols), "fused_intern": capped}
+    for (col, cap), prof in zip(pairs, profs):
+        assert tp.column_profile(col) is prof          # cached on ITS column
+        _assert_scan_equal(prof, tp._py_scan(col.values))
+        if cap is None:
+            assert prof._interned == {}
+        else:
+            _assert_interned_equal(prof._interned[cap],
+                                   tp._py_intern(col.values, cap))
+
+
+def test_a_failing_column_raises_from_the_pool(monkeypatch):
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(4)))
+    cols = _text_columns(4, 100)
+    cols[2] = Column(T.Text, _column(["a", 7, "b"]))
+    with pytest.raises(TypeError):
+        list(tp.profile_columns([(c, 30) for c in cols]))
+
+
+def _smart_text_stage(names, **params):
+    from transmogrifai_tpu.features import FeatureBuilder
+    from transmogrifai_tpu.ops.text import SmartTextVectorizer
+
+    st = SmartTextVectorizer(**params)
+    st.set_input(*[FeatureBuilder.Text(n).as_predictor() for n in names])
+    return st
+
+
+def test_fit_after_a_profiled_batch_only_hits_and_a_score_batch_interns_nothing():
+    cols = _text_columns(6, 3000)
+    names = [f"t{j}" for j in range(len(cols))]
+    batch = ColumnBatch(dict(zip(names, cols)), 3000)
+    st = _smart_text_stage(names, num_hashes=64, max_cardinality=30)
+    before = _counters()
+    list(tp.profile_columns([(c, 30) for c in cols]))
+    assert _moved(before) == {"scan": 6, "fused_intern": 6}
+    before = _counters()
+    model = st.fit(batch)
+    assert _moved(before) == {"intern.hit": 6}
+    strategies = model.fitted["strategies"]
+    assert set(strategies.values()) == {"pivot", "hash"}
+    out = np.asarray(model.transform(batch).values)
+
+    # the same fit with nothing profiled ahead walks every column again
+    fresh = ColumnBatch({n: Column(T.Text, c.values.copy())
+                         for n, c in zip(names, cols)}, 3000)
+    before = _counters()
+    model2 = _smart_text_stage(names, num_hashes=64,
+                               max_cardinality=30).fit(fresh)
+    assert _moved(before) == {"scan": 6, "intern.miss": 6}
+    assert model2.fitted["strategies"] == strategies
+    assert np.array_equal(np.asarray(model2.transform(fresh).values), out)
+
+    # a score batch: profiled without a cap; only a pivoted column's vocab
+    # lookup interns (values(-1), as before), a hashed column never
+    score = ColumnBatch({n: Column(T.Text, c.values.copy())
+                         for n, c in zip(names, cols)}, 3000)
+    before = _counters()
+    assert np.array_equal(np.asarray(model.transform(score).values), out)
+    pivots = sum(s == "pivot" for s in strategies.values())
+    moved = _moved(before)
+    assert moved.pop("scan") == 6
+    assert moved.pop("intern.miss") == pivots
+    assert "fused_intern" not in moved
+    for n in names:
+        interned = tp.column_profile(score[n])._interned
+        assert list(interned) == ([-1] if strategies[n] == "pivot" else [])
+
+
+def test_the_object_array_is_never_copied_into_a_list(monkeypatch):
+    def no_list(*a, **k):
+        raise AssertionError("a text column was copied into a list")
+
+    monkeypatch.setattr(tp, "list", no_list, raising=False)
+    col = _text_columns(1, 2000)[0]
+    prof = tp.column_profile(col, 30)
+    assert prof._strings is col.values
+    prof.values(30), prof.values(-1), prof.values(0)
+    plain = tp.column_profile(Column(T.Text, col.values.copy()))
+    plain.values(3)
+    with open(os.path.join(os.path.dirname(tp.__file__), "..", "..",
+                           "native", "textprof.cpp")) as fh:
+        src = fh.read()
+    assert "PySequence_Fast(" not in src and "ALLOW_THREADS" in src
+
+
+def test_prefetch_profiles_side_by_side_and_transfers_in_feature_order(
+        monkeypatch):
+    """``Workflow._prefetch_text_profiles`` returns early on the CPU
+    backend; told it is on an accelerator, it profiles every column of the
+    hashing stages with the stage's cap, puts the packed ids on the device
+    from the calling thread in feature order, and says so on its span."""
+    import jax
+
+    from transmogrifai_tpu import workflow as wf_mod
+    from transmogrifai_tpu.profiling import host_link_bytes
+    from transmogrifai_tpu.workflow import Workflow
+
+    rows = 2000
+    monkeypatch.setattr(wf_mod, "PREFETCH_MIN_ROWS", rows)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(3)))
+    order = []
+    real = tp.TextProfile.prefetch
+
+    def spy(self, num_hashes):
+        order.append((id(self), threading.get_ident()))
+        return real(self, num_hashes)
+
+    monkeypatch.setattr(tp.TextProfile, "prefetch", spy)
+    cols = _text_columns(5, rows)
+    names = [f"t{j}" for j in range(len(cols))]
+    batch = ColumnBatch(dict(zip(names, cols)), rows)
+    st = _smart_text_stage(names, num_hashes=64, max_cardinality=7)
+    workflow = Workflow().set_input_batch(batch).set_result_features(
+        st.get_output())
+    before, link = _counters(), host_link_bytes()
+    tracer = Tracer("prefetch")
+    with use_tracer(tracer):
+        workflow._prefetch_text_profiles(batch)
+    assert _moved(before) == {"scan": 5, "fused_intern": 5}
+    assert order == [(id(tp.column_profile(c)), threading.get_ident())
+                     for c in cols]
+    assert host_link_bytes() - link == sum(
+        tp.column_profile(c)._device_packed[64].nbytes for c in cols)
+    (sp,) = [s for s in tracer.spans if s.name == "prefetch.text_profiles"]
+    assert sp.attrs == {"columns": 5, "rows": rows, "workers": 3}
+    assert {s.thread for s in tracer.spans} == {sp.thread}
+    before = _counters()
+    st.fit(batch)
+    assert _moved(before) == {"intern.hit": 5}

@@ -1,12 +1,13 @@
-"""One-pass text column profile shared by every host consumer of a text
+"""One-walk text column profile shared by every host consumer of a text
 column (reference parity targets: RawFeatureFilter's presence + hashed value
 distribution RawFeatureFilter.scala:137, SmartTextVectorizer's TextStats fit
 pass SmartTextVectorizer.scala:80-123, OpHashingTF's tokenize+hash transform).
 
 The transmogrification hot path used to rescan each text column once per
-consumer — a Python-object walk over millions of cells each time.  Here ONE
-native pass (native/textprof.cpp) computes *parameter-free* per-row
-products, cached on the Column instance:
+consumer — a Python-object walk over millions of cells each time.  Here one
+native walk (native/textprof.cpp ``profile``) reads the column's object
+array in place — no list copy of it is ever made — and computes the
+*parameter-free* per-row products, cached on the Column instance:
 
 * ``null``/``empty``/``lengths``  — presence + TextStats length stats
 * ``crc``      — full zlib crc32 per value; rebin with ``% text_bins`` for
@@ -15,17 +16,37 @@ products, cached on the Column instance:
   token; rebucket with ``% num_hashes`` for any hash width
 
 Value interning (``values(cap)``) is the only cap-dependent product and is
-cached per cap.  All consumers fall back to pure Python when the native
-toolchain is absent — identical results, slower.
+cached per cap.  A caller that knows the cap before the column is first
+walked (``column_profile(col, cap)``: ``profile_columns`` for a training
+batch) gets it from that same walk; any other ``values(cap)`` that no cached
+interning answers walks the column once more (native ``intern``, in place
+too).  A score batch is never interned unless a consumer asks.
+
+The native walk holds the GIL only while it fetches a block of rows' utf-8
+pointers; hashing, tokenising and the intern table run with it released.
+``profile_columns`` therefore walks a batch's columns side by side on a
+few threads, as the cores the process may use allow
+(``os.sched_getaffinity``).
+
+The counters ``text_profile.scan`` (columns walked), ``.fused_intern``
+(interned by that walk), ``.intern.hit`` / ``.intern.miss`` (``values(cap)``
+answered from the cache / by another walk) and the gauge
+``text_profile.workers`` say which of this happened.  All consumers fall
+back to pure Python when the native toolchain is absent — identical
+results, slower.
 """
 
 from __future__ import annotations
 
+import os
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from ..telemetry import REGISTRY
 
 
 @dataclass
@@ -125,11 +146,14 @@ class TextProfile:
         results are provably identical: a non-frozen capped run equals the
         exact run, and an exact run with U distinct values equals any
         capped run with cap >= U (the freeze never engages)."""
-        if cap in self._interned:
-            return self._interned[cap]
-        for iv in self._interned.values():
-            if not iv.frozen and (cap < 0 or len(iv.uniq) <= cap):
-                return iv
+        iv = self._interned.get(cap)
+        if iv is None:
+            iv = next((c for c in self._interned.values() if not c.frozen
+                       and (cap < 0 or len(c.uniq) <= cap)), None)
+        if iv is not None:
+            REGISTRY.counter("text_profile.intern.hit").inc()
+            return iv
+        REGISTRY.counter("text_profile.intern.miss").inc()
         self._interned[cap] = _intern(self._strings, cap)
         return self._interned[cap]
 
@@ -191,66 +215,120 @@ def _py_intern(strings: Sequence, cap: int) -> InternedValues:
                           frozen=cap >= 0 and len(uniq) > cap)
 
 
-def _intern(strings, cap: int) -> InternedValues:
+def _interned_values(uniq, counts, codes, cap: int) -> InternedValues:
+    return InternedValues(uniq, counts, codes, cap,
+                          frozen=cap >= 0 and len(uniq) > cap)
+
+
+def _intern(strings: np.ndarray, cap: int) -> InternedValues:
     from ..native import load
 
     native = load("textprof")
     if native is None:
         return _py_intern(strings, cap)
-    uniq, counts, codes = native.intern(list(strings), cap)
-    return InternedValues(list(uniq), counts, codes, cap,
-                          frozen=cap >= 0 and len(uniq) > cap)
+    return _interned_values(*native.intern(strings, cap), cap)
 
 
-def scan_strings(strings, min_token_len: int = 1) -> TextProfile:
-    """Profile a string sequence (native pass when available)."""
-    from ..native import load
+def _object_column(strings) -> np.ndarray:
+    """``strings`` as the 1-D object array the native walk reads in place:
+    itself when it is one already."""
+    if isinstance(strings, np.ndarray) and strings.dtype == object \
+            and strings.ndim == 1:
+        return strings
+    arr = np.empty(len(strings), dtype=object)
+    arr[:] = strings
+    return arr
+
+
+def _splice_fallback(strings, lens, hashes, fallback, min_token_len):
+    """Non-ASCII rows (``lens`` -1): the Python tokenizer's hashes spliced
+    in at each row's place, for exact unicode case-folding parity."""
     from .text import fnv1a_32, tokenize_text
 
+    rows = [[fnv1a_32(t) for t in tokenize_text(strings[i], min_token_len)]
+            for i in fallback]
+    counts = np.fromiter(map(len, rows), np.int64, count=len(rows))
+    lens = lens.copy()
+    lens[fallback] = 0
+    at = np.cumsum(lens) - lens          # where each row's hashes start
+    hashes = np.insert(
+        hashes, np.repeat(at[fallback], counts),
+        np.fromiter((h for r in rows for h in r), np.uint32,
+                    count=int(counts.sum())))
+    lens[fallback] = counts
+    return lens, hashes
+
+
+def scan_strings(strings, min_token_len: int = 1,
+                 cap: Optional[int] = None) -> TextProfile:
+    """Profile a string sequence (one native walk when available).  With a
+    ``cap`` the same walk also interns the values, as ``values(cap)`` would
+    by a second one."""
+    from ..native import load
+
+    strings = _object_column(strings)
     native = load("textprof")
+    REGISTRY.counter("text_profile.scan").inc()
     if native is None:
         prof = _py_scan(strings, min_token_len)
     else:
-        d = native.scan(list(strings), min_token_len)
-        lens = d["tok_lens"]
-        hashes = d["tok_hash"]
-        fallback = d["fallback"]
-        if fallback:
-            # non-ASCII rows: splice the Python tokenizer's hashes in place
-            # for exact unicode case-folding parity
-            fb = {i: np.asarray(
-                [fnv1a_32(t) for t in tokenize_text(strings[i],
-                                                    min_token_len)],
-                np.uint32) for i in fallback}
-            out_lens = lens.copy()
-            pieces: List[np.ndarray] = []
-            pos = 0
-            for i, L in enumerate(lens):
-                if L < 0:
-                    out_lens[i] = len(fb[i])
-                    pieces.append(fb[i])
-                elif L:
-                    pieces.append(hashes[pos:pos + L])
-                    pos += L
-            hashes = (np.concatenate(pieces).astype(np.uint32) if pieces
-                      else np.zeros(0, np.uint32))
-            lens = out_lens
-        prof = TextProfile(d["null"].astype(bool), d["empty"].astype(bool),
-                           d["lengths"], d["crc"], lens, hashes)
-    prof._strings = strings if isinstance(strings, np.ndarray) \
-        else np.asarray(list(strings), dtype=object)
+        d = native.profile(strings, min_token_len, cap)
+        lens, hashes = d["tok_lens"], d["tok_hash"]
+        if d["fallback"].size:
+            lens, hashes = _splice_fallback(strings, lens, hashes,
+                                            d["fallback"], min_token_len)
+        prof = TextProfile(d["null"], d["empty"], d["lengths"], d["crc"],
+                           lens, hashes)
+        if cap is not None:
+            prof._interned[cap] = _interned_values(
+                d["uniq"], d["counts"], d["codes"], cap)
+            REGISTRY.counter("text_profile.fused_intern").inc()
+    prof._strings = strings
     return prof
 
 
-def column_profile(col) -> TextProfile:
+def column_profile(col, cap: Optional[int] = None) -> TextProfile:
     """Profile of a text-kind Column, computed once and cached on the
-    instance (Columns are immutable throughout the framework)."""
+    instance (Columns are immutable throughout the framework).  ``cap``
+    matters only to the call that walks the column: it then interns too."""
     prof = getattr(col, "_text_profile", None)
     if prof is None:
         from .categorical import _col_strings
-        prof = scan_strings(_col_strings(col))
+        prof = scan_strings(_col_strings(col), cap=cap)
         try:
             object.__setattr__(col, "_text_profile", prof)
         except Exception:  # pragma: no cover — exotic column subtype
             pass
     return prof
+
+
+# Phase one of a walk holds the GIL for about a fifth of it, so past four or
+# five walks at once the rest queue for it: on a 13-core and on a 30-core
+# host four workers were as fast as eight and faster than one a core
+# (PERF.md §5).
+_MAX_WORKERS = 4
+
+
+def pool_size(columns: int) -> int:
+    """Worker threads ``profile_columns`` walks that many columns on: the
+    cores this process may run on, up to ``_MAX_WORKERS``."""
+    return min(columns, len(os.sched_getaffinity(0)), _MAX_WORKERS)
+
+
+def profile_columns(columns: Sequence[Tuple[object, Optional[int]]]
+                    ) -> Iterator[TextProfile]:
+    """``column_profile(col, cap)`` of every (column, cap) pair, yielded in
+    order, the columns walked side by side on ``pool_size`` worker threads,
+    so the caller works on a profile while later columns are still walked.
+    One worker is a plain loop."""
+    from ..native import load
+
+    workers = pool_size(len(columns))
+    REGISTRY.gauge("text_profile.workers").set(workers)
+    if workers <= 1:
+        for col, cap in columns:
+            yield column_profile(col, cap)
+        return
+    load("textprof")        # built and imported once, before the threads
+    with ThreadPoolExecutor(workers) as pool:
+        yield from pool.map(lambda cc: column_profile(*cc), columns)

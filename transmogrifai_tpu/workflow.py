@@ -332,30 +332,36 @@ class Workflow(_WorkflowCore):
         return model
 
     def _prefetch_text_profiles(self, batch) -> None:
-        """Start the async host→device transfers a training run will need,
-        up front: packed token ids for hashing vectorizers (profiled ONCE,
-        cached on the Column) and the bf16-wire copies of numeric raw
-        columns + the label.  The 5-12 MB/s host link then overlaps
-        RawFeatureFilter + fit host work instead of serializing after it
-        (the TPU analog of the reference keeping row work on executors,
-        SmartTextVectorizer.scala:80).  Large batches only: tiny workflows
-        would pay dispatch latency for nothing."""
+        """Do up front what a training run will need of its raw columns:
+        every text column of a hashing vectorizer profiled ONCE
+        (``ops.text_profile.profile_columns``: one native walk a column,
+        a few columns side by side on worker threads, interned in that walk
+        at the stage's ``max_cardinality`` so that the fit finds
+        ``values(cap)`` cached), its packed token ids put on the device
+        from this thread in feature order, and the bf16-wire copies of
+        numeric raw columns + the label.  The async host→device transfers
+        then overlap RawFeatureFilter + fit host work instead of
+        serializing after it (the TPU analog of the reference keeping row
+        work on executors, SmartTextVectorizer.scala:80).  Large batches
+        only: tiny workflows would pay dispatch latency for nothing."""
         if len(batch) < PREFETCH_MIN_ROWS:
             return
         import jax
 
         from .columns import to_device_f32
         from .ops.text import HashingVectorizer, SmartTextVectorizer
+        from .ops.text_profile import pool_size, profile_columns
         from .telemetry import span
         if jax.default_backend() == "cpu":
             return      # no slow link to hide
         try:
-            with span("prefetch.text_profiles"):
+            with span("prefetch.text_profiles", rows=len(batch)) as sp:
+                columns, hashes = [], []
                 for st in dag_stages(compute_dag(self.result_features)):
                     if not isinstance(st, (SmartTextVectorizer,
                                            HashingVectorizer)):
                         continue
-                    num_hashes = int(st.get("num_hashes") or 0)
+                    cap = st.get("max_cardinality")     # None: no interning
                     for f in st.input_features:
                         col = batch.get(f.name)
                         if col is None or not col.is_host_object():
@@ -365,10 +371,15 @@ class Workflow(_WorkflowCore):
                                 next((v for v in vals if v is not None), ""),
                                 str):
                             continue    # token lists take the legacy path
-                        from .ops.text_profile import column_profile
-                        prof = column_profile(col)
-                        if num_hashes:
-                            prof.prefetch(num_hashes)
+                        columns.append((col, cap))
+                        hashes.append(int(st.get("num_hashes") or 0))
+                if sp is not None:
+                    sp.attrs.update(columns=len(columns),
+                                    workers=pool_size(len(columns)))
+                for prof, num_hashes in zip(profile_columns(columns),
+                                            hashes):
+                    if num_hashes:
+                        prof.prefetch(num_hashes)
             # numeric raw columns + label: the weakref transfer cache makes
             # these THE copies every later consumer (frontier _prep,
             # vectorizer fits, selector y) reuses

@@ -3,13 +3,13 @@
     python chip_smoke.py
 
 drives the AutoML main path in ONE process (a chip has one owner) through the
-entry points a user calls, at the width the repo benches:
+entry points a user calls:
 
-  A  dense sweep      bench.dense_workflow — 1,000,000 x 28 RealNN ->
+  A  dense sweep      dense_workflow — 1,000,000 x 28 RealNN ->
                       transmogrify -> sanity_check -> 3-fold CV over 4 LR +
                       RF(20 trees, depth 6) + GBT(20 rounds, depth 3) ->
                       Workflow.train() -> evaluate() -> score()
-  B  transmogrify     bench.transmog_workflow — 100,000 rows of text /
+  B  transmogrify     transmog_workflow — 100,000 rows of text /
                       picklist / map / real columns, RawFeatureFilter on, LR
                       selector -> train -> score -> save -> load -> re-score
   C  serve            save() of the phase-A model -> start_server(bundle,
@@ -55,8 +55,8 @@ ROWS_A = 1_000_000
 ROWS_B = 100_000
 
 # What `JAX_PLATFORMS=cpu python chip_smoke.py --cpu-reference` printed at
-# ROWS_A / ROWS_B with the seeds baked into bench.make_data /
-# bench.make_transmog_columns (this sandbox, jax 0.9.0, XLA:CPU, f32 + host
+# ROWS_A / ROWS_B with the seeds baked into make_data /
+# make_transmog_columns (this sandbox, jax 0.9.0, XLA:CPU, f32 + host
 # paths; PR 21).  The chip must land inside AUROC_BAND of these and may log
 # no failure event the CPU run did not.
 CPU_REFERENCE = {
@@ -208,6 +208,193 @@ def check_bundle_aot(name, rec, bundle, installed):
             f"{len(exported)} executables exported, {installed} installed")
 
 
+DENSE_D = 28
+
+
+def make_data(n: int, d: int = DENSE_D, seed: int = 0):
+    """HIGGS-difficulty synthetic: linear signal damped to sqrt(d) scale plus
+    mild interactions, unit noise — best-model AuROC lands near 0.80 like the
+    real HIGGS benchmark (calibrated against sklearn LR/GBT)."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, d)).astype(np.float32)
+    w = rng.normal(size=d).astype(np.float32) / np.sqrt(d)
+    logits = (X @ w + 0.35 * (X[:, 0] * X[:, 1]) - 0.25 * (X[:, 2] ** 2)
+              + 0.1 + 0.3 * np.sin(2 * X[:, 3]))
+    y = (logits + rng.normal(size=n).astype(np.float32) > 0).astype(np.float32)
+    return X, y
+
+
+def make_transmog_columns(n: int, seed: int = 1):
+    """Mixed-type raw columns for the transmogrification workload.
+
+    Returns (cols dict for ColumnBatch, schema dict).
+    """
+    import numpy as np
+
+    from transmogrifai_tpu import types as T
+    from transmogrifai_tpu.columns import Column, column_from_values
+
+    rng = np.random.default_rng(seed)
+    vocab = np.asarray([f"tok{i}" for i in range(50_000)])
+    common = np.asarray([f"word{i}" for i in range(40)])
+
+    def text_col(p_null=0.2, lo=4, hi=9):
+        lens = rng.integers(lo, hi, size=n)
+        toks = vocab[rng.integers(0, len(vocab), size=(n, hi))]
+        salt = common[rng.integers(0, len(common), size=(n, 2))]
+        out = np.empty(n, dtype=object)
+        null = rng.random(n) < p_null
+        for i in range(n):
+            if null[i]:
+                out[i] = None
+            else:
+                out[i] = " ".join(np.concatenate([salt[i], toks[i, :lens[i]]]))
+        return out, null
+
+    t1, _ = text_col()
+    t2, _ = text_col()
+    t3, _ = text_col(p_null=0.3, lo=3, hi=6)
+
+    cats1 = np.asarray([f"c{i}" for i in range(20)])
+    cats2 = np.asarray([f"k{i}" for i in range(50)])
+    c1_idx = rng.integers(0, len(cats1), size=n)
+    c1 = cats1[c1_idx].astype(object)
+    c1[rng.random(n) < 0.1] = None
+    c2 = cats2[rng.integers(0, len(cats2), size=n)].astype(object)
+    c2[rng.random(n) < 0.2] = None
+
+    rvals = rng.normal(size=(n, 4)).astype(np.float32)
+    rnull = rng.random((n, 4)) < 0.2
+
+    mvals = rng.normal(size=(n, 3)).astype(np.float32)
+    mkeys = ("a", "b", "c")
+    mpresent = rng.random((n, 3)) < 0.8
+    rmap = np.empty(n, dtype=object)
+    for i in range(n):
+        rmap[i] = {k: float(mvals[i, j]) for j, k in enumerate(mkeys)
+                   if mpresent[i, j]}
+
+    logits = (0.8 * (c1_idx % 3 == 0).astype(np.float32)
+              + np.where(rnull[:, 0], 0.0, rvals[:, 0])
+              + 0.5 * np.where(mpresent[:, 0], mvals[:, 0], 0.0))
+    y = (logits + rng.normal(size=n).astype(np.float32) > 0.4).astype(np.float32)
+
+    cols = {
+        "label": Column(T.RealNN, y),
+        "text1": column_from_values(T.Text, t1),
+        "text2": column_from_values(T.Text, t2),
+        "text3": column_from_values(T.Text, t3),
+        "cat1": column_from_values(T.PickList, c1),
+        "cat2": column_from_values(T.PickList, c2),
+        "rmap": Column(T.RealMap, rmap),
+    }
+    for j in range(4):
+        vals = [None if rnull[i, j] else float(rvals[i, j]) for i in range(n)]
+        cols[f"r{j}"] = column_from_values(T.Real, vals)
+    schema = {"label": T.RealNN, "text1": T.Text, "text2": T.Text,
+              "text3": T.Text, "cat1": T.PickList, "cat2": T.PickList,
+              "rmap": T.RealMap, "r0": T.Real, "r1": T.Real, "r2": T.Real,
+              "r3": T.Real}
+    return cols, schema
+
+
+def dense_workflow(N: int):
+    """Phase A's user program:
+    N x 28 RealNN -> transmogrify -> sanity_check -> 3-fold CV over
+    {4 LR, RF(20 trees, depth 6), GBT(20 rounds, depth 3)}.
+    Returns (workflow, batch, selector)."""
+    from transmogrifai_tpu.columns import Column, ColumnBatch
+    from transmogrifai_tpu.features import FeatureBuilder
+    from transmogrifai_tpu.models.linear import OpLogisticRegression
+    from transmogrifai_tpu.models.trees import (OpGBTClassifier,
+                                                OpRandomForestClassifier)
+    from transmogrifai_tpu.ops.transmogrify import transmogrify
+    from transmogrifai_tpu.selector import (BinaryClassificationModelSelector,
+                                            ModelCandidate, grid)
+    from transmogrifai_tpu.types import RealNN
+    from transmogrifai_tpu.workflow import Workflow
+
+    D = DENSE_D
+    X, y = make_data(N, D)
+
+    label = FeatureBuilder.RealNN("label").as_response()
+    feats = [FeatureBuilder.RealNN(f"f{i}").as_predictor() for i in range(D)]
+    fv = transmogrify(feats)
+    checked = label.sanity_check(fv, remove_bad_features=True)
+
+    models = [
+        ModelCandidate(OpLogisticRegression(),
+                       grid(reg_param=[0.001, 0.01, 0.1, 0.2],
+                            elastic_net_param=[0.1], max_iter=[50]),
+                       "OpLogisticRegression"),
+        ModelCandidate(OpRandomForestClassifier(),
+                       grid(num_trees=[20], max_depth=[6],
+                            min_instances_per_node=[10]),
+                       "OpRandomForestClassifier"),
+        ModelCandidate(OpGBTClassifier(),
+                       grid(max_iter=[20], max_depth=[3],
+                            min_instances_per_node=[10]),
+                       "OpGBTClassifier"),
+    ]
+    selector = BinaryClassificationModelSelector(models=models)
+    selector.set_input(label, checked)
+    pred = selector.get_output()
+
+    cols = {"label": Column(RealNN, y)}
+    for i in range(D):
+        cols[f"f{i}"] = Column(RealNN, X[:, i])
+    batch = ColumnBatch(cols, N)
+
+    wf = Workflow().set_input_batch(batch).set_result_features(pred)
+    return wf, batch, selector
+
+
+def family_cv_metrics(model, selector):
+    """Per-family best CV metric: a silently-degraded tree
+    fitter must show up even when LR wins.  "Best" follows the validation
+    evaluator's direction, not a max assumption."""
+    larger_better = bool(selector.validator.evaluator.is_larger_better)
+    fam = {}
+    for r in model.selected_model.summary.validation_results:
+        v = next(iter(r.metric_values.values()), None)
+        if v is not None and (r.model_name not in fam
+                              or (v > fam[r.model_name]) == larger_better):
+            fam[r.model_name] = round(float(v), 4)
+    return fam
+
+
+def transmog_workflow(N: int):
+    """Phase B's user program:
+    mixed text/picklist/map/real columns -> transmogrify -> sanity_check ->
+    LR selector, RawFeatureFilter on.  Returns (workflow, batch)."""
+    from transmogrifai_tpu.columns import ColumnBatch
+    from transmogrifai_tpu.features import features_from_schema
+    from transmogrifai_tpu.models.linear import OpLogisticRegression
+    from transmogrifai_tpu.ops.transmogrify import transmogrify
+    from transmogrifai_tpu.selector import (BinaryClassificationModelSelector,
+                                            ModelCandidate, grid)
+    from transmogrifai_tpu.workflow import Workflow
+
+    cols, schema = make_transmog_columns(N)
+    batch = ColumnBatch(cols, N)
+
+    label, predictors = features_from_schema(schema, response="label")
+    fv = transmogrify(predictors)
+    checked = label.sanity_check(fv, remove_bad_features=True)
+    selector = BinaryClassificationModelSelector(models=[
+        ModelCandidate(OpLogisticRegression(),
+                       grid(reg_param=[0.01, 0.1], max_iter=[50]),
+                       "OpLogisticRegression")])
+    selector.set_input(label, checked)
+    pred = selector.get_output()
+
+    wf = (Workflow().set_input_batch(batch).set_result_features(pred)
+          .with_raw_feature_filter(min_fill_rate=0.01))
+    return wf, batch
+
+
 def predictions(scored, pred_name):
     import numpy as np
     vals = scored[pred_name].values
@@ -219,21 +406,20 @@ def phase_a(rows, report, reference):
     """Dense sweep; returns (model, batch, pred_name) for phase C."""
     import numpy as np
 
-    import bench
     from transmogrifai_tpu.evaluators import Evaluators
     from transmogrifai_tpu.parallel.memory import last_plan
     from transmogrifai_tpu.telemetry import REGISTRY
 
     with phase("A", report) as (rec, log):
         plan_before = last_plan()
-        wf, batch, selector, _ = bench.dense_workflow(rows)
+        wf, batch, selector = dense_workflow(rows)
         model = wf.train()
         auroc = model.evaluate(Evaluators.BinaryClassification.auROC(),
                                batch=batch)["AuROC"]
         pred_name = next(f.name for f in model.result_features)
         pred, prob = predictions(model.score(), pred_name)
 
-        fam, _ = bench.family_cv_metrics(model, selector)
+        fam = family_cv_metrics(model, selector)
         rec.update(rows=rows, family_cv_metrics=fam,
                    winner=model.selected_model.summary.best_model_name,
                    mesh_devices=REGISTRY.snapshot()["gauges"].get(
@@ -255,14 +441,13 @@ def phase_a(rows, report, reference):
 def phase_b(rows, report, reference, tmp):
     import numpy as np
 
-    import bench
     from transmogrifai_tpu.evaluators import Evaluators
     from transmogrifai_tpu.parallel.memory import last_plan
     from transmogrifai_tpu.workflow import WorkflowModel
 
     with phase("B", report) as (rec, log):
         plan_before = last_plan()
-        wf, batch, _ = bench.transmog_workflow(rows)
+        wf, batch = transmog_workflow(rows)
         model = wf.train()
         auroc = model.evaluate(Evaluators.BinaryClassification.auROC(),
                                batch=batch)["AuROC"]

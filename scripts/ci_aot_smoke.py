@@ -28,7 +28,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 SUMMARY_NAME = "aot-smoke.json"
 
-# fresh-process serve probe; mirrors bench.py's serve_cold_start child.
+# fresh-process serve probe.
 # The compile listeners install before the engine exists so every backend
 # compile in this process is observed.
 _CHILD = r"""

@@ -196,10 +196,11 @@ def test_a_cast_or_a_new_layout_is_counted(monkeypatch):
     before = REGISTRY.counters().get("mesh.relayout_bytes", 0)
     cv = OpCrossValidation(num_folds=2, seed=1,
                            evaluator=Evaluators.BinaryClassification.auPR())
-    cv.validate([ModelCandidate(OpLogisticRegression(),
-                                grid(reg_param=[0.1], max_iter=[2]), "LR")],
-                batch, "y", "x")
-    assert cv.last_mesh is not None
+    result = cv.validate(
+        [ModelCandidate(OpLogisticRegression(),
+                        grid(reg_param=[0.1], max_iter=[2]), "LR")],
+        batch, "y", "x")
+    assert result.placement.mesh is not None
     moved = REGISTRY.counters()["mesh.relayout_bytes"] - before
     assert moved == n * d * 2          # bfloat16 still: re-laid, not cast
 

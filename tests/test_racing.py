@@ -1,8 +1,8 @@
 """Tests for the sweep racing engine (successive halving, ISSUE 4) and the
 compile-reuse layer: determinism vs the full-CV sweep, raced_out markers in
 the summary, tiny-grid parity, checkpoint-signature invalidation on racing
-config changes, degraded notes on unraceable paths, and the fit-padding
-ladder."""
+config changes, degraded notes on unraceable paths, and the exactness of
+zero-weight pad rows."""
 
 import numpy as np
 import pytest
@@ -15,7 +15,6 @@ from transmogrifai_tpu.models.linear import OpLogisticRegression
 from transmogrifai_tpu.ops.transmogrify import transmogrify
 from transmogrifai_tpu.selector import (BinaryClassificationModelSelector,
                                         ModelCandidate, grid)
-from transmogrifai_tpu.tuning import _fit_pad_rows
 from transmogrifai_tpu.workflow import Workflow
 
 LR_GRID = grid(reg_param=[0.001, 0.01, 0.1, 0.2],
@@ -191,23 +190,11 @@ class TestCheckpointSignature:
         assert replayed(m3)
 
 
-class TestFitPaddingLadder:
-    def test_ladder_below_floor_is_exact(self):
-        assert _fit_pad_rows(1) == 1
-        assert _fit_pad_rows(4096) == 4096
-
-    def test_ladder_is_geometric_and_quantized(self):
-        n1 = _fit_pad_rows(5000)
-        assert n1 >= 5000 and n1 % 256 == 0
-        # monotone, and nearby sizes share a rung (the whole point)
-        assert _fit_pad_rows(5001) >= n1
-        assert _fit_pad_rows(n1 - 100) == n1
-        assert _fit_pad_rows(20000) == _fit_pad_rows(19999)
-
+class TestZeroWeightPadding:
     def test_zero_weight_padding_leaves_linear_fit_exact(self):
-        """The padding ladder appends zero-weight rows; every reduction in
-        the linear solvers is sample-weighted, so the coefficients must not
-        move."""
+        """The mesh pads the row count to its device quantum with
+        zero-weight rows; every reduction in the linear solvers is
+        sample-weighted, so the coefficients must not move."""
         import jax.numpy as jnp
         rng = np.random.default_rng(3)
         N, D, pad = 257, 5, 63

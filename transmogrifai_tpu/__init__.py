@@ -20,9 +20,8 @@ import os as _os
 #   1. JAX_COMPILATION_CACHE_DIR set — jax reads it itself; the operator
 #      placed the cache and children inherit the variable.
 #   2. TRANSMOGRIFAI_COMPILE_CACHE=<dir> — <dir>/<JAX_PLATFORMS or default>,
-#      every program cached (0 s floor); also opts in to fit-row padding and
-#      background pre-tracing (tuning._fit_padding_enabled, aot.pretrace_-
-#      enabled), with or without (1).
+#      every program cached (0 s floor); also opts in to background
+#      pre-tracing (aot.pretrace_enabled), with or without (1).
 #   3. neither — DEFAULT_COMPILE_CACHE_DIR, one fixed git-ignored directory
 #      in the checkout, 0.1 s floor.
 # TRANSMOGRIFAI_COMPILE_CACHE=0 / TRANSMOGRIFAI_COMPILATION_CACHE=0 leave

@@ -413,7 +413,7 @@ _IDLE.set()
 def pretrace_enabled() -> bool:
     """Pre-tracing pays a background compile so the foreground fit becomes a
     persistent-cache hit — without the cache it would literally double the
-    compile bill, so it keys on the same env the fit-shape padding does.
+    compile bill, so it keys on the env that places the cache.
     A configured executable registry also qualifies: its pre-trace pass can
     skip the compile entirely (deserialize a published executable) and its
     misses publish for the whole fleet."""

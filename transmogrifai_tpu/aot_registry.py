@@ -6,8 +6,8 @@ process runs ~100 XLA compiles for the six-candidate dense sweep (count from
 a CPU run; compile TIME on the chip is in PERF.md), and every pool worker,
 tenant activation,
 hostgroup rank, and lifecycle retrain re-derives the same executables.  The
-programs themselves are already canonicalized — fit-shape ladder rungs,
-positional pytree names at the jit boundary — so their identities are
+programs themselves are already canonicalized — positional pytree names
+at the jit boundary — so their identities are
 stable across processes and machines with the same ABI.
 
 This module is the registry those identities key into: a content-addressed,

@@ -64,7 +64,7 @@ def _mxu_dtype():
 _SHARED_BINS: Dict[int, Any] = {}
 
 # id(X) → (weakref(X), n_real) for zero-weight-padded matrices: the sweep's
-# fit-shape padding (tuning.register_real_rows) appends all-zero rows whose
+# mesh placement (tuning.Placement.lay_matrix) appends all-zero rows whose
 # fold weight is 0 everywhere.  Every tree statistic is sample-weighted, so
 # those rows already contribute nothing to fits — but the UNWEIGHTED
 # quantile sketch in build_bin_splits would see them as a spike at 0 and

@@ -9,7 +9,7 @@ assembles each device's row shard from bounded host slices instead:
   * at most two chunk-sized host staging buffers are alive at any moment
     (double buffering: chunk *i* transfers while chunk *i+1* is sliced), so
     peak staging is O(TRANSMOGRIFAI_DEVICE_CHUNK_BYTES), not O(dataset);
-  * pad rows (device-divisibility quantum, fit-shape ladder rungs) are
+  * pad rows (the device-divisibility quantum) are
     synthesised on-device with ``jnp.zeros`` — zero host-link bytes;
   * the assembled shards are stitched into one logically-sharded array via
     ``jax.make_array_from_single_device_arrays``, indistinguishable to the

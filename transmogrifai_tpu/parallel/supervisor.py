@@ -6,9 +6,8 @@ raised, and plain SIGTERM does not kill the hung process — only SIGKILL
 does.  ``resilience.run_with_deadline``'s thread watchdog can *raise* on the
 hang but cannot *reclaim* the thread, so anything that must actually free
 the resources has to live in a child process the parent can escalate-kill.
-This module is that discipline as a subsystem (``bench.py``,
-``scripts/run_scale_bench.py``, the serving pool and the host-group launcher
-all start their children through it):
+This module is that discipline as a subsystem (the serving pool and the
+host-group launcher start their children through it):
 
 * ``run_supervised`` — run a child under a SIGTERM→SIGKILL escalation
   deadline (the ``timeout -k`` shape, as a library call).
@@ -395,8 +394,7 @@ def probe_devices(timeout_s: Optional[float] = None, *,
     A hung init surfaces as ``status="outage", cause="hang"`` within
     ``timeout_s + grace_s`` instead of stalling the caller forever; a
     reachable runtime reports its platform + device inventory; a CPU
-    fallback when ``expect_accelerator`` is set reads as ``degraded`` —
-    what ``bench.py``'s launcher refuses to measure.
+    fallback when ``expect_accelerator`` is set reads as ``degraded``.
     ``chaos`` prepends a :data:`CHAOS_PRELUDES` failure mode to the child."""
     timeout_s = probe_timeout_s() if timeout_s is None else float(timeout_s)
     t0 = time.time()

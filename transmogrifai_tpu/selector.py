@@ -19,6 +19,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from .checkpoint import active_sweep_checkpoint
 from .columns import Column, ColumnBatch
 from .evaluators import (Evaluators, OpBinaryClassificationEvaluator,
                          OpEvaluatorBase, OpMultiClassificationEvaluator,
@@ -26,6 +27,7 @@ from .evaluators import (Evaluators, OpBinaryClassificationEvaluator,
 from .models.base import PredictionModel, PredictorEstimator, extract_xy
 from .resilience import record_failure
 from .stages.base import Estimator
+from .telemetry import span
 from .tuning import (DataBalancer, DataCutter, DataSplitter, ModelCandidate,
                      OpCrossValidation, OpTrainValidationSplit, OpValidator,
                      Splitter, ValidationResult)
@@ -263,87 +265,25 @@ class ModelSelector(Estimator):
 
     def _refit_reusing_grid_executable(self, result, X, y):
         """Final full-data refit through the SAME batched (fold × grid)
-        program the CV already compiled: with identical array shapes (all-ones
-        fold weights [F, N], the winner's params padded to the family's grid
-        width G) jax's executable cache hits and the refit costs F·G redundant
-        cheap fits instead of compiling + loading a fresh single-fit
-        program.  Returns None
-        (→ caller falls back to ``fit_arrays``) when the shapes differ (e.g. a
-        Balancer resampled the train set) or anything goes wrong.
-
-        With racing/padding live, the winning family's last batched fit may
-        have run on fewer folds (survivor round: F-1), a survivor-sized grid,
-        or ladder-padded rows — ``validator.family_fit_meta`` records the
-        exact (folds, rows, lanes) of the family's most recent batched
-        program, and the refit mirrors it (padding X/y with zero-weight rows
-        when needed) so the executable-cache key matches."""
+        program the CV already compiled: the sweep's placement lays ``(X, y)``
+        and all-ones fold weights out as the winning family's last batched
+        fit was (racing may have ended it on fewer folds and a survivor-sized
+        grid), so jax's executable cache hits and the refit costs F·G
+        redundant cheap fits instead of compiling + loading a fresh
+        single-fit program.  Returns None (→ caller falls back to
+        ``fit_arrays``) when the family ran no batched fit, the rows differ
+        (e.g. a Balancer resampled the train set) or anything goes wrong."""
         cand = next((c for c in self.models
                      if c.model_name == result.best.model_name), None)
-        if cand is None or not cand.grid:
-            return None
-        meta = getattr(self.validator, "family_fit_meta", {}).get(
-            result.best.model_name)
-        if meta is not None:
-            if meta["real_rows"] != X.shape[0]:
-                meta = None   # Balancer/Cutter changed the final train rows
-            elif meta["padded"] and not getattr(
-                    cand.estimator, "weighted_pad_exact", False):
-                meta = None   # never zero-pad an estimator that can't take it
-        shape = getattr(self.validator, "last_fit_shape", None)
-        if meta is None and (shape is None or shape[1] != X.shape[0]):
+        meta = result.fit_meta.get(result.best.model_name)
+        if cand is None or not cand.grid or meta is None:
             return None
         try:
-            import jax
-            import jax.numpy as jnp
-
-            if meta is not None:
-                F, rows, lanes = meta["folds"], meta["rows"], meta["lanes"]
-            else:
-                F, rows, lanes = shape[0], shape[1], len(cand.grid)
-            from .sparse.matrix import SparseMatrix
-
-            pad = rows - X.shape[0]
-            if pad:
-                if isinstance(X, SparseMatrix):
-                    X = X.pad_rows(rows)   # empty rows, zero-weight below
-                else:
-                    Xj = X if isinstance(X, jax.Array) else jnp.asarray(
-                        X, jnp.float32)
-                    X = jnp.pad(Xj, ((0, pad), (0, 0)))
-                y = jnp.pad(jnp.asarray(y, jnp.float32), (0, pad))
-            # all-ones fold weights materialize ON DEVICE — zero wire bytes;
-            # padded rows get weight 0 so they can't perturb the fit
-            W = jnp.ones((F, rows), jnp.float32)
-            if pad:
-                W = W.at[:, -pad:].set(0.0)
-            mesh = getattr(self.validator, "last_mesh", None)
-            if mesh is not None:
-                # match the CV call's shardings exactly — the jit cache keys
-                # on them, so a layout mismatch would recompile the whole
-                # batched program instead of reusing it
-                from .parallel import data_sharding, stream_to_device
-                if isinstance(X, SparseMatrix):
-                    # DeviceTable dispatch: same row partition and nnz-rung
-                    # capacities as the CV stream (same data, same mesh), so
-                    # the flat-component shapes match the sweep's compiled
-                    # program exactly
-                    X = stream_to_device(X, mesh, pad_to=rows)
-                else:
-                    X = jax.device_put(
-                        X if isinstance(X, jax.Array)
-                        else jnp.asarray(X, jnp.float32),
-                        data_sharding(mesh, 2))
-                y = jax.device_put(jnp.asarray(y, jnp.float32),
-                                   data_sharding(mesh, 1))
-                W = jax.device_put(jnp.asarray(W),
-                                   data_sharding(mesh, 2, row_axis=1))
-            if pad and not isinstance(X, SparseMatrix):
-                # tree families quantile-bin over the true rows only, same
-                # as the sweep's padded fit
-                from .models.trees import register_real_rows
-                register_real_rows(X, rows - pad)
-            grids = [dict(result.best_params)] * lanes
-            return cand.estimator.fit_arrays_grid(X, y, W, grids)[0][0]
+            arrays = result.placement.refit_arrays(X, y, meta["folds"])
+            if arrays is None:
+                return None
+            grids = [dict(result.best_params)] * meta["lanes"]
+            return cand.estimator.fit_arrays_grid(*arrays, grids)[0][0]
         except Exception as e:  # noqa: BLE001 — reuse is an optimization only
             record_failure(self.uid, "degraded", e,
                            point="selector.refit_reuse",
@@ -404,7 +344,6 @@ class ModelSelector(Estimator):
             train_batch = self.splitter.validation_prepare(batch, label)
         best_est: PredictorEstimator = result.best.estimator
         X, y = extract_xy(train_batch, label_f, feats_f)
-        from .telemetry import span
         with span("selector.winner_refit", model=result.best.model_name):
             fitted = self._refit_reusing_grid_executable(result, X, y)
             if fitted is None:
@@ -465,14 +404,12 @@ class ModelSelector(Estimator):
         # seal the sweep checkpoint with the winner: a later resume of an
         # already-finished sweep sees every candidate replayed AND which one
         # won, so restart cost is one full-data refit, not a re-sweep
-        from .checkpoint import active_sweep_checkpoint
         cp = active_sweep_checkpoint()
         if cp is not None:
             try:
                 cp.set_winner(result.best.model_name, result.best_params,
                               float(result.best_metric))
             except Exception as e:  # noqa: BLE001 — durability is best-effort
-                from .resilience import record_failure
                 record_failure("selector", "degraded", e,
                                point="checkpoint.save",
                                fallback="winner not persisted")

@@ -4,7 +4,7 @@
 binary body carrying one contiguous array per feature, so the server builds
 its device ``ColumnBatch`` with one ``np.frombuffer`` view per feature
 instead of per-record JSON dict decode (the single-process throughput
-ceiling BENCH_STANDING documented across five rounds).  JSON remains the
+ceiling).  JSON remains the
 compatibility path; this format is opt-in per request.
 
 Layout (all integers little-endian)::

@@ -13,17 +13,33 @@ Reference defaults preserved: NumFolds=3, Parallelism=8, stratify=false
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+import copy
+import logging
+import time
+from collections import defaultdict
+from concurrent.futures import ThreadPoolExecutor
+from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-import logging
-
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from .columns import ColumnBatch
+from .aot import pretrace_enabled, pretrace_submit
+from .checkpoint import (SweepCheckpoint, TrainingPreempted,
+                         active_sweep_checkpoint, shutdown_requested)
+from .columns import ColumnBatch, to_device_f32
+from .dag import apply_dag, fit_dag
 from .evaluators import OpEvaluatorBase
+from .models.trees import predict_trees_sum_grouped, register_real_rows
+from .obsv import BOARD
+from .parallel import (data_sharding, hostgroup, maybe_data_mesh, memory,
+                       pad_rows_for, stream_to_device, supervisor)
+from .profiling import record_racing
 from .resilience import (AllCandidatesFailed, active_failure_log,
                          maybe_inject, record_failure)
+from .sparse.matrix import SparseMatrix
+from .telemetry import event, span
 
 logger = logging.getLogger(__name__)
 
@@ -289,9 +305,6 @@ def _grid_margins(X, C, b):
     materializes."""
     global _GRID_MARGINS_JIT
     if _GRID_MARGINS_JIT is None:
-        import jax
-        import jax.numpy as jnp
-
         @jax.jit
         def fn(X, C, b):
             return jnp.einsum("nd,kd->nk", X, C,
@@ -310,9 +323,6 @@ def _multinomial_pred_grid(X, C3, B):
     prediction exactly."""
     global _MULTI_PRED_JIT
     if _MULTI_PRED_JIT is None:
-        import jax
-        import jax.numpy as jnp
-
         @jax.jit
         def fn(X, C3, B):
             m = jnp.einsum("nd,kdc->nkc", X, C3,
@@ -322,43 +332,12 @@ def _multinomial_pred_grid(X, C3, B):
     return _MULTI_PRED_JIT(X, C3, B)
 
 
-# fit-program row-count canonicalization (ISSUE 4 compile reuse): pad N up a
-# geometric ladder with zero-weight rows so re-trains at nearby sizes hit the
-# SAME compiled fit executable.  Zero-weight padding is exact for the linear
-# solvers (every reduction is weight-normalized — see
-# models/solvers.linear_grid_fit); tree fitters bin features with unweighted
-# quantiles, so only estimators declaring ``weighted_pad_exact`` opt in.
-_FIT_PAD_FLOOR = 4096
-_FIT_PAD_STEP = 1.25
-_FIT_PAD_QUANTUM = 256
-
-
-def _fit_pad_rows(n: int) -> int:
-    """Smallest ladder rung >= n.  n <= the floor returns n unchanged, so
-    small fixtures (and every tier-1 test) keep bit-identical shapes."""
-    if n <= _FIT_PAD_FLOOR:
-        return int(n)
-    rung = _FIT_PAD_FLOOR
-    while rung < n:
-        rung = int(-(-int(rung * _FIT_PAD_STEP) // _FIT_PAD_QUANTUM)
-                   * _FIT_PAD_QUANTUM)
-    return rung
-
-
-def _fit_padding_enabled() -> bool:
-    """Shape canonicalization only pays off with a persistent compile cache
-    to hit, so it rides the TRANSMOGRIFAI_COMPILE_CACHE opt-in."""
-    import os
-    cc = os.environ.get("TRANSMOGRIFAI_COMPILE_CACHE")
-    return bool(cc) and cc != "0"
-
-
 _FOLD_MASK_FNS: Dict[int, Any] = {}
 
 # uint8 fold-assignment sentinels: 255 = "in no validation fold" (a TVS row
 # outside the held-out slice — it trains in every fold), 254 = "zero-weight
-# pad row" (mesh device-divisibility quantum / ladder rung — it belongs to
-# NO fold, training or validation)
+# pad row" (the mesh's device-divisibility quantum — it belongs to NO fold,
+# training or validation)
 _NO_FOLD = 255
 _PAD_FOLD = 254
 
@@ -369,9 +348,6 @@ def _fold_masks_from_assignment(assign, n_folds: int):
     byte per row instead of the materialized masks.  A sharded assignment
     propagates its row sharding into the masks (axis 1), so the mesh path
     never materializes [F, N] weights on the host."""
-    import jax
-    import jax.numpy as jnp
-
     fn = _FOLD_MASK_FNS.get(n_folds)
     if fn is None:
         @jax.jit
@@ -422,6 +398,759 @@ class ValidationResult:
     validation_type: str
     metric_name: str
     is_larger_better: bool
+    # how the sweep's last fold group lay on the devices (arrays dropped) and,
+    # per family, the folds and lanes of its last batched fit: the winner's
+    # refit asks the placement for arrays of exactly that layout and so runs
+    # the program the sweep compiled
+    placement: Optional["Placement"] = None
+    fit_meta: Dict[str, Dict[str, int]] = field(default_factory=dict)
+
+
+# --------------------------------------------------------------------------
+# the sweep's stages: plan -> place -> fit -> score
+# --------------------------------------------------------------------------
+
+# From this many rows on, a grid with an ``hbm_heavy`` family fits its
+# families one after another: side by side their HBM working sets (each TREE
+# family budgets ~6 GiB of one-hot space) no longer fit, and sequential fits
+# make the peak the max, not the sum.
+_SERIAL_FROM_ROWS = 4_000_000
+
+_REPLAYED = object()     # in place of a fitted grid: scores came from the cp
+_PREEMPTED = object()    # in place of a fitted grid: a stop won the boundary
+
+
+def _survivor_count(G: int, eta: float, min_survivors: int) -> int:
+    return max(min_survivors, int(np.ceil(G / eta)))
+
+
+@dataclass(frozen=True)
+class SweepPlan:
+    """What one attempt of ``validate`` decides before it touches the matrix.
+
+    Racing (successive halving): the full grid is screened on fold 0 only,
+    each family is pruned to ``survivor_count(G)`` and the remaining folds run
+    for survivors only.  A family whose survivor floor covers its whole grid
+    is not raced — tiny grids are bit-identical to an unraced sweep.  Racing
+    runs on the mesh too: rounds A and B are the same batched programs with a
+    fold-sliced weight block, and GSPMD shards them identically.
+
+    Replay: families the ambient sweep checkpoint holds under an unchanged
+    signature (model, position, grid, racing configuration) are not fitted
+    again.  Fast path only — the in-fold-DAG path accumulates a candidate's
+    metrics over several fold groups, so a per-family snapshot would persist
+    half-filled metric lists."""
+    splits: List[Tuple[np.ndarray, np.ndarray]]
+    in_fold_dag: Optional[List[List[Any]]]
+    racing_eta: float
+    racing_min_survivors: int
+    raced_flags: Tuple[bool, ...]
+    # families fit concurrently on a thread pool (≙ the reference's Futures
+    # fan-out, OpValidator.scala:320-349 + `parallelism` :106).  Device
+    # execution serializes on the TPU stream; the win is overlapping the XLA
+    # *compiles* of the per-family batched programs
+    n_workers: int
+    checkpoint: Optional[SweepCheckpoint]
+    signatures: Tuple[str, ...]               # () without a checkpoint
+    replayed: Dict[int, List[Dict[str, Any]]]  # candidate index -> stored
+    # the recovery attempts key the chaos seams, so that a retry is not
+    # killed again by a sticky injector decision
+    attempt: int = 0
+    oom_attempt: int = 0
+
+    def survivor_count(self, G: int) -> int:
+        return _survivor_count(G, self.racing_eta, self.racing_min_survivors)
+
+
+def plan_sweep(validator: "OpValidator", candidates: Sequence[ModelCandidate],
+               y_all: np.ndarray, in_fold_dag=None, attempt: int = 0,
+               oom_attempt: int = 0) -> SweepPlan:
+    splits = validator.splits(y_all)
+    racing_on, eta, min_surv = validator._racing_config()
+    race_path_ok = not in_fold_dag and len(splits) >= 2
+    if racing_on and not race_path_ok:
+        # the flag is on by default — say WHY this sweep runs unraced
+        reason = ("in-fold DAG refits feature stages per fold"
+                  if in_fold_dag else
+                  "single train/validation split (racing needs >= 2 "
+                  "folds)")
+        record_failure("validator", "degraded",
+                       f"racing disabled: {reason}",
+                       point="selector.racing",
+                       validation_type=validator.validation_type)
+    raced = tuple(racing_on and race_path_ok
+                  and _survivor_count(len(c.grid), eta, min_surv) < len(c.grid)
+                  for c in candidates)
+    cp = None if in_fold_dag else active_sweep_checkpoint()
+    sigs: List[str] = []
+    replayed: Dict[int, List[Dict[str, Any]]] = {}
+    if cp is not None:
+        for ci, cand in enumerate(candidates):
+            sigs.append(SweepCheckpoint.candidate_signature(
+                cand.model_name, ci, cand.grid,
+                racing=({"enabled": True, "eta": eta,
+                         "minSurvivors": min_surv} if raced[ci]
+                        else {"enabled": False})))
+            stored = cp.results_for(sigs[-1])
+            if stored is not None:
+                replayed[ci] = stored
+    n_workers = min(validator.parallelism, len(candidates))
+    if len(y_all) >= _SERIAL_FROM_ROWS and any(
+            getattr(c.estimator, "hbm_heavy", False) for c in candidates):
+        n_workers = 1
+    return SweepPlan(splits, in_fold_dag, eta, min_surv, raced, n_workers,
+                     cp, tuple(sigs), replayed, attempt, oom_attempt)
+
+
+@dataclass
+class Placement:
+    """Where the sweep's arrays lie: the one place that decides padding,
+    dtype and sharding, for the sweep and for the winner's refit after it.
+
+    On a mesh the matrix is row-sharded over the 'data' axis and GSPMD
+    inserts the collectives inside every batched fit/metric program (SURVEY
+    §2.6 P1/P3 on the REAL path); the row count pads up to the
+    device-divisible quantum with zero-weight rows, which is exact for
+    ``weighted_pad_exact`` families, and one padded matrix serves them all.
+    Sparse matrices shard like dense ones: entries sort by row, partition at
+    device row boundaries and pad to a common per-device nnz rung
+    (DeviceTable); the segment-sum fitters tolerate the zero pads exactly."""
+    N: int                              # real rows
+    N_fit: int                          # rows of every placed array
+    mesh: Any = None
+    is_sparse: bool = False
+    chunk_bytes: Optional[int] = None   # the preflight's streaming budget
+    X: Any = None
+    y: Any = None                       # [N_fit] float32 on the device
+    W: Any = None                       # [folds, N_fit] training weights
+    va_masks: Sequence[Any] = ()        # per fold, [N_fit] on the device
+    va_slices: Sequence[np.ndarray] = ()
+    y_host: Optional[np.ndarray] = None
+    _va_rows: Dict[int, Tuple[Any, np.ndarray]] = field(default_factory=dict)
+
+    def descriptor(self) -> "Placement":
+        """This layout without its arrays, for ``ValidationResult``."""
+        return replace(self, X=None, y=None, W=None, va_masks=(),
+                       va_slices=(), y_host=None, _va_rows={})
+
+    def lay_matrix(self, X) -> Tuple[Any, int]:
+        """``X`` over the mesh, padded to ``N_fit`` rows, and the bytes a
+        cast, a pad or a change of layout moved on the device."""
+        moved = 0
+        sparse = isinstance(X, SparseMatrix)
+        if isinstance(X, jax.Array):
+            # already device-resident (the fused transform's output): kept in
+            # the dtype it is stored in, as on one device — a bfloat16 matrix
+            # stays bfloat16 and the fit programs accumulate in float32; a
+            # float32 copy would double the bytes a chip holds and reads
+            want = data_sharding(self.mesh, 2)
+            Xj = X
+            if X.dtype not in (jnp.float32, jnp.bfloat16):
+                Xj = X.astype(jnp.float32)
+            if self.N_fit > self.N:
+                Xj = jnp.pad(Xj, ((0, self.N_fit - self.N), (0, 0)))
+            if Xj is not X or not X.sharding.is_equivalent_to(want, X.ndim):
+                moved = int(Xj.nbytes)
+            X = jax.device_put(Xj, want)
+        else:
+            # chunked host→device streaming: each device's row shard is
+            # assembled from bounded host slices, so peak staging is
+            # O(TRANSMOGRIFAI_DEVICE_CHUNK_BYTES), not O(dataset).  COO
+            # entries stream by nnz range under the same budget; empty pad
+            # rows own no entries
+            X = stream_to_device(
+                X if sparse else np.asarray(X, dtype=np.float32),
+                self.mesh, pad_to=self.N_fit, chunk_bytes=self.chunk_bytes)
+        if self.N_fit > self.N and not sparse:
+            # tree families quantile-bin over the true rows only — keeps
+            # padded split points identical to unpadded ones (sparse grids
+            # are linear-only: no binning to protect)
+            register_real_rows(X, self.N)
+        return X, moved
+
+    def refit_arrays(self, X, y, folds: int):
+        """Full-data ``(X, y)`` and all-ones weights ``[folds, N_fit]`` laid
+        out as the sweep's batched fits were (rows, pad rows at weight 0,
+        dtype, shardings): the jit cache keys on all of them, so a mismatch
+        would compile the whole batched program again.  None when the rows
+        are not the sweep's (a Balancer/Cutter resampled the train set)."""
+        if X.shape[0] != self.N:
+            return None
+        pad = self.N_fit - self.N
+        # all-ones fold weights materialize ON DEVICE — zero wire bytes
+        W = jnp.ones((folds, self.N_fit), jnp.float32)
+        if self.mesh is None:
+            return X, y, W        # one device never pads
+        if pad:
+            W = W.at[:, -pad:].set(0.0)
+        X, _ = self.lay_matrix(X)
+        y = jax.device_put(jnp.pad(jnp.asarray(y, jnp.float32), (0, pad)),
+                           data_sharding(self.mesh, 1))
+        return X, y, jax.device_put(W, data_sharding(self.mesh, 2, row_axis=1))
+
+    def validation_rows(self, f: int) -> Tuple[Any, np.ndarray]:
+        """Fold ``f``'s validation slice on the host for the per-candidate
+        fallback, pulled once per FOLD so every fallback candidate shares
+        one transfer."""
+        if f not in self._va_rows:
+            va_idx = self.va_slices[f]
+            if self.is_sparse:
+                # the slice STAYS sparse: sparse-capable models consume the
+                # COO stream in predict_arrays; models without a sparse path
+                # fail loudly (__array__ raises) and are skipped
+                xv = self.X.take_rows(np.asarray(va_idx))
+            else:
+                # gather ONLY the validation slice on device, then pull — the
+                # full matrix is folds-times bigger and the link is the
+                # bottleneck.  bf16-stored matrices cast to f32 on device
+                # first: numpy kernels on ml_dtypes bf16 are slow on host
+                xv = np.asarray(jnp.take(
+                    self.X, jnp.asarray(va_idx), axis=0).astype(jnp.float32))
+            self._va_rows[f] = (xv, self.y_host[va_idx])
+        return self._va_rows[f]
+
+
+def place(X, y32: np.ndarray, fsplits, candidates: Sequence[ModelCandidate],
+          splitter: Optional[Splitter] = None,
+          y_all: Optional[np.ndarray] = None) -> Placement:
+    """Lay one fold group over the devices, under the span
+    ``selector.place``: the matrix, the label, the fold weights and the
+    validation masks.  ``splitter.validation_prepare_weights`` applies
+    Balancer/Cutter preparation to each fold's *training* rows; scoring
+    stays on the untouched validation slice."""
+    from .telemetry import REGISTRY
+    N = X.shape[0]
+    # zero-weight row padding is exact only for families that declare it —
+    # one non-exact family in the grid keeps the whole shared matrix unpadded
+    pad_exact_all = all(getattr(c.estimator, "weighted_pad_exact", False)
+                        for c in candidates)
+    with span("selector.place") as sp:
+        mesh = maybe_data_mesh(N, pad=pad_exact_all)
+        if (mesh is None and not pad_exact_all
+                and maybe_data_mesh(N, pad=True) is not None):
+            # honest degrade: the mesh WAS viable (pad-divisible) but a mixed
+            # grid pinned the matrix unpadded and indivisible — operators see
+            # single-device as a degrade, not a choice
+            record_failure(
+                "sweep", "degraded",
+                RuntimeError(
+                    f"N={N} indivisible and grid mixes non-pad-exact "
+                    f"families: sweep falls back to single device"),
+                point="selector.mesh", fallback="single_device")
+            REGISTRY.counter("selector.mesh_degraded").inc()
+        p = Placement(N=N, N_fit=N, mesh=mesh,
+                      is_sparse=isinstance(X, SparseMatrix),
+                      va_slices=[va for _, va in fsplits], y_host=y32)
+        relayout_bytes = 0
+        if mesh is not None:
+            p.N_fit = N + pad_rows_for(N, mesh)
+            if memory.memory_governor_enabled():
+                # preflight: estimate rows × dtype × grid-width × fold-panel
+                # against the per-device budget and choose chunk bytes (and
+                # grid partitioning, read back by the fits) BEFORE the first
+                # transfer, so that a large sweep does not discover OOM by
+                # dying in a device_put
+                p.chunk_bytes = memory.plan_sweep_memory(
+                    rows=p.N_fit,
+                    cols=(int(X.shape[1])
+                          if p.is_sparse or getattr(X, "ndim", 1) == 2
+                          else 1),
+                    folds=len(fsplits),
+                    grid_width=max((len(c.grid) for c in candidates),
+                                   default=1),
+                    devices=int(mesh.devices.size),
+                    # a device-resident matrix stays as it is stored
+                    dtype_bytes=(int(X.dtype.itemsize)
+                                 if isinstance(X, jax.Array) else 4),
+                    nnz=int(X.nnz) if p.is_sparse else None).chunk_bytes
+            p.X, relayout_bytes = p.lay_matrix(X)
+            p.y = stream_to_device(y32, mesh, pad_to=p.N_fit,
+                                   chunk_bytes=p.chunk_bytes)
+        else:
+            # ONE host→device transfer shared by every candidate family; the
+            # label goes over an exact wire (bf16 only when verified
+            # lossless), shared with every other consumer of its buffer
+            p.X = (X if isinstance(X, jax.Array) or p.is_sparse
+                   else to_device_f32(X))
+            p.y = to_device_f32(y32, exact=True)
+        p.W, p.va_masks = _fold_weights(p, fsplits, splitter, y_all)
+        n_dev = 1 if mesh is None else int(mesh.devices.size)
+        REGISTRY.gauge("mesh.devices").set(n_dev)
+        if mesh is not None:
+            REGISTRY.counter("mesh.relayout_bytes").inc(relayout_bytes)
+        if sp is not None:
+            sp.attrs.update(
+                rows=int(N), pad_rows=int(p.N_fit - N), devices=n_dev,
+                dtype=str(getattr(p.X, "dtype", "")),
+                relayout_bytes=relayout_bytes,
+                bytes_placed=sum(int(getattr(a, "nbytes", 0))
+                                 for a in (p.X, p.y, p.W, *p.va_masks)))
+    return p
+
+
+def _fold_weights(p: Placement, fsplits, splitter, y_all):
+    """(training weights [F, N_fit], per-fold validation masks) on the
+    device, sharded by row under a mesh."""
+    F, mesh = len(fsplits), p.mesh
+    neutral = splitter is None or (
+        type(splitter).validation_prepare_weights
+        is Splitter.validation_prepare_weights)
+    # dense per-fold weight rows only materialize when a splitter may modify
+    # them
+    W_rows = []
+    if not (neutral and F < _PAD_FOLD):
+        neutral = True
+        for tr_idx, _ in fsplits:
+            w = np.zeros(p.N, np.float32)
+            w[tr_idx] = 1.0
+            if splitter is not None:
+                w2 = splitter.validation_prepare_weights(y_all, w)
+                neutral = neutral and w2 is w
+                w = w2
+            W_rows.append(w)
+    if neutral and F < _PAD_FOLD:
+        # fold masks from ONE [N] uint8 assignment shipped over the link —
+        # 1 byte/row instead of (folds+1)×4 bytes/row of train + validation
+        # masks.  On the mesh the assignment is row-sharded first so the
+        # [F, N] masks materialize directly with the fit programs' expected
+        # sharding.
+        assign = np.full(p.N_fit, _NO_FOLD, np.uint8)
+        assign[p.N:] = _PAD_FOLD   # pad rows join NO fold, ever
+        for f, (_, va_idx) in enumerate(fsplits):
+            assign[va_idx] = f
+        aj = jnp.asarray(assign)
+        if mesh is not None:
+            aj = jax.device_put(aj, data_sharding(mesh, 1))
+        W, VA = _fold_masks_from_assignment(aj, F)
+        return W, [VA[f] for f in range(F)]
+    masks = []
+    for va_idx in p.va_slices:
+        vm = np.zeros(p.N, np.float32)
+        vm[va_idx] = 1.0
+        # under a mesh the pad tail streams in as zeros — never validated;
+        # a 0/1 mask is exact on the bf16 wire
+        masks.append(stream_to_device(vm, mesh, pad_to=p.N_fit,
+                                      chunk_bytes=p.chunk_bytes)
+                     if mesh is not None else to_device_f32(vm))
+    W = np.stack(W_rows)
+    if mesh is not None:
+        return stream_to_device(W, mesh, row_axis=1, pad_to=p.N_fit,
+                                chunk_bytes=p.chunk_bytes), masks
+    # one shared transfer; family fits see a no-op conversion.  exact=True:
+    # bf16 wire only when verified lossless (0/1 fold masks; balancer
+    # keep/drop weights) — custom splitters may emit arbitrary weights, which
+    # go exact f32
+    return to_device_f32(W, exact=True), masks
+
+
+def pretrace_families(p: Placement, plan: SweepPlan,
+                      candidates: Sequence[ModelCandidate]) -> None:
+    """Concurrent pre-trace (aot.py): lower+compile each supporting family's
+    grid programs on a background thread NOW, so that by the time the fits
+    reach them the persistent compile cache already holds the executables.
+    Compile-only — sweep winners are bitwise unaffected."""
+    if not pretrace_enabled():
+        return
+    for ci, cand in enumerate(candidates):
+        if (ci in plan.replayed or not getattr(
+                cand.estimator, "supports_pretrace", False)):
+            continue
+
+        def submit(Wblk, grid, est=cand.estimator, name=cand.model_name,
+                   X=p.X, y=p.y):
+            pretrace_submit(name, lambda: est.pretrace_arrays_grid(
+                X, y, Wblk, grid))
+        if plan.raced_flags[ci]:
+            # round A (full grid, fold 0) is certain; round B's survivor
+            # subset is data-dependent — pre-trace a same-sized prefix as a
+            # best-effort shape/static match (a miss just forfeits the
+            # overlap)
+            submit(p.W[:1], cand.grid)
+            submit(p.W, cand.grid[:plan.survivor_count(len(cand.grid))])
+        else:
+            submit(p.W, cand.grid)
+
+
+def fit_family(p: Placement, cand: ModelCandidate, Wblk, grid,
+               plan: SweepPlan) -> Tuple[list, bool]:
+    """One family's (fold × grid) block over the placed arrays as ONE batched
+    program → (``fitted[fold][point]``, whether the batched program ran).
+    A block that fails is retried point by point, so one bad candidate cannot
+    take down its family (≙ Try-wrapped fits in OpValidator.getSummary); a
+    failed point is None."""
+    name = cand.model_name
+    try:
+        maybe_inject("selector.candidate_fit", key=name)
+        # chaos seams for a mid-sweep device loss and allocator OOM
+        maybe_inject("supervisor.device_loss",
+                     key=f"{name}:fit:a{plan.attempt}")
+        maybe_inject("memory.device_oom",
+                     key=f"{name}:fit:o{plan.oom_attempt}")
+        if memory.per_candidate_fallback():
+            # memory ladder's last rung: no batched grid program at all —
+            # the per-(fold, point) working set is the smallest the sweep
+            # can make
+            raise MemoryError("memory ladder: per-candidate fallback")
+        parts = memory.grid_partitions()
+        if parts > 1 and len(grid) > 1:
+            # memory ladder rung 2+ (or the preflight plan): split the
+            # batched program into grid sub-batches so each program's lane
+            # working set shrinks with the partition count
+            sub = -(-len(grid) // min(parts, len(grid)))
+            outs = [cand.estimator.fit_arrays_grid(p.X, p.y, Wblk,
+                                                   grid[i:i + sub])
+                    for i in range(0, len(grid), sub)]
+            return [[fit for o in outs for fit in o[f]]
+                    for f in range(len(outs[0]))], True
+        return cand.estimator.fit_arrays_grid(p.X, p.y, Wblk, grid), True
+    except Exception as e:  # noqa: BLE001
+        # a lost device is NOT a bad candidate: per-point refits on a dead
+        # mesh would fail K×|grid| more times — let the sweep-level recovery
+        # rebuild the surviving mesh instead
+        if supervisor.is_device_loss(e):
+            raise
+        # allocator exhaustion is not a bad candidate either — unless the
+        # ladder already reached its last rung, where per-point refits ARE
+        # the recovery
+        if (memory.is_memory_exhaustion(e)
+                and not memory.per_candidate_fallback()):
+            raise
+        record_failure(name, "degraded", e, point="selector.candidate_fit",
+                       fallback="per-point refits")
+    fitted_grid = []
+    for f in range(len(Wblk)):
+        with span("selector.fold_fit", model=name, fold=f, degraded=True):
+            row = []
+            for gi, params in enumerate(grid):
+                try:
+                    maybe_inject("selector.candidate_fit", key=name)
+                    est = copy.deepcopy(cand.estimator)
+                    for k, v in params.items():
+                        est.set(k, v)
+                    row.append(est.fit_arrays(p.X, p.y,
+                                              sample_weight=Wblk[f]))
+                except Exception as e2:  # noqa: BLE001
+                    if supervisor.is_device_loss(e2):
+                        raise
+                    record_failure(name, "skipped", e2,
+                                   point="selector.candidate_fit",
+                                   fold=f, grid_index=gi)
+                    row.append(None)
+        fitted_grid.append(row)
+    return fitted_grid, False
+
+
+def fit_round(round_name: str, jobs: Sequence[Any], p: Placement,
+              plan: SweepPlan, fit_meta: Dict[str, Dict[str, int]],
+              preempted: List[str]) -> list:
+    """Fit one round's families, on the pool or one after another.  A job is
+    ``(candidate, weight block)`` or ``_REPLAYED``; the answer per job is the
+    fitted grid, ``_REPLAYED``, or ``_PREEMPTED`` where a requested graceful
+    stop (signal or injected preemption) won over starting new work.
+    ``fit_meta`` learns the folds and lanes of each family's batched fit."""
+    left = [len(jobs)]   # feeds the /statusz board's ETA, per round
+    BOARD.publish(round=round_name, fitsQueued=len(jobs))
+
+    def one(job):
+        if job is _REPLAYED:
+            return _REPLAYED
+        cand, Wblk = job
+        if shutdown_requested(key=cand.model_name):
+            preempted.append(cand.model_name)
+            return _PREEMPTED
+        BOARD.publish(candidate=cand.model_name,
+                      candidateGrid=len(cand.grid),
+                      candidateFolds=int(len(Wblk)))
+        t0 = time.perf_counter()
+        # worker threads have no span of their own, so this parents under
+        # the orchestrating selector.sweep span even through the pool
+        with span("selector.candidate_fit", model=cand.model_name,
+                  grid=len(cand.grid), folds=int(len(Wblk))):
+            fitted, batched = fit_family(p, cand, Wblk, cand.grid, plan)
+        if batched:
+            fit_meta[cand.model_name] = {"folds": len(fitted),
+                                         "lanes": len(cand.grid)}
+        else:
+            fit_meta.pop(cand.model_name, None)
+        left[0] = max(0, left[0] - 1)
+        BOARD.note_unit(time.perf_counter() - t0, remaining_units=left[0])
+        return fitted
+
+    workers = min(plan.n_workers, len(jobs))
+    if workers > 1:
+        with ThreadPoolExecutor(max_workers=workers) as pool:
+            return list(pool.map(one, jobs))
+    return [one(job) for job in jobs]
+
+
+def _make_model(cand, params, fitted):
+    est = cand.estimator
+    return est.model_cls(fitted=fitted, **{**est._params, **params})
+
+
+def _device_metric(evaluator, cand, params, fitted, X, y, w):
+    """Score a candidate entirely on device (see metrics_device); None →
+    caller falls back to the host path.  Device scalars are returned as-is
+    (defer=True) and pulled in one batch afterwards."""
+    try:
+        model = _make_model(cand, params, fitted)
+        if not hasattr(model, "device_scores"):
+            return None
+        return evaluator.evaluate_masked(y, model.device_scores(X), w,
+                                         defer=True)
+    except Exception:  # noqa: BLE001
+        return None
+
+
+def _host_metric(evaluator, cand, params, fitted, X_va, y_va):
+    try:
+        maybe_inject("selector.candidate_metric", key=cand.model_name)
+        pred = _make_model(cand, params, fitted).predict_arrays(X_va)
+        return evaluator.evaluate(y_va, pred)
+    except Exception as e:  # noqa: BLE001 — candidate robustness
+        if supervisor.is_device_loss(e) or memory.is_memory_exhaustion(e):
+            raise   # sweep-level recovery, not a NaN score
+        record_failure(cand.model_name, "skipped", e,
+                       point="selector.candidate_metric",
+                       params=dict(params))
+        return float("nan")
+
+
+def score_block(validator: "OpValidator", p: Placement, plan: SweepPlan,
+                cand: ModelCandidate, ci: int, fitted_grid, fold_offset: int,
+                n_folds: int, rec) -> None:
+    """Score a fitted (n_folds × grid) block against validation folds
+    [fold_offset, fold_offset + n_folds): the batched panel first, then per
+    candidate on the device, then per candidate on the host.  ``rec`` lets
+    racing remap a survivor sub-grid's local indices back to the family's
+    full grid."""
+    BOARD.publish(scoring=cand.model_name, foldOffset=fold_offset,
+                  foldCount=n_folds)
+    # chaos seam: a device lost between fitting and scoring — fires AFTER
+    # earlier families checkpointed, so the recovery sweep demonstrably
+    # replays them from the SweepCheckpoint
+    maybe_inject("supervisor.device_loss",
+                 key=f"{cand.model_name}:score:a{plan.attempt}")
+    maybe_inject("memory.device_oom",
+                 key=f"{cand.model_name}:score:o{plan.oom_attempt}")
+    masks = p.va_masks[fold_offset:fold_offset + n_folds]
+    if validator._record_grid_metrics_batched(cand, ci, fitted_grid, p.X,
+                                              p.y, masks, rec):
+        return
+    for f_local, mask in enumerate(masks):
+        for gi, params in enumerate(cand.grid):
+            fitted = fitted_grid[f_local][gi]
+            if fitted is None:
+                rec(cand, ci, gi, params, float("nan"))
+                continue
+            metric = _device_metric(validator.evaluator, cand, params,
+                                    fitted, p.X, p.y, mask)
+            if metric is None:
+                metric = _host_metric(
+                    validator.evaluator, cand, params, fitted,
+                    *p.validation_rows(fold_offset + f_local))
+            rec(cand, ci, gi, params, metric)
+
+
+class ResultBook:
+    """The sweep's scores: one ``ValidatedCandidate`` per (family, grid
+    point), in the order first recorded — replayed families first."""
+
+    def __init__(self, plan: SweepPlan, candidates: Sequence[ModelCandidate]):
+        self.plan = plan
+        self.results: Dict[Tuple[str, int], ValidatedCandidate] = {}
+        # device-scalar metrics are recorded lazily and pulled host-side in
+        # ONE stacked transfer — a per-candidate float() costs a full
+        # host-link round trip each
+        self._deferred: List[Tuple[Any, list, int]] = []
+        for ci, stored in plan.replayed.items():
+            cand = candidates[ci]
+            for gi, r in enumerate(stored):
+                self.results[(cand.model_name, ci * 10000 + gi)] = \
+                    ValidatedCandidate(
+                        cand.model_name, dict(r.get("params") or {}),
+                        [float(v) for v in (r.get("metricValues") or [])],
+                        candidate_index=ci,
+                        raced_out=bool(r.get("racedOut", False)))
+            record_failure(cand.model_name, "resumed",
+                           f"replayed {len(stored)} grid point(s) from "
+                           "sweep checkpoint", point="checkpoint.load",
+                           candidate_index=ci)
+
+    def get(self, cand, ci: int, gi: int) -> Optional[ValidatedCandidate]:
+        return self.results.get((cand.model_name, ci * 10000 + gi))
+
+    def record(self, cand, ci, gi, params, metric) -> None:
+        r = self.get(cand, ci, gi)
+        if r is None:
+            r = self.results[(cand.model_name, ci * 10000 + gi)] = \
+                ValidatedCandidate(cand.model_name, dict(params), [],
+                                   candidate_index=ci)
+        if isinstance(metric, jax.Array):
+            r.metric_values.append(float("nan"))   # patched by ``drain``
+            self._deferred.append((metric, r.metric_values,
+                                   len(r.metric_values) - 1))
+        else:
+            r.metric_values.append(float(metric))
+
+    def drain(self) -> None:
+        """Pull every pending device-scalar metric in one stacked transfer
+        (falling back to per-metric pulls on failure).  Called at the end of
+        the grid, before ranking, and before each sweep-checkpoint flush — a
+        flushed family's metric values must be real numbers, not the NaN
+        placeholders the batched pull would patch later."""
+        if not self._deferred:
+            return
+        try:
+            vals = np.asarray(jnp.stack([m for m, _, _ in self._deferred]))
+        except Exception as e:  # noqa: BLE001 — candidate robustness: one
+            # bad candidate's runtime failure must not kill the whole grid;
+            # fall back to per-metric pulls (failed ones stay NaN)
+            record_failure("validator", "degraded", e,
+                           point="selector.metric_pull",
+                           fallback="per-metric pulls")
+            vals = []
+            for m, _, _ in self._deferred:
+                try:
+                    vals.append(float(m))
+                except Exception as e2:  # noqa: BLE001
+                    record_failure("validator", "skipped", e2,
+                                   point="selector.metric_pull")
+                    vals.append(float("nan"))
+        for v, (_, lst, i) in zip(vals, self._deferred):
+            lst[i] = float(v)
+        self._deferred.clear()
+
+    def checkpoint_family(self, ci: int, cand, fitted_grid) -> None:
+        """Persist one completed candidate family into the ambient sweep
+        checkpoint (atomic flush), if there is one.  A checkpoint-write
+        failure degrades — the sweep's correctness never depends on its
+        durability."""
+        cp = self.plan.checkpoint
+        if cp is None:
+            return
+        self.drain()
+        found = (self.get(cand, ci, gi) for gi in range(len(cand.grid)))
+        entry = [{"params": r.params, "metricValues": r.metric_values,
+                  "racedOut": r.raced_out} for r in found if r is not None]
+        try:
+            cp.record_candidate(
+                self.plan.signatures[ci], cand.model_name, ci, entry,
+                fitted_grid=fitted_grid
+                if isinstance(fitted_grid, list) else None)
+            cp.flush()
+            BOARD.publish(lastCheckpointFamily=cand.model_name)
+        except Exception as e:  # noqa: BLE001
+            record_failure(cand.model_name, "degraded", e,
+                           point="checkpoint.save",
+                           fallback="sweep continues unpersisted")
+
+
+def prune_raced(book: ResultBook, plan: SweepPlan,
+                candidates: Sequence[ModelCandidate], race_live: List[int],
+                larger_better: bool) -> Dict[int, List[int]]:
+    """Rank each raced family's fold-0 screen in the evaluator's direction
+    and mark what falls past the survivor floor ``raced_out``: the
+    (folds-1) × (grid - survivors) fits never run.  → the surviving grid
+    indices per candidate index, ascending."""
+    book.drain()   # ranking needs numbers, not deferred slots
+    sign = 1.0 if larger_better else -1.0
+    survivors: Dict[int, List[int]] = {}
+    raced_out: Dict[str, int] = {}
+    for ci in race_live:
+        cand = candidates[ci]
+        G = len(cand.grid)
+        S = plan.survivor_count(G)
+
+        def keyf(gi):
+            r = book.get(cand, ci, gi)
+            v = r.metric_values[0] if r and r.metric_values else float("nan")
+            return sign * v if np.isfinite(v) else -np.inf
+
+        # deterministic: ties and NaNs break by grid position
+        order = sorted(range(G), key=lambda gi: (-keyf(gi), gi))
+        for gi in order[S:]:
+            r = book.get(cand, ci, gi)
+            if r is not None:
+                r.raced_out = True
+        event("selector.racing.prune", model=cand.model_name, grid=G,
+              survivors=S, pruned=G - S)
+        raced_out[cand.model_name] = G - S
+        BOARD.publish(racedOut=dict(raced_out))
+        survivors[ci] = sorted(order[:S])
+    return survivors
+
+
+def choose_winner(book: ResultBook, candidates: Sequence[ModelCandidate],
+                  validator: "OpValidator") -> ValidationResult:
+    book.drain()   # ONE pull for every device-scalar metric left
+    evaluator = validator.evaluator
+    all_results = list(book.results.values())
+    sign = 1.0 if evaluator.is_larger_better else -1.0
+    # raced-out points carry a fold-0 screen mean only; comparing that
+    # against survivors' full-k-fold means would be apples-to-oranges, so
+    # they are excluded from winner selection (kept in all_results for the
+    # summary). If racing somehow pruned everything that finished, fall back
+    # to the full list rather than fail the sweep.
+    scored = [(sign * r.mean_metric, r) for r in all_results
+              if np.isfinite(r.mean_metric) and not r.raced_out]
+    if not scored:
+        scored = [(sign * r.mean_metric, r) for r in all_results
+                  if np.isfinite(r.mean_metric)]
+    if not scored:
+        # aggregate error with per-candidate causes from the failure log —
+        # "nothing survived" alone is undebuggable at 3am
+        causes: Dict[str, str] = {}
+        for ev in active_failure_log().events:
+            if ev.point.startswith("selector.") and ev.cause:
+                causes.setdefault(ev.stage, ev.cause)
+        for cand in candidates:
+            causes.setdefault(cand.model_name, "no finite validation metric")
+        raise AllCandidatesFailed(
+            "all model candidates failed validation", causes)
+    _, best = max(scored, key=lambda t: t[0])
+    best_est = copy.deepcopy(candidates[best.candidate_index].estimator)
+    for k, v in best.params.items():
+        best_est.set(k, v)
+    return ValidationResult(
+        best=ModelCandidate(best_est, [dict(best.params)], best.model_name),
+        best_params=dict(best.params),
+        best_metric=best.mean_metric,
+        all_results=all_results,
+        validation_type=validator.validation_type,
+        metric_name=evaluator.default_metric,
+        is_larger_better=evaluator.is_larger_better)
+
+
+def _fold_groups(plan: SweepPlan, batch: ColumnBatch, features: str):
+    """(X, fold splits) groups: one shared X across folds normally; per-fold
+    X when feature stages must be refit inside the fold (leakage guard,
+    ≙ OpCrossValidation.validate:87-147 DAG copy+refit).  A generator, so
+    only one fold's full-size matrix is resident at a time.  The matrix keeps
+    its residency: device arrays stay on device (the host link is the
+    bottleneck on real TPU hardware) and sparse matrices pass through —
+    densifying one here is exactly the [N, num_hashes] blow-up the
+    representation avoids."""
+    def values(b):
+        v = b[features].values
+        if isinstance(v, (jax.Array, SparseMatrix)):
+            return v
+        return np.asarray(v, dtype=np.float32)
+
+    if len(plan.replayed) == len(plan.raced_flags):
+        # every candidate replayed from the sweep checkpoint — no data
+        # matrix, fold masks, or device transfers needed
+        return
+    if not plan.in_fold_dag:
+        yield values(batch), plan.splits
+        return
+    for f, (tr_idx, va_idx) in enumerate(plan.splits):
+        with span("selector.fold_fit", fold=f, in_fold_dag=True):
+            dag_copy = [[copy.deepcopy(s) for s in layer]
+                        for layer in plan.in_fold_dag]
+            _, fitted_dag = fit_dag(batch.take_rows(tr_idx), dag_copy)
+            full = apply_dag(batch, fitted_dag)
+        yield values(full), [(tr_idx, va_idx)]
 
 
 class OpValidator:
@@ -445,15 +1174,12 @@ class OpValidator:
         self.seed = int(seed)
         self.stratify = bool(stratify)
         self.parallelism = int(parallelism)
-        # successive-halving sweep racing (ISSUE 4): None defers to
-        # DefaultSelectorParams so OpParams/selector factories can retune
-        # the fleet-wide defaults without touching every validator ctor
+        # sweep racing: None defers to DefaultSelectorParams so
+        # OpParams/selector factories can retune the fleet-wide defaults
+        # without touching every validator ctor
         self.racing = racing
         self.racing_eta = racing_eta
         self.racing_min_survivors = racing_min_survivors
-        # per-family (folds, rows, lanes) of the last batched fit block —
-        # the selector's winner refit reuses the SAME compiled executable
-        self.family_fit_meta: Dict[str, Dict[str, Any]] = {}
 
     def _racing_config(self) -> Tuple[bool, float, int]:
         """(enabled, eta, min_survivors) with DefaultSelectorParams filling
@@ -494,14 +1220,6 @@ class OpValidator:
             iters = nxt
         return np.asarray(out, dtype=np.int64)
 
-    def _maybe_mesh(self, n_rows: int, pad: bool = False):
-        """Shared data-axis mesh policy (parallel.mesh.maybe_data_mesh).
-        ``pad=True`` lets the sweep take the mesh on non-divisible row counts
-        (the sweep appends zero-weight pad rows, which is exact for
-        ``weighted_pad_exact`` families)."""
-        from .parallel.mesh import maybe_data_mesh
-        return maybe_data_mesh(n_rows, pad=pad)
-
     def _record_grid_metrics_batched(self, cand, ci, fitted_grid, X, y_dev,
                                      va_masks_dev, record) -> bool:
         """Score a LINEAR family's whole (fold × grid) block with ONE matmul
@@ -511,9 +1229,6 @@ class OpValidator:
         replace per-model sigmoid scores exactly.  Returns False when the
         family/evaluator has no batched form (caller keeps the per-candidate
         path)."""
-        import jax
-        import jax.numpy as jnp
-
         if (self.evaluator is None
                 or type(self.evaluator).evaluate_masked_grid
                 is OpEvaluatorBase.evaluate_masked_grid):
@@ -547,7 +1262,6 @@ class OpValidator:
                 coefs.append(c)
                 intercepts.append(fitted.get("intercept", 0.0))
         try:
-            from .sparse.matrix import SparseMatrix
             if multinomial:
                 # multinomial coef is stored [D, C] (see LinearPredictionModel)
                 C3 = jnp.stack([jnp.asarray(c, jnp.float32) for c in coefs])
@@ -624,12 +1338,6 @@ class OpValidator:
         margin (positive affine in the leaf sum), so the AUC metrics match
         the per-candidate path.  Replaces one predict+metric dispatch chain
         per (fold × grid point) with one per (fold × shape group)."""
-        from collections import defaultdict
-
-        import jax.numpy as jnp
-
-        from .models.trees import predict_trees_sum_grouped
-
         F = len(va_masks_dev)
         G = len(cand.grid)
         panel_input = getattr(self.evaluator, "grid_panel_input", "scores")
@@ -678,7 +1386,6 @@ class OpValidator:
                     else:
                         S = sums[..., 1]
                 else:
-                    import jax
                     eta = jnp.asarray([float(m["eta"]) for _, m in members],
                                       jnp.float32)
                     base = jnp.asarray([float(m["base"]) for _, m in members],
@@ -734,25 +1441,18 @@ class OpValidator:
         per retry, resuming from the same checkpoint.  Bounded by
         TRANSMOGRIFAI_OOM_RECOVERIES; an exhausted ladder raises typed
         ``MemoryExhaustedError`` with the attempted plan attached."""
-        from .parallel import hostgroup as _hostgroup
-        from .parallel import memory as _memory
-        from .parallel import supervisor as _supervisor
-        from .telemetry import span
         # inside a multi-process host group the sweep span carries the rank
         # so merged traces attribute each sweep lane to its host
         _hg_attrs = {}
-        if _hostgroup.hostgroup_env_present():
-            _hg_attrs = {"hostgroup_rank": _hostgroup.current_rank(),
-                         "hostgroup_world": _hostgroup.group_world_size()}
+        if hostgroup.hostgroup_env_present():
+            _hg_attrs = {"hostgroup_rank": hostgroup.current_rank(),
+                         "hostgroup_world": hostgroup.group_world_size()}
         # the one-per-family fallback warning is scoped to THIS validate:
         # a second train in the same process surfaces its own fallbacks
         _reset_logged_fallbacks()
-        from .obsv import BOARD
         attempt = 0
         oom_attempt = 0
         while True:
-            self._sweep_attempt = attempt
-            self._oom_attempt = oom_attempt
             # control-plane seam: the retry loop is the coarse boundary —
             # /statusz shows which recovery lane the sweep is in
             BOARD.publish(phase="sweep", sweepAttempt=attempt,
@@ -762,7 +1462,7 @@ class OpValidator:
             # the RSS watchdog's hard watermark surfaces HERE, on the
             # governed thread, where a typed error can be handled — not as
             # a kernel OOM-kill of an arbitrary victim
-            _memory.check_host_pressure()
+            memory.check_host_pressure()
             try:
                 with span("selector.sweep", candidates=len(candidates),
                           validation_type=self.validation_type,
@@ -772,21 +1472,23 @@ class OpValidator:
                     return self._validate_impl(candidates, batch, label,
                                                features,
                                                in_fold_dag=in_fold_dag,
-                                               splitter=splitter)
+                                               splitter=splitter,
+                                               attempt=attempt,
+                                               oom_attempt=oom_attempt)
             except Exception as e:  # noqa: BLE001 — classify, maybe recover
-                if _supervisor.is_device_loss(e):
-                    if attempt >= _supervisor.max_sweep_recoveries():
+                if supervisor.is_device_loss(e):
+                    if attempt >= supervisor.max_sweep_recoveries():
                         raise
-                    _supervisor.note_sweep_device_loss(e, attempt=attempt,
+                    supervisor.note_sweep_device_loss(e, attempt=attempt,
                                                        stage="validator")
                     attempt += 1
                     continue
-                if _memory.is_memory_exhaustion(e):
-                    if not _memory.memory_governor_enabled():
+                if memory.is_memory_exhaustion(e):
+                    if not memory.memory_governor_enabled():
                         raise   # --no-memory-governor: propagate unchanged
-                    if oom_attempt >= _memory.max_oom_recoveries():
-                        raise _memory.as_memory_exhausted(e) from e
-                    _memory.note_sweep_memory_exhaustion(
+                    if oom_attempt >= memory.max_oom_recoveries():
+                        raise memory.as_memory_exhausted(e) from e
+                    memory.note_sweep_memory_exhaustion(
                         e, attempt=oom_attempt, stage="validator")
                     oom_attempt += 1
                     continue
@@ -795,915 +1497,97 @@ class OpValidator:
     def _validate_impl(self, candidates: Sequence[ModelCandidate],
                        batch: ColumnBatch, label: str, features: str,
                        in_fold_dag: Optional[List[List[Any]]] = None,
-                       splitter: Optional[Splitter] = None
+                       splitter: Optional[Splitter] = None,
+                       attempt: int = 0, oom_attempt: int = 0
                        ) -> ValidationResult:
-        """Run the CV/TVS grid.
+        """One attempt at the CV/TVS grid: plan, then for each fold group
+        place → fit → score, racing in two rounds, then the winner.
 
         The fast path (no in-fold DAG) keeps ONE data matrix in HBM and turns
         folds into per-row weight masks, so each candidate family trains its
         whole (fold × grid) block as a single batched XLA program
         (``fit_arrays_grid``) with zero fold-shape recompiles — the TPU
         re-design of the reference's k×Σ|grid| Spark-job fan-out
-        (OpValidator.scala:320-349).  ``splitter.validation_prepare_weights``
-        applies Balancer/Cutter preparation to each fold's *training* rows
-        (scoring stays on the untouched validation slice), matching the
-        reference flow.
+        (OpValidator.scala:320-349).
         """
-        import copy
-
-        from .dag import apply_dag, fit_dag
-
         y_all = np.asarray(batch[label].values, dtype=np.float64)
-        splits = self.splits(y_all)
-
-        # -- successive-halving racing plan (ISSUE 4) ----------------------
-        # Screen the full grid on fold 0 only, prune to the top 1/eta per
-        # family (floored at min_survivors), run the remaining folds for
-        # survivors only.  The parity guard keeps any family whose survivor
-        # floor covers its whole grid on the exact full-CV path — tiny grids
-        # are bit-identical to an unraced sweep.
-        racing_on, racing_eta, racing_min_surv = self._racing_config()
-        # racing runs on the mesh-sharded path too: round A/B fits are the
-        # same batched programs with a fold-sliced weight block, and GSPMD
-        # shards them identically — no single-device carve-out needed
-        race_path_ok = not in_fold_dag and len(splits) >= 2
-        if racing_on and not race_path_ok:
-            # the flag is on by default — say WHY this sweep runs unraced
-            # instead of silently ignoring it (ISSUE 4 satellite)
-            reason = ("in-fold DAG refits feature stages per fold"
-                      if in_fold_dag else
-                      "single train/validation split (racing needs >= 2 "
-                      "folds)")
-            record_failure("validator", "degraded",
-                           f"racing disabled: {reason}",
-                           point="selector.racing",
-                           validation_type=self.validation_type)
-
-        def _survivor_count(G: int) -> int:
-            return max(racing_min_surv, int(np.ceil(G / racing_eta)))
-
-        raced_flags = [racing_on and race_path_ok
-                       and _survivor_count(len(c.grid)) < len(c.grid)
-                       for c in candidates]
-
-        def _racing_sig(ci: int) -> Dict[str, Any]:
-            if not raced_flags[ci]:
-                return {"enabled": False}
-            return {"enabled": True, "eta": racing_eta,
-                    "minSurvivors": racing_min_surv}
-
-        results: Dict[Tuple[str, int], ValidatedCandidate] = {}
-        # device-scalar metrics are recorded lazily and pulled host-side in
-        # ONE stacked transfer at the end — a per-candidate float() costs a
-        # full host-link round trip each
-        deferred: List[Tuple[Any, list]] = []
-
-        # resumable sweep: candidates already completed in the ambient sweep
-        # checkpoint replay their scores instead of re-fitting.  Fast path
-        # only — the in-fold-DAG path accumulates each candidate's metrics
-        # across several fold groups, so a per-family snapshot would persist
-        # half-filled metric lists.
-        from .checkpoint import (SweepCheckpoint, TrainingPreempted,
-                                 active_sweep_checkpoint, shutdown_requested)
-        sweep_cp = None if in_fold_dag else active_sweep_checkpoint()
-        sweep_sigs: List[str] = []
-        replayed: set = set()
-        preempted: List[str] = []
-        if sweep_cp is not None:
-            for ci, cand in enumerate(candidates):
-                sig = SweepCheckpoint.candidate_signature(
-                    cand.model_name, ci, cand.grid, racing=_racing_sig(ci))
-                sweep_sigs.append(sig)
-                stored = sweep_cp.results_for(sig)
-                if stored is None:
-                    continue
-                replayed.add(ci)
-                for gi, r in enumerate(stored):
-                    key = (cand.model_name, ci * 10000 + gi)
-                    results[key] = ValidatedCandidate(
-                        cand.model_name, dict(r.get("params") or {}),
-                        [float(v) for v in (r.get("metricValues") or [])],
-                        candidate_index=ci,
-                        raced_out=bool(r.get("racedOut", False)))
-                record_failure(cand.model_name, "resumed",
-                               f"replayed {len(stored)} grid point(s) from "
-                               "sweep checkpoint", point="checkpoint.load",
-                               candidate_index=ci)
-        live = [ci for ci in range(len(candidates)) if ci not in replayed]
-        _REPLAYED = object()     # sentinel fitted_grid: scores came from cp
-        _PREEMPTED = object()    # sentinel fitted_grid: stop won the boundary
-
-        def record(cand, ci, gi, params, metric):
-            key = (cand.model_name, ci * 10000 + gi)
-            if key not in results:
-                results[key] = ValidatedCandidate(
-                    cand.model_name, dict(params), [], candidate_index=ci)
-            vals = results[key].metric_values
-            if isinstance(metric, jax.Array):
-                vals.append(float("nan"))      # patched by the batched pull
-                deferred.append((metric, (vals, len(vals) - 1)))
-            else:
-                vals.append(float(metric))
-
-        def make_model(cand, params, fitted):
-            est = cand.estimator
-            return est.model_cls(fitted=fitted, **{**est._params, **params})
-
-        def device_metric(cand, params, fitted, X_dev, y_dev, w_dev):
-            """Score a candidate entirely on device (see metrics_device);
-            None → caller falls back to the host path.  Device scalars are
-            returned as-is (defer=True) and pulled in one batch afterwards."""
-            try:
-                model = make_model(cand, params, fitted)
-                if not hasattr(model, "device_scores"):
-                    return None
-                return self.evaluator.evaluate_masked(
-                    y_dev, model.device_scores(X_dev), w_dev, defer=True)
-            except Exception:  # noqa: BLE001
-                return None
-
-        def host_metric(cand, params, fitted, X_va, y_va):
-            try:
-                maybe_inject("selector.candidate_metric", key=cand.model_name)
-                model = make_model(cand, params, fitted)
-                pred = model.predict_arrays(X_va)
-                return self.evaluator.evaluate(y_va, pred)
-            except Exception as e:  # noqa: BLE001 — candidate robustness
-                from .parallel.memory import is_memory_exhaustion
-                from .parallel.supervisor import is_device_loss
-                if is_device_loss(e) or is_memory_exhaustion(e):
-                    raise   # sweep-level recovery, not a NaN score
-                record_failure(cand.model_name, "skipped", e,
-                               point="selector.candidate_metric",
-                               params=dict(params))
-                return float("nan")
-
-        # (X, fold splits) groups: shared X across folds normally; per-fold X
-        # when feature stages must be refit inside the fold (leakage guard,
-        # ≙ OpCrossValidation.validate:87-147 DAG copy+refit).  A generator so
-        # only one fold's full-size matrix is resident at a time.
-        def _col_values(b):
-            """Feature matrix in its native residency: device arrays stay on
-            device (the host link is the bottleneck on real TPU hardware);
-            sparse matrices pass through — densifying one here is exactly
-            the [N, num_hashes] blow-up the representation avoids."""
-            v = b[features].values
-            if isinstance(v, (jax.Array, SparseMatrix)):
-                return v
-            return np.asarray(v, dtype=np.float32)
-
-        def fold_groups():
-            if not live:
-                # every candidate replayed from the sweep checkpoint — no
-                # data matrix, fold masks, or device transfers needed
-                return
-            if in_fold_dag:
-                from .telemetry import span as _span
-                for f, (tr_idx, va_idx) in enumerate(splits):
-                    with _span("selector.fold_fit", fold=f, in_fold_dag=True):
-                        dag_copy = [[copy.deepcopy(s) for s in layer]
-                                    for layer in in_fold_dag]
-                        _, fitted_dag = fit_dag(batch.take_rows(tr_idx),
-                                                dag_copy)
-                        full = apply_dag(batch, fitted_dag)
-                    yield _col_values(full), [(tr_idx, va_idx)]
-            else:
-                yield _col_values(batch), splits
-
-        import jax
-        import jax.numpy as jnp
-
-        from .sparse.matrix import SparseMatrix
-
-        def drain_deferred():
-            """Pull every pending device-scalar metric in one stacked
-            transfer (falling back to per-metric pulls on failure).  Called
-            at the end of the grid, and before each sweep-checkpoint flush —
-            a flushed family's metric values must be real numbers, not the
-            NaN placeholders the batched pull would patch later."""
-            if not deferred:
-                return
-            try:
-                vals = np.asarray(jnp.stack([m for m, _ in deferred]))
-            except Exception as e:  # noqa: BLE001 — candidate robustness: one
-                # bad candidate's runtime failure must not kill the whole
-                # grid; fall back to per-metric pulls (failed ones stay NaN)
-                record_failure("validator", "degraded", e,
-                               point="selector.metric_pull",
-                               fallback="per-metric pulls")
-                vals = []
-                for m, _ in deferred:
-                    try:
-                        vals.append(float(m))
-                    except Exception as e2:  # noqa: BLE001
-                        record_failure("validator", "skipped", e2,
-                                       point="selector.metric_pull")
-                        vals.append(float("nan"))
-            for v, (lst, i) in zip(vals, (slot for _, slot in deferred)):
-                lst[i] = float(v)
-            deferred.clear()
-
-        def checkpoint_family(ci, cand, fitted_grid):
-            """Persist one completed candidate family into the ambient sweep
-            checkpoint (atomic flush).  A checkpoint-write failure degrades —
-            the sweep's correctness never depends on its durability."""
-            entry = []
-            for gi in range(len(cand.grid)):
-                r = results.get((cand.model_name, ci * 10000 + gi))
-                if r is not None:
-                    entry.append({"params": r.params,
-                                  "metricValues": r.metric_values,
-                                  "racedOut": r.raced_out})
-            try:
-                sweep_cp.record_candidate(
-                    sweep_sigs[ci], cand.model_name, ci, entry,
-                    fitted_grid=fitted_grid
-                    if isinstance(fitted_grid, list) else None)
-                sweep_cp.flush()
-                from .obsv import BOARD
-                BOARD.publish(lastCheckpointFamily=cand.model_name)
-            except Exception as e:  # noqa: BLE001
-                record_failure(cand.model_name, "degraded", e,
-                               point="checkpoint.save",
-                               fallback="sweep continues unpersisted")
-
+        plan = plan_sweep(self, candidates, y_all, in_fold_dag, attempt,
+                          oom_attempt)
+        book = ResultBook(plan, candidates)
         # reuse the label column's own buffer so the weakref-keyed transfer
         # cache shares ONE host→device shipment with SanityChecker/evaluate
         y32 = np.asarray(batch[label].values, dtype=np.float32)
-        # shape of the fold-weight mask used for the batched fits — the final
-        # refit reuses it to hit the SAME compiled executable (shape-keyed)
-        self.last_fit_shape = None if in_fold_dag else (len(splits), len(y32))
-        self.family_fit_meta = {}
-        if not live:
-            # fully-replayed sweep: no grid executable was compiled this
-            # process, so the winner refit must take the plain fit path
-            self.last_fit_shape = None
-            self.last_mesh = None
-        from .columns import to_device_f32
-        from .telemetry import REGISTRY, span
-        # zero-weight row padding (mesh divisibility quantum, ladder rungs)
-        # is exact only for families that declare it — one non-exact family
-        # in the grid keeps the whole shared matrix unpadded
-        pad_exact_all = all(getattr(c.estimator, "weighted_pad_exact", False)
-                            for c in candidates)
-        for X, fsplits in fold_groups():
-            is_sparse = isinstance(X, SparseMatrix)
-            N = X.shape[0]
-            # one device data plane (ISSUE 19): sparse matrices shard over
-            # the mesh 'data' axis like dense ones — entries sort by row,
-            # partition at device row boundaries, pad to a common per-device
-            # nnz rung (DeviceTable).  Global row_ids let GSPMD insert the
-            # collectives; the segment-sum fitters tolerate the zero pads
-            # exactly (value 0.0 addends at an in-range row).
-            # everything the sweep lays over the devices, under one span: the
-            # matrix, the label, the fold assignment and the masks
-            with span("selector.place") as place:
-                mesh = self._maybe_mesh(N, pad=pad_exact_all)
-                self.last_mesh = mesh
-                if (mesh is None and not pad_exact_all
-                        and self._maybe_mesh(N, pad=True) is not None):
-                    # honest degrade: the mesh WAS viable (pad-divisible) but a
-                    # mixed grid (some family not weighted_pad_exact) pinned
-                    # the matrix unpadded and indivisible — record it so bench
-                    # aux and operators see single-device as a degrade, not a
-                    # choice
-                    record_failure(
-                        "sweep", "degraded",
-                        RuntimeError(
-                            f"N={N} indivisible and grid mixes non-pad-exact "
-                            f"families: sweep falls back to single device"),
-                        point="selector.mesh", fallback="single_device")
-                    REGISTRY.counter("selector.mesh_degraded").inc()
-                from .parallel import (data_axis_size, data_sharding,
-                                       pad_rows_for, stream_to_device)
-                from .parallel import memory as _mem
-                _plan_chunk = None   # preflight-chosen streaming chunk bytes
-                N_fit = N
-                relayout_bytes = 0   # moved on the device to suit the mesh
-                if mesh is not None:
-                    # multi-device: row-shard the matrix over the mesh 'data'
-                    # axis and let GSPMD insert the collectives inside every
-                    # batched fit/metric program (SURVEY §2.6 P1/P3 on the REAL
-                    # path). Row count pads up to the device-divisible quantum
-                    # — and, with the compile cache on, up to the fit-shape
-                    # ladder rung — with zero-weight rows; one padded matrix
-                    # serves every family (all are weighted_pad_exact whenever
-                    # N_fit > N).
-                    extent = data_axis_size(mesh)
-                    N_fit = N + pad_rows_for(N, mesh)
-                    if _fit_padding_enabled() and pad_exact_all:
-                        rung = _fit_pad_rows(N)
-                        N_fit = max(N_fit, -(-rung // extent) * extent)
-                    if N_fit > N and not pad_exact_all:
-                        N_fit = N   # divisible N, mixed families: no ladder pad
-                    if _mem.memory_governor_enabled():
-                        # preflight (ISSUE 15): estimate the padded-rung ×
-                        # dtype × grid-width × fold-panel footprint against the
-                        # per-device budget and choose chunk bytes (and grid
-                        # partitioning, read back by the fit bodies) BEFORE the
-                        # first transfer — the 11M-row regime stops discovering
-                        # OOM by dying in batched_device_put
-                        plan = _mem.plan_sweep_memory(
-                            rows=N_fit,
-                            cols=(int(X.shape[1])
-                                  if is_sparse or getattr(X, "ndim", 1) == 2
-                                  else 1),
-                            folds=len(fsplits),
-                            grid_width=max((len(c.grid) for c in candidates),
-                                           default=1),
-                            devices=int(mesh.devices.size),
-                            # a device-resident matrix stays as it is stored
-                            dtype_bytes=(int(X.dtype.itemsize)
-                                         if isinstance(X, jax.Array) else 4),
-                            nnz=int(X.nnz) if is_sparse else None)
-                        _plan_chunk = plan.chunk_bytes
-                    if is_sparse:
-                        # COO entries stream by nnz range under the same chunk
-                        # budget (DeviceTable dispatch inside
-                        # stream_to_device); empty pad rows own no entries, so
-                        # the nnz-rung pads are the only on-device synthesis
-                        X = stream_to_device(X, mesh, pad_to=N_fit,
-                                             chunk_bytes=_plan_chunk)
-                    elif isinstance(X, jax.Array):
-                        # already device-resident (the fused transform's
-                        # output): kept in the dtype it is stored in, as on
-                        # one device — a bfloat16 matrix stays bfloat16 and
-                        # the fit programs accumulate in float32; a float32
-                        # copy would double the bytes a chip holds and reads.
-                        # What a cast, a pad or a change of layout moves on
-                        # the device is counted (mesh.relayout_bytes)
-                        want = data_sharding(mesh, 2)
-                        Xj = X
-                        if X.dtype not in (jnp.float32, jnp.bfloat16):
-                            Xj = X.astype(jnp.float32)
-                        if N_fit > N:
-                            Xj = jnp.pad(Xj, ((0, N_fit - N), (0, 0)))
-                        if Xj is not X or not X.sharding.is_equivalent_to(
-                                want, X.ndim):
-                            relayout_bytes = int(Xj.nbytes)
-                        X = jax.device_put(Xj, want)
-                    else:
-                        # chunked host→device streaming: assemble each device's
-                        # row shard from bounded host slices so peak staging is
-                        # O(TRANSMOGRIFAI_DEVICE_CHUNK_BYTES), not O(dataset) —
-                        # the one-shot device_put staged the whole matrix
-                        X = stream_to_device(np.asarray(X, dtype=np.float32),
-                                             mesh, pad_to=N_fit,
-                                             chunk_bytes=_plan_chunk)
-                    if N_fit > N and not is_sparse:
-                        # tree families quantile-bin over the true rows only —
-                        # keeps padded split points identical to unpadded ones
-                        # (sparse grids are linear-only: no binning to protect)
-                        from .models.trees import register_real_rows
-                        register_real_rows(X, N)
-                elif not isinstance(X, jax.Array) and not is_sparse:
-                    # ONE host→device transfer shared by every candidate family
-                    # — the host link is the scarce resource
-                    X = to_device_f32(X)
-                is_dev = isinstance(X, jax.Array) or is_sparse
-                y_dev = None
-                if is_dev:
-                    # exact wire (bf16 only when verified lossless), shared
-                    # with every other consumer of the same label buffer
-                    y_dev = (stream_to_device(y32, mesh, pad_to=N_fit,
-                                              chunk_bytes=_plan_chunk)
-                             if mesh is not None else
-                             to_device_f32(y32, exact=True))
-                X_host = None if is_dev else X   # lazy d2h only if a fallback needs it
-                va_slices = [va for _, va in fsplits]
-                va_masks_dev = []
-                assign = np.full(N_fit, _NO_FOLD, np.uint8)
-                if N_fit > N:
-                    assign[N:] = _PAD_FOLD   # pad rows join NO fold, ever
-                for f, (_, va_idx) in enumerate(fsplits):
-                    assign[va_idx] = f
-                # dense per-fold weight rows only materialize when a splitter
-                # may modify them (or the host path needs them below)
-                W_rows = []
-                neutral = splitter is None or (
-                    type(splitter).validation_prepare_weights
-                    is Splitter.validation_prepare_weights)
-                if not neutral or not (is_dev and len(fsplits) < _PAD_FOLD):
-                    neutral = True
-                    for f, (tr_idx, _) in enumerate(fsplits):
-                        w = np.zeros(N, np.float32)
-                        w[tr_idx] = 1.0
-                        if splitter is not None:
-                            w2 = splitter.validation_prepare_weights(y_all, w)
-                            neutral = neutral and w2 is w
-                            w = w2
-                        W_rows.append(w)
-                if is_dev and neutral and len(fsplits) < _PAD_FOLD:
-                    # fold masks from ONE [N] uint8 assignment shipped over the
-                    # link — 1 byte/row instead of (folds+1)×4 bytes/row of
-                    # train + validation masks.  On the mesh the assignment is
-                    # row-sharded first so the [F, N] masks materialize
-                    # directly with the fit programs' expected sharding.
-                    aj = jnp.asarray(assign)
-                    if mesh is not None:
-                        aj = jax.device_put(aj, data_sharding(mesh, 1))
-                    Wd, VAd = _fold_masks_from_assignment(aj, len(fsplits))
-                    W = Wd
-                    va_masks_dev = [VAd[f] for f in range(len(fsplits))]
-                else:
-                    W = np.stack(W_rows)
-                    if is_dev:
-                        for va_idx in va_slices:
-                            vm = np.zeros(N, np.float32)
-                            vm[va_idx] = 1.0
-                            if mesh is not None:
-                                # pad tail streams in as zeros — never
-                                # validated
-                                vmj = stream_to_device(vm, mesh, pad_to=N_fit,
-                                                       chunk_bytes=_plan_chunk)
-                            else:
-                                vmj = to_device_f32(vm)  # 0/1 mask: bf16 exact
-                            va_masks_dev.append(vmj)
-                    if mesh is not None:
-                        W = stream_to_device(W, mesh, row_axis=1, pad_to=N_fit,
-                                             chunk_bytes=_plan_chunk)
-                    else:
-                        # one shared transfer; family fits see a no-op
-                        # conversion. exact=True: bf16 wire only when verified
-                        # lossless (0/1 fold masks; balancer keep/drop weights)
-                        # — custom splitters may emit arbitrary weights, which
-                        # go exact f32
-                        W = to_device_f32(W, exact=True)
-                n_dev = 1 if mesh is None else int(mesh.devices.size)
-                REGISTRY.gauge("mesh.devices").set(n_dev)
-                if mesh is not None:
-                    REGISTRY.counter("mesh.relayout_bytes").inc(relayout_bytes)
-                if place is not None:
-                    place.attrs.update(
-                        rows=int(N), pad_rows=int(N_fit - N), devices=n_dev,
-                        dtype=str(getattr(X, "dtype", "")),
-                        relayout_bytes=relayout_bytes,
-                        bytes_placed=sum(
-                            int(getattr(a, "nbytes", 0))
-                            for a in (X, y_dev, W, *va_masks_dev)))
-            # fit-shape canonicalization (ISSUE 4 compile reuse): one shared
-            # zero-weight-row-padded copy of (X, y) serves every pad-exact
-            # family, so nearby row counts land on the same ladder rung and
-            # hit the persistent compile cache.  The mesh path already folded
-            # its ladder rung into N_fit during streaming, so this separate
-            # padded copy exists only off-mesh.
-            pad_rows = 0
-            X_pad = y_pad = None
-            if (_fit_padding_enabled() and mesh is None
-                    and any(getattr(c.estimator, "weighted_pad_exact", False)
-                            for c in candidates)):
-                pad_rows = _fit_pad_rows(N) - N
-            if pad_rows:
-                if is_sparse:
-                    # empty rows own no COO entries and carry weight 0 —
-                    # exact for the weight-normalized sparse fitters
-                    X_pad = X.pad_rows(N + pad_rows)
-                    y_pad = jnp.pad(y_dev, (0, pad_rows))
-                elif is_dev:
-                    X_pad = jnp.pad(X, ((0, pad_rows), (0, 0)))
-                    y_pad = jnp.pad(y_dev, (0, pad_rows))
-                else:
-                    X_pad = np.pad(X, ((0, pad_rows), (0, 0)))
-                    y_pad = np.pad(y32, (0, pad_rows))
-                if not is_sparse:
-                    # tree families quantile-bin over the true rows only —
-                    # keeps padded split points identical to unpadded ones
-                    from .models.trees import register_real_rows
-                    register_real_rows(X_pad, N)
-
-            def _pad_weight_cols(Wblk):
-                if isinstance(Wblk, np.ndarray):
-                    return np.pad(Wblk, ((0, 0), (0, pad_rows)))
-                return jnp.pad(Wblk, ((0, 0), (0, pad_rows)))
-
-            # concurrent pre-trace (aot.py): lower+compile each supporting
-            # family's grid programs on a background thread NOW, so by the
-            # time the fit pool below reaches them the persistent compile
-            # cache already holds the executables and
-            # new_compiles_during_train collapses into overlapped wall time.
-            # Compile-only — sweep winners are bitwise unaffected.
-            from .aot import pretrace_enabled, pretrace_submit
-            if pretrace_enabled():
-                for ci, cand in enumerate(candidates):
-                    if (ci in replayed or not getattr(
-                            cand.estimator, "supports_pretrace", False)):
-                        continue
-                    use_pad = bool(pad_rows) and getattr(
-                        cand.estimator, "weighted_pad_exact", False)
-                    Xf = X_pad if use_pad else X
-                    yf = (y_pad if use_pad
-                          else y_dev if y_dev is not None else y32)
-
-                    def _submit(Wblk, grid, est=cand.estimator, Xf=Xf,
-                                yf=yf, name=cand.model_name):
-                        Wf = _pad_weight_cols(Wblk) if use_pad else Wblk
-                        pretrace_submit(
-                            name, lambda: est.pretrace_arrays_grid(
-                                Xf, yf, Wf, grid))
-                    if raced_flags[ci]:
-                        # round A (full grid, fold 0) is certain; round B's
-                        # survivor subset is data-dependent — pre-trace a
-                        # same-sized prefix as a best-effort shape/static
-                        # match (a miss just forfeits the overlap)
-                        _submit(W[:1], cand.grid)
-                        _submit(W, cand.grid[:_survivor_count(
-                            len(cand.grid))])
-                    else:
-                        _submit(W, cand.grid)
-
-            # control-plane progress: candidate-fit boundaries feed the
-            # /statusz board (current family + grid point) and the per-unit
-            # EWMA behind its ETA.  _fits_left is per round (A, then B).
-            _fits_left = [0]
-
-            def fit_candidate(cand, Wblk, grid):
-                # per-candidate trace span: worker threads have no span of
-                # their own, so this parents under the orchestrating
-                # selector.sweep span even through the thread pool
-                import time as _time
-
-                from .obsv import BOARD
-                from .telemetry import span as _span
-                BOARD.publish(candidate=cand.model_name,
-                              candidateGrid=len(grid),
-                              candidateFolds=int(len(Wblk)))
-                t0 = _time.perf_counter()
-                with _span("selector.candidate_fit", model=cand.model_name,
-                           grid=len(grid), folds=int(len(Wblk))):
-                    out = _fit_candidate_body(cand, Wblk, grid)
-                _fits_left[0] = max(0, _fits_left[0] - 1)
-                BOARD.note_unit(_time.perf_counter() - t0,
-                                remaining_units=_fits_left[0])
-                return out
-
-            def _fit_candidate_body(cand, Wblk, grid):
-                from .parallel import memory as _memq
-                from .telemetry import span as _span
-                use_pad = bool(pad_rows) and getattr(
-                    cand.estimator, "weighted_pad_exact", False)
-                Xf = X_pad if use_pad else X
-                yf = (y_pad if use_pad
-                      else y_dev if y_dev is not None else y32)
-                Wf = _pad_weight_cols(Wblk) if use_pad else Wblk
-                try:
-                    maybe_inject("selector.candidate_fit", key=cand.model_name)
-                    # chaos seam for mid-sweep device loss during a fit; the
-                    # key carries the sweep attempt so the post-recovery
-                    # retry is not re-killed by a sticky injector decision
-                    maybe_inject(
-                        "supervisor.device_loss",
-                        key=f"{cand.model_name}:fit:"
-                            f"a{getattr(self, '_sweep_attempt', 0)}")
-                    # chaos seam for a mid-sweep allocator OOM; keyed by the
-                    # memory-ladder attempt for the same reason — the
-                    # shrunken retry must not be re-killed
-                    maybe_inject(
-                        "memory.device_oom",
-                        key=f"{cand.model_name}:fit:"
-                            f"o{getattr(self, '_oom_attempt', 0)}")
-                    if _memq.per_candidate_fallback():
-                        # memory ladder's last rung: no batched grid program
-                        # at all — the per-(fold, point) working set is the
-                        # smallest the sweep can make
-                        raise MemoryError(
-                            "memory ladder: per-candidate fallback")
-                    parts = _memq.grid_partitions()
-                    if parts > 1 and len(grid) > 1:
-                        # memory ladder rung 2+ (or the preflight plan):
-                        # split the batched (fold × grid) program into grid
-                        # sub-batches so each program's lane working set
-                        # shrinks with the partition count
-                        sub = -(-len(grid) // min(parts, len(grid)))
-                        outs = [cand.estimator.fit_arrays_grid(
-                                    Xf, yf, Wf, grid[i:i + sub])
-                                for i in range(0, len(grid), sub)]
-                        out = [[fit for o in outs for fit in o[f]]
-                               for f in range(len(outs[0]))]
-                    else:
-                        out = cand.estimator.fit_arrays_grid(Xf, yf, Wf,
-                                                             grid)
-                    self.family_fit_meta[cand.model_name] = {
-                        "folds": len(out), "rows": int(Xf.shape[0]),
-                        "real_rows": int(N), "lanes": len(grid),
-                        # ladder copy OR mesh-streamed quantum/rung padding
-                        "padded": int(Xf.shape[0]) > int(N)}
-                    return out
-                except Exception as e:  # noqa: BLE001
-                    # a lost device is NOT a bad candidate: per-point refits
-                    # on a dead mesh would fail K×|grid| more times — let the
-                    # sweep-level recovery rebuild the surviving mesh instead
-                    from .parallel.supervisor import is_device_loss
-                    if is_device_loss(e):
-                        raise
-                    # allocator exhaustion is not a bad candidate either —
-                    # unless the ladder already reached its last rung, where
-                    # per-point refits ARE the recovery
-                    if (_memq.is_memory_exhaustion(e)
-                            and not _memq.per_candidate_fallback()):
-                        raise
-                    # batched fit failed as a block — retry per point so one
-                    # bad candidate can't take down the family (≙ Try-wrapped
-                    # fits in OpValidator.getSummary).  Per-point refits run
-                    # unpadded: exactness beats executable reuse on a path
-                    # that is already degraded.
-                    record_failure(cand.model_name, "degraded", e,
-                                   point="selector.candidate_fit",
-                                   fallback="per-point refits")
-                    self.family_fit_meta.pop(cand.model_name, None)
-                    fitted_grid = []
-                    for f in range(len(Wblk)):
-                        with _span("selector.fold_fit",
-                                   model=cand.model_name, fold=f,
-                                   degraded=True):
-                            row = []
-                            for gi, params in enumerate(grid):
-                                try:
-                                    maybe_inject("selector.candidate_fit",
-                                                 key=cand.model_name)
-                                    est = copy.deepcopy(cand.estimator)
-                                    for k, v in params.items():
-                                        est.set(k, v)
-                                    # mesh path: X carries streamed pad rows,
-                                    # so pair it with the matching padded
-                                    # sharded label/weight vectors
-                                    yfb = y_dev if mesh is not None else y32
-                                    row.append(est.fit_arrays(
-                                        X, yfb, sample_weight=Wblk[f]))
-                                except Exception as e2:  # noqa: BLE001
-                                    if is_device_loss(e2):
-                                        raise
-                                    record_failure(
-                                        cand.model_name, "skipped", e2,
-                                        point="selector.candidate_fit",
-                                        fold=f, grid_index=gi)
-                                    row.append(None)
-                        fitted_grid.append(row)
-                    return fitted_grid
-
-            # candidate families fit concurrently on a thread pool (≙ the
-            # reference's Futures fan-out, OpValidator.scala:320-349 +
-            # `parallelism` :106).  Device execution serializes on the TPU
-            # stream; the win is overlapping the XLA *compiles* of the
-            # per-family batched programs, which dominate first-run wall.
-            # At very large N the families' HBM working sets no longer fit
-            # side by side (each TREE family budgets ~6 GiB of one-hot
-            # space) — fit sequentially so peak = max, not sum.  Grids with
-            # no HBM-heavy family keep the compile-overlap pool at any N.
-            import os as _os
-
-            def fit_or_skip(icand):
-                """Candidate boundary: replay beats fit, and a requested
-                graceful stop (signal or injected preemption) wins over
-                starting new work."""
-                ci, cand = icand
-                if ci in replayed:
-                    return _REPLAYED
-                if shutdown_requested(key=cand.model_name):
-                    preempted.append(cand.model_name)
-                    return _PREEMPTED
-                if raced_flags[ci]:
-                    # successive-halving round A: full grid, fold 0 only
-                    return fit_candidate(cand, W[:1], cand.grid)
-                return fit_candidate(cand, W, cand.grid)
-
-            serial_rows = int(_os.environ.get(
-                "TRANSMOGRIFAI_SERIAL_FIT_ROWS", 4_000_000))
-            n_workers = min(self.parallelism, len(candidates))
-            if N >= serial_rows and any(
-                    getattr(c.estimator, "hbm_heavy", False)
-                    for c in candidates):
-                n_workers = 1
-            indexed = list(enumerate(candidates))
-            _fits_left[0] = len(indexed)
-            from .obsv import BOARD
-            BOARD.publish(round="A", fitsQueued=len(indexed))
-            if n_workers > 1:
-                from concurrent.futures import ThreadPoolExecutor
-                with ThreadPoolExecutor(max_workers=n_workers) as pool:
-                    fitted_grids = list(pool.map(fit_or_skip, indexed))
-            else:
-                fitted_grids = [fit_or_skip(ic) for ic in indexed]
-
-            va_cache: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
-
-            def va_slice(f, va_idx):
-                """Pulled validation slice, cached per FOLD so every
-                fallback candidate shares one transfer."""
-                if f not in va_cache:
-                    nonlocal X_host
-                    if is_sparse:
-                        # the slice STAYS sparse: sparse-capable models
-                        # consume the COO stream in predict_arrays; models
-                        # without a sparse path fail loudly (__array__
-                        # raises) and the resilience layer skips them
-                        xv = X.take_rows(np.asarray(va_idx))
-                    elif is_dev:
-                        # gather ONLY the validation slice on device, then
-                        # pull — the full matrix is folds-times bigger and
-                        # the link is the bottleneck.  Cast bf16-stored
-                        # matrices to f32 on device first: numpy kernels on
-                        # ml_dtypes bf16 are limited/slow on host
-                        xv = np.asarray(jnp.take(
-                            X, jnp.asarray(va_idx), axis=0
-                        ).astype(jnp.float32))
-                    else:
-                        if X_host is None:
-                            X_host = np.asarray(X)
-                        xv = X_host[va_idx]
-                    va_cache[f] = (xv, y32[va_idx])
-                return va_cache[f]
-
-            def score_block(cand, ci, fitted_grid, fold_offset, n_folds,
-                            rec):
-                """Score a fitted (n_folds × grid) block against validation
-                folds [fold_offset, fold_offset + n_folds) — batched fast
-                path first, device/host per-candidate fallback otherwise.
-                ``rec`` lets racing remap a survivor sub-grid's local
-                indices back to the family's full grid."""
-                BOARD.publish(scoring=cand.model_name,
-                              foldOffset=fold_offset, foldCount=n_folds)
-                # chaos seam: a device lost between fitting and scoring —
-                # fires AFTER earlier families checkpointed, so the recovery
-                # sweep demonstrably replays them from the SweepCheckpoint
-                maybe_inject(
-                    "supervisor.device_loss",
-                    key=f"{cand.model_name}:score:"
-                        f"a{getattr(self, '_sweep_attempt', 0)}")
-                maybe_inject(
-                    "memory.device_oom",
-                    key=f"{cand.model_name}:score:"
-                        f"o{getattr(self, '_oom_attempt', 0)}")
-                masks = va_masks_dev[fold_offset:fold_offset + n_folds]
-                if (is_dev and self._record_grid_metrics_batched(
-                        cand, ci, fitted_grid, X, y_dev, masks, rec)):
-                    return
-                for f_local in range(n_folds):
-                    f = fold_offset + f_local
-                    va_idx = va_slices[f]
-                    for gi, params in enumerate(cand.grid):
-                        fitted = fitted_grid[f_local][gi]
-                        if fitted is None:
-                            rec(cand, ci, gi, params, float("nan"))
-                            continue
-                        metric = None
-                        if is_dev:
-                            metric = device_metric(cand, params, fitted,
-                                                   X, y_dev,
-                                                   va_masks_dev[f])
-                        if metric is None:
-                            metric = host_metric(cand, params, fitted,
-                                                 *va_slice(f, va_idx))
-                        rec(cand, ci, gi, params, metric)
-
-            # round A: raced families score their fold-0 screen; unraced
-            # families score (and checkpoint) their full CV block exactly
-            # as an unraced sweep would
+        placement = None
+        fit_meta: Dict[str, Dict[str, int]] = {}
+        preempted: List[str] = []
+        for X, fsplits in _fold_groups(plan, batch, features):
+            placement = p = place(X, y32, fsplits, candidates, splitter,
+                                  y_all)
+            pretrace_families(p, plan, candidates)
+            # round A: raced families fit and score their fold-0 screen;
+            # unraced families fit, score and checkpoint their full block
+            # exactly as an unraced sweep would
+            fitted = fit_round(
+                "A", [_REPLAYED if ci in plan.replayed else
+                      (cand, p.W[:1] if plan.raced_flags[ci] else p.W)
+                      for ci, cand in enumerate(candidates)],
+                p, plan, fit_meta, preempted)
             for ci, cand in enumerate(candidates):
-                fitted_grid = fitted_grids[ci]
-                if fitted_grid is _REPLAYED or fitted_grid is _PREEMPTED:
+                if fitted[ci] is _REPLAYED or fitted[ci] is _PREEMPTED:
                     continue
-                if raced_flags[ci]:
-                    score_block(cand, ci, fitted_grid, 0, 1, record)
+                if plan.raced_flags[ci]:
+                    score_block(self, p, plan, cand, ci, fitted[ci], 0, 1,
+                                book.record)
                     continue
-                score_block(cand, ci, fitted_grid, 0, len(fsplits), record)
-                if sweep_cp is not None:
-                    drain_deferred()
-                    checkpoint_family(ci, cand, fitted_grid)
-
-            # round B: rank each raced family's fold-0 screen in the
-            # evaluator's direction, prune past the survivor floor, then fit
-            # + score ONLY the survivors on the remaining folds — the
-            # (folds-1) × (grid - survivors) fits never run
+                score_block(self, p, plan, cand, ci, fitted[ci], 0,
+                            len(fsplits), book.record)
+                book.checkpoint_family(ci, cand, fitted[ci])
+            # round B: prune each raced family past its survivor floor, then
+            # fit + score ONLY the survivors on the remaining folds
             race_live = [ci for ci in range(len(candidates))
-                         if raced_flags[ci]
-                         and fitted_grids[ci] is not _REPLAYED
-                         and fitted_grids[ci] is not _PREEMPTED]
-            if race_live:
-                drain_deferred()   # ranking needs numbers, not deferred slots
-                sign = 1.0 if self.evaluator.is_larger_better else -1.0
-                _raced_out: Dict[str, int] = {}
+                         if plan.raced_flags[ci]
+                         and fitted[ci] is not _REPLAYED
+                         and fitted[ci] is not _PREEMPTED]
+            if not race_live:
+                continue
+            survivors = prune_raced(book, plan, candidates, race_live,
+                                    self.evaluator.is_larger_better)
+            subs = {ci: ModelCandidate(
+                        candidates[ci].estimator,
+                        [dict(candidates[ci].grid[g]) for g in survivors[ci]],
+                        candidates[ci].model_name) for ci in race_live}
+            fitted_b = fit_round("B", [(subs[ci], p.W[1:])
+                                       for ci in race_live],
+                                 p, plan, fit_meta, preempted)
+            rest = len(fsplits) - 1
+            for ci, fb in zip(race_live, fitted_b):
+                if fb is _PREEMPTED:
+                    continue
+                cand, kept = candidates[ci], survivors[ci]
 
-                def prune(ci, cand):
-                    G = len(cand.grid)
-                    S = _survivor_count(G)
+                def rec(_c, _ci, gi_local, params, metric,
+                        _cand=cand, _i=ci, _map=kept):
+                    book.record(_cand, _i, _map[gi_local], params, metric)
 
-                    def keyf(gi):
-                        r = results.get((cand.model_name, ci * 10000 + gi))
-                        v = (r.metric_values[0]
-                             if r and r.metric_values else float("nan"))
-                        return sign * v if np.isfinite(v) else -np.inf
-
-                    # deterministic: ties and NaNs break by grid position
-                    order = sorted(range(G), key=lambda gi: (-keyf(gi), gi))
-                    for gi in order[S:]:
-                        r = results.get((cand.model_name, ci * 10000 + gi))
-                        if r is not None:
-                            r.raced_out = True
-                    from .telemetry import event as _event
-                    _event("selector.racing.prune", model=cand.model_name,
-                           grid=G, survivors=S, pruned=G - S)
-                    _raced_out[cand.model_name] = G - S
-                    BOARD.publish(racedOut=dict(_raced_out))
-                    return sorted(order[:S])
-
-                survivors_by_ci = {ci: prune(ci, candidates[ci])
-                                   for ci in race_live}
-
-                def sub_candidate(ci):
-                    cand = candidates[ci]
-                    return ModelCandidate(
-                        cand.estimator,
-                        [dict(cand.grid[g]) for g in survivors_by_ci[ci]],
-                        cand.model_name)
-
-                def fit_survivors(ci):
-                    cand = candidates[ci]
-                    if shutdown_requested(key=cand.model_name):
-                        preempted.append(cand.model_name)
-                        return _PREEMPTED
-                    sub = sub_candidate(ci)
-                    return fit_candidate(sub, W[1:], sub.grid)
-
-                _fits_left[0] = len(race_live)
-                BOARD.publish(round="B", fitsQueued=len(race_live))
-                if n_workers > 1 and len(race_live) > 1:
-                    from concurrent.futures import ThreadPoolExecutor
-                    with ThreadPoolExecutor(
-                            max_workers=min(n_workers,
-                                            len(race_live))) as pool:
-                        fitted_b = list(pool.map(fit_survivors, race_live))
-                else:
-                    fitted_b = [fit_survivors(ci) for ci in race_live]
-
-                from .profiling import record_racing
-                rest = len(fsplits) - 1
-                for ci, fb in zip(race_live, fitted_b):
-                    cand = candidates[ci]
-                    if fb is _PREEMPTED:
-                        continue
-                    survivors = survivors_by_ci[ci]
-
-                    def rec(_c, _ci, gi_local, params, metric,
-                            _map=survivors, _cand=cand, _i=ci):
-                        record(_cand, _i, _map[gi_local], params, metric)
-
-                    score_block(sub_candidate(ci), ci, fb, 1, rest, rec)
-                    record_racing(rest * (len(cand.grid) - len(survivors)),
-                                  len(cand.grid) - len(survivors))
-                    if sweep_cp is not None:
-                        drain_deferred()
-                        checkpoint_family(ci, cand, None)
-
+                score_block(self, p, plan, subs[ci], ci, fb, 1, rest, rec)
+                record_racing(rest * (len(cand.grid) - len(kept)),
+                              len(cand.grid) - len(kept))
+                book.checkpoint_family(ci, cand, None)
         if preempted:
             # graceful stop honored at a candidate boundary: everything
             # completed so far is drained + flushed (per family, above);
             # hand the caller the resume point instead of dying mid-write
-            drain_deferred()
+            book.drain()
             raise TrainingPreempted(
                 "selector sweep stopped before candidate(s) "
                 + ", ".join(sorted(set(preempted))),
-                resume_from=sweep_cp.path if sweep_cp is not None else None)
-
-        drain_deferred()   # ONE pull for every device-scalar metric left
-
-        all_results = list(results.values())
-        sign = 1.0 if self.evaluator.is_larger_better else -1.0
-        # raced-out points carry a fold-0 screen mean only; comparing that
-        # against survivors' full-k-fold means would be apples-to-oranges,
-        # so they are excluded from winner selection (kept in all_results
-        # for the summary). If racing somehow pruned everything that
-        # finished, fall back to the full list rather than fail the sweep.
-        scored = [(sign * r.mean_metric, r) for r in all_results
-                  if np.isfinite(r.mean_metric) and not r.raced_out]
-        if not scored:
-            scored = [(sign * r.mean_metric, r) for r in all_results
-                      if np.isfinite(r.mean_metric)]
-        if not scored:
-            # aggregate error with per-candidate causes from the failure log
-            # — "nothing survived" alone is undebuggable at 3am
-            causes: Dict[str, str] = {}
-            for ev in active_failure_log().events:
-                if ev.point.startswith("selector.") and ev.cause:
-                    causes.setdefault(ev.stage, ev.cause)
-            for cand in candidates:
-                causes.setdefault(cand.model_name,
-                                  "no finite validation metric")
-            raise AllCandidatesFailed(
-                "all model candidates failed validation", causes)
-        best_score, best_res = max(scored, key=lambda t: t[0])
-        best_cand = candidates[best_res.candidate_index]
-        import copy as _c
-        best_est = _c.deepcopy(best_cand.estimator)
-        for k, v in best_res.params.items():
-            best_est.set(k, v)
-        return ValidationResult(
-            best=ModelCandidate(best_est, [dict(best_res.params)], best_res.model_name),
-            best_params=dict(best_res.params),
-            best_metric=best_res.mean_metric,
-            all_results=all_results,
-            validation_type=self.validation_type,
-            metric_name=self.evaluator.default_metric,
-            is_larger_better=self.evaluator.is_larger_better)
+                resume_from=(plan.checkpoint.path
+                             if plan.checkpoint is not None else None))
+        result = choose_winner(book, candidates, self)
+        if placement is not None:
+            result.placement = placement.descriptor()
+            result.fit_meta = fit_meta
+        return result
 
 
 class OpCrossValidation(OpValidator):

@@ -2,6 +2,7 @@
 (≙ OpBinaryClassificationEvaluatorTest etc.)."""
 
 import numpy as np
+import pytest
 
 from transmogrifai_tpu.evaluators import (Evaluators, aupr, auroc,
                                           binary_confusion)
@@ -286,6 +287,168 @@ def test_fold_grid_metric_panel_matches_per_fold():
         np.testing.assert_allclose(
             p_pr[f], np.asarray(masked_aupr_grid(y, S3[:, f, :], W[f])),
             atol=1e-6)
+
+
+# --------------------------------------------------------------------------
+# the panel's tie groups by scans (PR 32): against the host float64
+# evaluators, and against the searchsorted form's own numbers
+# --------------------------------------------------------------------------
+
+PANEL_TIES = ("none", "heavy", "all_equal")
+PANEL_WEIGHTS = ("mask01", "fractional", "no_positive", "all_zero")
+PANEL_ROWS, PANEL_FOLDS, PANEL_GRID = 4096, 2, 2
+
+
+def panel_case(ties, weights, rows=PANEL_ROWS,
+               lanes=PANEL_FOLDS * PANEL_GRID):
+    """y [rows], S [rows, lanes], W [lanes, rows], float32.  A row that tops
+    a lane carries weight in every mask (unless the mask is empty), so the
+    artefact of ROADMAP D10a stays out: it has a test of its own."""
+    rng = np.random.default_rng(
+        [32, PANEL_TIES.index(ties), PANEL_WEIGHTS.index(weights)])
+    y = (rng.random(rows) < 0.35).astype(np.float32)
+    S = (rng.normal(size=(rows, lanes)) + 0.8 * y[:, None]).astype(np.float32)
+    if ties == "heavy":
+        S = (np.round(S * 10.0) / 10.0).astype(np.float32)
+    elif ties == "all_equal":
+        S = np.full((rows, lanes), 0.25, np.float32)
+    if weights == "fractional":
+        W = rng.integers(0, 5, size=(lanes, rows)) / 4.0
+    elif weights == "all_zero":
+        W = np.zeros((lanes, rows))
+    else:
+        W = rng.random((lanes, rows)) < 0.4
+        if weights == "no_positive":
+            W &= y < 0.5
+    W = W.astype(np.float32)
+    if weights in ("mask01", "fractional") and ties != "all_equal":
+        W[:, (S == S.max(axis=0)).any(axis=1)] = 1.0
+    return y, S, W
+
+
+def host_weighted(metric, y, s, w):
+    """The host float64 metric under weights that are quarters: a row of
+    weight k/4 is k equal rows (they share a tie group, so the curve is the
+    weighted one)."""
+    reps = np.rint(4.0 * w).astype(int)
+    return metric(np.repeat(y, reps), np.repeat(s.astype(np.float64), reps))
+
+
+PANEL_CASES = [(t, w) for t in PANEL_TIES for w in PANEL_WEIGHTS]
+PANEL_ATOL = 5e-6          # float32 sums of 4,096 terms against float64
+
+
+@pytest.mark.parametrize("ties,weights", PANEL_CASES)
+def test_masked_metrics_match_host(ties, weights):
+    from transmogrifai_tpu.metrics_device import masked_aupr, masked_auroc
+    y, S, W = panel_case(ties, weights)
+    for lane in range(S.shape[1]):
+        s, w = S[:, lane], W[lane]
+        assert abs(float(masked_auroc(y, s, w))
+                   - host_weighted(auroc, y, s, w)) < PANEL_ATOL
+        assert abs(float(masked_aupr(y, s, w))
+                   - host_weighted(aupr, y, s, w)) < PANEL_ATOL
+
+
+@pytest.mark.parametrize("ties,weights", PANEL_CASES)
+def test_masked_grid_forms_match_host(ties, weights):
+    """``_grid`` under one shared mask and under a mask a candidate, and
+    ``_fold_grid``: every lane is the host's number for its column and mask."""
+    from transmogrifai_tpu import metrics_device as md
+    y, S, W = panel_case(ties, weights)
+    S3 = S.reshape(PANEL_ROWS, PANEL_FOLDS, PANEL_GRID)
+    for host, grid, fold_grid in (
+            (auroc, md.masked_auroc_grid, md.masked_auroc_fold_grid),
+            (aupr, md.masked_aupr_grid, md.masked_aupr_fold_grid)):
+        want = lambda lane, mask: host_weighted(host, y, S[:, lane], W[mask])
+        lanes = range(S.shape[1])
+        np.testing.assert_allclose(
+            np.asarray(grid(y, S, W)), [want(k, k) for k in lanes],
+            rtol=0, atol=PANEL_ATOL)
+        np.testing.assert_allclose(
+            np.asarray(grid(y, S, W[0])), [want(k, 0) for k in lanes],
+            rtol=0, atol=PANEL_ATOL)
+        np.testing.assert_allclose(
+            np.asarray(fold_grid(y, S3, W[:PANEL_FOLDS])),
+            [[want(f * PANEL_GRID + g, f) for g in range(PANEL_GRID)]
+             for f in range(PANEL_FOLDS)], rtol=0, atol=PANEL_ATOL)
+
+
+# float32 bit patterns read from the parent commit 554b65f (the searchsorted
+# form) on these cases, XLA:CPU; an empty class reads 0.0 in every form
+PANEL_GOLDEN = {
+    "none": {
+        "auroc_grid": [1060177013, 1060275503, 1059916757, 1060468668],
+        "aupr_grid": [1057343758, 1058652915, 1057529751, 1057753773],
+        "auroc_fold_grid": [1060177013, 1060657737, 1059977596, 1060654645],
+        "aupr_fold_grid": [1057343758, 1058405195, 1058136637, 1058694018]},
+    "heavy": {
+        "auroc_grid": [1060621746, 1060553258, 1060468714, 1060544513],
+        "aupr_grid": [1057799973, 1058248577, 1058519350, 1058427721],
+        "auroc_fold_grid": [1060621746, 1060616575, 1060886121, 1060663967],
+        "aupr_fold_grid": [1057799973, 1057809304, 1058521136, 1058513390]},
+    "all_equal": {
+        "auroc_grid": [1056964608] * 4,
+        "aupr_grid": [1059890505, 1059837706, 1059794439, 1059936748],
+        "auroc_fold_grid": [1056964608] * 4,
+        "aupr_fold_grid": [1059890505, 1059890505, 1059837706, 1059837706]},
+}
+# a weight-0 row ranked first (ROADMAP D10a), lane 0 of the mask01 cases
+PANEL_GOLDEN_D10A = {"none": (1060177013, 1057328937),
+                     "heavy": (1060617433, 1057784912)}
+
+
+def float32_bits(a):
+    return np.asarray(a).view(np.uint32).ravel().tolist()
+
+
+@pytest.mark.parametrize("weights", ["mask01", "no_positive", "all_zero"])
+@pytest.mark.parametrize("ties", PANEL_TIES)
+def test_masked_metrics_equal_the_searchsorted_form_to_the_bit(ties, weights):
+    """Under a 0/1 mask every count is an integer below 2^24, exact in
+    float32 in any order: the scans find the curve's points the binary
+    search found, and the same float32 comes out."""
+    from transmogrifai_tpu import metrics_device as md
+    y, S, W = panel_case(ties, weights)
+    S3 = S.reshape(PANEL_ROWS, PANEL_FOLDS, PANEL_GRID)
+    golden = (PANEL_GOLDEN[ties] if weights == "mask01"
+              else dict.fromkeys(PANEL_GOLDEN[ties], [0] * 4))
+    got = {"auroc_grid": md.masked_auroc_grid(y, S, W),
+           "aupr_grid": md.masked_aupr_grid(y, S, W),
+           "auroc_fold_grid": md.masked_auroc_fold_grid(
+               y, S3, W[:PANEL_FOLDS]),
+           "aupr_fold_grid": md.masked_aupr_fold_grid(y, S3, W[:PANEL_FOLDS])}
+    assert {k: float32_bits(v) for k, v in got.items()} == golden
+    # one lane alone is lane 0 of the grid
+    assert float32_bits(md.masked_auroc(y, S[:, 0], W[0])) == \
+        golden["auroc_grid"][:1]
+    assert float32_bits(md.masked_aupr(y, S[:, 0], W[0])) == \
+        golden["aupr_grid"][:1]
+
+
+@pytest.mark.parametrize("ties", ["none", "heavy"])
+def test_masked_aupr_weight_zero_row_ranked_first_is_pinned(ties):
+    """ROADMAP D10a, pinned at today's reading so that the PR that repairs
+    it changes it knowingly: a row of weight 0 that outranks every
+    validation row adds a point (recall 0, precision 0) after the prepended
+    (0, 1), so the first trapezoid loses half the first group's recall.
+    AuROC does not see the row."""
+    from transmogrifai_tpu.metrics_device import masked_aupr, masked_auroc
+    y, S, W = panel_case(ties, "mask01")
+    s, w = S[:, 0].copy(), W[0].copy()
+    i = int(np.argmin(s))
+    s[i], w[i] = s.max() + 1.0, 0.0
+    keep = w > 0
+    top = s[keep] == s[keep].max()
+    first_recall = (y[keep][top] > 0.5).sum() / (y[keep] > 0.5).sum()
+    assert first_recall > 0
+    got_roc, got_pr = masked_auroc(y, s, w), masked_aupr(y, s, w)
+    assert abs(float(got_pr) - (aupr(y[keep], s[keep].astype(np.float64))
+                                - 0.5 * first_recall)) < PANEL_ATOL
+    assert abs(float(got_roc)
+               - auroc(y[keep], s[keep].astype(np.float64))) < PANEL_ATOL
+    assert (float32_bits(got_roc) + float32_bits(got_pr)
+            == list(PANEL_GOLDEN_D10A[ties]))
 
 
 def test_validator_batched_linear_metrics_match_fallback(monkeypatch):

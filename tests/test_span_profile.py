@@ -624,6 +624,32 @@ def test_compiled_program_holds_its_scope_names(case):
         assert "linear.refit" not in text     # a grid lane is no refit
 
 
+PANEL_PROGRAMS = {          # case: (function, scores' shape, masks)
+    "aupr_fold_grid": ("masked_aupr_fold_grid", (64, 2, 3), FOLDS),
+    "auroc_fold_grid": ("masked_auroc_fold_grid", (64, 2, 3), FOLDS),
+    "aupr_grid": ("masked_aupr_grid", (64, 3), FOLDS[0]),
+    "auroc_grid": ("masked_auroc_grid", (64, 3), FOLDS[0]),
+    "aupr_grid_mask_a_candidate": ("masked_aupr_grid", (64, 2), FOLDS),
+    "auroc_grid_mask_a_candidate": ("masked_auroc_grid", (64, 2), FOLDS),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PANEL_PROGRAMS))
+def test_panel_program_sorts_once_and_gathers_nothing(case):
+    """The panel finds its tie groups by a comparison with the neighbour and
+    a scan (PR 32).  A data-dependent gather is what a TPU pays 12.5 ns an
+    element for, and ``searchsorted(s, s)`` was ⌈log2(rows+1)⌉ of them in a
+    ``while``: this is the guard against a later edit bringing one back."""
+    from transmogrifai_tpu import metrics_device
+    function, shape, masks = PANEL_PROGRAMS[case]
+    scores = np.linspace(-1.0, 1.0, int(np.prod(shape)),
+                         dtype=np.float32).reshape(shape)
+    text = hlo(getattr(metrics_device, function), Y, scores, masks)
+    assert ("panel.aupr" if "aupr" in function else "panel.auroc") in text
+    counts = {op: text.count(f" {op}(") for op in ("gather", "while", "sort")}
+    assert counts == {"gather": 0, "while": 0, "sort": 1}
+
+
 def test_fused_transform_names_each_stage_by_class_and_kind(narrow):
     from transmogrifai_tpu.compiled import ScoreProgram, _stage_scope
     model = narrow[1]

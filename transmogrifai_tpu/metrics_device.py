@@ -10,8 +10,16 @@ slicing never changes array shapes (one compile covers every fold) and only
 the final scalar crosses the link.
 
 Ties are handled exactly (midranks for AuROC, threshold grouping for AuPR)
-via the sorted-searchsorted trick: for sorted scores, searchsorted(s, s,
-"left"/"right") gives each row's tie-group boundaries without dynamic shapes.
+without a data-dependent gather, which is the expensive operation on a TPU
+(12.5 ns an element where a sort or a scan streams; ledger, PR 31): a row's
+signed weight w·(2·[y > 0.5] − 1) rides through the ONE sort as its payload,
+so nothing is fetched by ``order`` afterwards, and since the keys come out
+sorted a tie group's ends are a comparison with the neighbour; every row then
+reads the running sum at its group's end through a cumulative min or max
+(``_at_group_end`` / ``_at_group_start``), because a running sum of
+non-negative weights never falls.  Until PR 32 the group ends were
+``searchsorted(s, s)``, a binary search of the sorted scores in themselves:
+⌈log2(rows+1)⌉ steps, each a gather of every row of every lane.
 
 ≙ reference evaluators OpBinaryClassificationEvaluator.scala:67-185 /
 OpRegressionEvaluator / OpMultiClassificationEvaluator semantics.
@@ -25,22 +33,50 @@ import jax
 import jax.numpy as jnp
 
 
+def _sort_with_signed_weight(key, y, w):
+    """Sort rows by ``key`` with the signed weight as the payload; returns the
+    sorted keys and the sorted rows' positive and negative weights.  Two
+    operands, as ``argsort`` moves (key + iota): a stable sort would add an
+    iota of its own as a third (and half again of the compile), and the order
+    inside a tie group decides no point of either curve — every running sum
+    is read at a group's end; it can move the rounding of a float32 sum past
+    2^24 by an ulp."""
+    signed = w * jnp.where(y > 0.5, 1.0, -1.0)
+    key, signed = jax.lax.sort((key, signed), num_keys=1, is_stable=False)
+    return key, jnp.maximum(signed, 0.0), jnp.maximum(-signed, 0.0)
+
+
+def _group_ends(key):
+    """(first, last): whether a row of the sorted ``key`` opens / closes its
+    tie group."""
+    edge = key[1:] != key[:-1]
+    true = jnp.ones(1, bool)
+    return jnp.concatenate([true, edge]), jnp.concatenate([edge, true])
+
+
+def _at_group_end(run, last):
+    """Every row reads the non-decreasing ``run`` at its group's last row.
+    (Counts under a 0/1 mask are exact; with fractional weights the scan's
+    own rounding can dip ``run`` by an ulp, and a row then reads that.)"""
+    return jax.lax.cummin(jnp.where(last, run, jnp.inf), axis=0, reverse=True)
+
+
+def _at_group_start(run, first):
+    """Every row reads the non-decreasing ``run`` at its group's first row."""
+    return jax.lax.cummax(jnp.where(first, run, -jnp.inf), axis=0)
+
+
 @jax.jit
 @jax.named_scope("panel.auroc")
 def masked_auroc(y: jnp.ndarray, scores: jnp.ndarray, w: jnp.ndarray):
     """Weighted Mann-Whitney AUC with exact tie handling.  ``w`` is a 0/1 (or
     weighted) row mask; rows with w=0 are ignored."""
-    order = jnp.argsort(scores)
-    ss = scores[order]
-    yy = y[order]
-    ww = w[order]
-    wpos = ww * (yy > 0.5)
-    wneg = ww * (yy <= 0.5)
-    prefix_neg = jnp.concatenate([jnp.zeros(1, wneg.dtype), jnp.cumsum(wneg)])
-    left = jnp.searchsorted(ss, ss, side="left")
-    right = jnp.searchsorted(ss, ss, side="right")
-    below = prefix_neg[left]                   # neg weight strictly below
-    same = prefix_neg[right] - prefix_neg[left]  # neg weight in tie group
+    ss, wpos, wneg = _sort_with_signed_weight(scores, y, w)
+    first, last = _group_ends(ss)
+    neg_run = jnp.cumsum(wneg)
+    neg_before = jnp.concatenate([jnp.zeros(1, wneg.dtype), neg_run[:-1]])
+    below = _at_group_start(neg_before, first)   # neg weight strictly below
+    same = _at_group_end(neg_run, last) - below  # neg weight in tie group
     num = jnp.sum(wpos * (below + 0.5 * same))
     n_pos = jnp.sum(wpos)
     n_neg = jnp.sum(wneg)
@@ -52,19 +88,15 @@ def masked_auroc(y: jnp.ndarray, scores: jnp.ndarray, w: jnp.ndarray):
 def masked_aupr(y: jnp.ndarray, scores: jnp.ndarray, w: jnp.ndarray):
     """Weighted area under the PR curve, MLlib-style (threshold-grouped,
     trapezoid over recall with a prepended (0, 1) point)."""
-    order = jnp.argsort(-scores)
-    ss = scores[order]
-    yy = y[order]
-    ww = w[order]
-    tp_run = jnp.cumsum(ww * (yy > 0.5))
-    fp_run = jnp.cumsum(ww * (yy <= 0.5))
+    neg, wpos, wneg = _sort_with_signed_weight(-scores, y, w)
+    tp_run = jnp.cumsum(wpos)
+    fp_run = jnp.cumsum(wneg)
     # group rows by distinct threshold: every row reads its tie-group's LAST
     # cumsum (the value at the threshold boundary); duplicated points then
     # contribute zero width to the trapezoid
-    neg = -ss  # ascending for searchsorted
-    right = jnp.searchsorted(neg, neg, side="right") - 1
-    tp = tp_run[right]
-    fp = fp_run[right]
+    _, last = _group_ends(neg)
+    tp = _at_group_end(tp_run, last)
+    fp = _at_group_end(fp_run, last)
     n_pos = jnp.maximum(tp_run[-1], 1e-12)
     precision = tp / jnp.maximum(tp + fp, 1e-12)
     recall = tp / n_pos

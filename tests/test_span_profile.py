@@ -561,7 +561,8 @@ def test_readers_say_what_benchmark_json_says():
         mod, entry = reader(name), entries[name]
         assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
             entry["layer"], entry["unit"], entry["source"], entry["moves"])
-        assert entry["workloads"] == ["mixed_sweep", "mixed_sweep_x4"]
+        assert entry["workloads"] == ["mixed_sweep", "mixed_sweep_x4",
+                                      "text_sweep"]
         assert entry["better"] == "lower"
 
 

@@ -145,25 +145,64 @@ def _scatter_counts_device(ids, lens_padded, n, num_hashes, binary):
     return (counts > 0).astype(jnp.float32) if binary else counts
 
 
+# bytes the chip gives a ``[cap, 3]`` int32 intermediate (it tiles the 3 to
+# 128 lanes: 512 B a word) above which the ids unpack lane by lane instead
+_STACKED_UNPACK_BYTES = 1 << 28
+
+
+def _unpack_ids3(words, lens_padded):
+    """Packed words [cap] + tokens a row [n + 1] (the last entry is the
+    padding's) → (rows, ids) of every token slot, both flat [3 * cap].  The
+    scatter that follows adds, so the order of the pairs is free.
+
+    Short wires (the Criteo cells' one-token values: 65,536 words a column)
+    stack the three lanes and repeat the rows by their lengths, in token
+    order.  The stack is a ``[cap, 3]`` intermediate that the chip tiles to
+    128 lanes, 42 times its bytes — 25.8 GB at 50 M words, which no chip
+    holds — so a long wire (free text) goes lane by lane, rows and ids only
+    ever flat: first every word's low id, then its middle one, then its high
+    one.  Slot ``3 w + l`` lies in the row whose end it has not passed: a
+    row's end at that slot is marked at ``l * cap + w`` (one scatter), and
+    one cumulative sum over words counts the ends before each word.  Which
+    form runs follows from the wire's static length alone; the benchmark
+    has cells on both sides (PERF.md §6, PR 34)."""
+    cap = words.shape[0]
+    lanes = [words & 0x3FF, (words >> 10) & 0x3FF, (words >> 20) & 0x3FF]
+    if cap * 512 <= _STACKED_UNPACK_BYTES:
+        rows = jnp.repeat(jnp.arange(lens_padded.shape[0]), lens_padded,
+                          total_repeat_length=3 * cap)
+        return rows, jnp.stack(lanes, axis=1).reshape(-1)
+    ends = jnp.cumsum(lens_padded)[:-1]
+    at = jnp.where(ends < 3 * cap, ends % 3 * cap + ends // 3, 3 * cap)
+    marks = jnp.zeros((3 * cap,), jnp.int32).at[at].add(1, mode="drop")
+    low, mid, high = marks[:cap], marks[cap:2 * cap], marks[2 * cap:]
+    ended = low + mid + high
+    row0 = jnp.cumsum(ended) - ended + low
+    row1 = row0 + mid
+    return (jnp.concatenate([row0, row1, row1 + high]),
+            jnp.concatenate(lanes))
+
+
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))
 def _scatter_counts_packed(words, lens_padded, n, num_hashes, binary):
     """Packed-wire variant: each int32 word carries THREE 10-bit bucket ids
     (token order preserved), tripling the effective host-link bandwidth of
     the hashing trick — the ids unpack with two shifts on device."""
-    ids = jnp.stack([words & 0x3FF, (words >> 10) & 0x3FF,
-                     (words >> 20) & 0x3FF], axis=1).reshape(-1)
-    rows = jnp.repeat(jnp.arange(n + 1), lens_padded,
-                      total_repeat_length=ids.shape[0])
+    rows, ids = _unpack_ids3(words, lens_padded)
     counts = jnp.zeros((n + 1, num_hashes + 1), jnp.float32)
-    counts = counts.at[rows, ids].add(1.0)
+    with jax.named_scope("text.hash_counts"):
+        counts = counts.at[rows, ids].add(1.0)
     counts = counts[:n, :num_hashes]
     return (counts > 0).astype(jnp.float32) if binary else counts
 
 
 def _size_class(n: int, floor: int = 1024) -> int:
-    """Smallest {2^k, 1.5·2^k} >= n — tighter than pure powers of two (max
-    33% padding instead of 100%) while keeping the jit-recompile count
-    bounded at two shapes per octave."""
+    """Smallest {2^k, 1.5·2^k} >= n — tighter than pure powers of two (at
+    most a third of the wire is padding, instead of a half) while keeping
+    the jit-recompile count bounded at two shapes per octave.  Measured on
+    free text (two columns, 83 tokens a row; the counters ``text.tokens`` /
+    ``text.token_slots``): 18.6 % of the id slots shipped are padding at
+    1,048,576, 1,572,864 and 2,097,152 rows alike (PERF.md §5, PR 34)."""
     if n <= floor:
         return floor
     k = int(np.ceil(np.log2(n)))
@@ -472,13 +511,12 @@ class SmartTextVectorizerModel(TransformerModel):
                 else:
                     words, lens_p = w[keys[0]], w[keys[1]]
                     h = info
-                    ids = jnp.stack([words & 0x3FF, (words >> 10) & 0x3FF,
-                                     (words >> 20) & 0x3FF], axis=1).reshape(-1)
+                    rows, ids = _unpack_ids3(words, lens_p)
                     nr = lens_p.shape[0] - 1
-                    rows = jnp.repeat(jnp.arange(nr + 1), lens_p,
-                                      total_repeat_length=ids.shape[0])
                     counts = jnp.zeros((nr + 1, h + 1), jnp.float32)
-                    counts = counts.at[rows, ids].add(1.0)[:nr, :h].astype(dtype)
+                    with jax.named_scope("text.hash_counts"):
+                        counts = counts.at[rows, ids].add(1.0)
+                    counts = counts[:nr, :h].astype(dtype)
                     if keys[2] is not None:
                         counts = jnp.concatenate(
                             [counts,

@@ -31,7 +31,11 @@ few threads, as the cores the process may use allow
 The counters ``text_profile.scan`` (columns walked), ``.fused_intern``
 (interned by that walk), ``.intern.hit`` / ``.intern.miss`` (``values(cap)``
 answered from the cache / by another walk) and the gauge
-``text_profile.workers`` say which of this happened.  All consumers fall
+``text_profile.workers`` say which of this happened; ``text.tokens`` /
+``text.token_slots`` count the tokens packed for the device and the id slots
+shipped for them (``text.pack_ids`` is the span), and
+``text.rows_python_tokenized`` the rows the native walk left to the Python
+tokenizer (span ``text.python_tokenize``).  All consumers fall
 back to pure Python when the native toolchain is absent — identical
 results, slower.
 """
@@ -46,7 +50,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..telemetry import REGISTRY
+from ..telemetry import REGISTRY, span
 
 
 @dataclass
@@ -112,7 +116,13 @@ class TextProfile:
         int32 word; ops/text.py pack/scatter pair), cached per hash width.
         ``prefetch`` starts the async host→device transfer early so the
         slow link overlaps RFF/fit host work instead of serializing after
-        it.  None when the width needs the unpacked path."""
+        it.  That holds for the TRANSFER alone: the modulo, the packing and
+        the pad run on the calling thread (span ``text.pack_ids``), and at
+        free text's size they are what the device waits for — 4.55 s of the
+        11.9 s of ``prefetch.text_profiles`` in a 19.9 s train of 2,097,152
+        rows of 83 tokens, the device idle for all 11.9 s (PERF.md §5,
+        PR 34); on the Criteo cells' one-token values it is 0.1 s.  None
+        when the width needs the unpacked path."""
         if num_hashes >= 1024:
             return None
         dev = self._device_packed.get(num_hashes)
@@ -120,14 +130,20 @@ class TextProfile:
             import jax
 
             from .text import _pack_ids3, _sentinel3, _size_class
-            _, flat = self.buckets(num_hashes)
-            words = _pack_ids3(flat, num_hashes)
-            cap = _size_class(words.size)
-            wp = np.full(cap, _sentinel3(num_hashes), np.int32)
-            wp[:words.size] = words
-            dev = jax.device_put(wp)      # async; consumers queue on it
+            with span("text.pack_ids", num_hashes=num_hashes) as sp:
+                _, flat = self.buckets(num_hashes)
+                words = _pack_ids3(flat, num_hashes)
+                cap = _size_class(words.size)
+                wp = np.full(cap, _sentinel3(num_hashes), np.int32)
+                wp[:words.size] = words
+                dev = jax.device_put(wp)      # async; consumers queue on it
+                if sp is not None:
+                    sp.attrs.update(tokens=int(flat.size),
+                                    words=int(words.size), capacity=cap)
             from ..profiling import add_host_link_bytes
             add_host_link_bytes(wp.nbytes)
+            REGISTRY.counter("text.tokens").inc(int(flat.size))
+            REGISTRY.counter("text.token_slots").inc(3 * cap)
             self._device_packed[num_hashes] = dev
         return dev
 
@@ -275,8 +291,11 @@ def scan_strings(strings, min_token_len: int = 1,
         d = native.profile(strings, min_token_len, cap)
         lens, hashes = d["tok_lens"], d["tok_hash"]
         if d["fallback"].size:
-            lens, hashes = _splice_fallback(strings, lens, hashes,
-                                            d["fallback"], min_token_len)
+            with span("text.python_tokenize", rows=int(d["fallback"].size)):
+                lens, hashes = _splice_fallback(strings, lens, hashes,
+                                                d["fallback"], min_token_len)
+            REGISTRY.counter("text.rows_python_tokenized").inc(
+                int(d["fallback"].size))
         prof = TextProfile(d["null"], d["empty"], d["lengths"], d["crc"],
                            lens, hashes)
         if cap is not None:

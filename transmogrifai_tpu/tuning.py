@@ -848,6 +848,7 @@ def fit_round(round_name: str, jobs: Sequence[Any], p: Placement,
     fitted grid, ``_REPLAYED``, or ``_PREEMPTED`` where a requested graceful
     stop (signal or injected preemption) won over starting new work.
     ``fit_meta`` learns the folds and lanes of each family's batched fit."""
+    from .telemetry import REGISTRY
     left = [len(jobs)]   # feeds the /statusz board's ETA, per round
     BOARD.publish(round=round_name, fitsQueued=len(jobs))
 
@@ -862,6 +863,8 @@ def fit_round(round_name: str, jobs: Sequence[Any], p: Placement,
                       candidateGrid=len(cand.grid),
                       candidateFolds=int(len(Wblk)))
         t0 = time.perf_counter()
+        # one a family a round: a round of two families counts two
+        REGISTRY.counter("selector.family_rounds").inc()
         # worker threads have no span of their own, so this parents under
         # the orchestrating selector.sweep span even through the pool
         with span("selector.candidate_fit", model=cand.model_name,

@@ -9,7 +9,8 @@
 // *parameter-free* per-row products and, when asked, the value interning in
 // the same walk, so callers rebin/reuse without rescanning:
 //
-//   profile(arr, min_token_len=1, cap=None) -> dict
+//   profile(arr, min_token_len=1, cap=None, frozen=None, until_frozen=False)
+//       -> dict
 //     null:     bool[N]    True where value is None
 //     empty:    bool[N]    True where value == "" (present-but-empty: RFF
 //                          counts it as missing, TextStats counts it)
@@ -22,6 +23,17 @@
 //                          % num_hashes for any hash width)
 //     fallback: intp[F]    rows with tok_lens == -1, ascending
 //     uniq, counts, codes  only with a cap: what intern(arr, cap) returns
+//     rows:     int        rows walked: N, or fewer with until_frozen
+//
+//   A ROW RANGE of a column is a slice view of its array (any stride is read
+//   in place), and two arguments let a long column be walked as ranges:
+//   `until_frozen` stops the walk at the end of the block in which a capped
+//   table froze (the arrays keep N elements, the first `rows` written), and
+//   `frozen` — the values of such a frozen table, in order — makes the walk
+//   of a later range rebuild that small table and only look up in it: from
+//   the freeze on no count moves and a row's code is its value's id or -2,
+//   whatever came before it, so the ranges after the freeze are independent
+//   of each other (`codes` alone is returned of the interning then).
 //
 //   intern(arr, cap=-1) -> (uniq list[str], counts int64[U], codes int32[N])
 //     Value interning in first-occurrence order.  codes: -1 null, -2 value
@@ -30,6 +42,18 @@
 //     freeze semantics (SmartTextVectorizer.scala:182-230 analog pinned in
 //     ops/text.py TextStats.of_column): once the table holds cap+1 distinct
 //     values ALL counting stops; lengths elsewhere keep accumulating.
+//
+//   pack_ids3(hashes, num_hashes, offset, out, carry, last) -> words written
+//     The packed token wire of the hashing trick (ops/text.py _pack_ids3 is
+//     the definition): token t of a column goes, modulo num_hashes (< 1024),
+//     into lane t % 3 of word t / 3, ten bits a lane.  `hashes` are the
+//     tokens [offset, offset + T) of the column and `out` its whole int32
+//     buffer; the call writes the words whose FIRST lane is one of its
+//     tokens, taking the lanes past its last token from `carry` (the next
+//     tokens of the column, at most two) and, where the column ends, the
+//     sentinel num_hashes; with `last` also the sentinel words from there to
+//     the end of `out`.  So the pieces of a column pack side by side, each
+//     word written by exactly one call, with the GIL released.
 //
 // The walk goes a block of rows at a time, in two phases.  Phase one, under
 // the GIL: each row's utf-8 pointer, byte length and code-point length (the
@@ -94,6 +118,37 @@ struct Tables {
 };
 const Tables TABLES;
 
+// CRC-32 of `n` bytes, eight a step (the byte-wise chain of dependent table
+// loads is what a short value costs most); `seen` ORs every byte in, so its
+// high bits say whether any is non-ASCII.
+inline uint32_t crc32_of(const char* data, Py_ssize_t n, uint64_t* seen) {
+    uint32_t c = 0xFFFFFFFFu;
+    Py_ssize_t k = 0;
+#if defined(__BYTE_ORDER__) && __BYTE_ORDER__ == __ORDER_LITTLE_ENDIAN__
+    for (; k + 8 <= n; k += 8) {
+        uint64_t w;
+        memcpy(&w, data + k, 8);
+        *seen |= w;
+        const uint32_t lo = static_cast<uint32_t>(w) ^ c;
+        const uint32_t hi = static_cast<uint32_t>(w >> 32);
+        c = TABLES.crc[7][lo & 0xFFu] ^
+            TABLES.crc[6][(lo >> 8) & 0xFFu] ^
+            TABLES.crc[5][(lo >> 16) & 0xFFu] ^
+            TABLES.crc[4][lo >> 24] ^
+            TABLES.crc[3][hi & 0xFFu] ^
+            TABLES.crc[2][(hi >> 8) & 0xFFu] ^
+            TABLES.crc[1][(hi >> 16) & 0xFFu] ^
+            TABLES.crc[0][hi >> 24];
+    }
+#endif
+    for (; k < n; ++k) {
+        const unsigned char b = static_cast<unsigned char>(data[k]);
+        *seen |= b;
+        c = TABLES.crc[0][(c ^ b) & 0xFFu] ^ (c >> 8);
+    }
+    return c ^ 0xFFFFFFFFu;
+}
+
 // The intern table: open addressing over views of the first occurrences'
 // utf-8 bytes, indexed by the CRC-32 the row needs anyway.  A capped table
 // is a few dozen slots that stay in cache, and a frozen one answers the
@@ -145,6 +200,9 @@ struct Walk {
     bool intern = false;        // interning wanted
     Py_ssize_t min_len = 1;
     Py_ssize_t cap = -1;
+    bool frozen = false;        // the table was handed in frozen: look up only
+    bool until_frozen = false;  // stop after the block in which it freezes
+    Py_ssize_t rows = 0;        // rows walked
 
     // per-row outputs (numpy buffers, every element written)
     npy_bool* null = nullptr;
@@ -175,35 +233,9 @@ struct Walk {
                 continue;
             }
             const Py_ssize_t n = blen[j];
-            // CRC-32, eight bytes a step (the byte-wise chain of dependent
-            // table loads is what a short value costs most), and whether
-            // any byte is non-ASCII
-            uint32_t c = 0xFFFFFFFFu;
+            // the CRC, and whether any byte is non-ASCII
             uint64_t seen = 0;
-            Py_ssize_t k = 0;
-#if defined(__BYTE_ORDER__) && __BYTE_ORDER__ == __ORDER_LITTLE_ENDIAN__
-            for (; k + 8 <= n; k += 8) {
-                uint64_t w;
-                memcpy(&w, data + k, 8);
-                seen |= w;
-                const uint32_t lo = static_cast<uint32_t>(w) ^ c;
-                const uint32_t hi = static_cast<uint32_t>(w >> 32);
-                c = TABLES.crc[7][lo & 0xFFu] ^
-                    TABLES.crc[6][(lo >> 8) & 0xFFu] ^
-                    TABLES.crc[5][(lo >> 16) & 0xFFu] ^
-                    TABLES.crc[4][lo >> 24] ^
-                    TABLES.crc[3][hi & 0xFFu] ^
-                    TABLES.crc[2][(hi >> 8) & 0xFFu] ^
-                    TABLES.crc[1][(hi >> 16) & 0xFFu] ^
-                    TABLES.crc[0][hi >> 24];
-            }
-#endif
-            for (; k < n; ++k) {
-                const unsigned char b = static_cast<unsigned char>(data[k]);
-                seen |= b;
-                c = TABLES.crc[0][(c ^ b) & 0xFFu] ^ (c >> 8);
-            }
-            c ^= 0xFFFFFFFFu;
+            const uint32_t c = crc32_of(data, n, &seen);
             if (scan) {
                 null[i] = 0;
                 empty[i] = n == 0;
@@ -248,8 +280,8 @@ struct Walk {
         // inserts AND increments of existing keys — happens only while the
         // table holds <= cap distinct values; the (cap+1)-th value may
         // still insert, after which every increment stops
-        const bool can_count =
-            cap < 0 || static_cast<Py_ssize_t>(uniq_row.size()) <= cap;
+        const bool can_count = !frozen &&
+            (cap < 0 || static_cast<Py_ssize_t>(uniq_row.size()) <= cap);
         const size_t size = static_cast<size_t>(n);
         const int32_t found = table.find(data, size, c);
         if (found >= 0) {
@@ -363,7 +395,42 @@ bool walk_column(PyArrayObject* arr, Walk& w) {
             PyErr_NoMemory();
             return false;
         }
+        w.rows = start + m;
+        if (w.until_frozen && w.cap >= 0 &&
+            static_cast<Py_ssize_t>(w.uniq_row.size()) > w.cap)
+            break;
     }
+    return true;
+}
+
+// Rebuilds a frozen table from its values, in order, so that a later range
+// of the column finds the ids its first rows gave them.  `values` (a tuple
+// this call owns) keeps the strings, and so their utf-8 bytes, alive for the
+// walk.  False with a Python error set on failure.
+bool seed_frozen(PyObject* values, Walk& w) {
+    const Py_ssize_t count = PyTuple_GET_SIZE(values);
+    for (Py_ssize_t id = 0; id < count; ++id) {
+        PyObject* s = PyTuple_GET_ITEM(values, id);
+        if (!PyUnicode_Check(s)) {
+            PyErr_SetString(PyExc_TypeError,
+                            "textprof: a frozen value that is no str");
+            return false;
+        }
+        Py_ssize_t n;
+        const char* data = PyUnicode_AsUTF8AndSize(s, &n);
+        if (!data) return false;
+        uint64_t seen = 0;
+        const uint32_t c = crc32_of(data, n, &seen);
+        const size_t size = static_cast<size_t>(n);
+        try {
+            if (w.table.find(data, size, c) < 0)
+                w.table.insert(data, size, c, static_cast<int32_t>(id));
+        } catch (const std::exception&) {
+            PyErr_NoMemory();
+            return false;
+        }
+    }
+    w.frozen = true;
     return true;
 }
 
@@ -393,17 +460,35 @@ PyObject* profile(PyObject*, PyObject* args) {
     PyObject* obj;
     Py_ssize_t min_len = 1;
     PyObject* cap_obj = Py_None;
-    if (!PyArg_ParseTuple(args, "O|nO", &obj, &min_len, &cap_obj))
+    PyObject* frozen_obj = Py_None;
+    int until_frozen = 0;
+    if (!PyArg_ParseTuple(args, "O|nOOp", &obj, &min_len, &cap_obj,
+                          &frozen_obj, &until_frozen))
         return nullptr;
     PyArrayObject* arr = object_column(obj);
     if (!arr) return nullptr;
     Walk w;
     w.scan = true;
     w.min_len = min_len;
+    w.until_frozen = until_frozen != 0;
     if (cap_obj != Py_None) {
         w.intern = true;
         w.cap = PyLong_AsSsize_t(cap_obj);
         if (w.cap == -1 && PyErr_Occurred()) return nullptr;
+    }
+    PyObject* frozen = nullptr;
+    if (frozen_obj != Py_None) {
+        if (!w.intern) {
+            PyErr_SetString(PyExc_ValueError,
+                            "textprof: frozen values without a cap");
+            return nullptr;
+        }
+        frozen = PySequence_Tuple(frozen_obj);
+        if (!frozen) return nullptr;
+        if (!seed_frozen(frozen, w)) {
+            Py_DECREF(frozen);
+            return nullptr;
+        }
     }
 
     const npy_intp n = PyArray_DIM(arr, 0);
@@ -425,18 +510,21 @@ PyObject* profile(PyObject*, PyObject* args) {
         tok_hash = array_of(w.tok_hash, NPY_UINT32);
         fallback = array_of(w.fallback, NPY_INTP);
         ok = tok_hash && fallback &&
-             (!w.intern || interned_parts(arr, w, &uniq, &counts));
+             (!w.intern || w.frozen ||
+              interned_parts(arr, w, &uniq, &counts));
     }
     if (ok)
-        out = Py_BuildValue("{s:O,s:O,s:O,s:O,s:O,s:O,s:O}",
+        out = Py_BuildValue("{s:O,s:O,s:O,s:O,s:O,s:O,s:O,s:n}",
                             "null", nulls, "empty", empty, "lengths", lengths,
                             "crc", crc, "tok_lens", tok_lens,
-                            "tok_hash", tok_hash, "fallback", fallback);
+                            "tok_hash", tok_hash, "fallback", fallback,
+                            "rows", w.rows);
     if (out && w.intern &&
-        (PyDict_SetItemString(out, "uniq", uniq) < 0 ||
-         PyDict_SetItemString(out, "counts", counts) < 0 ||
-         PyDict_SetItemString(out, "codes", codes) < 0))
+        (PyDict_SetItemString(out, "codes", codes) < 0 ||
+         (!w.frozen && (PyDict_SetItemString(out, "uniq", uniq) < 0 ||
+                        PyDict_SetItemString(out, "counts", counts) < 0))))
         Py_CLEAR(out);
+    Py_XDECREF(frozen);
     Py_XDECREF(nulls);
     Py_XDECREF(empty);
     Py_XDECREF(lengths);
@@ -471,12 +559,90 @@ PyObject* intern_values(PyObject*, PyObject* args) {
     return out;
 }
 
+// `obj` as a C-contiguous 1-D array of `type`, or nullptr with TypeError set.
+PyArrayObject* flat_array(PyObject* obj, int type, const char* what) {
+    if (!PyArray_Check(obj) ||
+        PyArray_NDIM(reinterpret_cast<PyArrayObject*>(obj)) != 1 ||
+        PyArray_TYPE(reinterpret_cast<PyArrayObject*>(obj)) != type ||
+        !PyArray_IS_C_CONTIGUOUS(reinterpret_cast<PyArrayObject*>(obj))) {
+        PyErr_Format(PyExc_TypeError,
+                     "textprof: %s is not a contiguous 1-D array of its type",
+                     what);
+        return nullptr;
+    }
+    return reinterpret_cast<PyArrayObject*>(obj);
+}
+
+PyObject* pack_ids3(PyObject*, PyObject* args) {
+    PyObject *hashes_obj, *out_obj, *carry_obj;
+    Py_ssize_t num_hashes, offset;
+    int last;
+    if (!PyArg_ParseTuple(args, "OnnOOp", &hashes_obj, &num_hashes, &offset,
+                          &out_obj, &carry_obj, &last))
+        return nullptr;
+    PyArrayObject* hashes = flat_array(hashes_obj, NPY_UINT32, "hashes");
+    PyArrayObject* out = flat_array(out_obj, NPY_INT32, "out");
+    PyArrayObject* carry = flat_array(carry_obj, NPY_UINT32, "carry");
+    if (!hashes || !out || !carry) return nullptr;
+    const Py_ssize_t tokens = PyArray_DIM(hashes, 0);
+    const Py_ssize_t carried = PyArray_DIM(carry, 0);
+    const Py_ssize_t cap = PyArray_DIM(out, 0);
+    // the words whose first lane is a token of this piece
+    const Py_ssize_t first = (offset + 2) / 3;
+    const Py_ssize_t end = (offset + tokens + 2) / 3;
+    if (num_hashes < 1 || num_hashes >= 1024 || offset < 0 || carried > 2 ||
+        end > cap || !PyArray_ISWRITEABLE(out)) {
+        PyErr_SetString(PyExc_ValueError,
+                        "textprof: pack_ids3 wants 1 <= num_hashes < 1024, "
+                        "offset >= 0, at most two carried tokens and a "
+                        "writable out that holds the piece's words");
+        return nullptr;
+    }
+    const uint32_t* h = static_cast<const uint32_t*>(PyArray_DATA(hashes));
+    const uint32_t* more = static_cast<const uint32_t*>(PyArray_DATA(carry));
+    int32_t* words = static_cast<int32_t*>(PyArray_DATA(out));
+    const uint32_t width = static_cast<uint32_t>(num_hashes);
+    Py_BEGIN_ALLOW_THREADS
+    // the words that lie whole inside the piece, then the one that does not
+    const uint32_t* t = h + (3 * first - offset);
+    Py_ssize_t w = first;
+    for (; 3 * w + 2 < offset + tokens; ++w, t += 3)
+        words[w] = static_cast<int32_t>(
+            t[0] % width | (t[1] % width) << 10 | (t[2] % width) << 20);
+    for (; w < end; ++w) {
+        uint32_t word = 0;
+        for (int lane = 0; lane < 3; ++lane) {
+            const Py_ssize_t i = 3 * w + lane - offset;
+            const uint32_t id = i < tokens ? h[i] % width
+                : i - tokens < carried ? more[i - tokens] % width : width;
+            word |= id << (10 * lane);
+        }
+        words[w] = static_cast<int32_t>(word);
+    }
+    if (last) {
+        const int32_t sentinel =
+            static_cast<int32_t>(width | width << 10 | width << 20);
+        std::fill(words + end, words + cap, sentinel);
+    }
+    Py_END_ALLOW_THREADS
+    return PyLong_FromSsize_t(end - first);
+}
+
 PyMethodDef methods[] = {
     {"profile", profile, METH_VARARGS,
-     "profile(arr, min_token_len=1, cap=None) -> dict of parameter-free "
-     "per-row products (null/empty/lengths/crc/tok_lens/tok_hash/fallback) "
-     "of a 1-D object ndarray, read in place; with a cap also "
-     "uniq/counts/codes as intern(arr, cap) gives them, from the same walk"},
+     "profile(arr, min_token_len=1, cap=None, frozen=None, "
+     "until_frozen=False) -> dict of parameter-free per-row products "
+     "(null/empty/lengths/crc/tok_lens/tok_hash/fallback, rows) of a 1-D "
+     "object ndarray, read in place; with a cap also uniq/counts/codes as "
+     "intern(arr, cap) gives them, from the same walk; until_frozen stops "
+     "after the block in which the table froze, frozen (that table's "
+     "values) makes the walk of a later range look up only"},
+    {"pack_ids3", pack_ids3, METH_VARARGS,
+     "pack_ids3(hashes, num_hashes, offset, out, carry, last) -> words "
+     "written: the words of the packed token wire whose first lane is one "
+     "of the tokens [offset, offset + len(hashes)) of a column, lanes past "
+     "them from carry, then the sentinel; with last the sentinel words to "
+     "the end of out"},
     {"intern", intern_values, METH_VARARGS,
      "intern(arr, cap=-1) -> (uniq, counts int64[U], codes int32[N]) of a "
      "1-D object ndarray, read in place; cap>=0 applies the TextStats "

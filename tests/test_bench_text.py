@@ -130,12 +130,13 @@ def title_modulo_511(mp):
     """The title's tokens (the column that has missing values) land in
     their hash modulo 511."""
     from transmogrifai_tpu.ops.text_profile import TextProfile
-    buckets = TextProfile.buckets
 
-    def bent(self, num_hashes):
-        return buckets(self, num_hashes - 1 if self.null.any()
-                       else num_hashes)
-    mp.setattr(TextProfile, "buckets", bent)
+    def bent(method):
+        return lambda self, num_hashes: method(
+            self, num_hashes - 1 if self.null.any() else num_hashes)
+    # the packed wire the device reads, and the ids a host path reads
+    for name in ("pack_jobs", "buckets"):
+        mp.setattr(TextProfile, name, bent(getattr(TextProfile, name)))
 
 
 def capitals_not_folded(mp):

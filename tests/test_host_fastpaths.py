@@ -10,13 +10,15 @@ import pytest
 
 from transmogrifai_tpu import types as T
 from transmogrifai_tpu.columns import Column, ColumnBatch, column_from_values
+from transmogrifai_tpu.native import load
 from transmogrifai_tpu.ops.text import (TextStats, _counts_from_flat,
-                                        _pack_ids3, _size_class,
+                                        _pack_ids3, _sentinel3, _size_class,
                                         device_counts_from_flat,
                                         fnv1a_32, hash_tokens_flat,
                                         tokenize_text)
-from transmogrifai_tpu.ops.text_profile import (_py_intern, _py_scan,
-                                                scan_strings)
+from transmogrifai_tpu.ops.text_profile import (TextProfile, _py_intern,
+                                                _py_scan, scan_strings)
+from transmogrifai_tpu.telemetry import REGISTRY
 
 
 def _mixed_strings(n=3000, seed=0):
@@ -114,6 +116,92 @@ def test_pack_ids3_roundtrip_and_size_class():
     assert _size_class(1025) == 1536
     assert _size_class(1537) == 2048
     assert _size_class(5) == 1024
+
+
+def _padded_words(hashes, num_hashes):
+    """The wire as ops/text.py defines it: ``_pack_ids3`` of the bucket ids,
+    padded with sentinel words to the size class."""
+    words = _pack_ids3((hashes % np.uint32(num_hashes)).astype(np.int32),
+                       num_hashes)
+    out = np.full(_size_class(words.size), _sentinel3(num_hashes), np.int32)
+    out[:words.size] = words
+    return out
+
+
+def _profile_in_pieces(hashes, cuts):
+    edges = [0, *cuts, hashes.size]
+    rows = np.zeros(1, np.int32)
+    return TextProfile(rows.astype(bool), rows.astype(bool), rows,
+                       rows.astype(np.uint32), rows,
+                       [hashes[a:b] for a, b in zip(edges[:-1], edges[1:])])
+
+
+@pytest.mark.parametrize("offset", [0, 1, 2])
+@pytest.mark.parametrize("total", [0, 3000, 3001, 3002])
+@pytest.mark.parametrize("num_hashes", [2, 500, 512, 1023])
+def test_native_pack_equals_pack_ids3_word_for_word(num_hashes, total,
+                                                    offset):
+    """A column's tokens in three pieces, the second starting at a token
+    offset ≡ ``offset`` (mod 3) and the third one token long, packed piece
+    by piece in any order: the words, the sentinel lanes of the last one and
+    the sentinel words up to the size class are ``_pack_ids3``'s."""
+    if load("textprof") is None:
+        pytest.skip("no native toolchain")
+    rng = np.random.default_rng(num_hashes + total)
+    hashes = rng.integers(0, 2 ** 32, size=total, dtype=np.uint64
+                          ).astype(np.uint32)
+    cuts = sorted({min(total, 999 + offset), max(total - 1, 0)})
+    prof = _profile_in_pieces(hashes, cuts)
+    assert prof.tokens == total
+    before = REGISTRY.counters().get("text.pack_native", 0)
+    words, jobs = prof.pack_jobs(num_hashes)
+    assert len(jobs) == len(cuts) + 1
+    words[:] = -1
+    for job in reversed(jobs):
+        job()
+    assert words.dtype == np.int32
+    assert np.array_equal(words, _padded_words(hashes, num_hashes))
+    assert REGISTRY.counters()["text.pack_native"] == before + 1
+    assert np.array_equal(prof.tok_hash, hashes)      # joined on demand
+
+
+@pytest.mark.parametrize("num_hashes", [2, 500, 512, 1023])
+def test_pack_falls_back_to_numpy_without_the_native_module(monkeypatch,
+                                                            num_hashes):
+    from transmogrifai_tpu import native
+    monkeypatch.setattr(native, "load", lambda name: None)
+    rng = np.random.default_rng(num_hashes)
+    hashes = rng.integers(0, 2 ** 32, size=1001, dtype=np.uint64
+                          ).astype(np.uint32)
+    prof = _profile_in_pieces(hashes, [400])
+    before = REGISTRY.counters().get("text.pack_numpy", 0)
+    words, (job,) = prof.pack_jobs(num_hashes)
+    job()
+    assert np.array_equal(words, _padded_words(hashes, num_hashes))
+    assert REGISTRY.counters()["text.pack_numpy"] == before + 1
+    assert np.array_equal(np.asarray(prof.device_ids(num_hashes)), words)
+
+
+def test_native_pack_refuses_what_it_cannot_pack():
+    native = load("textprof")
+    if native is None:
+        pytest.skip("no native toolchain")
+    hashes = np.arange(10, dtype=np.uint32)
+    none = np.empty(0, np.uint32)
+    out = np.empty(1024, np.int32)
+    for bad in [(hashes, 1024, 0, out, none, True),        # an 11-bit id
+                (hashes, 0, 0, out, none, True),
+                (hashes, 512, -1, out, none, True),
+                (hashes, 512, 0, out[:3], none, True),     # 4 words needed
+                (hashes, 512, 0, out, hashes[:3], True)]:  # 3 carried
+        with pytest.raises(ValueError):
+            native.pack_ids3(*bad)
+    for bad in [(hashes.astype(np.int64), 512, 0, out, none, True),
+                (hashes[::2], 512, 0, out, none, True),
+                (hashes, 512, 0, out.astype(np.int64), none, True),
+                (list(hashes), 512, 0, out, none, True)]:
+        with pytest.raises(TypeError):
+            native.pack_ids3(*bad)
 
 
 def test_map_expansion_parity_and_fallback():

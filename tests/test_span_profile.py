@@ -342,6 +342,70 @@ def test_prefetch_spans_open_where_the_prefetch_runs(monkeypatch):
     assert got["prefetch.numeric"] == ["prefetch.numeric", "phase.prefetch"]
 
 
+def test_a_ranged_prefetch_leaves_what_the_text_metrics_read(monkeypatch):
+    """A prefetch over a column long enough to be walked by row range, as an
+    accelerator host runs it under a traced train: ``text.pack_ids`` (its
+    attrs on every piece) and ``prefetch.text_profiles`` are in
+    ``train.span_profile``, so ``text_pack_s`` and ``text_profile_s`` read
+    numbers; the wire's counters move by what one walk and one numpy pack
+    moved them by; the range counters say how the column was cut."""
+    from transmogrifai_tpu import workflow as workflow_mod
+    from transmogrifai_tpu.ops import text_profile as tp
+    from transmogrifai_tpu.ops.text import (SmartTextVectorizer,
+                                            _size_class)
+    from transmogrifai_tpu.features import FeatureBuilder
+    from transmogrifai_tpu.native import load
+    if load("textprof") is None:
+        pytest.skip("no native toolchain")
+    rows = 3 * tp.BLOCK_ROWS                # a head and two ranges
+    values = np.asarray([f"w{i} x{i % 5} y" for i in range(97)],
+                        dtype=object)[np.arange(rows) % 97]
+    whole = tp.scan_strings(values.copy())
+    capacity = _size_class(-(-whole.tokens // 3))
+    batch = ColumnBatch({"txt": Column(T.Text, values)}, rows)
+    st = SmartTextVectorizer(num_hashes=64, max_cardinality=7)
+    st.set_input(FeatureBuilder.Text("txt").as_predictor())
+    wf = Workflow().set_input_batch(batch).set_result_features(
+        st.get_output())
+    monkeypatch.setattr(tp, "MIN_RANGE_BLOCKS", 1)
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(4)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    names = ("text.tokens", "text.token_slots", "text.pack_native",
+             "text.pack_numpy", "text_profile.range_walks",
+             "text_profile.scan")
+    before = {k: REGISTRY.counters().get(k, 0) for k in names}
+    link = profiling.host_link_bytes()
+    tracer = Tracer("ranged-prefetch")
+    with use_tracer(tracer):
+        with tracer.span("workflow.train") as root:
+            wf._prefetch_text_profiles(batch)
+        telemetry.publish_train_profile(root)
+    monkeypatch.undo()
+    moved = {k: REGISTRY.counters().get(k, 0) - v for k, v in before.items()}
+    assert moved == {"text.tokens": whole.tokens,
+                     "text.token_slots": 3 * capacity, "text.pack_native": 1,
+                     "text.pack_numpy": 0, "text_profile.range_walks": 2,
+                     "text_profile.scan": 1}
+    assert profiling.host_link_bytes() - link == 4 * capacity
+    assert REGISTRY.gauge("text_profile.ranges").value == 3
+    packs = [s for s in tracer.spans if s.name == "text.pack_ids"]
+    assert len(packs) == 3
+    assert all(set(s.attrs) == {"tokens", "words", "capacity", "num_hashes"}
+               and s.attrs["capacity"] == capacity
+               and s.attrs["num_hashes"] == 64 for s in packs)
+    assert sum(s.attrs["tokens"] for s in packs) == whole.tokens
+    assert sum(s.attrs["words"] for s in packs) == -(-whole.tokens // 3)
+    (prefetch,) = [s for s in tracer.spans
+                   if s.name == "prefetch.text_profiles"]
+    assert prefetch.attrs["workers"] == 3 and all(
+        chain(tracer.spans, s)[1] == "prefetch.text_profiles" for s in packs)
+    table = REGISTRY.gauge("train.span_profile").value
+    assert table["text.pack_ids"]["count"] == 3
+    for metric in ("text_pack_s", "text_profile_s"):
+        reader = importlib.import_module(f"benchmark.layer_metrics.{metric}")
+        assert reader.read({"trace": True}) >= 0.0      # a number, not None
+
+
 def test_phase_timer_reads_the_monotonic_clock(monkeypatch):
     import time
     monkeypatch.setattr(time, "time", lambda: (_ for _ in ()).throw(

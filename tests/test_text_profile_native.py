@@ -14,6 +14,7 @@ import pytest
 from transmogrifai_tpu import types as T
 from transmogrifai_tpu.columns import Column, ColumnBatch
 from transmogrifai_tpu.native import load
+from transmogrifai_tpu.ops import text as text_ops
 from transmogrifai_tpu.ops import text_profile as tp
 from transmogrifai_tpu.telemetry import REGISTRY, Tracer, use_tracer
 
@@ -96,6 +97,25 @@ def test_fused_pass_equals_python_scan_and_intern(case, cap):
     _assert_interned_equal(tp._intern(arr, cap), tp._py_intern(arr, cap))
 
 
+def test_non_ascii_rows_are_lowered_by_python_and_hashed_natively():
+    """The splice of the rows the walk leaves to Python: every code point
+    whose lowering is ASCII or longer than itself (the Kelvin sign is 'k',
+    'İ' is 'i' and a combining dot), and every 211th of the others, between
+    ASCII letters: tokens and hashes are the Python tokenizer's."""
+    points = [cp for cp in range(0x80, 0x110000)
+              if not 0xD800 <= cp <= 0xDFFF
+              and (cp % 211 == 0 or len(chr(cp).lower()) > 1
+                   or chr(cp).lower().isascii())]
+    assert 0x212A in points and 0x130 in points
+    arr = _column([f"Ab{chr(cp)}Cd {chr(cp)}e'F_{chr(cp)}" for cp in points])
+    before = REGISTRY.counters().get("text.rows_python_tokenized", 0)
+    for min_len in (1, 3):
+        _assert_scan_equal(tp.scan_strings(arr, min_len),
+                           tp._py_scan(arr, min_len))
+    assert REGISTRY.counters()["text.rows_python_tokenized"] \
+        == before + 2 * len(points)
+
+
 def test_blocks_strides_and_what_the_walk_refuses():
     native = load("textprof")
     rng = np.random.default_rng(0)
@@ -141,11 +161,13 @@ def test_pool_gives_the_same_profiles_in_feature_order(monkeypatch, cores,
     monkeypatch.setattr(tp, "_MAX_WORKERS", most)
     cols = _text_columns()
     pairs = [(c, 30 if j % 3 else None) for j, c in enumerate(cols)]
+    triples = [(c, cap, 64 if j % 2 else None)
+               for j, (c, cap) in enumerate(pairs)]
     before = _counters()
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
-        profs = list(tp.profile_columns(pairs))
+        profs = list(tp.profile_columns(triples))
     finally:
         sys.setswitchinterval(interval)
     assert REGISTRY.gauge("text_profile.workers").value == min(
@@ -167,7 +189,157 @@ def test_a_failing_column_raises_from_the_pool(monkeypatch):
     cols = _text_columns(4, 100)
     cols[2] = Column(T.Text, _column(["a", 7, "b"]))
     with pytest.raises(TypeError):
-        list(tp.profile_columns([(c, 30) for c in cols]))
+        list(tp.profile_columns([(c, 30, 64) for c in cols]))
+
+
+# -- a long column walked by row range -------------------------------------
+
+BLOCK = tp.BLOCK_ROWS
+RANGED_ROWS = 5 * BLOCK + 17            # a head of one block, four tail ranges
+
+
+def _long_column(kind):
+    """Short values, cheap to walk: what matters is where the capped table
+    freezes and what lies at the blocks' edges, where the ranges are cut."""
+    rows = np.arange(RANGED_ROWS)
+    many = np.asarray([f"v{i} w{i % 7}" for i in range(257)], dtype=object)
+    if kind == "freezes_in_block_0":
+        arr = many[(rows * 31 + rows // 5) % len(many)]
+    elif kind == "freezes_in_block_1":
+        # two values for a block and a bit, then many
+        arr = np.where(rows < BLOCK + 4321, many[rows % 2],
+                       many[(rows * 31) % len(many)])
+    else:                               # never (for a cap of 1 or more)
+        arr = np.where(rows % 3 == 0, None, "only value")
+    arr = arr.astype(object)
+    edges = np.arange(BLOCK, RANGED_ROWS, BLOCK)
+    if kind != "never_freezes":
+        # non-ASCII rows on both sides of every edge, and a range of None
+        arr[edges - 1] = "Ünï tøken " + many[edges % len(many)]
+        arr[edges] = "日本語 テキスト"
+        arr[3 * BLOCK:4 * BLOCK] = None
+    return arr
+
+
+@pytest.fixture(scope="module")
+def long_columns():
+    """{kind: (the column, its ``_py_scan``)}, scanned in Python once."""
+    return {kind: (arr, tp._py_scan(arr)) for kind, arr in
+            ((k, _long_column(k)) for k in ("freezes_in_block_0",
+                                            "freezes_in_block_1",
+                                            "never_freezes"))}
+
+
+@pytest.fixture
+def small_ranges(monkeypatch):
+    """Ranges of one block on four workers, so that a column of five blocks
+    is cut as ``text_sweep``'s 28 are."""
+    monkeypatch.setattr(tp, "MIN_RANGE_BLOCKS", 1)
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(4)))
+
+
+def _range_counters():
+    c = REGISTRY.counters()
+    return (REGISTRY.gauge("text_profile.ranges").value,
+            c.get("text_profile.range_walks", 0))
+
+
+@pytest.mark.parametrize("cap", [None, -1, 0, 1, 30])
+@pytest.mark.parametrize("kind", ["freezes_in_block_0", "freezes_in_block_1",
+                                  "never_freezes"])
+def test_head_and_ranges_equal_python_scan_and_intern(long_columns,
+                                                      small_ranges, kind,
+                                                      cap):
+    arr, ref = long_columns[kind]
+    col = Column(T.Text, arr)
+    before, walked = _counters(), _range_counters()[1]
+    (prof,) = tp.profile_columns([(col, cap, 500)])
+    assert tp.column_profile(col) is prof and prof._strings is arr
+    pieces = len(prof.hash_pieces)          # tok_hash joins them
+    assert prof.tokens == ref.tok_hash.size
+    _assert_scan_equal(prof, ref)
+    # one COLUMN scanned and interned, however many ranges walked it
+    assert _moved(before) == {"scan": 1, **(
+        {} if cap is None else {"fused_intern": 1})}
+    if cap is None:
+        assert prof._interned == {}
+    else:
+        assert list(prof._interned) == [cap]
+        _assert_interned_equal(prof._interned[cap], tp._py_intern(arr, cap))
+    # where the table froze says how the column was cut
+    frozen = cap is not None and prof._interned[cap].frozen
+    head_blocks = {None: 0, -1: 5}.get(
+        cap, (2 if kind == "freezes_in_block_1" and cap == 30 else 1)
+        if frozen else 5)
+    tails = 0 if head_blocks == 5 else 4
+    assert _range_counters() == ((head_blocks > 0) + tails, walked + tails)
+    assert pieces == (head_blocks > 0) + tails
+    # the joined words of the ranged column are the unranged column's
+    whole = tp.scan_strings(arr)
+    words, jobs = whole.pack_jobs(500)
+    assert len(jobs) == 1
+    jobs[0]()
+    assert np.array_equal(prof._host_words[500], words)
+    flat = ref.buckets(500)[1]
+    packed = text_ops._pack_ids3(flat, 500)
+    assert np.array_equal(words[:packed.size], packed)
+    assert np.all(words[packed.size:] == text_ops._sentinel3(500))
+
+
+def test_a_strided_column_is_cut_into_ranges_of_views(small_ranges):
+    arr = np.repeat(_long_column("freezes_in_block_0"), 2)[::2]
+    assert not arr.flags.c_contiguous and len(arr) == RANGED_ROWS
+    (prof,) = tp.profile_columns([(Column(T.Text, arr), 3, 64)])
+    assert _range_counters()[0] == 5
+    whole = tp.scan_strings(arr.copy(), cap=3)
+    _assert_scan_equal(prof, whole)
+    _assert_interned_equal(prof._interned[3], whole._interned[3])
+    assert np.array_equal(np.asarray(prof.device_ids(64)),
+                          np.asarray(whole.device_ids(64)))
+    assert prof._host_words == {}           # handed to the link, not kept
+
+
+def test_a_short_column_and_an_exact_count_stay_one_walk(small_ranges,
+                                                         monkeypatch):
+    monkeypatch.setattr(tp, "MIN_RANGE_BLOCKS", 3)
+    arr = _long_column("freezes_in_block_0")    # 5 blocks < 1 + 2 * 3
+    walked = _range_counters()[1]
+    profs = list(tp.profile_columns([(Column(T.Text, arr), 30, 64),
+                                     (Column(T.Text, arr[:100]), None, 64)]))
+    assert _range_counters() == (2, walked)
+    assert [len(p.hash_pieces) for p in profs] == [1, 1]
+    assert REGISTRY.gauge("text_profile.workers").value == 2
+    assert tp._ranges(BLOCK, 28 * BLOCK, 4) == [
+        (BLOCK * a, BLOCK * b) for a, b in ((1, 7), (7, 14), (14, 21),
+                                            (21, 28))]
+    assert tp._ranges(0, 7 * BLOCK + 5, 4) == [(0, 4 * BLOCK),
+                                               (4 * BLOCK, 7 * BLOCK + 5)]
+    assert tp._ranges(BLOCK, BLOCK + 9, 4) == [(BLOCK, BLOCK + 9)]
+
+
+@pytest.mark.parametrize("where", ["head", "tail"])
+def test_a_failing_range_raises_from_the_pool(small_ranges, where):
+    arr = _long_column("freezes_in_block_0")
+    arr[{"head": 100, "tail": 2 * BLOCK + 100}[where]] = 7
+    cols = _text_columns(2, 100) + [Column(T.Text, arr)]
+    with pytest.raises(TypeError):
+        list(tp.profile_columns([(c, 30, 64) for c in cols]))
+
+
+def test_native_range_arguments():
+    native = load("textprof")
+    arr = _column(["a", "b", "a", None, "c", "b", "d"])
+    # until_frozen on a column shorter than a block: every row walked
+    d = native.profile(arr, 1, 1, None, True)
+    assert d["rows"] == 7 and d["uniq"] == ["a", "b"]
+    # a frozen table handed in: looked up only, nothing counted or returned
+    d = native.profile(arr[2:], 1, 1, ["a", "b"])
+    assert d["rows"] == 5 and "uniq" not in d and "counts" not in d
+    assert d["codes"].tolist() == [0, -1, -2, 1, -2]
+    with pytest.raises(ValueError):
+        native.profile(arr, 1, None, ["a"])          # frozen without a cap
+    with pytest.raises(TypeError):
+        native.profile(arr, 1, 1, ["a", 3])
 
 
 def _smart_text_stage(names, **params):
@@ -185,7 +357,7 @@ def test_fit_after_a_profiled_batch_only_hits_and_a_score_batch_interns_nothing(
     batch = ColumnBatch(dict(zip(names, cols)), 3000)
     st = _smart_text_stage(names, num_hashes=64, max_cardinality=30)
     before = _counters()
-    list(tp.profile_columns([(c, 30) for c in cols]))
+    list(tp.profile_columns([(c, 30, None) for c in cols]))
     assert _moved(before) == {"scan": 6, "fused_intern": 6}
     before = _counters()
     model = st.fit(batch)
@@ -241,8 +413,9 @@ def test_prefetch_profiles_side_by_side_and_transfers_in_feature_order(
         monkeypatch):
     """``Workflow._prefetch_text_profiles`` returns early on the CPU
     backend; told it is on an accelerator, it profiles every column of the
-    hashing stages with the stage's cap, puts the packed ids on the device
-    from the calling thread in feature order, and says so on its span."""
+    hashing stages with the stage's cap, has the workers pack their ids,
+    puts the packed words on the device from the calling thread in feature
+    order, and says so on its span."""
     import jax
 
     from transmogrifai_tpu import workflow as wf_mod
@@ -278,7 +451,12 @@ def test_prefetch_profiles_side_by_side_and_transfers_in_feature_order(
         tp.column_profile(c)._device_packed[64].nbytes for c in cols)
     (sp,) = [s for s in tracer.spans if s.name == "prefetch.text_profiles"]
     assert sp.attrs == {"columns": 5, "rows": rows, "workers": 3}
-    assert {s.thread for s in tracer.spans} == {sp.thread}
+    packs = [s for s in tracer.spans if s.name == "text.pack_ids"]
+    assert len(packs) == 5                  # packed by the workers, inside
+    assert {s.parent_id for s in packs} == {sp.span_id}     # the prefetch
+    assert sp.thread == threading.get_ident() \
+        and sp.thread not in {s.thread for s in packs}
+    assert {s.thread for s in tracer.spans if s not in packs} == {sp.thread}
     before = _counters()
     st.fit(batch)
     assert _moved(before) == {"intern.hit": 5}

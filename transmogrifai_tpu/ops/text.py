@@ -486,7 +486,7 @@ class SmartTextVectorizerModel(TransformerModel):
                     plan.append(("null", None, (f"null{i}",)))
             else:
                 words = prof.device_ids(num_hashes)
-                total = int(prof.tok_hash.size)
+                total = prof.tokens
                 cap = int(words.shape[0])
                 wire[f"words{i}"] = words
                 wire[f"lens{i}"] = np.append(

@@ -23,17 +23,25 @@ interning answers walks the column once more (native ``intern``, in place
 too).  A score batch is never interned unless a consumer asks.
 
 The native walk holds the GIL only while it fetches a block of rows' utf-8
-pointers; hashing, tokenising and the intern table run with it released.
-``profile_columns`` therefore walks a batch's columns side by side on a
-few threads, as the cores the process may use allow
-(``os.sched_getaffinity``).
+pointers; hashing, tokenising and the intern table run with it released, and
+so does the packing of the token ids into the words the device reads
+(native ``pack_ids3``).  ``profile_columns`` therefore spreads a batch's
+host text work over a few threads, as the cores the process may use allow
+(``os.sched_getaffinity``), and its unit of work is a ROW RANGE of a column
+that ends in its share of the packed wire: a short column is one range, a
+long one is walked in order until its interning is settled (the *head*) and
+as contiguous ranges side by side from there (the *tail*), and every range
+then packs its own words into the column's one buffer.
 
 The counters ``text_profile.scan`` (columns walked), ``.fused_intern``
 (interned by that walk), ``.intern.hit`` / ``.intern.miss`` (``values(cap)``
-answered from the cache / by another walk) and the gauge
-``text_profile.workers`` say which of this happened; ``text.tokens`` /
-``text.token_slots`` count the tokens packed for the device and the id slots
-shipped for them (``text.pack_ids`` is the span), and
+answered from the cache / by another walk) count COLUMNS, however a column
+was cut; ``text_profile.range_walks`` counts the tail ranges walked and the
+gauges ``text_profile.ranges`` / ``.workers`` the walks and the threads of
+the last ``profile_columns``.  ``text.tokens`` / ``text.token_slots`` count
+the tokens packed for the device and the id slots shipped for them
+(``text.pack_ids`` is the span of a piece's packing; ``text.pack_native`` /
+``text.pack_numpy`` say which code packed a column), and
 ``text.rows_python_tokenized`` the rows the native walk left to the Python
 tokenizer (span ``text.python_tokenize``).  All consumers fall
 back to pure Python when the native toolchain is absent — identical
@@ -44,9 +52,11 @@ from __future__ import annotations
 
 import os
 import zlib
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 from dataclasses import dataclass, field
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from functools import partial
+from typing import (Callable, Dict, Generator, Iterator, List, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
 
@@ -79,10 +89,24 @@ class TextProfile:
     lengths: np.ndarray      # int32[N] (code points; 0 for null)
     crc: np.ndarray          # uint32[N] (0 for null)
     tok_lens: np.ndarray     # int32[N]
-    tok_hash: np.ndarray     # uint32[total] full FNV-1a per token
+    # full FNV-1a per token, uint32, in row order: one piece a row range the
+    # column was walked as (``tok_hash`` joins them for who wants one array)
+    hash_pieces: List[np.ndarray]
     _interned: Dict[int, InternedValues] = field(default_factory=dict)
     _strings: Optional[np.ndarray] = None   # kept for lazy interning
+    _host_words: Dict[int, np.ndarray] = field(default_factory=dict)
     _device_packed: Dict[int, object] = field(default_factory=dict)
+
+    @property
+    def tokens(self) -> int:
+        return sum(int(p.size) for p in self.hash_pieces)
+
+    @property
+    def tok_hash(self) -> np.ndarray:
+        """uint32[tokens]: the pieces joined, once."""
+        if len(self.hash_pieces) != 1:
+            self.hash_pieces = [np.concatenate(self.hash_pieces)]
+        return self.hash_pieces[0]
 
     @property
     def presence(self) -> np.ndarray:
@@ -111,39 +135,85 @@ class TextProfile:
         return (self.tok_lens,
                 (self.tok_hash % np.uint32(num_hashes)).astype(np.int32))
 
+    def pack_jobs(self, num_hashes: int
+                  ) -> Tuple[np.ndarray, List[Callable[[], None]]]:
+        """The column's packed token wire for ``num_hashes`` < 1024 (3 ×
+        10-bit ids per int32 word, padded with sentinel words to its size
+        class; ops/text.py ``_pack_ids3`` / ``_size_class`` / ``_sentinel3``
+        define it) as a host buffer and the jobs that fill it: one a piece
+        of ``hash_pieces``, each writing the words that start inside its
+        piece (native ``pack_ids3``, the GIL released), so they may run side
+        by side on any threads.  The buffer is whole when all have run.
+        Without the native module one job packs the column in numpy."""
+        from ..native import load
+        from .text import _pack_ids3, _sentinel3, _size_class
+
+        native = load("textprof")
+        tokens = self.tokens
+        out = np.empty(_size_class((tokens + 2) // 3), np.int32)
+        attrs = dict(num_hashes=num_hashes, capacity=int(out.size))
+        if native is None:
+            REGISTRY.counter("text.pack_numpy").inc()
+
+            def pack_all() -> None:
+                with span("text.pack_ids", tokens=tokens, **attrs) as sp:
+                    words = _pack_ids3(self.buckets(num_hashes)[1],
+                                       num_hashes)
+                    out[:words.size] = words
+                    out[words.size:] = _sentinel3(num_hashes)
+                    if sp is not None:
+                        sp.attrs.update(words=int(words.size))
+            return out, [pack_all]
+        REGISTRY.counter("text.pack_native").inc()
+        pieces = list(self.hash_pieces)
+
+        def pack_piece(k: int, offset: int) -> None:
+            # the lanes its last word has past the piece: the column's next
+            # tokens, wherever they lie
+            carry = [p[:2] for p in pieces[k + 1:] if p.size][:2]
+            carry = (np.concatenate(carry)[:2] if carry
+                     else np.empty(0, np.uint32))
+            with span("text.pack_ids", tokens=int(pieces[k].size),
+                      **attrs) as sp:
+                words = native.pack_ids3(pieces[k], num_hashes, offset, out,
+                                         carry, k == len(pieces) - 1)
+                if sp is not None:
+                    sp.attrs.update(words=words)
+
+        offsets = np.cumsum([0] + [p.size for p in pieces[:-1]])
+        return out, [partial(pack_piece, k, int(at))
+                     for k, at in enumerate(offsets)]
+
     def device_ids(self, num_hashes: int):
         """Packed token-bucket ids resident on device (3 × 10-bit ids per
         int32 word; ops/text.py pack/scatter pair), cached per hash width.
         ``prefetch`` starts the async host→device transfer early so the
         slow link overlaps RFF/fit host work instead of serializing after
-        it.  That holds for the TRANSFER alone: the modulo, the packing and
-        the pad run on the calling thread (span ``text.pack_ids``), and at
-        free text's size they are what the device waits for — 4.55 s of the
-        11.9 s of ``prefetch.text_profiles`` in a 19.9 s train of 2,097,152
-        rows of 83 tokens, the device idle for all 11.9 s (PERF.md §5,
-        PR 34); on the Criteo cells' one-token values it is 0.1 s.  None
-        when the width needs the unpacked path."""
+        it.  The words come from ``pack_jobs``: ``profile_columns`` has run
+        them on its workers for the width a training batch asked for, and
+        this call then only hands the buffer to the link; for a width nobody
+        packed ahead (a score batch, the eager vectorizer paths) they run
+        here, on the calling thread.  In numpy on the calling thread this
+        was what the device waited for on free text — 4.07 s of the 10.9 s
+        of ``prefetch.text_profiles`` in a 17.3 s train of 1,835,008 rows of
+        83 tokens (PERF.md §6, PR 35).  None when the width needs the
+        unpacked path."""
         if num_hashes >= 1024:
             return None
         dev = self._device_packed.get(num_hashes)
         if dev is None:
             import jax
 
-            from .text import _pack_ids3, _sentinel3, _size_class
-            with span("text.pack_ids", num_hashes=num_hashes) as sp:
-                _, flat = self.buckets(num_hashes)
-                words = _pack_ids3(flat, num_hashes)
-                cap = _size_class(words.size)
-                wp = np.full(cap, _sentinel3(num_hashes), np.int32)
-                wp[:words.size] = words
-                dev = jax.device_put(wp)      # async; consumers queue on it
-                if sp is not None:
-                    sp.attrs.update(tokens=int(flat.size),
-                                    words=int(words.size), capacity=cap)
             from ..profiling import add_host_link_bytes
-            add_host_link_bytes(wp.nbytes)
-            REGISTRY.counter("text.tokens").inc(int(flat.size))
-            REGISTRY.counter("text.token_slots").inc(3 * cap)
+            words = self._host_words.pop(num_hashes, None)
+            if words is None:
+                words, jobs = self.pack_jobs(num_hashes)
+                for job in jobs:
+                    job()
+            dev = jax.device_put(words)       # async; consumers queue on it
+            add_host_link_bytes(words.nbytes)
+            REGISTRY.counter("text.tokens").inc(self.tokens)
+            REGISTRY.counter("text.token_slots").inc(3 * int(words.size))
             self._device_packed[num_hashes] = dev
         return dev
 
@@ -198,7 +268,7 @@ def _py_scan(strings: Sequence, min_token_len: int = 1) -> TextProfile:
         tok_lens[i] = len(toks)
         hashes.extend(fnv1a_32(t) for t in toks)
     return TextProfile(null, empty, lengths, crc, tok_lens,
-                       np.asarray(hashes, np.uint32))
+                       [np.asarray(hashes, np.uint32)])
 
 
 def _py_intern(strings: Sequence, cap: int) -> InternedValues:
@@ -256,23 +326,79 @@ def _object_column(strings) -> np.ndarray:
     return arr
 
 
-def _splice_fallback(strings, lens, hashes, fallback, min_token_len):
-    """Non-ASCII rows (``lens`` -1): the Python tokenizer's hashes spliced
-    in at each row's place, for exact unicode case-folding parity."""
-    from .text import fnv1a_32, tokenize_text
-
-    rows = [[fnv1a_32(t) for t in tokenize_text(strings[i], min_token_len)]
-            for i in fallback]
-    counts = np.fromiter(map(len, rows), np.int64, count=len(rows))
+def _splice_fallback(native, strings, lens, hashes, fallback, min_token_len):
+    """Non-ASCII rows (``lens`` -1) tokenized as the Python tokenizer does
+    (ops/text.py ``tokenize_text``: ``str.lower()``, then maximal runs of
+    [a-z0-9_'], every other character a separator), for exact unicode
+    case-folding parity, and their hashes spliced in at each row's place.
+    Only the lowering needs Python: lowered, and with each character that is
+    still not ASCII turned into a separator, a row is ASCII and the native
+    walk hashes its tokens."""
+    lowered = np.empty(len(fallback), dtype=object)
+    lowered[:] = [strings[i].lower().encode("ascii", "replace").decode()
+                  for i in fallback]
+    d = native.profile(lowered, min_token_len)
+    counts, theirs = d["tok_lens"], d["tok_hash"]
     lens = lens.copy()
     lens[fallback] = 0
     at = np.cumsum(lens) - lens          # where each row's hashes start
-    hashes = np.insert(
-        hashes, np.repeat(at[fallback], counts),
-        np.fromiter((h for r in rows for h in r), np.uint32,
-                    count=int(counts.sum())))
+    # stretches of the walk's hashes and the rows' own, in turn.  What the
+    # splice costs is this one fresh copy of the range's hashes (0.8 s for a
+    # column's 573 MB on the chip's host; np.insert costs the same)
+    parts, done = [], 0
+    for cut, a, b in zip(at[fallback], np.cumsum(counts) - counts,
+                         np.cumsum(counts)):
+        parts += [hashes[done:cut], theirs[a:b]]
+        done = cut
     lens[fallback] = counts
-    return lens, hashes
+    return lens, np.concatenate(parts + [hashes[done:]])
+
+
+ROW_FIELDS = ("null", "empty", "lengths", "crc", "tok_lens")
+
+
+def _walk(native, strings: np.ndarray, min_token_len: int,
+          cap: Optional[int], frozen: Optional[List[str]] = None,
+          until_frozen: bool = False) -> dict:
+    """One native walk of ``strings`` — a column or a row range of one —
+    as ``native.profile`` returns it, the rows it left to the Python
+    tokenizer spliced in.  ``until_frozen``: the walk ends with the block in
+    which the capped table froze, and the per-row arrays with it.
+    ``frozen``: the values of a table an earlier range froze; the walk only
+    looks them up (``codes``)."""
+    d = native.profile(strings, min_token_len, cap, frozen, until_frozen)
+    if d["rows"] < len(strings):
+        for f in ROW_FIELDS + ("codes",):
+            d[f] = d[f][:d["rows"]]
+    if d["fallback"].size:
+        with span("text.python_tokenize", rows=int(d["fallback"].size)):
+            d["tok_lens"], d["tok_hash"] = _splice_fallback(
+                native, strings, d["tok_lens"], d["tok_hash"], d["fallback"],
+                min_token_len)
+        REGISTRY.counter("text.rows_python_tokenized").inc(
+            int(d["fallback"].size))
+    return d
+
+
+def _profile_of(walks: List[dict], strings: np.ndarray,
+                cap: Optional[int]) -> TextProfile:
+    """The profile of a column from the walks of its row ranges, in row
+    order: per-row products joined, token hashes left in pieces, the
+    interning the first walk's (the only one that counted) with every
+    range's codes."""
+    def joined(f):
+        return (walks[0][f] if len(walks) == 1
+                else np.concatenate([w[f] for w in walks]))
+
+    prof = TextProfile(*map(joined, ROW_FIELDS),
+                       [w["tok_hash"] for w in walks])
+    if cap is not None:
+        prof._interned[cap] = _interned_values(
+            walks[0]["uniq"], walks[0]["counts"], joined("codes"), cap)
+        REGISTRY.counter("text_profile.fused_intern").inc()
+    REGISTRY.counter("text_profile.scan").inc()
+    prof._strings = strings
+    return prof
 
 
 def scan_strings(strings, min_token_len: int = 1,
@@ -284,26 +410,13 @@ def scan_strings(strings, min_token_len: int = 1,
 
     strings = _object_column(strings)
     native = load("textprof")
-    REGISTRY.counter("text_profile.scan").inc()
     if native is None:
+        REGISTRY.counter("text_profile.scan").inc()
         prof = _py_scan(strings, min_token_len)
-    else:
-        d = native.profile(strings, min_token_len, cap)
-        lens, hashes = d["tok_lens"], d["tok_hash"]
-        if d["fallback"].size:
-            with span("text.python_tokenize", rows=int(d["fallback"].size)):
-                lens, hashes = _splice_fallback(strings, lens, hashes,
-                                                d["fallback"], min_token_len)
-            REGISTRY.counter("text.rows_python_tokenized").inc(
-                int(d["fallback"].size))
-        prof = TextProfile(d["null"], d["empty"], d["lengths"], d["crc"],
-                           lens, hashes)
-        if cap is not None:
-            prof._interned[cap] = _interned_values(
-                d["uniq"], d["counts"], d["codes"], cap)
-            REGISTRY.counter("text_profile.fused_intern").inc()
-    prof._strings = strings
-    return prof
+        prof._strings = strings
+        return prof
+    return _profile_of([_walk(native, strings, min_token_len, cap)], strings,
+                       cap)
 
 
 def column_profile(col, cap: Optional[int] = None) -> TextProfile:
@@ -313,41 +426,194 @@ def column_profile(col, cap: Optional[int] = None) -> TextProfile:
     prof = getattr(col, "_text_profile", None)
     if prof is None:
         from .categorical import _col_strings
-        prof = scan_strings(_col_strings(col), cap=cap)
-        try:
-            object.__setattr__(col, "_text_profile", prof)
-        except Exception:  # pragma: no cover — exotic column subtype
-            pass
+        prof = _remember(col, scan_strings(_col_strings(col), cap=cap))
     return prof
 
 
-# Phase one of a walk holds the GIL for about a fifth of it, so past four or
-# five walks at once the rest queue for it: on a 13-core and on a 30-core
-# host four workers were as fast as eight and faster than one a core
-# (PERF.md §5).
+def _remember(col, prof: TextProfile) -> TextProfile:
+    try:
+        object.__setattr__(col, "_text_profile", prof)
+    except Exception:  # pragma: no cover — exotic column subtype
+        pass
+    return prof
+
+
+# Phase one of a walk holds the GIL for about a fifth of it on one-token
+# values, so past four or five walks at once the rest queue for it: on a
+# 13-core and on a 30-core host four workers were as fast as eight and faster
+# than one a core (PERF.md §6, PR 30).  Phase one is per ROW, so on free
+# text's long rows it is a hundredth of a walk and ranges scale further: a
+# column of 1,835,008 rows of 78 tokens took 2.34 s as 4 ranges on 4 workers
+# and 1.71 s as 13 on 13 (a 13-core host; PERF.md §6, PR 35).  Four is kept:
+# the one pool serves both kinds of column, and telling them apart is a
+# scheduler that weighs a walk by the GIL share its head observed (ROADMAP
+# S7).
 _MAX_WORKERS = 4
 
+# Rows a block of the native walk (native/textprof.cpp BLOCK_ROWS): a head
+# ends on a block's edge, and ranges are cut there.
+BLOCK_ROWS = 65536
 
-def pool_size(columns: int) -> int:
-    """Worker threads ``profile_columns`` walks that many columns on: the
-    cores this process may run on, up to ``_MAX_WORKERS``."""
-    return min(columns, len(os.sched_getaffinity(0)), _MAX_WORKERS)
+# The smallest row range worth a work item of its own, in blocks; a column
+# is cut only where its tail gives two such.  A walk of one-token values is
+# bound by the GIL, not by cores, so cutting it buys nothing and costs the
+# head's serial block, the extra items and the join: 26 columns of 786,432
+# rows (12 blocks) took 0.45–0.48 s whole and 0.54–0.73 s cut at 4 blocks
+# into 64 walks, on the same 4 workers (a 13-core host; PERF.md §6, PR 35).
+# At 6 such a column stays whole, and free text's 28 blocks still give one
+# range a worker.
+MIN_RANGE_BLOCKS = 6
 
 
-def profile_columns(columns: Sequence[Tuple[object, Optional[int]]]
+def pool_size(items: int) -> int:
+    """Worker threads ``profile_columns`` spreads that many work items over:
+    the cores this process may run on, up to ``_MAX_WORKERS``."""
+    return min(items, len(os.sched_getaffinity(0)), _MAX_WORKERS)
+
+
+def _ranges(start: int, rows: int, most: int) -> List[Tuple[int, int]]:
+    """Rows [start, rows) cut into at most ``most`` contiguous ranges of
+    whole blocks (the last ends with the column), none under
+    ``MIN_RANGE_BLOCKS`` unless it is the only one."""
+    blocks = -(-(rows - start) // BLOCK_ROWS)
+    count = max(1, min(most, blocks // MIN_RANGE_BLOCKS))
+    edges = [start + (blocks * k // count) * BLOCK_ROWS
+             for k in range(count)] + [rows]
+    return list(zip(edges[:-1], edges[1:]))
+
+
+def _planned_walks(rows: int, cap: Optional[int]) -> int:
+    """Walks ``_column_plan`` cuts a column of that many rows into when its
+    table freezes in its first block (all a plan can know before the walk):
+    what ``pool_size`` is asked for."""
+    head = 0 if cap is None else BLOCK_ROWS
+    if (cap is not None and cap < 0) \
+            or rows < head + 2 * MIN_RANGE_BLOCKS * BLOCK_ROWS:
+        return 1
+    return (head > 0) + len(_ranges(head, rows, _MAX_WORKERS))
+
+
+Jobs = List[Callable[[], object]]
+
+
+def _column_plan(col, cap: Optional[int], num_hashes: Optional[int],
+                 workers: int) -> Generator[Jobs, list, TextProfile]:
+    """``column_profile(col, cap)`` and, for a ``num_hashes`` the packed
+    wire serves, the column's host words, as steps of jobs: every job of a
+    step may run beside the others, the step's results (in the jobs' order)
+    are sent back in, and the profile is returned at the end.
+
+    A long column is walked by row range.  The *head*: whole blocks from row
+    0, in order, until the interning is settled — no rows when no cap is
+    asked, every row when the table never freezes (an exact count, or fewer
+    distinct values than the cap: the one walk of a short column).  The
+    *tail*: the rows left, as contiguous ranges that only look the head's
+    frozen values up, so that they are independent of each other; their
+    per-row products join in row order and equal the one walk's exactly.
+    Then each range packs its own words."""
+    from ..native import load
+    from .categorical import _col_strings
+
+    prof = getattr(col, "_text_profile", None)
+    if prof is None:
+        strings = _object_column(_col_strings(col))
+        native = load("textprof")
+        rows = len(strings)
+        if native is None or _planned_walks(rows, cap) == 1:
+            walks = yield [partial(scan_strings, strings, cap=cap)]
+            prof = walks[0]
+        else:
+            walks = []
+            if cap is not None:
+                walks = yield [partial(_walk, native, strings, 1, cap,
+                                       until_frozen=True)]
+            done = walks[0]["rows"] if walks else 0
+            if done < rows:
+                frozen = walks[0]["uniq"] if walks else None
+                cuts = _ranges(done, rows, workers)
+                walks += yield [partial(_walk, native, strings[a:b], 1, cap,
+                                        frozen) for a, b in cuts]
+                REGISTRY.counter("text_profile.range_walks").inc(len(cuts))
+            prof = _profile_of(walks, strings, cap)
+        walked = REGISTRY.gauge("text_profile.ranges")
+        walked.set(walked.value + len(walks))   # plans advance on one thread
+        _remember(col, prof)
+    if num_hashes and num_hashes < 1024 \
+            and num_hashes not in prof._device_packed:
+        words, jobs = prof.pack_jobs(num_hashes)
+        yield jobs
+        prof._host_words[num_hashes] = words
+    return prof
+
+
+def _run_here(plan: Generator[Jobs, list, TextProfile]) -> TextProfile:
+    """``plan``'s jobs one after another on the calling thread."""
+    results = None
+    while True:
+        try:
+            jobs = plan.send(results)
+        except StopIteration as stop:
+            return stop.value
+        results = [job() for job in jobs]
+
+
+def _run_on(pool: ThreadPoolExecutor,
+            plans: List[Generator[Jobs, list, TextProfile]]
+            ) -> Iterator[TextProfile]:
+    """Every plan's jobs on ``pool``, all plans under way at once, each
+    plan's profile yielded in the plans' order as soon as it is whole.  The
+    plans themselves advance on the calling thread, between the waits."""
+    running = {}        # future -> (plan, job) it is
+    step = {}           # plan -> [results so far, jobs still out]
+    whole = {}          # plan -> its profile
+
+    def advance(i: int, results: Optional[list]) -> None:
+        try:
+            jobs = plans[i].send(results)
+        except StopIteration as stop:
+            whole[i] = stop.value
+            return
+        step[i] = [[None] * len(jobs), len(jobs)]
+        for k, job in enumerate(jobs):
+            running[pool.submit(job)] = (i, k)
+
+    for i in range(len(plans)):
+        advance(i, None)
+    for i in range(len(plans)):
+        while i not in whole:
+            for done in wait(running, return_when=FIRST_COMPLETED).done:
+                j, k = running.pop(done)
+                step[j][0][k] = done.result()       # raises what the job did
+                step[j][1] -= 1
+                if not step[j][1]:
+                    advance(j, step.pop(j)[0])
+        yield whole.pop(i)
+
+
+def profile_columns(columns: Sequence[Tuple[object, Optional[int],
+                                            Optional[int]]]
                     ) -> Iterator[TextProfile]:
-    """``column_profile(col, cap)`` of every (column, cap) pair, yielded in
-    order, the columns walked side by side on ``pool_size`` worker threads,
-    so the caller works on a profile while later columns are still walked.
-    One worker is a plain loop."""
+    """``column_profile(col, cap)`` of every (column, cap, num_hashes)
+    triple, yielded in order, each with its packed words for ``num_hashes``
+    ready on the host (``TextProfile.device_ids`` then only starts the
+    transfer), the work spread over ``pool_size`` worker threads by row
+    range (``_column_plan``), so the caller works on a profile while later
+    columns are still walked.  One worker is a plain loop."""
     from ..native import load
 
-    workers = pool_size(len(columns))
+    walks = sum(_planned_walks(len(col), cap) for col, cap, _ in columns
+                if getattr(col, "_text_profile", None) is None)
+    workers = pool_size(max(walks, len(columns)))
     REGISTRY.gauge("text_profile.workers").set(workers)
+    REGISTRY.gauge("text_profile.ranges").set(0)
+    plans = [_column_plan(col, cap, num_hashes, workers)
+             for col, cap, num_hashes in columns]
     if workers <= 1:
-        for col, cap in columns:
-            yield column_profile(col, cap)
+        yield from map(_run_here, plans)
         return
     load("textprof")        # built and imported once, before the threads
-    with ThreadPoolExecutor(workers) as pool:
-        yield from pool.map(lambda cc: column_profile(*cc), columns)
+    pool = ThreadPoolExecutor(workers)
+    try:
+        yield from _run_on(pool, plans)
+    finally:
+        pool.shutdown(cancel_futures=True)

@@ -334,12 +334,13 @@ class Workflow(_WorkflowCore):
     def _prefetch_text_profiles(self, batch) -> None:
         """Do up front what a training run will need of its raw columns:
         every text column of a hashing vectorizer profiled ONCE
-        (``ops.text_profile.profile_columns``: one native walk a column,
-        a few columns side by side on worker threads, interned in that walk
-        at the stage's ``max_cardinality`` so that the fit finds
-        ``values(cap)`` cached), its packed token ids put on the device
-        from this thread in feature order, and the bf16-wire copies of
-        numeric raw columns + the label.  The async host→device transfers
+        (``ops.text_profile.profile_columns``: walked natively by row range
+        on a few worker threads, interned in that walk at the stage's
+        ``max_cardinality`` so that the fit finds ``values(cap)`` cached,
+        its token ids packed for the stage's ``num_hashes`` by the same
+        workers), the packed words handed to the link from this thread in
+        feature order, and the bf16-wire copies of numeric raw columns +
+        the label.  The async host→device transfers
         then overlap RawFeatureFilter + fit host work instead of
         serializing after it (the TPU analog of the reference keeping row
         work on executors, SmartTextVectorizer.scala:80).  Large batches
@@ -350,13 +351,13 @@ class Workflow(_WorkflowCore):
 
         from .columns import to_device_f32
         from .ops.text import HashingVectorizer, SmartTextVectorizer
-        from .ops.text_profile import pool_size, profile_columns
-        from .telemetry import span
+        from .ops.text_profile import profile_columns
+        from .telemetry import REGISTRY, span
         if jax.default_backend() == "cpu":
             return      # no slow link to hide
         try:
             with span("prefetch.text_profiles", rows=len(batch)) as sp:
-                columns, hashes = [], []
+                columns = []
                 for st in dag_stages(compute_dag(self.result_features)):
                     if not isinstance(st, (SmartTextVectorizer,
                                            HashingVectorizer)):
@@ -371,15 +372,16 @@ class Workflow(_WorkflowCore):
                                 next((v for v in vals if v is not None), ""),
                                 str):
                             continue    # token lists take the legacy path
-                        columns.append((col, cap))
-                        hashes.append(int(st.get("num_hashes") or 0))
-                if sp is not None:
-                    sp.attrs.update(columns=len(columns),
-                                    workers=pool_size(len(columns)))
-                for prof, num_hashes in zip(profile_columns(columns),
-                                            hashes):
+                        columns.append(
+                            (col, cap, int(st.get("num_hashes") or 0)))
+                for prof, (_, _, num_hashes) in zip(
+                        profile_columns(columns), columns):
                     if num_hashes:
                         prof.prefetch(num_hashes)
+                if sp is not None:
+                    sp.attrs.update(
+                        columns=len(columns),
+                        workers=REGISTRY.gauge("text_profile.workers").value)
             # numeric raw columns + label: the weakref transfer cache makes
             # these THE copies every later consumer (frontier _prep,
             # vectorizer fits, selector y) reuses

@@ -37,3 +37,21 @@ def eight_device_mesh():
     from transmogrifai_tpu.parallel import make_mesh
     assert len(jax.devices()) >= 8, "conftest must provide 8 virtual devices"
     return make_mesh(8, model_parallel=2)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _memory_ladder_starts_clean():
+    """The memory governor's ladder and its count of shrinks are the
+    process's, and a worker runs file after file: a file of OOM drills must
+    not make a later file's trains read as shrunk (``benchmark/produced.py``
+    counts any train of a process that has ever shrunk as failed, and which
+    files share a worker changes with every file added).  The counter is
+    dropped through the registry's private table: a counter is monotonic for
+    every user of a process and the registry has, rightly, no public way to
+    forget one; only a test process starts over between files."""
+    from transmogrifai_tpu.parallel import memory
+    from transmogrifai_tpu.telemetry import REGISTRY
+    memory.reset_memory_degrade()
+    with REGISTRY._lock:
+        REGISTRY._counters.pop("memory.shrinks_total", None)
+    yield

@@ -15,6 +15,7 @@ count; (i) the configuration's file; (j) the cell's part of
 ``run.py --selftest``.
 """
 
+import contextlib
 import importlib
 import json
 import os
@@ -455,34 +456,105 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache and
+    cannot be read back without a chip: keep such compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 def test_hash_scatter_compiles_for_the_chip_at_the_cells_size(one_chip):
     """The text column's scatter at the configuration's own rows and the
     size class of its token wire, compiled for a v5e chip: it has to fit the
     chip's 16 GB (the form that stacked the lanes as ``[words, 3]`` asked for
     25.8 GB in one copy and was refused).  Counts nothing but bytes."""
     import jax.numpy as jnp
-    from jax.experimental.compilation_cache import compilation_cache
     from transmogrifai_tpu.ops import text
     cfg = run.load_json("benchmark", "configs", "amazon_polarity_text.json")
     rows = cfg["rows"]
     tokens = rows * cfg["generator"]["text"]["mean_tokens"]
     words = text._size_class(-(-tokens // 3))
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with no_compile_cache():
         compiled = text._scatter_counts_packed.lower(
             jax.ShapeDtypeStruct((words,), jnp.int32, sharding=one_chip),
             jax.ShapeDtypeStruct((rows + 1,), jnp.int32, sharding=one_chip),
             rows, 512, False).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
     m = compiled.memory_analysis()
     held = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes)
     assert m.output_size_in_bytes == rows * 512 * 4
     assert held < 12 * 2 ** 30, m
+
+
+def test_round_b_panel_compiles_for_the_chip_at_the_typed_cells_size(
+        one_chip):
+    """The metric panel of two folds by three survivors (every cell's
+    logistic family after racing) at ``nyc_taxi_typed``'s rows, compiled for
+    a v5e chip.  Mapped over [N, 2, 3] where the axes lay, the compiler
+    tiled (2, 3) to (8, 128), 768 bytes a score: 8.5 GB at 1.8 M rows and
+    no program at all at 8.4 M (``metrics_device._rows_last``).  Here with
+    this file's other compile: one worker loads the TPU's compiler."""
+    import jax.numpy as jnp
+    from transmogrifai_tpu import metrics_device
+    rows = run.load_json("benchmark", "configs", "nyc_taxi_typed.json")["rows"]
+    with no_compile_cache():
+        held = {}
+        for fn in (metrics_device.masked_aupr_fold_grid,
+                   metrics_device.masked_auroc_fold_grid):
+            m = fn.lower(
+                jax.ShapeDtypeStruct((rows,), jnp.float32, sharding=one_chip),
+                jax.ShapeDtypeStruct((rows, 2, 3), jnp.float32,
+                                     sharding=one_chip),
+                jax.ShapeDtypeStruct((2, rows), jnp.float32,
+                                     sharding=one_chip)
+            ).compile().memory_analysis()
+            held[fn.__name__] = m.temp_size_in_bytes / (6 * rows)
+    assert all(b < 48.0 for b in held.values()), held
+
+
+def test_packed_null_bits_unpack_cheaply_on_the_chip(one_chip):
+    """The coordinate vectorizer's device body at ``nyc_taxi_typed``'s rows,
+    compiled for a v5e chip.  With eight consecutive rows a word the unpack
+    was a ``[W, 8] -> [N]`` reshape across the lane tile: fused into the
+    fill it made 30 MB of code and took the compiler 235 s; in bit planes
+    (``columns.pack_bits``) the body is under 3 MB.  Counts bytes, not
+    seconds.  Here with this file's other compiles: one worker loads the
+    TPU's compiler."""
+    import jax.numpy as jnp
+    from transmogrifai_tpu import types as T
+    from transmogrifai_tpu.columns import Column, ColumnBatch
+    from transmogrifai_tpu.features import features_from_schema
+    from transmogrifai_tpu.ops.geo import GeolocationVectorizer
+    rows = run.load_json("benchmark", "configs", "nyc_taxi_typed.json")["rows"]
+    _, (f,) = features_from_schema({"y": T.RealNN, "g": T.Geolocation},
+                                   response="y")
+    few = ColumnBatch({"g": Column(
+        T.Geolocation, np.ones((16, 3), np.float32), np.arange(16) % 5 > 0)},
+        16)
+    wire, body = GeolocationVectorizer().set_input(f).fit(
+        few).transform_staged(few)
+
+    def at_size(a):
+        per_row = {16: rows, 2: -(-rows // 8)}.get(a.shape[0])
+        if a.ndim != 1 or per_row is None:
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(
+            (per_row,), jnp.bfloat16 if a.dtype == np.float32 else a.dtype,
+            sharding=one_chip)
+    with no_compile_cache():
+        m = jax.jit(lambda w: body(w).values).lower(
+            {k: at_size(v) for k, v in wire.items()}
+        ).compile().memory_analysis()
+    assert m.generated_code_size_in_bytes < 8e6, m
+    assert m.temp_size_in_bytes < 48 * rows, m
 
 
 # (g) ----------------------------------------------------------------------
@@ -546,17 +618,22 @@ def test_readers_and_appended_cells_say_what_benchmark_json_says():
         mod, m = reader(name), entries[name]
         assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
             m["layer"], m["unit"], m["source"], m["moves"])
-        assert m["workloads"] == [CELL] and m["better"] == "lower"
+        # the cell's own readers; ISSUE 36 appended its cell to the one of
+        # them that reads something there
+        assert m["workloads"] == [CELL] + ["typed_sweep"] * (
+            name == "text_profile_s") and m["better"] == "lower"
         assert m["layer"] in {e["layer"] for e in MANIFEST["per_layer"][:18]}
     for name in ("prologue_s", "selector_s", "host_link_MB",
                  "window_compiles", "setup_compile_s", "device_idle_share",
                  "sweep_mfu", "peak_hbm_GiB", "prologue_idle_s",
                  "transform_s", "sanity_s", "refit_s", "train_jit_s"):
-        assert entries[name]["workloads"][-1] == CELL, name
+        # appended last by ISSUE 34, and the next cell after it by ISSUE 36
+        assert entries[name]["workloads"][-2:] == [CELL, "typed_sweep"], name
     for name in ("mesh_devices", "place_s", "relayout_MB", "sweep_mfu_x4",
                  "chip_rows_per_s"):
         assert CELL not in entries[name]["workloads"], name
-    assert [m["name"] for m in MANIFEST["per_layer"][-3:]] == list(
+    # entries 18 to 20: what ISSUE 34 appended, where later issues append
+    assert [m["name"] for m in MANIFEST["per_layer"][18:21]] == list(
         NEW_READERS)
 
 

@@ -626,7 +626,7 @@ def test_readers_say_what_benchmark_json_says():
         assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
             entry["layer"], entry["unit"], entry["source"], entry["moves"])
         assert entry["workloads"] == ["mixed_sweep", "mixed_sweep_x4",
-                                      "text_sweep"]
+                                      "text_sweep", "typed_sweep"]
         assert entry["better"] == "lower"
 
 

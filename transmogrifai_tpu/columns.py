@@ -85,10 +85,20 @@ def feature_matrix_dtype(n_elems: int):
 
 
 def pack_bits(arr) -> np.ndarray:
-    """Boolean/0-1 array → packed uint8 wire (8 rows per byte, little-endian
-    bit order so the device unpack is a shift+mask)."""
-    return np.packbits(np.asarray(arr).astype(bool).reshape(-1),
-                       bitorder="little")
+    """Boolean/0-1 array → packed uint8 wire, a bit a row, in bit PLANES:
+    with W = ceil(n / 8) words, row r is bit ``r // W`` of word ``r % W``.
+    The device unpack is then eight shift+masks of the whole wire laid end
+    to end (``[8, W]`` flattened, the words along the lanes).  (Eight
+    consecutive rows a word would need a ``[W, 8] → [n]`` reshape on the
+    device, which the chip tiles to 128 lanes: 403 MB of temporaries and,
+    fused into its consumers, four minutes of compile at six million rows;
+    PERF.md §6.)"""
+    bits = np.asarray(arr).astype(bool).reshape(-1)
+    words = -(-bits.size // 8)
+    if bits.size != 8 * words:
+        bits = np.concatenate([bits, np.zeros(8 * words - bits.size, bool)])
+    return np.packbits(bits.reshape(8, words), axis=0,
+                       bitorder="little").reshape(-1)
 
 
 def unpack_bits_device(words, n: int, shape=None):
@@ -96,9 +106,9 @@ def unpack_bits_device(words, n: int, shape=None):
     elements (optionally reshaped).  Traceable."""
     import jax.numpy as jnp
 
-    bits = (words[:, None].astype(jnp.int32)
-            >> jnp.arange(8, dtype=jnp.int32)[None, :]) & 1
-    flat = bits.reshape(-1)[:n].astype(jnp.float32)
+    planes = (words.astype(jnp.int32)[None, :]
+              >> jnp.arange(8, dtype=jnp.int32)[:, None]) & 1      # [8, W]
+    flat = planes.reshape(-1)[:n].astype(jnp.float32)
     return flat if shape is None else flat.reshape(shape)
 
 

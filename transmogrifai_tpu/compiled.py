@@ -342,12 +342,16 @@ class ScoreProgram:
     # -- execution ----------------------------------------------------------
     def __call__(self, batch: ColumnBatch, keep_intermediate: bool = False
                  ) -> ColumnBatch:
+        # stages run outside a compiled segment; created at 0 by a flush
+        # that runs none, so that a reader can tell 0 from no counter
+        host_stages = REGISTRY.counter("transform.host_stages")
         for _attempt in range(len(self.stages) + 1):
             segments = self._partition(batch)
             b = batch
             try:
                 for i, (is_dev, stages) in enumerate(segments):
                     if not is_dev:
+                        host_stages.inc(len(stages))
                         with span("transform.host_stage", stages=len(stages)):
                             for st in stages:
                                 b = st.transform_batch(b)
@@ -455,12 +459,24 @@ class ScoreProgram:
         wires: Dict[str, Any] = {}
         with span("transform.stage_wires", stages=len(run)):
             for st in run:
-                if all(batch[f.name].is_device for f in st.input_features
-                       if f.name in batch):
+                # a device op reads its columns inside the program; a staged
+                # stage (``_partition`` put it here for its staged form)
+                # always goes through its prologue, arrays or not: an int64
+                # date is an array and still has no place on the chip
+                if st.is_device_op and all(
+                        batch[f.name].is_device for f in st.input_features
+                        if f.name in batch):
                     continue
                 res = None
                 try:
-                    res = st.transform_staged(batch)
+                    with span("transform.stage_wires." + type(st).__name__,
+                              rows=len(batch),
+                              columns=len(st.input_features)) as sp:
+                        res = st.transform_staged(batch)
+                        if sp is not None and res is not None:
+                            sp.attrs["wire_bytes"] = int(sum(
+                                getattr(v, "nbytes", 0)
+                                for v in res[0].values()))
                 except Exception as e:  # noqa: BLE001 — demotion signal
                     raise _StageTraceError(st.uid, e) from e
                 if res is None:
@@ -630,6 +646,7 @@ class ScoreProgram:
                            fallback="eager per-stage execution")
             self._forget(key)
             self._demoted.update(st.uid for st in run)
+            REGISTRY.counter("transform.host_stages").inc(len(run))
             b = batch
             for st in run:
                 b = st.transform_batch(b)

@@ -13,7 +13,7 @@ reductions are vectorised; text features hash into bins like the reference.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -21,7 +21,7 @@ import zlib
 
 from .columns import Column, ColumnBatch
 from .features import Feature
-from .telemetry import span
+from .telemetry import REGISTRY, span
 from .types import is_map_kind, is_numeric_kind, is_text_kind
 
 
@@ -180,6 +180,13 @@ def compute_sketches(raw_features: Sequence[Feature], batch: ColumnBatch,
                 int(sum(1 for m in col.values if not m)),
                 text_counts=np.zeros(text_bins))
             continue
+        if _list_held_as_array(col):
+            present = _value_presence(col)
+            out[(f.name, None)] = FeatureSketch(
+                f.name, None, n, int((~present).sum()),
+                text_counts=_array_item_bins(np.asarray(col.values), present,
+                                             text_bins))
+            continue
         vals = (list(col.values) if col.is_host_object()
                 else np.asarray(col.values))
         if not col.is_host_object() and col.mask is not None:
@@ -195,6 +202,8 @@ def _sketch_of(name, key, vals, kind, max_bins, text_bins) -> FeatureSketch:
 
     n = len(vals)
     vkind = map_value_kind(kind) if is_map_kind(kind) else kind
+    if isinstance(vals, list) or not is_numeric_kind(vkind):
+        _python_rows().inc(n)
     if is_numeric_kind(vkind):
         arr = np.asarray(
             [float(v) if isinstance(v, (int, float, np.floating, np.integer))
@@ -252,6 +261,11 @@ def merge_sketches(a: Dict, b: Dict) -> Dict:
 _HIST_FNS: Dict[int, Any] = {}
 
 
+def _python_rows():
+    """Counter of the rows a distribution or a sketch walked in Python."""
+    return REGISTRY.counter("rff.python_rows")
+
+
 def _sharded_numeric_hist(mesh, arr, keep, lo, hi, bins: int) -> np.ndarray:
     """np.histogram over [lo, hi] with the COUNT REDUCTION sharded over the
     mesh 'data' axis (XLA inserts the psum).  Bin indices are computed on
@@ -282,6 +296,61 @@ def _sharded_numeric_hist(mesh, arr, keep, lo, hi, bins: int) -> np.ndarray:
     i = jax.device_put(jnp.asarray(idx), data_sharding(mesh, 1))
     m = jax.device_put(jnp.asarray(valid), data_sharding(mesh, 1))
     return np.asarray(fn(i, m)).astype(np.float64)
+
+
+# Rows a block of a numeric column's walk.  A pass over a whole column of
+# millions of rows makes temporaries of tens of MB each (the float64 copy,
+# the finite ones, the kept values), which the allocator maps and the kernel
+# faults in anew for every pass; a block's stay in cache and are reused.
+_BLOCK_ROWS = 1 << 16
+
+
+def _finite_blocks(values: np.ndarray, present: Optional[np.ndarray]
+                   ) -> Iterator[np.ndarray]:
+    """The present, finite values of a numeric column as float64, a block
+    of rows at a time."""
+    for s in range(0, len(values), _BLOCK_ROWS):
+        x = np.asarray(values[s:s + _BLOCK_ROWS], dtype=np.float64)
+        keep = np.isfinite(x)
+        if present is not None:
+            keep &= present[s:s + _BLOCK_ROWS]
+        yield x[keep]
+
+
+def _finite_range(values: np.ndarray, present: Optional[np.ndarray]
+                  ) -> Optional[Tuple[float, float]]:
+    """(min, max) of the present, finite values; None where there is none."""
+    lo, hi = np.inf, -np.inf
+    for x in _finite_blocks(values, present):
+        if x.size:
+            lo, hi = min(lo, x.min()), max(hi, x.max())
+    return (float(lo), float(hi)) if lo <= hi else None
+
+
+def _array_item_bins(values: np.ndarray, present: np.ndarray,
+                     text_bins: int) -> np.ndarray:
+    """Hashed-item histogram of a list-valued column held as an array
+    ([N, K], or [N]): the bins the row-by-row branch of ``_histogram_of``
+    gives the same rows as lists, from each DISTINCT value hashed once and
+    weighted by its count — no Python over rows.  Floats are told apart
+    by their bits, as their strings tell 0.0 from -0.0."""
+    h = np.zeros(text_bins)
+    values = values.reshape(len(values), -1)
+    for j in range(values.shape[1]):
+        col = np.ascontiguousarray(values[:, j][present])
+        bits = col.view(f"u{col.itemsize}") if col.dtype.kind == "f" else col
+        uniq, counts = np.unique(bits, return_counts=True)
+        uniq = uniq.view(col.dtype)
+        bins = np.fromiter((_stable_text_bin(u, text_bins)
+                            for u in uniq.tolist()), np.int64, len(uniq))
+        h += np.bincount(bins, weights=counts, minlength=text_bins)
+    return h
+
+
+def _list_held_as_array(col: Column) -> bool:
+    """A column of lists (a Geolocation's triples) stored as one array."""
+    return not col.is_host_object() and not is_numeric_kind(col.kind) \
+        and not isinstance(col.values, dict)
 
 
 def _stable_text_bin(item, text_bins: int) -> int:
@@ -346,12 +415,10 @@ def numeric_ranges(feature: Feature, col: Column
                 out[k] = r
         return out
     if is_numeric_kind(kind) and not col.is_host_object():
-        vals = np.asarray(col.values, dtype=np.float64)
-        if col.mask is not None:
-            vals = vals[np.asarray(col.mask)]
-        vals = vals[np.isfinite(vals)]
-        if vals.size:
-            out[None] = (float(vals.min()), float(vals.max()))
+        r = _finite_range(np.asarray(col.values), None if col.mask is None
+                          else np.asarray(col.mask))
+        if r is not None:
+            out[None] = r
     elif is_numeric_kind(kind):
         r = rng_of(list(col.values))
         if r is not None:
@@ -422,6 +489,8 @@ def compute_distribution(feature: Feature, col: Column, bins: int,
         # hashed whole-value bins straight from the cached column profile
         from .ops.text_profile import column_profile
         dist = column_profile(col).crc_hist(text_bins)
+    elif _list_held_as_array(col):
+        dist = _array_item_bins(np.asarray(col.values), present, text_bins)
     else:
         dist = _histogram_of(list(np.asarray(col.values, dtype=object))
                              if col.is_host_object() else np.asarray(col.values),
@@ -436,29 +505,35 @@ def compute_distribution(feature: Feature, col: Column, bins: int,
 def _histogram_of(vals, present: np.ndarray, kind, bins: int,
                   text_bins: int, value_range=None) -> np.ndarray:
     if is_numeric_kind(kind):
-        arr = np.asarray(
-            [float(v) if (v is not None and not isinstance(v, str)) else np.nan
-             for v in vals] if isinstance(vals, list) else vals,
-            dtype=np.float64)
-        keep = present & np.isfinite(arr)
-        if not keep.any():
-            return np.zeros(bins)
-        if value_range is not None:
-            lo, hi = value_range
-        else:
-            lo, hi = float(arr[keep].min()), float(arr[keep].max())
+        if isinstance(vals, list):
+            _python_rows().inc(len(vals))
+            vals = np.asarray(
+                [float(v) if (v is not None and not isinstance(v, str))
+                 else np.nan for v in vals], dtype=np.float64)
+        if value_range is None:
+            value_range = _finite_range(vals, present)
+            if value_range is None:
+                return np.zeros(bins)
+        lo, hi = value_range
         if lo == hi:
             hi = lo + 1.0
         # multi-device: the binning reduction runs as one GSPMD program with
         # rows sharded over 'data' (≙ RawFeatureFilter's executor-side
         # FeatureDistribution reduce, RawFeatureFilter.scala:137)
         from .parallel.mesh import maybe_data_mesh
-        mesh = maybe_data_mesh(int(arr.size))
+        mesh = maybe_data_mesh(int(vals.size))
         if mesh is not None:
+            arr = np.asarray(vals, dtype=np.float64)
+            keep = present & np.isfinite(arr)
+            if not keep.any():
+                return np.zeros(bins)
             return _sharded_numeric_hist(mesh, arr, keep, lo, hi, bins)
-        h, _ = np.histogram(arr[keep], bins=bins, range=(lo, hi))
-        return h.astype(np.float64)
+        h = np.zeros(bins)
+        for x in _finite_blocks(vals, present):
+            h += np.histogram(x, bins=bins, range=(lo, hi))[0]
+        return h
     # text-ish: hash values into text_bins (≙ text hashed into bins)
+    _python_rows().inc(len(vals))
     h = np.zeros(text_bins)
     for v, p in zip(vals, present):
         if not p or v is None:
@@ -466,6 +541,32 @@ def _histogram_of(vals, present: np.ndarray, kind, bins: int,
         for item in (v if isinstance(v, (list, set, tuple)) else [v]):
             h[_stable_text_bin(item, text_bins)] += 1.0
     return h
+
+
+class _CentredLabel:
+    """The label about its mean, made once a filter: what the correlation
+    of every feature's presence with the label shares."""
+
+    def __init__(self, values):
+        y = np.asarray(values, dtype=np.float64)
+        self.centred = y - y.mean() if y.size else y
+        self.sum_squares = float(np.einsum("i,i->", self.centred,
+                                           self.centred))
+
+    def correlation_with(self, presence: np.ndarray) -> float:
+        """Pearson correlation of a 0/1 vector with the label; NaN where
+        either is constant.  For a 0/1 vector the centred products add up
+        to the sum of the centred label over the rows that are 1, and its
+        own squares to n·p·(1 − p)."""
+        presence = np.asarray(presence, dtype=bool)
+        n, ones = presence.size, int(np.count_nonzero(presence))
+        if not self.sum_squares > 0 or ones in (0, n):
+            return float("nan")
+        over_ones = sum(float(self.centred[s:s + _BLOCK_ROWS][
+            presence[s:s + _BLOCK_ROWS]].sum())
+            for s in range(0, n, _BLOCK_ROWS))
+        return over_ones / np.sqrt(ones * (1.0 - ones / n)
+                                   * self.sum_squares)
 
 
 @dataclass
@@ -514,22 +615,23 @@ class RawFeatureFilter:
         """≙ generateFilteredRaw:486: returns (clean batch, dropped features,
         results)."""
         results = RawFeatureFilterResults()
-        label_values: Optional[np.ndarray] = None
+        label: Optional[_CentredLabel] = None
         label_name = next((f.name for f in raw_features if f.is_response), None)
         if label_name and label_name in batch:
-            label_values = np.asarray(batch[label_name].values, dtype=np.float64)
+            label = _CentredLabel(batch[label_name].values)
 
         score_batch = None
         if self.score_reader is not None:
             score_batch = self.score_reader.generate_batch(
                 [f for f in raw_features if not f.is_response])
 
+        _python_rows()      # at 0 where no column is walked in Python
         with span("rff.distributions", features=len(raw_features)):
             per_feature = self._distributions(batch, score_batch,
                                               raw_features, results)
         with span("rff.decide", features=len(per_feature)):
             for f, fdists, sdists in per_feature:
-                self._decide(f, fdists, sdists, batch, label_values, results)
+                self._decide(f, fdists, sdists, batch, label, results)
             return self._clean(batch, raw_features, results)
 
     def _distributions(self, batch, score_batch, raw_features, results):
@@ -558,8 +660,7 @@ class RawFeatureFilter:
             per_feature.append((f, fdists, sdists))
         return per_feature
 
-    def _decide(self, f, fdists, sdists, batch, label_values, results
-                ) -> None:
+    def _decide(self, f, fdists, sdists, batch, label, results) -> None:
         """Record in ``results`` whether ``f`` (or some of its map keys) is
         dropped, and why."""
         if f.name in self.protected:
@@ -571,13 +672,11 @@ class RawFeatureFilter:
             reasons.append(
                 f"fill rate {fdists[0].fill_rate:.4f} < minFillRate")
         # null-label correlation (leakage through missingness)
-        if label_values is not None and len(np.unique(label_values)) > 1:
-            presence = _value_presence(batch[f.name]).astype(np.float64)
-            if presence.std() > 0:
-                corr = float(np.corrcoef(presence, label_values)[0, 1])
-                if np.isfinite(corr) and abs(corr) > self.max_correlation:
-                    reasons.append(
-                        f"null-label correlation {corr:.4f} > max")
+        if label is not None:
+            corr = label.correlation_with(_value_presence(batch[f.name]))
+            if np.isfinite(corr) and abs(corr) > self.max_correlation:
+                reasons.append(
+                    f"null-label correlation {corr:.4f} > max")
 
         # train-vs-score distribution shift, compared PER KEY for maps
         # (≙ getFeaturesToExclude pairing distributions by (name, key));

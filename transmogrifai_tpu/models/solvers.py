@@ -110,20 +110,43 @@ class FitResult(NamedTuple):
     objective: jnp.ndarray  # final objective value
 
 
+def _exact_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``a @ b`` of a [D] vector of means with coefficients, in float32 on
+    every platform: at the chip's default precision both would be rounded to
+    bfloat16 first, and a mean of 40.75 times a coefficient of hundreds
+    would lose the intercept's digits.  On the CPU it is ``a @ b``."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+def _about(X: jnp.ndarray, mean: jnp.ndarray,
+           pivot: Optional[jnp.ndarray]):
+    """(a function that gives X about the pivot, the mean about the pivot).
+    The subtraction is written where a product reads it, inside the loops'
+    bodies, so that it fuses into the product's operand as the storage
+    dtype's conversion does; hoisted, it would be a float32 copy of X."""
+    if pivot is None:
+        return (lambda: X), mean
+    return (lambda: X - pivot), mean - pivot
+
+
 @jax.named_scope("linear.lipschitz")
 def _spectral_norm_sq_weighted(X: jnp.ndarray, wn: jnp.ndarray,
                                mean: jnp.ndarray, scale: jnp.ndarray,
-                               iters: int = 16) -> jnp.ndarray:
+                               iters: int = 16,
+                               pivot: Optional[jnp.ndarray] = None
+                               ) -> jnp.ndarray:
     """λ_max of Xs^T diag(wn) Xs for the IMPLICITLY standardized matrix
     Xs = (X - mean)/scale, never materializing Xs or the weighted product —
-    one shared HBM-resident X serves every (fold × grid) lane."""
+    one shared HBM-resident X serves every (fold × grid) lane.  ``pivot``:
+    see ``fista_fit``."""
     d = X.shape[1]
     v = jnp.full((d,), 1.0 / jnp.sqrt(d), jnp.float32)
+    about, off = _about(X, mean, pivot)
 
     def mv(v):
-        u = (X @ (v / scale)) - mean @ (v / scale)     # Xs @ v  [N]
-        u = wn * u
-        return (X.T @ u - mean * jnp.sum(u)) / scale   # Xs^T u  [D]
+        vs = v / scale
+        u = wn * (about() @ vs - _exact_dot(off, vs))    # Xs @ v  [N]
+        return (about().T @ u - off * jnp.sum(u)) / scale   # Xs^T u  [D]
 
     def body(_, v):
         u = mv(v)
@@ -246,7 +269,8 @@ def fista_fit(X: jnp.ndarray, y: jnp.ndarray, sample_weight: jnp.ndarray,
               tol: float = 1e-6, n_classes: int = 1,
               mean: Optional[jnp.ndarray] = None,
               scale: Optional[jnp.ndarray] = None,
-              sigma_sq: Optional[jnp.ndarray] = None) -> FitResult:
+              sigma_sq: Optional[jnp.ndarray] = None,
+              pivot: Optional[jnp.ndarray] = None) -> FitResult:
     """Accelerated proximal gradient with adaptive restart.
 
     minimises  mean_loss(Xs w + b) + l2/2 ||w||² + l1 ||w||₁  (no penalty on b)
@@ -263,6 +287,13 @@ def fista_fit(X: jnp.ndarray, y: jnp.ndarray, sample_weight: jnp.ndarray,
     single fit, not a grid lane — the winner's refit, a candidate fitted
     alone — and its operations carry the scope ``linear.refit`` in the
     device trace.
+
+    ``pivot`` [D] (``column_pivot``; with ``mean``) has every product read X
+    about it: Xs = ((X - pivot) - (mean - pivot))/scale, the same matrix.  A
+    column whose mean is hundreds of its deviations (a latitude: 40.75 +-
+    0.03) otherwise loses its standardized values in the rounding of
+    40.75 * v - mean * v, in float32 and, the coefficient rounded to
+    bfloat16, far sooner on the chip.
     """
     n, d = X.shape
     C = n_classes
@@ -273,25 +304,29 @@ def fista_fit(X: jnp.ndarray, y: jnp.ndarray, sample_weight: jnp.ndarray,
     mu = mean if std else jnp.zeros((d,), jnp.float32)
     sc = scale if std else jnp.ones((d,), jnp.float32)
 
+    pivot = pivot if std else None
+    about, off = _about(X, mu, pivot)
+
     def xs_mv(coef):
         """Xs @ coef without materializing Xs ([N] or [N, C])."""
         v = coef / (sc[:, None] if coef.ndim == 2 else sc)
-        return X @ v - mu @ v
+        return about() @ v - _exact_dot(off, v)
 
     def xs_tmv(glin):
         """Xs^T @ glin ([D] or [D, C])."""
         if glin.ndim == 2:
             sg = jnp.sum(glin, axis=0)
-            num = X.T @ glin - mu[:, None] * sg[None, :]
+            num = about().T @ glin - off[:, None] * sg[None, :]
             return num / sc[:, None]
-        return (X.T @ glin - mu * jnp.sum(glin)) / sc
+        return (about().T @ glin - off * jnp.sum(glin)) / sc
 
     # step size from Lipschitz bound: c * sigma_max(Xs_w)^2 (+ l2)
     wn = w / jnp.sum(w)
     with (jax.named_scope("linear.refit") if sigma_sq is None
           else contextlib.nullcontext()):
         if sigma_sq is None:
-            sigma_sq = _spectral_norm_sq_weighted(X, wn, mu, sc)
+            sigma_sq = _spectral_norm_sq_weighted(X, wn, mu, sc,
+                                                  pivot=pivot)
         return _fista_loop(xs_mv, xs_tmv, target, w, l2, l1, loss=loss, d=d,
                            n_classes=C, fit_intercept=fit_intercept,
                            max_iter=max_iter, tol=tol, sigma_sq=sigma_sq)
@@ -361,22 +396,29 @@ def linear_grid_fit(X: jnp.ndarray, y: jnp.ndarray, fold_weights: jnp.ndarray,
     (OpValidator.scala:320-349), re-expressed as nested vmap (SURVEY §2.6 P3).
     """
     d = X.shape[1]
+    pivot = column_pivot(X) if standardization else None
+    # the products read X about it only where the columns are centred: a
+    # shift is neutral there and nowhere else
+    product_pivot = pivot if fit_intercept else None
 
     def one_fold(w):
         if standardization:
-            mean, scale = standardize_moments(X, w, center=fit_intercept)
+            mean, scale = standardize_moments(X, w, center=fit_intercept,
+                                              pivot=pivot)
         else:
             mean, scale = (jnp.zeros((d,), jnp.float32), jnp.ones((d,), jnp.float32))
         # λ_max of the fold's weighted Gram is grid-independent: compute it
         # once per fold and share it across the vmapped grid lanes
         wn = w / jnp.sum(w)
-        sigma_sq = _spectral_norm_sq_weighted(X, wn, mean, scale)
+        sigma_sq = _spectral_norm_sq_weighted(X, wn, mean, scale,
+                                              pivot=product_pivot)
 
         def one_pt(l2, l1):
             res = fista_fit(X, y, w, l2, l1, loss=loss,
                             fit_intercept=fit_intercept, max_iter=max_iter,
                             tol=tol, n_classes=n_classes,
-                            mean=mean, scale=scale, sigma_sq=sigma_sq)
+                            mean=mean, scale=scale, sigma_sq=sigma_sq,
+                            pivot=product_pivot)
             return unscale_params(res, mean, scale, n_classes)
 
         return jax.vmap(one_pt)(l2s, l1s)
@@ -452,14 +494,41 @@ def ridge_grid_fit(X: jnp.ndarray, y: jnp.ndarray, fold_weights: jnp.ndarray,
     return jax.lax.map(one_fold, fold_weights)
 
 
+_PIVOT_ROWS = 4096
+_PIVOT_FAR = 16.0
+
+
+def column_pivot(X: jnp.ndarray) -> jnp.ndarray:
+    """[D] float32: for a column far from 0 for its spread (its first rows'
+    mean over ``_PIVOT_FAR`` of their deviations) that mean, as the matrix's
+    dtype holds it; 0 for every other column.  What is computed about it
+    keeps the digits such a column would lose about 0.  A stored value
+    within a factor of two of a pivot of its own dtype leaves an exact
+    difference, so the chip's bfloat16 products read it as they read the
+    column itself; and a pivot of 0 leaves a column's arithmetic as it
+    was."""
+    head = X[:_PIVOT_ROWS].astype(jnp.float32)
+    mean = jnp.mean(head, axis=0)
+    far = jnp.abs(mean) > _PIVOT_FAR * jnp.std(head, axis=0)
+    return jnp.where(far, mean.astype(X.dtype).astype(jnp.float32), 0.0)
+
+
 def standardize_moments(X: jnp.ndarray, sample_weight: jnp.ndarray,
-                        center: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                        center: bool, pivot: Optional[jnp.ndarray] = None
+                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Weighted standardisation moments (mean, scale) — consumers apply them
     IMPLICITLY inside their matvecs; the standardized matrix itself is never
     materialized (a per-(fold × grid) copy of X would dominate HBM)."""
     w = sample_weight / jnp.sum(sample_weight)
-    mean = w @ X
-    var = w @ (X * X) - mean * mean
+    # moments about the pivot: E[x^2] - mean^2 itself cancels to nothing in
+    # float32, and sooner under the chip's bfloat16 products, for a column
+    # whose mean is hundreds of its deviations (a latitude: 40.75 +- 0.03)
+    if pivot is None:
+        pivot = column_pivot(X)
+    Xc = X - pivot
+    shift = w @ Xc
+    mean = pivot + shift
+    var = w @ (Xc * Xc) - shift * shift
     scale = jnp.sqrt(jnp.maximum(var, 1e-12))
     mu = mean if center else jnp.zeros_like(mean)
     return mu, scale
@@ -590,8 +659,8 @@ def unscale_params(res: FitResult, mean: jnp.ndarray, scale: jnp.ndarray,
                    n_classes: int) -> FitResult:
     if n_classes > 1:
         coef = res.coef / scale[:, None]
-        intercept = res.intercept - mean @ coef
+        intercept = res.intercept - _exact_dot(mean, coef)
     else:
         coef = res.coef / scale
-        intercept = res.intercept - jnp.atleast_1d(mean @ coef)
+        intercept = res.intercept - jnp.atleast_1d(_exact_dot(mean, coef))
     return FitResult(coef, intercept, res.n_iter, res.objective)

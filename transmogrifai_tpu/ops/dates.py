@@ -9,12 +9,13 @@ sin/cos of the requested periods — is a pure device op; the period extraction
 
 from __future__ import annotations
 
+import math
 from typing import List, Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
 
-from ..columns import Column, ColumnBatch
+from ..columns import Column, ColumnBatch, pack_bits, unpack_bits_device
 from ..stages.base import Estimator, Transformer, TransformerModel
 from ..types import Date, DateList, Integral, OPVector, Real
 from ..vector_meta import NULL_INDICATOR, VectorColumnMeta, VectorMeta
@@ -48,26 +49,89 @@ def _period_fraction(ms: np.ndarray, period: str) -> np.ndarray:
     return (((ms + shift) % per) / per).astype(np.float32)
 
 
+# The wire of a date column: the chip has no int64, so a value crosses as two
+# int32, its day and the millisecond of that day.  Every period is a whole
+# number of some unit of a day (a week 7 days, the "month" 3,044 hundredths,
+# the "year" 3,652,425 ten-thousandths), so whole periods are cast out in
+# int32 arithmetic and only a remainder under 2**24 is ever made a float.
+# The day is sent modulo _DAY_CYCLE, a whole number of every period (761
+# days are 25 "months", 146,097 days 400 "years" and 20,871 weeks), so any
+# int64 millisecond has a wire.
+_DAY_CYCLE = 761 * 146097
+# period -> (days added, units in a day, units in the period)
+_PERIOD_UNITS = {"HourOfDay": (0, 1, 1), "DayOfWeek": (3, 1, 7),
+                 "DayOfMonth": (0, 100, 3044),
+                 "DayOfYear": (0, 10000, 3652425)}
+
+
+def _day_and_ms(ms) -> tuple:
+    """int64 epoch milliseconds -> (day modulo _DAY_CYCLE, millisecond of
+    the day), both int32; floor division, so dates before 1970 too."""
+    day, ms_of_day = np.divmod(np.asarray(ms, np.int64), _MS_DAY)
+    return (day % _DAY_CYCLE).astype(np.int32), ms_of_day.astype(np.int32)
+
+
+def _period_fraction_device(day, ms_of_day, period: str):
+    """``_period_fraction`` from the int32 wire, traceable.  The whole units
+    elapsed in the period and the rest of the last unit are divided apart and
+    added, two positive float32 terms: within three float32 ulps of the
+    fraction the int64 form gives."""
+    if period not in _PERIOD_UNITS:
+        raise ValueError(f"unknown time period {period}")
+    shift, per_day, per_period = _PERIOD_UNITS[period]
+    # the fewest days that are a whole number of periods: day * per_day
+    # stays inside int32 once the day is reduced by them
+    cycle = per_period // math.gcd(per_period, per_day)
+    ms_unit = _MS_DAY // per_day
+    whole = ms_of_day // ms_unit
+    rest = (ms_of_day - whole * ms_unit).astype(jnp.float32)
+    units = (((day + shift) % cycle) * per_day + whole) % per_period
+    return (units.astype(jnp.float32) / per_period
+            + rest / (float(ms_unit) * per_period))
+
+
 class DateToUnitCircleModel(TransformerModel):
     out_kind = OPVector
-    is_device_op = False  # int64 host modulo pre-pass, then device sin/cos
+    is_device_op = False  # int64 host split into the int32 wire, then device
+    supports_staging = True
+
+    def transform_staged(self, batch: ColumnBatch):
+        """Host prologue: every date as (day, millisecond of the day) int32
+        and its null bits packed.  Device body: the periods' phases, sin and
+        cos, zeros where null, the null column."""
+        periods = list(self.get("periods"))
+        track_nulls = self.get("track_nulls", True)
+        wire = {}
+        for i, f in enumerate(self.input_features):
+            col = batch[f.name]
+            wire[f"day{i}"], wire[f"ms{i}"] = _day_and_ms(col.values)
+            if col.mask is not None:
+                wire[f"null{i}"] = pack_bits(~np.asarray(col.mask))
+        count = len(self.input_features)
+        meta = self.fitted["meta"]
+
+        def body(w):
+            outs = []
+            for i in range(count):
+                day, ms = w[f"day{i}"], w[f"ms{i}"]
+                null = (unpack_bits_device(w[f"null{i}"], day.shape[0])
+                        if f"null{i}" in w
+                        else jnp.zeros(day.shape[0], jnp.float32))
+                here = 1.0 - null
+                for p in periods:
+                    ang = 2 * jnp.pi * _period_fraction_device(day, ms, p)
+                    outs += [jnp.sin(ang) * here, jnp.cos(ang) * here]
+                if track_nulls:
+                    outs.append(null)
+            return Column(OPVector, jnp.stack(outs, axis=1), meta=meta)
+
+        return wire, body
 
     def transform(self, batch: ColumnBatch) -> Column:
-        periods = self.get("periods")
-        outs = []
-        for f in self.input_features:
-            col = batch[f.name]
-            v = np.asarray(col.values, np.int64)
-            m = (jnp.ones(v.shape[0], bool) if col.mask is None
-                 else jnp.asarray(col.mask))
-            for p in periods:
-                frac = jnp.asarray(_period_fraction(v, p))
-                ang = 2 * jnp.pi * frac
-                outs.append(jnp.where(m, jnp.sin(ang), 0.0).astype(jnp.float32)[:, None])
-                outs.append(jnp.where(m, jnp.cos(ang), 0.0).astype(jnp.float32)[:, None])
-            if self.get("track_nulls", True):
-                outs.append((~m).astype(jnp.float32)[:, None])
-        return Column(OPVector, jnp.concatenate(outs, axis=1), meta=self.fitted["meta"])
+        """The staged form run eagerly: one arithmetic for the fused program,
+        local scoring and every eager path."""
+        wire, body = self.transform_staged(batch)
+        return body(wire)
 
 
 class DateToUnitCircleVectorizer(Estimator):

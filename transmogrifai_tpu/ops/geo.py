@@ -9,44 +9,79 @@ from typing import List
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
-from ..columns import Column, ColumnBatch
+from ..columns import Column, ColumnBatch, pack_bits, unpack_bits_device
 from ..stages.base import Estimator, TransformerModel
 from ..types import OPVector
 from ..vector_meta import NULL_INDICATOR, VectorColumnMeta, VectorMeta
 
 
 def _geo_arrays(col) -> tuple:
-    """Column of Geolocation → ([N,3] float32, [N] bool mask)."""
+    """Column of Geolocation → ([N,3] float32, [N] bool mask).  A column
+    held as objects (lists, None or empty where missing) is gathered by
+    numpy from its present values, not row by row."""
     if col.is_host_object():
         n = len(col.values)
         arr = np.zeros((n, 3), np.float32)
-        mask = np.zeros(n, bool)
-        for i, v in enumerate(col.values):
-            if v:
-                arr[i] = v[:3]
-                mask[i] = True
+        mask = np.fromiter(map(bool, col.values), bool, count=n)
+        if mask.any():
+            arr[mask] = np.asarray(
+                [v[:3] for v in col.values[mask].tolist()], np.float32)
         return arr, mask
     arr = np.asarray(col.values, np.float32)
     mask = (np.ones(len(arr), bool) if col.mask is None else np.asarray(col.mask))
     return arr, mask
 
 
+_PARTS = ("lat", "lon", "accuracy")
+
+
 class GeolocationVectorizerModel(TransformerModel):
     out_kind = OPVector
-    is_device_op = False
+    is_device_op = False  # host gather of the triples, then device fill
+    supports_staging = True
+
+    def transform_staged(self, batch: ColumnBatch):
+        """Host prologue: every column's latitudes, longitudes and accuracies
+        as three float32 vectors (bfloat16 on an accelerator's link, as
+        every real value; a vector each, so that the device slices no
+        ``[N, 3]`` operand along its lanes), its null bits packed, and the
+        fitted fills as their bit patterns (an operand, not a constant: the
+        program is the same for every fit; the bits, because a float32 wire
+        is rounded on the link).  Device body: the fill, the null column."""
+        track_nulls = self.get("track_nulls", True)
+        fills = np.asarray(self.fitted["fills"], np.float32)
+        wire = {"fills": fills.view(np.int32)}
+        for i, f in enumerate(self.input_features):
+            arr, mask = _geo_arrays(batch[f.name])
+            for k, part in enumerate(_PARTS):
+                wire[f"{part}{i}"] = np.ascontiguousarray(arr[:, k])
+            wire[f"null{i}"] = pack_bits(~mask)
+        count = len(fills)
+        meta = self.fitted["meta"]
+
+        def body(w):
+            fill = lax.bitcast_convert_type(jnp.asarray(w["fills"]),
+                                            jnp.float32)
+            outs = []
+            for i in range(count):
+                null = unpack_bits_device(w[f"null{i}"],
+                                          w[f"{_PARTS[0]}{i}"].shape[0])
+                for k, part in enumerate(_PARTS):
+                    x = jnp.asarray(w[f"{part}{i}"]).astype(jnp.float32)
+                    outs.append(jnp.where(null > 0, fill[i, k], x))
+                if track_nulls:
+                    outs.append(null)
+            return Column(OPVector, jnp.stack(outs, axis=1), meta=meta)
+
+        return wire, body
 
     def transform(self, batch: ColumnBatch) -> Column:
-        outs = []
-        for k, f in enumerate(self.input_features):
-            arr, mask = _geo_arrays(batch[f.name])
-            fill = np.asarray(self.fitted["fills"][k])
-            filled = np.where(mask[:, None], arr, fill[None, :])
-            outs.append(filled)
-            if self.get("track_nulls", True):
-                outs.append((~mask).astype(np.float32)[:, None])
-        out = np.concatenate(outs, axis=1)
-        return Column(OPVector, jnp.asarray(out), meta=self.fitted["meta"])
+        """The staged form run eagerly: one arithmetic for the fused program,
+        local scoring and every eager path."""
+        wire, body = self.transform_staged(batch)
+        return body(wire)
 
 
 class GeolocationVectorizer(Estimator):
@@ -60,11 +95,14 @@ class GeolocationVectorizer(Estimator):
         for f in self.input_features:
             arr, mask = _geo_arrays(batch[f.name])
             if self.get("fill_mode") == "mean" and mask.any():
-                fill = arr[mask].mean(axis=0)
+                # float64: a float32 running sum of millions of 40.75s drifts
+                fill = (np.add.reduce(arr, axis=0, dtype=np.float64,
+                                      where=mask[:, None])
+                        / mask.sum()).astype(np.float32)
             else:
                 fill = np.zeros(3, np.float32)
             fills.append(fill)
-            for d in ("lat", "lon", "accuracy"):
+            for d in _PARTS:
                 cols_meta.append(VectorColumnMeta(
                     f.name, f.kind.__name__, descriptor_value=d))
             if self.get("track_nulls", True):

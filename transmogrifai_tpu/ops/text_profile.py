@@ -116,9 +116,11 @@ class TextProfile:
     def crc_hist(self, text_bins: int) -> np.ndarray:
         """Hashed whole-value distribution over present rows — exactly
         filters._histogram_of's text branch (crc32 % text_bins)."""
-        bins = (self.crc[self.presence] % np.uint32(text_bins)).astype(
-            np.int64)
-        return np.bincount(bins, minlength=text_bins).astype(np.float64)
+        present, h = self.presence, np.zeros(text_bins)
+        for s in range(0, len(present), BLOCK_ROWS):    # temporaries in cache
+            crc = self.crc[s:s + BLOCK_ROWS][present[s:s + BLOCK_ROWS]]
+            h += np.bincount(crc % np.uint32(text_bins), minlength=text_bins)
+        return h
 
     def length_counts(self) -> Dict[int, int]:
         """≙ TextStats.length_counts (lengths of all non-null values)."""

@@ -21,7 +21,7 @@ import numpy as np
 
 from ..columns import Column, ColumnBatch
 from ..stages.base import Estimator, TransformerModel
-from ..telemetry import span
+from ..telemetry import REGISTRY, span
 from ..types import OPVector, RealNN
 from ..vector_meta import VectorMeta
 
@@ -297,6 +297,7 @@ class SanityChecker(Estimator):
             max_rule_conf = float(self.get("max_rule_confidence", 1.0))
             min_rule_supp = float(self.get("min_required_rule_support", 1.0))
             contingency_by_group: Dict[str, Dict] = {}
+            groups_dropped = 0
             for (parent, grouping), idxs in groups.items():
                 contingency = cont_all[:, [pos_of[i] for i in idxs]]  # [C, k]
                 # full contingency panel: Cramér's V + chi2 + PMI/MI + rule
@@ -320,8 +321,11 @@ class SanityChecker(Estimator):
                     if bad.any():
                         reasons.append("rule confidence leakage")
                 if reasons:
+                    groups_dropped += 1
                     for i in idxs:
                         group_fail.setdefault(i, []).extend(reasons)
+            # groups that fail whole, by Cramér's V or a rule's confidence
+            REGISTRY.counter("sanity.groups_dropped").inc(groups_dropped)
 
         with span("sanity.rules"):
             # per-column drop rules

@@ -333,10 +333,11 @@ class Workflow(_WorkflowCore):
 
     def _prefetch_text_profiles(self, batch) -> None:
         """Do up front what a training run will need of its raw columns:
-        every text column of a hashing vectorizer profiled ONCE
+        every text column of a hashing vectorizer or a pivot profiled ONCE
         (``ops.text_profile.profile_columns``: walked natively by row range
         on a few worker threads, interned in that walk at the stage's
-        ``max_cardinality`` so that the fit finds ``values(cap)`` cached,
+        ``max_cardinality``, a pivot's without a cap, so that the fit finds
+        ``values(cap)`` cached,
         its token ids packed for the stage's ``num_hashes`` by the same
         workers), the packed words handed to the link from this thread in
         feature order, and the bf16-wire copies of numeric raw columns +
@@ -350,6 +351,7 @@ class Workflow(_WorkflowCore):
         import jax
 
         from .columns import to_device_f32
+        from .ops.categorical import OneHotEstimator
         from .ops.text import HashingVectorizer, SmartTextVectorizer
         from .ops.text_profile import profile_columns
         from .telemetry import REGISTRY, span
@@ -359,10 +361,13 @@ class Workflow(_WorkflowCore):
             with span("prefetch.text_profiles", rows=len(batch)) as sp:
                 columns = []
                 for st in dag_stages(compute_dag(self.result_features)):
-                    if not isinstance(st, (SmartTextVectorizer,
-                                           HashingVectorizer)):
+                    if isinstance(st, OneHotEstimator):
+                        cap = -1        # a pivot counts every value
+                    elif isinstance(st, (SmartTextVectorizer,
+                                         HashingVectorizer)):
+                        cap = st.get("max_cardinality")  # None: no interning
+                    else:
                         continue
-                    cap = st.get("max_cardinality")     # None: no interning
                     for f in st.input_features:
                         col = batch.get(f.name)
                         if col is None or not col.is_host_object():
@@ -391,7 +396,9 @@ class Workflow(_WorkflowCore):
                     if col is None or col.is_host_object():
                         continue
                     v = col.values
-                    if (isinstance(v, np.ndarray)
+                    # a vector a column: a coordinate's triples cross as
+                    # their stage's wire makes them
+                    if (isinstance(v, np.ndarray) and v.ndim == 1
                             and v.dtype in (np.float32, np.float64)):
                         to_device_f32(v, exact=f.is_response)
         except Exception as e:  # noqa: BLE001 — prefetch must never break

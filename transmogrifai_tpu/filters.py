@@ -12,8 +12,11 @@ reductions are vectorised; text features hash into bins like the reference.
 
 from __future__ import annotations
 
+from concurrent.futures import Future
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
+from functools import partial
+from typing import (Any, Callable, Dict, Iterator, List, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -298,17 +301,39 @@ def _sharded_numeric_hist(mesh, arr, keep, lo, hi, bins: int) -> np.ndarray:
     return np.asarray(fn(i, m)).astype(np.float64)
 
 
-# Rows a block of a numeric column's walk.  A pass over a whole column of
-# millions of rows makes temporaries of tens of MB each (the float64 copy,
+# Rows a block of the numpy passes over a column.  A pass over a whole column
+# of millions of rows makes temporaries of tens of MB each (the float64 copy,
 # the finite ones, the kept values), which the allocator maps and the kernel
 # faults in anew for every pass; a block's stay in cache and are reused.
+# Who still walks by blocks: the range and the histogram of a numeric column
+# that ``_numdist`` cannot read as it is stored (no toolchain, another dtype)
+# and the label's sums in ``_CentredLabel``.  The native passes do not: they
+# read a column in place, one value at a time, and make no temporary.
 _BLOCK_ROWS = 1 << 16
+
+
+def _numdist(values, present):
+    """native/numdist.cpp where it can pass over ``values`` (and the mask
+    ``present``) as they are stored, with no GIL held; None where it cannot
+    — no toolchain, or a column that is not a 1-D float64, float32, int64
+    or int32 array — and the numpy passes by blocks stand."""
+    if not (isinstance(values, np.ndarray) and values.ndim == 1
+            and values.dtype.kind in "fi" and values.dtype.itemsize in (4, 8)
+            and values.dtype.isnative and values.flags.aligned):
+        return None
+    if present is not None and not (
+            isinstance(present, np.ndarray) and present.dtype == np.bool_
+            and present.shape == values.shape):
+        return None
+    from .native import load
+    return load("numdist")
 
 
 def _finite_blocks(values: np.ndarray, present: Optional[np.ndarray]
                    ) -> Iterator[np.ndarray]:
     """The present, finite values of a numeric column as float64, a block
-    of rows at a time."""
+    of rows at a time: the numpy form of what native/numdist.cpp reads in
+    place, and the plain statement its tests compare it with."""
     for s in range(0, len(values), _BLOCK_ROWS):
         x = np.asarray(values[s:s + _BLOCK_ROWS], dtype=np.float64)
         keep = np.isfinite(x)
@@ -320,11 +345,32 @@ def _finite_blocks(values: np.ndarray, present: Optional[np.ndarray]
 def _finite_range(values: np.ndarray, present: Optional[np.ndarray]
                   ) -> Optional[Tuple[float, float]]:
     """(min, max) of the present, finite values; None where there is none."""
+    native = _numdist(values, present)
+    if native is not None:
+        return native.range(values, present)
     lo, hi = np.inf, -np.inf
     for x in _finite_blocks(values, present):
         if x.size:
             lo, hi = min(lo, x.min()), max(hi, x.max())
     return (float(lo), float(hi)) if lo <= hi else None
+
+
+def _finite_histogram(values: np.ndarray, present: Optional[np.ndarray],
+                      lo: float, hi: float, bins: int) -> np.ndarray:
+    """``np.histogram``'s counts of the present, finite values over ``bins``
+    equal bins of [lo, hi], as float64: by native/numdist.cpp against the
+    edges numpy itself would make (so the counts are numpy's exactly), or by
+    numpy over blocks of rows."""
+    native = _numdist(values, present) if 0 < hi - lo < np.inf else None
+    if native is not None:
+        REGISTRY.counter("rff.native_columns").inc()
+        return native.histogram(values, present,
+                                np.linspace(lo, hi, bins + 1))
+    REGISTRY.counter("rff.numpy_columns").inc()
+    h = np.zeros(bins)
+    for x in _finite_blocks(values, present):
+        h += np.histogram(x, bins=bins, range=(lo, hi))[0]
+    return h
 
 
 def _array_item_bins(values: np.ndarray, present: np.ndarray,
@@ -528,10 +574,7 @@ def _histogram_of(vals, present: np.ndarray, kind, bins: int,
             if not keep.any():
                 return np.zeros(bins)
             return _sharded_numeric_hist(mesh, arr, keep, lo, hi, bins)
-        h = np.zeros(bins)
-        for x in _finite_blocks(vals, present):
-            h += np.histogram(x, bins=bins, range=(lo, hi))[0]
-        return h
+        return _finite_histogram(vals, present, lo, hi, bins)
     # text-ish: hash values into text_bins (≙ text hashed into bins)
     _python_rows().inc(len(vals))
     h = np.zeros(text_bins)
@@ -589,6 +632,60 @@ class RawFeatureFilterResults:
         }
 
 
+_COUNTERS = ("rff.python_rows", "rff.jobs", "rff.inline",
+             "rff.native_columns", "rff.numpy_columns")
+
+
+def _predictors(batch, score_batch, raw_features
+                ) -> Iterator[Tuple[Feature, Column, Optional[Column]]]:
+    """(feature, its train column, its score column or None) of every
+    predictor in ``batch``, in the features' order."""
+    for f in raw_features:
+        if f.name in batch and not f.is_response:
+            yield f, batch[f.name], (
+                score_batch[f.name] if score_batch is not None
+                and f.name in score_batch else None)
+
+
+def _passes_without_gil(f: Feature, col: Column) -> bool:
+    """Whether ``col``'s distribution holds no GIL while it passes over
+    rows, so that it may run beside other host work: an array (a numeric
+    column's native range and histogram, a list-valued array's
+    ``np.unique``) or strings (the native walk, or its cached profile).
+    Maps and other Python objects are walked in Python
+    (``rff.python_rows``) and stay with the thread that filters."""
+    if col.is_host_object():
+        return is_text_kind(f.kind)
+    return not isinstance(col.values, dict)
+
+
+class _StartedDistributions:
+    """What ``RawFeatureFilter.start_distributions`` left on a pool for one
+    ``filter_batch``: the batches and a future a feature."""
+
+    def __init__(self, batch, score_batch, pool):
+        self.batch, self.score_batch = batch, score_batch
+        self.jobs: Dict[str, Future] = {}
+        self._pool = pool
+        self._held: Dict[str, Callable[[], Any]] = {}
+
+    def add(self, name: str, job: Callable[[], Any], held: bool) -> None:
+        if held:
+            self._held[name] = job
+        else:
+            self.jobs[name] = self._pool.submit(job)
+
+    def walked(self, name: str) -> None:
+        """The caller has profiled the string column ``name``."""
+        job = self._held.pop(name, None)
+        if job is not None:
+            self.jobs[name] = self._pool.submit(job)
+
+    def cancel(self) -> None:
+        for job in self.jobs.values():
+            job.cancel()
+
+
 class RawFeatureFilter:
     """≙ RawFeatureFilter.scala: configurable thresholds, train + optional
     scoring reader."""
@@ -609,56 +706,128 @@ class RawFeatureFilter:
         self.text_bins = int(text_bins)
         self.score_reader = score_reader
         self.protected = set(protected_features)
+        self._started: Optional[_StartedDistributions] = None
+
+    def start_distributions(self, batch: ColumnBatch,
+                            raw_features: Sequence[Feature], pool,
+                            walked: Sequence[str] = ()
+                            ) -> "_StartedDistributions":
+        """Start on ``pool`` (a ``ThreadPoolExecutor``) what
+        ``filter_batch(batch, raw_features)`` will join: the score batch
+        read, and one job a predictor whose distribution passes over rows
+        with no GIL held (``_passes_without_gil``).  A column named in
+        ``walked`` is a string column the caller profiles itself: its job
+        waits until the caller says ``walked(name)`` on what is returned,
+        so that the column is walked once.  Every other predictor — and a
+        ``walked`` one never released — is computed by ``filter_batch``
+        itself, as it is when nothing was started."""
+        from .native import load
+        from .parallel.mesh import maybe_data_mesh
+        for module in ("numdist", "textprof"):
+            load(module)        # built and imported once, before the threads
+        score_batch = self._score_batch(raw_features)
+        started = _StartedDistributions(batch, score_batch, pool)
+        on_mesh = any(maybe_data_mesh(len(b)) is not None
+                      for b in (batch, score_batch) if b is not None)
+        for f, col, score_col in _predictors(batch, score_batch,
+                                             raw_features):
+            if not all(_passes_without_gil(f, c)
+                       for c in (col, score_col) if c is not None):
+                continue
+            # a histogram binned on the mesh is a device program: its
+            # dispatch stays with the thread that joins, fed by the job's range
+            started.add(f.name, partial(
+                self._feature_distributions, f, col, score_col,
+                histograms=not (on_mesh and is_numeric_kind(f.kind))),
+                held=f.name in walked)
+        self._started = started
+        return started
+
+    def _score_batch(self, raw_features) -> Optional[ColumnBatch]:
+        if self.score_reader is None:
+            return None
+        return self.score_reader.generate_batch(
+            [f for f in raw_features if not f.is_response])
 
     def filter_batch(self, batch: ColumnBatch, raw_features: Sequence[Feature]
                      ) -> Tuple[ColumnBatch, List[Feature], RawFeatureFilterResults]:
         """≙ generateFilteredRaw:486: returns (clean batch, dropped features,
-        results)."""
+        results).  What ``start_distributions`` started for this ``batch``
+        is joined here, where the rules first need it; the rest, or all of
+        it where nothing was started, is computed here by the same
+        function."""
+        started, self._started = self._started, None
+        if started is not None and started.batch is not batch:
+            started.cancel()
+            started = None
         results = RawFeatureFilterResults()
         label: Optional[_CentredLabel] = None
         label_name = next((f.name for f in raw_features if f.is_response), None)
         if label_name and label_name in batch:
             label = _CentredLabel(batch[label_name].values)
 
-        score_batch = None
-        if self.score_reader is not None:
-            score_batch = self.score_reader.generate_batch(
-                [f for f in raw_features if not f.is_response])
-
-        _python_rows()      # at 0 where no column is walked in Python
+        score_batch = (started.score_batch if started is not None
+                       else self._score_batch(raw_features))
+        for name in _COUNTERS:      # at 0 where nothing counts
+            REGISTRY.counter(name)
         with span("rff.distributions", features=len(raw_features)):
             per_feature = self._distributions(batch, score_batch,
-                                              raw_features, results)
+                                              raw_features, results, started)
         with span("rff.decide", features=len(per_feature)):
             for f, fdists, sdists in per_feature:
                 self._decide(f, fdists, sdists, batch, label, results)
             return self._clean(batch, raw_features, results)
 
-    def _distributions(self, batch, score_batch, raw_features, results):
+    def _distributions(self, batch, score_batch, raw_features, results,
+                       started=None):
         """[(feature, its train distributions, its score distributions)] of
-        every predictor in ``batch``; both lists also go into ``results``."""
+        every predictor in ``batch``, in the features' order; both lists
+        also go into ``results``.  A predictor ``started`` has a job for is
+        waited for (what the job raised is raised here), any other is
+        computed on this thread."""
         per_feature = []
-        for f in raw_features:
-            if f.name not in batch or f.is_response:
-                continue
-            # shared Summary range over BOTH readers so train and score bin
-            # identically (≙ Summary.scala) — a mean shift must move mass to
-            # different bins, or JS divergence can never see it
-            ranges = numeric_ranges(f, batch[f.name])
-            score_col = (score_batch[f.name] if score_batch is not None
-                         and f.name in score_batch else None)
-            if score_col is not None:
-                ranges = merge_ranges(ranges, numeric_ranges(f, score_col))
-            fdists = compute_distribution(f, batch[f.name], self.bins,
-                                          self.text_bins, ranges=ranges)
+        for f, col, score_col in _predictors(batch, score_batch,
+                                             raw_features):
+            job = started.jobs.get(f.name) if started is not None else None
+            REGISTRY.counter("rff.inline" if job is None else "rff.jobs").inc()
+            ranges, dists = (
+                self._feature_distributions(f, col, score_col) if job is None
+                else job.result())      # raises what the job raised
+            if dists is None:           # binned on the mesh, from this thread
+                _, dists = self._feature_distributions(f, col, score_col,
+                                                       ranges)
+            fdists, sdists = dists
             results.train_distributions.extend(fdists)
+            results.score_distributions.extend(sdists)
+            per_feature.append((f, fdists, sdists))
+        return per_feature
+
+    def _feature_distributions(self, f, col, score_col, ranges=None,
+                               histograms=True):
+        """(ranges, (train distributions, score distributions)) of one
+        predictor: the one function a distribution is computed by, whether a
+        pool's worker runs it or the thread that filters.  ``ranges``: the
+        Summary ranges where a job has found them; ``histograms`` False:
+        the ranges alone, None for the rest."""
+        with span("rff.feature", feature=f.name, kind=f.kind.__name__,
+                  rows=len(col)):
+            if ranges is None:
+                # shared Summary range over BOTH readers so train and score
+                # bin identically (≙ Summary.scala) — a mean shift must move
+                # mass to different bins, or JS divergence can never see it
+                ranges = numeric_ranges(f, col)
+                if score_col is not None:
+                    ranges = merge_ranges(ranges,
+                                          numeric_ranges(f, score_col))
+            if not histograms:
+                return ranges, None
+            fdists = compute_distribution(f, col, self.bins, self.text_bins,
+                                          ranges=ranges)
             sdists: List[FeatureDistribution] = []
             if score_col is not None:
                 sdists = compute_distribution(f, score_col, self.bins,
                                               self.text_bins, ranges=ranges)
-                results.score_distributions.extend(sdists)
-            per_feature.append((f, fdists, sdists))
-        return per_feature
+            return ranges, (fdists, sdists)
 
     def _decide(self, f, fdists, sdists, batch, label, results) -> None:
         """Record in ``results`` whether ``f`` (or some of its map keys) is

@@ -25,7 +25,7 @@ import sysconfig
 import warnings
 from typing import Any, Dict, Optional
 
-MODULES = ("fastcsv", "fasttok", "locofmt", "mapprof", "textprof")
+MODULES = ("fastcsv", "fasttok", "locofmt", "mapprof", "numdist", "textprof")
 
 _CACHE: dict = {}
 _REASONS: Dict[str, str] = {}

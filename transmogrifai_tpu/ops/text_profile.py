@@ -52,7 +52,9 @@ from __future__ import annotations
 
 import os
 import zlib
+from collections import deque
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from functools import partial
 from typing import (Callable, Dict, Generator, Iterator, List, Optional,
@@ -468,9 +470,29 @@ MIN_RANGE_BLOCKS = 6
 
 
 def pool_size(items: int) -> int:
-    """Worker threads ``profile_columns`` spreads that many work items over:
-    the cores this process may run on, up to ``_MAX_WORKERS``."""
+    """Walks and packs ``profile_columns`` keeps under way at once for that
+    many work items: the cores this process may run on, up to
+    ``_MAX_WORKERS``."""
     return min(items, len(os.sched_getaffinity(0)), _MAX_WORKERS)
+
+
+@contextmanager
+def host_pool(free_jobs: int) -> Iterator[Optional[ThreadPoolExecutor]]:
+    """The worker threads of one batch's host prologue, opened once by who
+    runs it (``Workflow.train``): ``profile_columns`` keeps its
+    ``pool_size`` walks on them, bound by the GIL as they are, and
+    ``free_jobs`` more items that hold no GIL while they pass over rows
+    (RawFeatureFilter's distributions) take the cores beyond.  None on one
+    core: every item then runs on the thread that asks for it."""
+    workers = min(len(os.sched_getaffinity(0)), _MAX_WORKERS + free_jobs)
+    if workers < 2:
+        yield None
+        return
+    pool = ThreadPoolExecutor(workers, thread_name_prefix="prologue")
+    try:
+        yield pool
+    finally:
+        pool.shutdown(cancel_futures=True)
 
 
 def _ranges(start: int, rows: int, most: int) -> List[Tuple[int, int]]:
@@ -560,11 +582,14 @@ def _run_here(plan: Generator[Jobs, list, TextProfile]) -> TextProfile:
 
 
 def _run_on(pool: ThreadPoolExecutor,
-            plans: List[Generator[Jobs, list, TextProfile]]
-            ) -> Iterator[TextProfile]:
-    """Every plan's jobs on ``pool``, all plans under way at once, each
-    plan's profile yielded in the plans' order as soon as it is whole.  The
-    plans themselves advance on the calling thread, between the waits."""
+            plans: List[Generator[Jobs, list, TextProfile]],
+            width: int) -> Iterator[TextProfile]:
+    """Every plan's jobs on ``pool``, at most ``width`` of them out at once
+    (the pool may be wider, and others' work on it), all plans under way
+    together, each plan's profile yielded in the plans' order as soon as it
+    is whole.  The plans themselves advance on the calling thread, between
+    the waits."""
+    ready = deque()     # (plan, job, the callable) not yet on the pool
     running = {}        # future -> (plan, job) it is
     step = {}           # plan -> [results so far, jobs still out]
     whole = {}          # plan -> its profile
@@ -576,31 +601,44 @@ def _run_on(pool: ThreadPoolExecutor,
             whole[i] = stop.value
             return
         step[i] = [[None] * len(jobs), len(jobs)]
-        for k, job in enumerate(jobs):
+        ready.extend((i, k, job) for k, job in enumerate(jobs))
+
+    def submit() -> None:
+        while ready and len(running) < width:
+            i, k, job = ready.popleft()
             running[pool.submit(job)] = (i, k)
 
-    for i in range(len(plans)):
-        advance(i, None)
-    for i in range(len(plans)):
-        while i not in whole:
-            for done in wait(running, return_when=FIRST_COMPLETED).done:
-                j, k = running.pop(done)
-                step[j][0][k] = done.result()       # raises what the job did
-                step[j][1] -= 1
-                if not step[j][1]:
-                    advance(j, step.pop(j)[0])
-        yield whole.pop(i)
+    try:
+        for i in range(len(plans)):
+            advance(i, None)
+        for i in range(len(plans)):
+            while i not in whole:
+                submit()
+                for done in wait(running, return_when=FIRST_COMPLETED).done:
+                    j, k = running.pop(done)
+                    step[j][0][k] = done.result()   # raises what the job did
+                    step[j][1] -= 1
+                    if not step[j][1]:
+                        advance(j, step.pop(j)[0])
+            submit()        # the caller may take its time with a profile
+            yield whole.pop(i)
+    finally:
+        for future in running:      # a caller that stopped early, or a raise
+            future.cancel()
 
 
 def profile_columns(columns: Sequence[Tuple[object, Optional[int],
-                                            Optional[int]]]
+                                            Optional[int]]],
+                    pool: Optional[ThreadPoolExecutor] = None
                     ) -> Iterator[TextProfile]:
     """``column_profile(col, cap)`` of every (column, cap, num_hashes)
     triple, yielded in order, each with its packed words for ``num_hashes``
     ready on the host (``TextProfile.device_ids`` then only starts the
     transfer), the work spread over ``pool_size`` worker threads by row
     range (``_column_plan``), so the caller works on a profile while later
-    columns are still walked.  One worker is a plain loop."""
+    columns are still walked.  The threads are ``pool``'s (``host_pool``,
+    which a train opens for its whole prologue), or a pool of its own for
+    the length of the call.  One worker is a plain loop."""
     from ..native import load
 
     walks = sum(_planned_walks(len(col), cap) for col, cap, _ in columns
@@ -614,8 +652,6 @@ def profile_columns(columns: Sequence[Tuple[object, Optional[int],
         yield from map(_run_here, plans)
         return
     load("textprof")        # built and imported once, before the threads
-    pool = ThreadPoolExecutor(workers)
-    try:
-        yield from _run_on(pool, plans)
-    finally:
-        pool.shutdown(cancel_futures=True)
+    with (nullcontext(pool) if pool is not None
+          else ThreadPoolExecutor(workers)) as threads:
+        yield from _run_on(threads, plans, workers)

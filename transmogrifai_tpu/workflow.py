@@ -12,6 +12,7 @@ unnecessary — HBM residency + XLA fusion replace them, SURVEY.md §2.6 P5).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -295,16 +296,24 @@ class Workflow(_WorkflowCore):
                                      stage="train")
             else:
                 batch = self.generate_raw_data()
-        with timer.phase("prefetch"):
-            self._prefetch_text_profiles(batch)
+        from .ops.text_profile import host_pool
         rff_results = None
-        if self._raw_feature_filter is not None:
-            with timer.phase("rff"):
-                batch, dropped, rff_results = \
-                    self._raw_feature_filter.filter_batch(
-                        batch, self.raw_features)
-                self.blacklisted = dropped
-                self._apply_blacklist()
+        # one pool for the prologue's host work over rows: the string walks
+        # and RawFeatureFilter's distributions start on it side by side, and
+        # the filter joins its own where its rules first need them.  Large
+        # batches only: a tiny one is done before a thread has started.
+        with (host_pool(len(self.raw_features))
+              if len(batch) >= PREFETCH_MIN_ROWS
+              else contextlib.nullcontext()) as pool:
+            with timer.phase("prefetch"):
+                self._prefetch_text_profiles(batch, pool)
+            if self._raw_feature_filter is not None:
+                with timer.phase("rff"):
+                    batch, dropped, rff_results = \
+                        self._raw_feature_filter.filter_batch(
+                            batch, self.raw_features)
+                    self.blacklisted = dropped
+                    self._apply_blacklist()
         dag = compute_dag(self.result_features)
         if self._sanitizers.get("serialization"):
             audit_stage_serialization(dag_stages(dag))
@@ -331,58 +340,56 @@ class Workflow(_WorkflowCore):
         model.failure_log = flog
         return model
 
-    def _prefetch_text_profiles(self, batch) -> None:
-        """Do up front what a training run will need of its raw columns:
-        every text column of a hashing vectorizer or a pivot profiled ONCE
-        (``ops.text_profile.profile_columns``: walked natively by row range
-        on a few worker threads, interned in that walk at the stage's
-        ``max_cardinality``, a pivot's without a cap, so that the fit finds
-        ``values(cap)`` cached,
-        its token ids packed for the stage's ``num_hashes`` by the same
-        workers), the packed words handed to the link from this thread in
+    def _prefetch_text_profiles(self, batch, pool=None) -> None:
+        """Start up front what a training run will need of its raw columns.
+        Large batches only: tiny workflows would pay dispatch latency for
+        nothing.
+
+        On every backend, where the train has a ``pool``
+        (``ops.text_profile.host_pool``) and a RawFeatureFilter: the
+        filter's distributions (``RawFeatureFilter.start_distributions``),
+        a job a predictor held as an array or as strings, which
+        ``filter_batch`` joins where its rules need them — they hide host
+        work behind host work, not the link.
+
+        On an accelerator, also: every text column of a hashing vectorizer
+        or a pivot profiled ONCE (``ops.text_profile.profile_columns``:
+        walked natively by row range on a few of the pool's threads,
+        interned in that walk at the stage's ``max_cardinality``, a pivot's
+        without a cap, so that the fit finds ``values(cap)`` cached, its
+        token ids packed for the stage's ``num_hashes`` by the same
+        workers; the filter's job for such a column starts when its walk
+        is whole), the packed words handed to the link from this thread in
         feature order, and the bf16-wire copies of numeric raw columns +
-        the label.  The async host→device transfers
-        then overlap RawFeatureFilter + fit host work instead of
-        serializing after it (the TPU analog of the reference keeping row
-        work on executors, SmartTextVectorizer.scala:80).  Large batches
-        only: tiny workflows would pay dispatch latency for nothing."""
+        the label.  The async host→device transfers then overlap
+        RawFeatureFilter + fit host work instead of serializing after it
+        (the TPU analog of the reference keeping row work on executors,
+        SmartTextVectorizer.scala:80); the CPU has no slow link to hide."""
         if len(batch) < PREFETCH_MIN_ROWS:
             return
         import jax
 
         from .columns import to_device_f32
-        from .ops.categorical import OneHotEstimator
-        from .ops.text import HashingVectorizer, SmartTextVectorizer
         from .ops.text_profile import profile_columns
         from .telemetry import REGISTRY, span
-        if jax.default_backend() == "cpu":
-            return      # no slow link to hide
+        accelerator = jax.default_backend() != "cpu"
+        columns = self._profiled_columns(batch) if accelerator else []
+        started = None
+        if pool is not None and self._raw_feature_filter is not None:
+            started = self._raw_feature_filter.start_distributions(
+                batch, self.raw_features, pool,
+                walked=[name for name, _ in columns])
+        if not accelerator:
+            return
         try:
             with span("prefetch.text_profiles", rows=len(batch)) as sp:
-                columns = []
-                for st in dag_stages(compute_dag(self.result_features)):
-                    if isinstance(st, OneHotEstimator):
-                        cap = -1        # a pivot counts every value
-                    elif isinstance(st, (SmartTextVectorizer,
-                                         HashingVectorizer)):
-                        cap = st.get("max_cardinality")  # None: no interning
-                    else:
-                        continue
-                    for f in st.input_features:
-                        col = batch.get(f.name)
-                        if col is None or not col.is_host_object():
-                            continue
-                        vals = col.values
-                        if len(vals) and not isinstance(
-                                next((v for v in vals if v is not None), ""),
-                                str):
-                            continue    # token lists take the legacy path
-                        columns.append(
-                            (col, cap, int(st.get("num_hashes") or 0)))
-                for prof, (_, _, num_hashes) in zip(
-                        profile_columns(columns), columns):
+                triples = [triple for _, triple in columns]
+                for prof, (name, (_, _, num_hashes)) in zip(
+                        profile_columns(triples, pool), columns):
                     if num_hashes:
                         prof.prefetch(num_hashes)
+                    if started is not None:
+                        started.walked(name)
                 if sp is not None:
                     sp.attrs.update(
                         columns=len(columns),
@@ -407,6 +414,32 @@ class Workflow(_WorkflowCore):
             from .resilience import record_failure
             record_failure("workflow.prefetch", "swallowed", e,
                            point="workflow.prefetch")
+
+    def _profiled_columns(self, batch):
+        """[(feature name, (column, cap, num_hashes))] of the string columns
+        a hashing vectorizer or a pivot of this workflow reads: what
+        ``profile_columns`` walks ahead of their fits."""
+        from .ops.categorical import OneHotEstimator
+        from .ops.text import HashingVectorizer, SmartTextVectorizer
+        columns = []
+        for st in dag_stages(compute_dag(self.result_features)):
+            if isinstance(st, OneHotEstimator):
+                cap = -1        # a pivot counts every value
+            elif isinstance(st, (SmartTextVectorizer, HashingVectorizer)):
+                cap = st.get("max_cardinality")  # None: no interning
+            else:
+                continue
+            for f in st.input_features:
+                col = batch.get(f.name)
+                if col is None or not col.is_host_object():
+                    continue
+                vals = col.values
+                if len(vals) and not isinstance(
+                        next((v for v in vals if v is not None), ""), str):
+                    continue    # token lists take the legacy path
+                columns.append(
+                    (f.name, (col, cap, int(st.get("num_hashes") or 0))))
+        return columns
 
     def _fit_plain(self, batch, dag, timer=None):
         """Fit the DAG with DEFERRED transform application: estimators fit

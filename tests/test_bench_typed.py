@@ -819,9 +819,12 @@ def test_readers_and_appended_cells_say_what_benchmark_json_says():
               "refit_s", "train_jit_s", "text_profile_s")
     for name in joined:
         assert entries[name]["workloads"][-1] == CELL, name
-    for name in set(entries) - set(joined) - set(NEW_READERS):
+    # what ISSUE 39 appended after them reads something in every cell
+    every_cell = ("prologue_wait_s", "prologue_queue_s", "stage_fit_s")
+    for name in set(entries) - set(joined) - set(NEW_READERS) - set(
+            every_cell):
         assert CELL not in entries[name]["workloads"], name
-    assert [m["name"] for m in MANIFEST["per_layer"][-3:]] == list(
+    assert [m["name"] for m in MANIFEST["per_layer"][21:24]] == list(
         NEW_READERS)
     assert [w["name"] for w in MANIFEST["workloads"]] == [
         "mixed_sweep", "mixed_sweep_x4", "text_sweep", CELL]

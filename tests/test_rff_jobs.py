@@ -7,7 +7,6 @@ time."""
 
 import os
 import threading
-from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import numpy as np
@@ -228,10 +227,15 @@ def test_jobs_on_a_pool_give_what_the_caller_computes(score):
     reader = BatchReader(typed_batch(seed=6, shift=400.0)[0]) \
         if score.startswith("a") else None
     kw = dict(max_js_divergence=0.5, score_reader=reader)
-    names = ("rff.jobs", "rff.inline", "rff.python_rows")
+    names = ("rff.python_rows",)
     before = counters(*names)
-    _, dropped, alone = filters.RawFeatureFilter(**kw).filter_batch(batch, raw)
-    assert moved(before)["rff.jobs"] == 0 and moved(before)["rff.inline"] == 8
+    tracer = Tracer("alone")
+    with use_tracer(tracer):
+        _, dropped, alone = filters.RawFeatureFilter(**kw).filter_batch(
+            batch, raw)
+    inline = [s for s in tracer.spans if s.name == "rff.feature"]
+    assert len(inline) == 8 and {s.thread for s in inline} == {
+        threading.get_ident()}
     python_rows = moved(before)["rff.python_rows"]
     assert python_rows > 0
 
@@ -246,7 +250,7 @@ def test_jobs_on_a_pool_give_what_the_caller_computes(score):
         return real(f, *a, **k)
 
     rff._feature_distributions = spy
-    with ThreadPoolExecutor(3) as pool:
+    with tp.HostPool(3) as pool:
         started = rff.start_distributions(batch, raw, pool)
         assert sorted(started.jobs) == ["amount", "at", "count", "pay",
                                         "when", "who"]
@@ -256,8 +260,7 @@ def test_jobs_on_a_pool_give_what_the_caller_computes(score):
     assert joined.to_json() == alone.to_json()
     assert [f.name for f in dropped_too] == [f.name for f in dropped]
     assert ("amount" in alone.dropped) == bool(reader)
-    assert moved(before) == {"rff.jobs": 6, "rff.inline": 2,
-                             "rff.python_rows": python_rows}
+    assert moved(before) == {"rff.python_rows": python_rows}
     if reader:
         assert reader.reads == reads + 1
     here = threading.get_ident()
@@ -288,14 +291,13 @@ def test_many_jobs_on_more_threads_than_cores_lose_no_count():
         return ColumnBatch(cols, rows), [label] + list(predictors)
 
     alone = filters.RawFeatureFilter().filter_batch(*batch_and_features())[2]
-    names = ("rff.jobs", "rff.inline", "rff.native_columns",
-             "rff.numpy_columns", "text_profile.scan")
+    names = ("rff.native_columns", "rff.numpy_columns", "text_profile.scan")
     batch, raw = batch_and_features()
     rff = filters.RawFeatureFilter()
     before, interval = counters(*names), sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
-        with ThreadPoolExecutor(24) as pool:
+        with tp.HostPool(24) as pool:
             started = rff.start_distributions(batch, raw, pool)
             for job in started.jobs.values():
                 job.result(timeout=120)
@@ -305,8 +307,7 @@ def test_many_jobs_on_more_threads_than_cores_lose_no_count():
     binned = moved(before)
     assert binned.pop("rff.native_columns") + binned.pop(
         "rff.numpy_columns") == 40
-    assert binned == {"rff.jobs": 52, "rff.inline": 0,
-                      "text_profile.scan": 12}
+    assert binned == {"text_profile.scan": 12} and len(started.jobs) == 52
     assert got.to_json() == alone.to_json()
 
 
@@ -314,11 +315,16 @@ def test_what_was_started_for_another_batch_is_not_joined():
     batch, raw = typed_batch()
     other, _ = typed_batch(seed=9)
     rff = filters.RawFeatureFilter()
-    before = counters("rff.jobs", "rff.inline")
-    with ThreadPoolExecutor(2) as pool:
-        rff.start_distributions(other, raw, pool)
+    here = []
+    real = rff._feature_distributions
+    with tp.HostPool(2) as pool:
+        started = rff.start_distributions(other, raw, pool)
+        rff._feature_distributions = lambda f, *a, **k: (
+            here.append((f.name, threading.get_ident())) or real(f, *a, **k))
         _, _, got = rff.filter_batch(batch, raw)
-    assert moved(before) == {"rff.jobs": 0, "rff.inline": 8}
+    # the six jobs started for the other batch are left; all eight here
+    assert len(started.jobs) == 6 and len(here) == 8
+    assert {t for _, t in here} == {threading.get_ident()}
     assert got.to_json() == filters.RawFeatureFilter().filter_batch(
         *typed_batch())[2].to_json()
 
@@ -326,18 +332,23 @@ def test_what_was_started_for_another_batch_is_not_joined():
 def test_a_walked_column_waits_for_its_walk_and_one_never_released_is_inline():
     batch, raw = typed_batch()
     rff = filters.RawFeatureFilter()
-    before = counters("rff.jobs", "rff.inline", "text_profile.scan")
-    with ThreadPoolExecutor(2) as pool:
+    before = counters("text_profile.scan")
+    inline = []
+    real = rff._feature_distributions
+    with tp.HostPool(2) as pool:
         started = rff.start_distributions(batch, raw, pool,
                                           walked=["pay", "who"])
+        rff._feature_distributions = lambda f, *a, **k: (
+            inline.append(f.name) or real(f, *a, **k))
         assert "pay" not in started.jobs and "who" not in started.jobs
         tp.column_profile(batch["pay"], 30)         # the caller's walk
         started.walked("pay")
         started.walked("pay")
         started.jobs["pay"].result(timeout=60)
         _, _, got = rff.filter_batch(batch, raw)
-    assert moved(before) == {"rff.jobs": 5, "rff.inline": 3,
-                             "text_profile.scan": 2}
+    assert moved(before) == {"text_profile.scan": 2}
+    assert sorted(started.jobs) == ["amount", "at", "count", "pay", "when"]
+    assert sorted(inline) == ["loose", "m", "who"]
     assert got.to_json() == filters.RawFeatureFilter().filter_batch(
         *typed_batch())[2].to_json()
 
@@ -380,8 +391,8 @@ def typed_workflow(rows=600, seed=3, score_reader=None, **rff):
             .with_raw_feature_filter(score_reader=score_reader, **rff))
 
 
-RFF_COUNTERS = ("rff.jobs", "rff.inline", "rff.native_columns",
-                "rff.numpy_columns", "rff.python_rows", "text_profile.scan")
+RFF_COUNTERS = ("rff.native_columns", "rff.numpy_columns", "rff.python_rows",
+                "text_profile.scan")
 
 
 @pytest.fixture
@@ -406,8 +417,9 @@ def test_a_large_train_computes_every_distribution_off_its_thread(
     with use_tracer(tracer):
         model = typed_workflow().train()
     assert moved(before) == {
-        "rff.jobs": 5, "rff.inline": 0, "rff.native_columns": 3,
-        "rff.numpy_columns": 0, "rff.python_rows": 0, "text_profile.scan": 2}
+        "rff.native_columns": 3, "rff.numpy_columns": 0, "rff.python_rows": 0,
+        "text_profile.scan": 2}
+    assert REGISTRY.gauge("prologue.workers").value == 6
     spans = tracer.spans
     by_id = {s.span_id: s for s in spans}
     features = [s for s in spans if s.name == "rff.feature"]
@@ -422,8 +434,10 @@ def test_a_large_train_computes_every_distribution_off_its_thread(
     for s in features:
         parent = by_id[s.parent_id]
         assert s.thread != train.thread and parent.thread == train.thread
+        # a job a worker starts between two phases (``PhaseTimer`` reads
+        # the devices' memory after a phase's span closes) is the train's
         assert parent.name in ("phase.prefetch", "phase.rff",
-                               "rff.distributions")
+                               "rff.distributions", "workflow.train")
         assert parent.start_s <= s.start_s
     assert not [s for s in spans if s.name.startswith("prefetch.")]
     profile = REGISTRY.gauge("train.span_profile").value
@@ -441,9 +455,10 @@ def test_a_large_train_computes_every_distribution_off_its_thread(
     with use_tracer(tracer):
         one_core = typed_workflow().train()
     assert moved(before) == {
-        "rff.jobs": 0, "rff.inline": 5, "rff.native_columns": 3,
-        "rff.numpy_columns": 0, "rff.python_rows": 0, "text_profile.scan": 2}
-    assert {s.thread for s in tracer.spans if s.name == "rff.feature"} == {
+        "rff.native_columns": 3, "rff.numpy_columns": 0, "rff.python_rows": 0,
+        "text_profile.scan": 2}
+    inline = [s for s in tracer.spans if s.name == "rff.feature"]
+    assert len(inline) == 5 and {s.thread for s in inline} == {
         threading.get_ident()}
     wf = typed_workflow()
     alone = wf._raw_feature_filter.filter_batch(wf.generate_raw_data(),
@@ -458,9 +473,12 @@ def test_a_small_train_starts_nothing(monkeypatch):
     real = tp.host_pool
     monkeypatch.setattr(tp, "host_pool",
                         lambda n: opened.append(n) or real(n))
-    before = counters("rff.jobs", "rff.inline")
-    typed_workflow().train()
-    assert moved(before) == {"rff.jobs": 0, "rff.inline": 5} and not opened
+    tracer = Tracer("small")
+    with use_tracer(tracer):
+        typed_workflow().train()
+    inline = [s for s in tracer.spans if s.name == "rff.feature"]
+    assert len(inline) == 5 and {s.thread for s in inline} == {
+        threading.get_ident()} and not opened
 
 
 def test_a_job_that_raises_surfaces_from_the_filter(large, monkeypatch):
@@ -491,9 +509,13 @@ def test_a_score_reader_drops_what_it_dropped_before(large, monkeypatch):
     x2 = shifted["x2"]
     shifted = shifted.with_column("x2", Column(x2.kind, x2.values + 50.0))
     reader = BatchReader(shifted)
-    before = counters("rff.jobs", "rff.inline")
-    model = typed_workflow(score_reader=reader, max_js_divergence=0.5).train()
-    assert moved(before) == {"rff.jobs": 5, "rff.inline": 0}
+    tracer = Tracer("score reader")
+    with use_tracer(tracer):
+        model = typed_workflow(score_reader=reader,
+                               max_js_divergence=0.5).train()
+    jobs = [s for s in tracer.spans if s.name == "rff.feature"]
+    assert len(jobs) == 5 and threading.get_ident() not in {
+        s.thread for s in jobs}
     assert reader.reads == 1
     assert model.rff_results.dropped == ["x2"]
     assert [f.name for f in model.blacklisted] == ["x2"]
@@ -525,13 +547,17 @@ def test_on_an_accelerator_a_strings_job_follows_its_walk(large,
                                                         wf.raw_features)
     monkeypatch.undo()
     assert moved(before) == {
-        "rff.jobs": 5, "rff.inline": 0,
         "rff.native_columns": 3 * (native.load("numdist") is not None),
         "rff.numpy_columns": 3 * (native.load("numdist") is None),
         "rff.python_rows": 0, "text_profile.scan": 2,
         "text_profile.fused_intern": 2}
-    (walks,) = [s for s in tracer.spans if s.name == "prefetch.text_profiles"]
-    assert walks.attrs["columns"] == 2
+    (prefetch,) = [s for s in tracer.spans
+                   if s.name == "prefetch.text_profiles"]
+    assert prefetch.attrs["columns"] == 2
+    walks = [s for s in tracer.spans if s.name == "prefetch.walk"]
+    assert sorted((s.attrs["column"], s.attrs["kind"]) for s in walks) == [
+        ("cat", "whole"), ("txt", "whole")]
+    assert threading.get_ident() not in {s.thread for s in walks}
     wf = typed_workflow()
     assert got.to_json() == wf._raw_feature_filter.filter_batch(
         wf.generate_raw_data(), wf.raw_features)[2].to_json()
@@ -563,8 +589,8 @@ def test_under_a_mesh_the_job_finds_the_range_and_the_caller_dispatches(
                                                             wf.raw_features)
     assert dispatched == [threading.get_ident()] * 3
     assert moved(before) == {
-        "rff.jobs": 5, "rff.inline": 0, "rff.native_columns": 0,
-        "rff.numpy_columns": 0, "rff.python_rows": 0, "text_profile.scan": 2}
+        "rff.native_columns": 0, "rff.numpy_columns": 0, "rff.python_rows": 0,
+        "text_profile.scan": 2}
     by_feature = {}
     for s in tracer.spans:
         if s.name == "rff.feature":
@@ -586,6 +612,7 @@ def test_the_walks_keep_to_their_width_on_a_wider_pool(monkeypatch):
     if native.load("textprof") is None:
         pytest.skip("no native toolchain")
     monkeypatch.setattr(tp, "_MAX_WORKERS", 2)
+    monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(8)))
     lock, out, most = threading.Lock(), [0], [0]
     real = tp.scan_strings
 
@@ -604,9 +631,12 @@ def test_the_walks_keep_to_their_width_on_a_wider_pool(monkeypatch):
     cols = [Column(T.Text, np.asarray(
         [f"v{(i * (j + 3)) % 997} w" for i in range(rows)], dtype=object))
         for j in range(9)]
+    before = counters("prologue.queue_s")
     with tp.host_pool(8) as pool:
         profs = list(tp.profile_columns([(c, 30, 64) for c in cols], pool))
-        assert REGISTRY.gauge("text_profile.workers").value == 2
+        assert REGISTRY.gauge("prologue.workers").value == pool.workers > 2
         assert pool.submit(lambda: 7).result(timeout=60) == 7
     assert 1 <= most[0] <= 2 and out[0] == 0
+    # nine walks on a width of two: the width held some back
+    assert moved(before)["prologue.queue_s"] > 0.0
     assert [p.tokens for p in profs] == [2 * rows] * 9

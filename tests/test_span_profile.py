@@ -216,7 +216,10 @@ def test_prologue_span_is_there_closed_and_under_its_phase(narrow, name):
 def test_transform_children_lie_under_transform_apply(narrow):
     spans = narrow[0].spans
     for sp in spans:
-        if sp.name.startswith("transform.") and sp.name != "transform.apply":
+        if sp.name.startswith("transform.fit."):    # a fit, not a flush
+            assert chain(spans, sp)[1].startswith("phase.fit:")
+        elif sp.name.startswith("transform.") \
+                and sp.name != "transform.apply":
             assert "transform.apply" in chain(spans, sp)
         if sp.name.startswith("sanity.") and sp.name != "sanity.fit":
             assert chain(spans, sp)[1] == "sanity.fit"
@@ -348,7 +351,7 @@ def test_a_ranged_prefetch_leaves_what_the_text_metrics_read(monkeypatch):
     attrs on every piece) and ``prefetch.text_profiles`` are in
     ``train.span_profile``, so ``text_pack_s`` and ``text_profile_s`` read
     numbers; the wire's counters move by what one walk and one numpy pack
-    moved them by; the range counters say how the column was cut."""
+    moved them by; the walks' spans say how the column was cut."""
     from transmogrifai_tpu import workflow as workflow_mod
     from transmogrifai_tpu.ops import text_profile as tp
     from transmogrifai_tpu.ops.text import (SmartTextVectorizer,
@@ -371,8 +374,7 @@ def test_a_ranged_prefetch_leaves_what_the_text_metrics_read(monkeypatch):
     monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(4)))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     names = ("text.tokens", "text.token_slots", "text.pack_native",
-             "text.pack_numpy", "text_profile.range_walks",
-             "text_profile.scan")
+             "text.pack_numpy", "text_profile.scan")
     before = {k: REGISTRY.counters().get(k, 0) for k in names}
     link = profiling.host_link_bytes()
     tracer = Tracer("ranged-prefetch")
@@ -384,10 +386,13 @@ def test_a_ranged_prefetch_leaves_what_the_text_metrics_read(monkeypatch):
     moved = {k: REGISTRY.counters().get(k, 0) - v for k, v in before.items()}
     assert moved == {"text.tokens": whole.tokens,
                      "text.token_slots": 3 * capacity, "text.pack_native": 1,
-                     "text.pack_numpy": 0, "text_profile.range_walks": 2,
-                     "text_profile.scan": 1}
+                     "text.pack_numpy": 0, "text_profile.scan": 1}
     assert profiling.host_link_bytes() - link == 4 * capacity
-    assert REGISTRY.gauge("text_profile.ranges").value == 3
+    walks = [s for s in tracer.spans if s.name == "prefetch.walk"]
+    assert sorted(s.attrs["kind"] for s in walks) == ["head", "range",
+                                                      "range"]
+    assert {s.attrs["column"] for s in walks} == {"txt"}
+    assert sum(s.attrs["rows"] for s in walks) == rows
     packs = [s for s in tracer.spans if s.name == "text.pack_ids"]
     assert len(packs) == 3
     assert all(set(s.attrs) == {"tokens", "words", "capacity", "num_hashes"}
@@ -397,8 +402,10 @@ def test_a_ranged_prefetch_leaves_what_the_text_metrics_read(monkeypatch):
     assert sum(s.attrs["words"] for s in packs) == -(-whole.tokens // 3)
     (prefetch,) = [s for s in tracer.spans
                    if s.name == "prefetch.text_profiles"]
-    assert prefetch.attrs["workers"] == 3 and all(
-        chain(tracer.spans, s)[1] == "prefetch.text_profiles" for s in packs)
+    assert prefetch.attrs == {"rows": rows, "columns": 1}
+    assert REGISTRY.gauge("prologue.workers").value == 3 and all(
+        chain(tracer.spans, s)[1] == "prefetch.text_profiles"
+        for s in packs + walks)
     table = REGISTRY.gauge("train.span_profile").value
     assert table["text.pack_ids"]["count"] == 3
     for metric in ("text_pack_s", "text_profile_s"):

@@ -167,11 +167,19 @@ def test_pool_gives_the_same_profiles_in_feature_order(monkeypatch, cores,
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
-        profs = list(tp.profile_columns(triples))
+        profs, walks = _walks(lambda: list(tp.profile_columns(triples)))
     finally:
         sys.setswitchinterval(interval)
-    assert REGISTRY.gauge("text_profile.workers").value == min(
-        cores, most, len(cols))
+    width = min(cores, most, len(cols))
+    assert sorted(s.attrs["column"] for s in walks) == list(range(len(cols)))
+    assert {s.attrs["kind"] for s in walks} == {"whole"}
+    here = threading.get_ident()
+    if width > 1:       # a pool of the call's own, as wide as its walks
+        assert REGISTRY.gauge("prologue.workers").value == width
+        assert here not in {s.thread for s in walks}
+    else:
+        assert {s.thread for s in walks} == {here}
+        assert all(s.attrs["queued_s"] == 0.0 for s in walks)
     capped = sum(cap is not None for _, cap in pairs)
     assert _moved(before) == {"scan": len(cols), "fused_intern": capped}
     for (col, cap), prof in zip(pairs, profs):
@@ -238,10 +246,19 @@ def small_ranges(monkeypatch):
     monkeypatch.setattr(os, "sched_getaffinity", lambda pid: set(range(4)))
 
 
-def _range_counters():
-    c = REGISTRY.counters()
-    return (REGISTRY.gauge("text_profile.ranges").value,
-            c.get("text_profile.range_walks", 0))
+def _walks(fn):
+    """(what ``fn()`` returned, the ``prefetch.walk`` spans it opened)."""
+    tracer = Tracer("walks")
+    with use_tracer(tracer):
+        out = fn()
+    return out, [s for s in tracer.spans if s.name == "prefetch.walk"]
+
+
+def _kinds(walks):
+    kinds = {}
+    for s in walks:
+        kinds[s.attrs["kind"]] = kinds.get(s.attrs["kind"], 0) + 1
+    return kinds
 
 
 @pytest.mark.parametrize("cap", [None, -1, 0, 1, 30])
@@ -252,8 +269,9 @@ def test_head_and_ranges_equal_python_scan_and_intern(long_columns,
                                                       cap):
     arr, ref = long_columns[kind]
     col = Column(T.Text, arr)
-    before, walked = _counters(), _range_counters()[1]
-    (prof,) = tp.profile_columns([(col, cap, 500)])
+    before = _counters()
+    (prof,), walks = _walks(lambda: list(
+        tp.profile_columns([(col, cap, 500)])))
     assert tp.column_profile(col) is prof and prof._strings is arr
     pieces = len(prof.hash_pieces)          # tok_hash joins them
     assert prof.tokens == ref.tok_hash.size
@@ -272,7 +290,13 @@ def test_head_and_ranges_equal_python_scan_and_intern(long_columns,
         cap, (2 if kind == "freezes_in_block_1" and cap == 30 else 1)
         if frozen else 5)
     tails = 0 if head_blocks == 5 else 4
-    assert _range_counters() == ((head_blocks > 0) + tails, walked + tails)
+    first = "whole" if cap == -1 else "head"      # an exact count: one walk
+    assert _kinds(walks) == {**({first: 1} if head_blocks else {}),
+                             **({"range": tails} if tails else {})}
+    assert sum(s.attrs["rows"] for s in walks) == RANGED_ROWS
+    assert all(s.attrs["rows"] == (RANGED_ROWS if head_blocks == 5
+                                   else head_blocks * BLOCK)
+               for s in walks if s.attrs["kind"] == "head")
     assert pieces == (head_blocks > 0) + tails
     # the joined words of the ranged column are the unranged column's
     whole = tp.scan_strings(arr)
@@ -289,8 +313,9 @@ def test_head_and_ranges_equal_python_scan_and_intern(long_columns,
 def test_a_strided_column_is_cut_into_ranges_of_views(small_ranges):
     arr = np.repeat(_long_column("freezes_in_block_0"), 2)[::2]
     assert not arr.flags.c_contiguous and len(arr) == RANGED_ROWS
-    (prof,) = tp.profile_columns([(Column(T.Text, arr), 3, 64)])
-    assert _range_counters()[0] == 5
+    (prof,), walks = _walks(lambda: list(
+        tp.profile_columns([(Column(T.Text, arr), 3, 64)])))
+    assert _kinds(walks) == {"head": 1, "range": 4}
     whole = tp.scan_strings(arr.copy(), cap=3)
     _assert_scan_equal(prof, whole)
     _assert_interned_equal(prof._interned[3], whole._interned[3])
@@ -303,12 +328,14 @@ def test_a_short_column_and_an_exact_count_stay_one_walk(small_ranges,
                                                          monkeypatch):
     monkeypatch.setattr(tp, "MIN_RANGE_BLOCKS", 3)
     arr = _long_column("freezes_in_block_0")    # 5 blocks < 1 + 2 * 3
-    walked = _range_counters()[1]
-    profs = list(tp.profile_columns([(Column(T.Text, arr), 30, 64),
-                                     (Column(T.Text, arr[:100]), None, 64)]))
-    assert _range_counters() == (2, walked)
+    profs, walks = _walks(lambda: list(tp.profile_columns(
+        [(Column(T.Text, arr), 30, 64), (Column(T.Text, arr[:100]), None,
+                                         64)], names=["long", "short"])))
+    assert sorted((s.attrs["column"], s.attrs["kind"], s.attrs["rows"])
+                  for s in walks) == [("long", "whole", RANGED_ROWS),
+                                      ("short", "whole", 100)]
     assert [len(p.hash_pieces) for p in profs] == [1, 1]
-    assert REGISTRY.gauge("text_profile.workers").value == 2
+    assert REGISTRY.gauge("prologue.workers").value == 2
     assert tp._ranges(BLOCK, 28 * BLOCK, 4) == [
         (BLOCK * a, BLOCK * b) for a, b in ((1, 7), (7, 14), (14, 21),
                                             (21, 28))]
@@ -450,13 +477,17 @@ def test_prefetch_profiles_side_by_side_and_transfers_in_feature_order(
     assert host_link_bytes() - link == sum(
         tp.column_profile(c)._device_packed[64].nbytes for c in cols)
     (sp,) = [s for s in tracer.spans if s.name == "prefetch.text_profiles"]
-    assert sp.attrs == {"columns": 5, "rows": rows, "workers": 3}
+    assert sp.attrs == {"columns": 5, "rows": rows}
+    assert REGISTRY.gauge("prologue.workers").value == 3
     packs = [s for s in tracer.spans if s.name == "text.pack_ids"]
+    walks = [s for s in tracer.spans if s.name == "prefetch.walk"]
     assert len(packs) == 5                  # packed by the workers, inside
-    assert {s.parent_id for s in packs} == {sp.span_id}     # the prefetch
+    assert sorted(s.attrs["column"] for s in walks) == names   # walked too
+    assert {s.parent_id for s in packs + walks} == {sp.span_id}
     assert sp.thread == threading.get_ident() \
-        and sp.thread not in {s.thread for s in packs}
-    assert {s.thread for s in tracer.spans if s not in packs} == {sp.thread}
+        and sp.thread not in {s.thread for s in packs + walks}
+    assert {s.thread for s in tracer.spans
+            if s not in packs + walks} == {sp.thread}
     before = _counters()
     st.fit(batch)
     assert _moved(before) == {"intern.hit": 5}

@@ -13,7 +13,7 @@ columns stay in HBM and XLA fuses the ops; no persistence hacks are needed
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from .columns import ColumnBatch
 from .features import Feature
@@ -57,13 +57,16 @@ def prune_batch(batch: ColumnBatch, remaining_stages, keep_names) -> ColumnBatch
     return batch.drop(drop) if drop else batch
 
 
-def fit_layer(batch: ColumnBatch, layer: StageLayer) -> Tuple[ColumnBatch, List[Transformer]]:
+def fit_layer(batch: ColumnBatch, layer: StageLayer,
+              fit: Optional[Callable[[Estimator, ColumnBatch], Transformer]]
+              = None) -> Tuple[ColumnBatch, List[Transformer]]:
     """Fit all estimators of a layer, then apply every transformer of the layer
-    (≙ fitAndTransformLayer, FitStagesUtil.scala:253)."""
+    (≙ fitAndTransformLayer, FitStagesUtil.scala:253).  ``fit(stage, batch)``
+    fits an estimator where ``stage.fit(batch)`` should not be called bare."""
     fitted: List[Transformer] = []
     for stage in layer:
         if isinstance(stage, Estimator):
-            model = stage.fit(batch)
+            model = fit(stage, batch) if fit else stage.fit(batch)
             fitted.append(model)
         elif isinstance(stage, Transformer):
             fitted.append(stage)

@@ -632,8 +632,7 @@ class RawFeatureFilterResults:
         }
 
 
-_COUNTERS = ("rff.python_rows", "rff.jobs", "rff.inline",
-             "rff.native_columns", "rff.numpy_columns")
+_COUNTERS = ("rff.python_rows", "rff.native_columns", "rff.numpy_columns")
 
 
 def _predictors(batch, score_batch, raw_features
@@ -660,8 +659,9 @@ def _passes_without_gil(f: Feature, col: Column) -> bool:
 
 
 class _StartedDistributions:
-    """What ``RawFeatureFilter.start_distributions`` left on a pool for one
-    ``filter_batch``: the batches and a future a feature."""
+    """What ``RawFeatureFilter.start_distributions`` left on a pool (an
+    ``ops.text_profile.HostPool``) for one ``filter_batch``: the batches
+    and a future a feature."""
 
     def __init__(self, batch, score_batch, pool):
         self.batch, self.score_batch = batch, score_batch
@@ -680,6 +680,10 @@ class _StartedDistributions:
         job = self._held.pop(name, None)
         if job is not None:
             self.jobs[name] = self._pool.submit(job)
+
+    def join(self, job: Future):
+        """What ``job`` returned (or raised), the wait the pool's to count."""
+        return self._pool.join(job)
 
     def cancel(self) -> None:
         for job in self.jobs.values():
@@ -712,7 +716,7 @@ class RawFeatureFilter:
                             raw_features: Sequence[Feature], pool,
                             walked: Sequence[str] = ()
                             ) -> "_StartedDistributions":
-        """Start on ``pool`` (a ``ThreadPoolExecutor``) what
+        """Start on ``pool`` (an ``ops.text_profile.HostPool``) what
         ``filter_batch(batch, raw_features)`` will join: the score batch
         read, and one job a predictor whose distribution passes over rows
         with no GIL held (``_passes_without_gil``).  A column named in
@@ -789,10 +793,9 @@ class RawFeatureFilter:
         for f, col, score_col in _predictors(batch, score_batch,
                                              raw_features):
             job = started.jobs.get(f.name) if started is not None else None
-            REGISTRY.counter("rff.inline" if job is None else "rff.jobs").inc()
             ranges, dists = (
                 self._feature_distributions(f, col, score_col) if job is None
-                else job.result())      # raises what the job raised
+                else started.join(job))     # raises what the job raised
             if dists is None:           # binned on the mesh, from this thread
                 _, dists = self._feature_distributions(f, col, score_col,
                                                        ranges)

@@ -36,9 +36,11 @@ then packs its own words into the column's one buffer.
 The counters ``text_profile.scan`` (columns walked), ``.fused_intern``
 (interned by that walk), ``.intern.hit`` / ``.intern.miss`` (``values(cap)``
 answered from the cache / by another walk) count COLUMNS, however a column
-was cut; ``text_profile.range_walks`` counts the tail ranges walked and the
-gauges ``text_profile.ranges`` / ``.workers`` the walks and the threads of
-the last ``profile_columns``.  ``text.tokens`` / ``text.token_slots`` count
+was cut; each walk is a span ``prefetch.walk`` on the thread that runs it,
+whose ``kind`` (``head``, ``range``, ``whole``) says how.  The jobs of a
+train's prologue run on one ``HostPool``, which accounts for all of them:
+``prologue.queue_s``, ``prologue.wait_s``, ``prologue.workers``.
+``text.tokens`` / ``text.token_slots`` count
 the tokens packed for the device and the id slots shipped for them
 (``text.pack_ids`` is the span of a piece's packing; ``text.pack_native`` /
 ``text.pack_numpy`` say which code packed a column), and
@@ -51,9 +53,12 @@ results, slower.
 from __future__ import annotations
 
 import os
+import threading
+import time
 import zlib
 from collections import deque
-from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
+from concurrent.futures import (FIRST_COMPLETED, Future, ThreadPoolExecutor,
+                                wait)
 from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from functools import partial
@@ -476,11 +481,125 @@ def pool_size(items: int) -> int:
     return min(items, len(os.sched_getaffinity(0)), _MAX_WORKERS)
 
 
+_JOB = threading.local()        # the HostPool job running on this thread
+
+
+def _queued_s() -> float:
+    """Seconds the ``HostPool`` job running on this thread was ready before
+    a worker took it up; 0 on a thread that runs no such job."""
+    return getattr(_JOB, "queued_s", 0.0)
+
+
+def _union_s(intervals: List[Tuple[float, float]]) -> float:
+    """Seconds covered by at least one of the (start, end) intervals."""
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
+class HostPool:
+    """The worker threads of one batch's host prologue and the one
+    accounting of every job on them, whoever submits it: the walks and
+    packs of ``profile_columns`` and RawFeatureFilter's distributions.  Of
+    each job it notes when it was ready, when a worker started it and when
+    it ended, and keeps in ``telemetry.REGISTRY``, with or without a tracer:
+
+    * counter ``prologue.queue_s``: wall seconds in which a ready job was
+      held back by a width — the pool's, every thread busy, or a
+      submitter's (``_run_on`` keeps at most ``pool_size`` walks out).  The
+      union over jobs, added when the pool closes; a job handed to a free
+      worker when it is ready was held back by nothing.
+    * counter ``prologue.wait_s``: seconds the calling thread was blocked
+      on the pool's jobs (``wait``, ``join``); what it computes itself is
+      not waiting.
+    * gauge ``prologue.workers``: the pool's threads."""
+
+    def __init__(self, workers: int):
+        self.workers = workers
+        self._threads = ThreadPoolExecutor(workers,
+                                           thread_name_prefix="prologue")
+        self._lock = threading.Lock()
+        self._out = 0           # jobs submitted and not ended
+        self._held: List[Tuple[float, float]] = []
+        REGISTRY.counter("prologue.wait_s")
+        REGISTRY.gauge("prologue.workers").set(workers)
+
+    def submit(self, fn: Callable[[], object],
+               ready: Optional[float] = None) -> Future:
+        """Run ``fn`` on a worker.  ``ready``: the ``time.monotonic()`` at
+        which the job became ready, where its submitter has held it back
+        since; None: it is ready now."""
+        with self._lock:
+            now = time.monotonic()
+            ready = now if ready is None else ready
+            held = ready if self._out >= self.workers else None
+            if held is None and ready < now:
+                self._held.append((ready, now))
+            self._out += 1
+        future = self._threads.submit(self._run, fn, ready, held)
+        future.add_done_callback(partial(self._dropped, held))
+        return future
+
+    def _run(self, fn, ready: float, held: Optional[float]):
+        start = time.monotonic()
+        _JOB.queued_s = start - ready
+        try:
+            return fn()
+        finally:
+            _JOB.queued_s = 0.0
+            with self._lock:
+                self._out -= 1
+                if held is not None:
+                    self._held.append((held, start))
+
+    def _dropped(self, held: Optional[float], future: Future) -> None:
+        """A job cancelled before a worker took it: ready until now."""
+        if future.cancelled():
+            with self._lock:
+                self._out -= 1
+                if held is not None:
+                    self._held.append((held, time.monotonic()))
+
+    def wait(self, futures) -> set:
+        """Those of ``futures`` that are done, once one is."""
+        t = time.monotonic()
+        done = wait(futures, return_when=FIRST_COMPLETED).done
+        REGISTRY.counter("prologue.wait_s").inc(time.monotonic() - t)
+        return done
+
+    def join(self, future: Future):
+        """``future.result()``: what the job returned or raised."""
+        if future.done():
+            return future.result()
+        t = time.monotonic()
+        try:
+            return future.result()
+        finally:
+            REGISTRY.counter("prologue.wait_s").inc(time.monotonic() - t)
+
+    def close(self) -> None:
+        """The threads shut down, the jobs no worker took dropped, and the
+        seconds jobs were held back added to ``prologue.queue_s``."""
+        self._threads.shutdown(cancel_futures=True)
+        with self._lock:
+            held, self._held = self._held, []
+        REGISTRY.counter("prologue.queue_s").inc(_union_s(held))
+
+    def __enter__(self) -> "HostPool":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
 @contextmanager
-def host_pool(free_jobs: int) -> Iterator[Optional[ThreadPoolExecutor]]:
-    """The worker threads of one batch's host prologue, opened once by who
+def host_pool(free_jobs: int) -> Iterator[Optional[HostPool]]:
+    """The ``HostPool`` of one batch's host prologue, opened once by who
     runs it (``Workflow.train``): ``profile_columns`` keeps its
-    ``pool_size`` walks on them, bound by the GIL as they are, and
+    ``pool_size`` walks on it, bound by the GIL as they are, and
     ``free_jobs`` more items that hold no GIL while they pass over rows
     (RawFeatureFilter's distributions) take the cores beyond.  None on one
     core: every item then runs on the thread that asks for it."""
@@ -488,11 +607,8 @@ def host_pool(free_jobs: int) -> Iterator[Optional[ThreadPoolExecutor]]:
     if workers < 2:
         yield None
         return
-    pool = ThreadPoolExecutor(workers, thread_name_prefix="prologue")
-    try:
+    with HostPool(workers) as pool:
         yield pool
-    finally:
-        pool.shutdown(cancel_futures=True)
 
 
 def _ranges(start: int, rows: int, most: int) -> List[Tuple[int, int]]:
@@ -520,12 +636,27 @@ def _planned_walks(rows: int, cap: Optional[int]) -> int:
 Jobs = List[Callable[[], object]]
 
 
+def _walking(column, kind: str, rows: Optional[int], fn: Callable, *args,
+             **kw):
+    """``fn(*args, **kw)``, one walk of ``column`` — ``whole``, its ``head``
+    or a tail ``range`` — under the span ``prefetch.walk`` on the thread
+    that runs it.  ``rows`` None: a head's, known when it has frozen."""
+    with span("prefetch.walk", column=column, kind=kind, rows=rows,
+              queued_s=_queued_s()) as sp:
+        out = fn(*args, **kw)
+        if sp is not None and rows is None:
+            sp.attrs["rows"] = out["rows"]
+        return out
+
+
 def _column_plan(col, cap: Optional[int], num_hashes: Optional[int],
-                 workers: int) -> Generator[Jobs, list, TextProfile]:
+                 workers: int, column=None
+                 ) -> Generator[Jobs, list, TextProfile]:
     """``column_profile(col, cap)`` and, for a ``num_hashes`` the packed
     wire serves, the column's host words, as steps of jobs: every job of a
     step may run beside the others, the step's results (in the jobs' order)
-    are sent back in, and the profile is returned at the end.
+    are sent back in, and the profile is returned at the end.  ``column``
+    names the column in its walks' spans.
 
     A long column is walked by row range.  The *head*: whole blocks from row
     0, in order, until the interning is settled — no rows when no cap is
@@ -544,23 +675,23 @@ def _column_plan(col, cap: Optional[int], num_hashes: Optional[int],
         native = load("textprof")
         rows = len(strings)
         if native is None or _planned_walks(rows, cap) == 1:
-            walks = yield [partial(scan_strings, strings, cap=cap)]
+            walks = yield [partial(_walking, column, "whole", rows,
+                                   scan_strings, strings, cap=cap)]
             prof = walks[0]
         else:
             walks = []
             if cap is not None:
-                walks = yield [partial(_walk, native, strings, 1, cap,
+                walks = yield [partial(_walking, column, "head", None, _walk,
+                                       native, strings, 1, cap,
                                        until_frozen=True)]
             done = walks[0]["rows"] if walks else 0
             if done < rows:
                 frozen = walks[0]["uniq"] if walks else None
-                cuts = _ranges(done, rows, workers)
-                walks += yield [partial(_walk, native, strings[a:b], 1, cap,
-                                        frozen) for a, b in cuts]
-                REGISTRY.counter("text_profile.range_walks").inc(len(cuts))
+                walks += yield [partial(_walking, column, "range", b - a,
+                                        _walk, native, strings[a:b], 1, cap,
+                                        frozen)
+                                for a, b in _ranges(done, rows, workers)]
             prof = _profile_of(walks, strings, cap)
-        walked = REGISTRY.gauge("text_profile.ranges")
-        walked.set(walked.value + len(walks))   # plans advance on one thread
         _remember(col, prof)
     if num_hashes and num_hashes < 1024 \
             and num_hashes not in prof._device_packed:
@@ -581,15 +712,16 @@ def _run_here(plan: Generator[Jobs, list, TextProfile]) -> TextProfile:
         results = [job() for job in jobs]
 
 
-def _run_on(pool: ThreadPoolExecutor,
+def _run_on(pool: HostPool,
             plans: List[Generator[Jobs, list, TextProfile]],
             width: int) -> Iterator[TextProfile]:
     """Every plan's jobs on ``pool``, at most ``width`` of them out at once
     (the pool may be wider, and others' work on it), all plans under way
     together, each plan's profile yielded in the plans' order as soon as it
     is whole.  The plans themselves advance on the calling thread, between
-    the waits."""
-    ready = deque()     # (plan, job, the callable) not yet on the pool
+    the waits; a job the width holds back is handed to the pool with the
+    time it became ready, for ``prologue.queue_s``."""
+    held = deque()      # (plan, job, the callable, ready since) not on the pool
     running = {}        # future -> (plan, job) it is
     step = {}           # plan -> [results so far, jobs still out]
     whole = {}          # plan -> its profile
@@ -601,12 +733,16 @@ def _run_on(pool: ThreadPoolExecutor,
             whole[i] = stop.value
             return
         step[i] = [[None] * len(jobs), len(jobs)]
-        ready.extend((i, k, job) for k, job in enumerate(jobs))
+        for k, job in enumerate(jobs):
+            if held or len(running) >= width:
+                held.append((i, k, job, time.monotonic()))
+            else:
+                running[pool.submit(job)] = (i, k)
 
     def submit() -> None:
-        while ready and len(running) < width:
-            i, k, job = ready.popleft()
-            running[pool.submit(job)] = (i, k)
+        while held and len(running) < width:
+            i, k, job, ready = held.popleft()
+            running[pool.submit(job, ready)] = (i, k)
 
     try:
         for i in range(len(plans)):
@@ -614,7 +750,7 @@ def _run_on(pool: ThreadPoolExecutor,
         for i in range(len(plans)):
             while i not in whole:
                 submit()
-                for done in wait(running, return_when=FIRST_COMPLETED).done:
+                for done in pool.wait(running):
                     j, k = running.pop(done)
                     step[j][0][k] = done.result()   # raises what the job did
                     step[j][1] -= 1
@@ -629,7 +765,8 @@ def _run_on(pool: ThreadPoolExecutor,
 
 def profile_columns(columns: Sequence[Tuple[object, Optional[int],
                                             Optional[int]]],
-                    pool: Optional[ThreadPoolExecutor] = None
+                    pool: Optional[HostPool] = None,
+                    names: Optional[Sequence[str]] = None
                     ) -> Iterator[TextProfile]:
     """``column_profile(col, cap)`` of every (column, cap, num_hashes)
     triple, yielded in order, each with its packed words for ``num_hashes``
@@ -637,21 +774,21 @@ def profile_columns(columns: Sequence[Tuple[object, Optional[int],
     transfer), the work spread over ``pool_size`` worker threads by row
     range (``_column_plan``), so the caller works on a profile while later
     columns are still walked.  The threads are ``pool``'s (``host_pool``,
-    which a train opens for its whole prologue), or a pool of its own for
-    the length of the call.  One worker is a plain loop."""
+    which a train opens for its whole prologue), or a ``HostPool`` of its
+    own for the length of the call.  One worker is a plain loop.  ``names``
+    (the columns' positions by default) name them in their walks' spans."""
     from ..native import load
 
     walks = sum(_planned_walks(len(col), cap) for col, cap, _ in columns
                 if getattr(col, "_text_profile", None) is None)
     workers = pool_size(max(walks, len(columns)))
-    REGISTRY.gauge("text_profile.workers").set(workers)
-    REGISTRY.gauge("text_profile.ranges").set(0)
-    plans = [_column_plan(col, cap, num_hashes, workers)
-             for col, cap, num_hashes in columns]
+    plans = [_column_plan(col, cap, num_hashes, workers, name)
+             for (col, cap, num_hashes), name in zip(
+                 columns, range(len(columns)) if names is None else names)]
     if workers <= 1:
         yield from map(_run_here, plans)
         return
     load("textprof")        # built and imported once, before the threads
     with (nullcontext(pool) if pool is not None
-          else ThreadPoolExecutor(workers)) as threads:
+          else HostPool(workers)) as threads:
         yield from _run_on(threads, plans, workers)

@@ -310,8 +310,10 @@ class Tracer:
         request it coalesced).  Without ``ctx`` the span rides the tracer's
         own trace id with a fresh 64-bit position."""
         tid = threading.get_ident()
-        now = time.monotonic() - self.t0_mono
         with self._lock:
+            # the start read under the lock that finds the parent: a span
+            # never starts before the parent another thread opened for it
+            now = time.monotonic() - self.t0_mono
             parent = self._parent(tid)
             sp = Span(name=name, span_id=f"s{next(self._ids)}",
                       parent_id=parent.span_id if parent else None,
@@ -345,9 +347,9 @@ class Tracer:
     def event(self, name: str, *, ctx: Optional[TraceContext] = None,
               **attrs) -> Span:
         """A zero-duration marker span (e.g. a racing prune decision)."""
-        now = time.monotonic() - self.t0_mono
         tid = threading.get_ident()
         with self._lock:
+            now = time.monotonic() - self.t0_mono
             parent = self._parent(tid)
             sp = Span(name=name, span_id=f"s{next(self._ids)}",
                       parent_id=parent.span_id if parent else None,

@@ -37,6 +37,21 @@ PARAMS_NPZ = "params.npz"
 PREFETCH_MIN_ROWS = 100_000
 
 
+
+def _fit_stage(st: Estimator, batch: ColumnBatch) -> Transformer:
+    """``st.fit(batch)`` in a fit phase, under the span
+    ``transform.fit.<class>`` — but for the selector and SanityChecker,
+    whose fits open ``selector.*`` and ``sanity.fit`` themselves."""
+    from .preparators.sanity_checker import SanityChecker
+    from .selector import ModelSelector
+    from .telemetry import span
+    if isinstance(st, (ModelSelector, SanityChecker)):
+        return st.fit(batch)
+    with span(f"transform.fit.{type(st).__name__}", rows=len(batch),
+              inputs=len(st.input_features)):
+        return st.fit(batch)
+
+
 class _WorkflowCore:
     """Shared between Workflow and WorkflowModel (≙ OpWorkflowCore.scala:52)."""
 
@@ -371,7 +386,7 @@ class Workflow(_WorkflowCore):
 
         from .columns import to_device_f32
         from .ops.text_profile import profile_columns
-        from .telemetry import REGISTRY, span
+        from .telemetry import span
         accelerator = jax.default_backend() != "cpu"
         columns = self._profiled_columns(batch) if accelerator else []
         started = None
@@ -382,18 +397,16 @@ class Workflow(_WorkflowCore):
         if not accelerator:
             return
         try:
-            with span("prefetch.text_profiles", rows=len(batch)) as sp:
-                triples = [triple for _, triple in columns]
+            with span("prefetch.text_profiles", rows=len(batch),
+                      columns=len(columns)):
+                names = [name for name, _ in columns]
                 for prof, (name, (_, _, num_hashes)) in zip(
-                        profile_columns(triples, pool), columns):
+                        profile_columns([triple for _, triple in columns],
+                                        pool, names), columns):
                     if num_hashes:
                         prof.prefetch(num_hashes)
                     if started is not None:
                         started.walked(name)
-                if sp is not None:
-                    sp.attrs.update(
-                        columns=len(columns),
-                        workers=REGISTRY.gauge("text_profile.workers").value)
             # numeric raw columns + label: the weakref transfer cache makes
             # these THE copies every later consumer (frontier _prep,
             # vectorizer fits, selector y) reuses
@@ -503,7 +516,7 @@ class Workflow(_WorkflowCore):
                             batch = flush(batch, itertools.chain(
                                 new_layer[j:],
                                 (s for l in dag[i + 1:] for s in l)))
-                        m = st.fit(batch)
+                        m = _fit_stage(st, batch)
                     elif isinstance(st, Transformer):
                         m = st
                     else:
@@ -539,7 +552,7 @@ class Workflow(_WorkflowCore):
             with timer.phase(
                     "fit:" + "+".join(sorted({type(s).__name__
                                               for s in layer}))):
-                batch, fitted = fit_layer(batch, layer)
+                batch, fitted = fit_layer(batch, layer, _fit_stage)
             fitted_dag.append(fitted)
         # 'during' estimators are refit per fold by the validator; fit them on
         # the full data first (the final model's feature stages) so every
@@ -549,7 +562,7 @@ class Workflow(_WorkflowCore):
             with timer.phase(
                     "fit:" + "+".join(sorted({type(s).__name__
                                               for s in dl}))):
-                batch, f2 = fit_layer(batch, dl)
+                batch, f2 = fit_layer(batch, dl, _fit_stage)
             fitted_dag.append(f2)
         for layer in after:
             new_layer = []

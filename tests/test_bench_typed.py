@@ -709,6 +709,9 @@ def test_spans_and_counters_of_a_traced_train(cell, traced):
                                  "sanity.groups_dropped": 1,
                                  "selector.family_rounds": 4}
     profile = traced["profile"]
+    # inline, as every train under 100,000 rows is: the flush's span holds
+    # its stages' prologues (with a pool they are workers' jobs, started at
+    # the fits: tests/test_stage_wire_jobs.py)
     assert profile["transform.stage_wires"]["total_s"] >= sum(
         profile["transform.stage_wires." + c]["total_s"]
         for c in ("OneHotModel", "DateToUnitCircleModel",

@@ -36,7 +36,7 @@ import numpy as np
 
 from .columns import Column, ColumnBatch
 from .resilience import maybe_inject, record_failure
-from .stages.base import Transformer
+from .stages.base import ColumnWired, Transformer
 from .telemetry import REGISTRY, span
 
 _WIRE_SEP = "\x00"      # wire-entry names: "<uid>\x00<key>" — never a column
@@ -342,9 +342,12 @@ class ScoreProgram:
     # -- execution ----------------------------------------------------------
     def __call__(self, batch: ColumnBatch, keep_intermediate: bool = False
                  ) -> ColumnBatch:
-        # stages run outside a compiled segment; created at 0 by a flush
-        # that runs none, so that a reader can tell 0 from no counter
+        # stages run outside a compiled segment, and input columns whose
+        # wire a train's prologue pool made ahead (``ColumnWired``); created
+        # at 0 by a flush that counts none, so that a reader can tell 0 from
+        # no counter
         host_stages = REGISTRY.counter("transform.host_stages")
+        REGISTRY.counter("transform.wires_ahead")
         for _attempt in range(len(self.stages) + 1):
             segments = self._partition(batch)
             b = batch
@@ -469,14 +472,22 @@ class ScoreProgram:
                     continue
                 res = None
                 try:
-                    with span("transform.stage_wires." + type(st).__name__,
-                              rows=len(batch),
-                              columns=len(st.input_features)) as sp:
-                        res = st.transform_staged(batch)
-                        if sp is not None and res is not None:
-                            sp.attrs["wire_bytes"] = int(sum(
-                                getattr(v, "nbytes", 0)
-                                for v in res[0].values()))
+                    # a wire the train's prologue pool made ahead, column by
+                    # column (``ColumnWired.start_wires``): joined here, its
+                    # work in the workers' spans; else made here
+                    parts = (st.take_wires(batch)
+                             if isinstance(st, ColumnWired) else None)
+                    if parts is not None:
+                        res = st.transform_staged(batch, parts)
+                    else:
+                        with span("transform.stage_wires."
+                                  + type(st).__name__, rows=len(batch),
+                                  columns=len(st.input_features)) as sp:
+                            res = st.transform_staged(batch)
+                            if sp is not None and res is not None:
+                                sp.attrs["wire_bytes"] = int(sum(
+                                    getattr(v, "nbytes", 0)
+                                    for v in res[0].values()))
                 except Exception as e:  # noqa: BLE001 — demotion signal
                     raise _StageTraceError(st.uid, e) from e
                 if res is None:

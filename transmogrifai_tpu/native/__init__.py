@@ -22,13 +22,19 @@ import os
 import subprocess
 import sys
 import sysconfig
+import threading
 import warnings
 from typing import Any, Dict, Optional
 
-MODULES = ("fastcsv", "fasttok", "locofmt", "mapprof", "numdist", "textprof")
+MODULES = ("datewire", "fastcsv", "fasttok", "locofmt", "mapprof", "numdist",
+           "textprof")
 
 _CACHE: dict = {}
 _REASONS: Dict[str, str] = {}
+# one build and import at a time: the first ``load`` of a module may come
+# from several of a pool's workers at once, and a second compile into the
+# same temporary file would lose its rename to the first
+_LOCK = threading.Lock()
 
 
 def _build_dir() -> str:
@@ -80,6 +86,13 @@ def load(name: str) -> Optional[Any]:
     :func:`fallback_reasons` says why).  Disable with TRANSMOGRIFAI_NATIVE=0."""
     if name in _CACHE:
         return _CACHE[name]
+    with _LOCK:
+        if name not in _CACHE:
+            _CACHE[name] = _build_and_import(name)
+    return _CACHE[name]
+
+
+def _build_and_import(name: str) -> Optional[Any]:
     mod = None
     if os.environ.get("TRANSMOGRIFAI_NATIVE", "1") == "0":
         _REASONS[name] = "disabled by TRANSMOGRIFAI_NATIVE=0"
@@ -97,8 +110,7 @@ def load(name: str) -> Optional[Any]:
             _REASONS[name] = f"{type(e).__name__}: {e}"
             warnings.warn(f"native module {name!r} unavailable, using the "
                           f"pure-Python path: {_REASONS[name]}",
-                          RuntimeWarning, stacklevel=2)
-    _CACHE[name] = mod
+                          RuntimeWarning, stacklevel=3)
     return mod
 
 

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..columns import Column, ColumnBatch
-from ..stages.base import Estimator, Transformer, TransformerModel
+from ..stages.base import (ColumnWired, Estimator, Transformer,
+                           TransformerModel)
 from ..types import Integral, OPVector, Real, RealNN, Text
 from ..vector_meta import (NULL_INDICATOR, OTHER_INDICATOR, VectorColumnMeta,
                            VectorMeta)
@@ -72,32 +73,41 @@ def encode_column(col: Column, vocab: Dict[str, int], other_id: int) -> np.ndarr
                     table[np.maximum(iv.codes, 0)]).astype(np.int32)
 
 
-class OneHotModel(TransformerModel):
+class OneHotModel(ColumnWired, TransformerModel):
     out_kind = OPVector
     is_device_op = False  # host vocab lookup, then device one-hot
     supports_staging = True
 
-    def transform_staged(self, batch: ColumnBatch):
-        """Host prologue: vocab-encode each feature through the cached
-        column profile (narrow uint8 wire).  Device body: one-hot expand +
+    def column_wire(self, i: int, col: Column):
+        """``ids{i}``: input ``i`` vocab-encoded through its cached column
+        profile (uint8 where the ids fit); None for a column not held as
+        strings."""
+        if not col.is_host_object():
+            return None
+        vocab: Dict[str, int] = self.fitted["vocabs"][
+            self.input_features[i].name]
+        ids = encode_column(col, vocab, len(vocab))
+        return {f"ids{i}": ids.astype(np.uint8) if len(vocab) + 1 < 256
+                else ids}
+
+    def transform_staged(self, batch: ColumnBatch, parts=None):
+        """Host prologue: vocab-encode each feature (``column_wire``, or
+        ``parts`` made by it already).  Device body: one-hot expand +
         concat — fuses into the surrounding XLA program."""
         track_other = self.get("track_other", True)
         track_nulls = self.get("track_nulls", True)
-        wire = {}
+        parts = self.column_wires(batch) if parts is None else parts
+        if parts is None or None in parts:
+            return None
+        wire = {k: v for part in parts for k, v in part.items()}
         plan = []
         for i, f in enumerate(self.input_features):
-            if f.name in batch and not batch[f.name].is_host_object():
-                return None
-            vocab: Dict[str, int] = self.fitted["vocabs"][f.name]
-            other_id = len(vocab)
-            ids = encode_column(batch[f.name], vocab, other_id)
+            other_id = len(self.fitted["vocabs"][f.name])
             cols = list(range(other_id))
             if track_other:
                 cols.append(other_id)
             if track_nulls:
                 cols.append(other_id + 1)
-            wire[f"ids{i}"] = (ids.astype(np.uint8) if other_id + 1 < 256
-                               else ids)
             plan.append((f"ids{i}", np.asarray(cols, np.int32)))
         n = len(batch)
         meta = self.fitted["meta"]

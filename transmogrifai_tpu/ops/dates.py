@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..columns import Column, ColumnBatch, pack_bits, unpack_bits_device
-from ..stages.base import Estimator, Transformer, TransformerModel
+from ..stages.base import (ColumnWired, Estimator, Transformer,
+                           TransformerModel)
 from ..types import Date, DateList, Integral, OPVector, Real
 from ..vector_meta import NULL_INDICATOR, VectorColumnMeta, VectorMeta
 
@@ -66,8 +67,15 @@ _PERIOD_UNITS = {"HourOfDay": (0, 1, 1), "DayOfWeek": (3, 1, 7),
 
 def _day_and_ms(ms) -> tuple:
     """int64 epoch milliseconds -> (day modulo _DAY_CYCLE, millisecond of
-    the day), both int32; floor division, so dates before 1970 too."""
-    day, ms_of_day = np.divmod(np.asarray(ms, np.int64), _MS_DAY)
+    the day), both int32; floor division, so dates before 1970 too.  One
+    pass of native/datewire.cpp over a 1-D array, the GIL released; numpy
+    where there is no toolchain or another shape."""
+    from ..native import load
+    ms = np.asarray(ms, np.int64)
+    native = load("datewire") if ms.ndim == 1 else None
+    if native is not None and ms.dtype.isnative and ms.flags.aligned:
+        return native.day_and_ms(ms)
+    day, ms_of_day = np.divmod(ms, _MS_DAY)
     return (day % _DAY_CYCLE).astype(np.int32), ms_of_day.astype(np.int32)
 
 
@@ -90,23 +98,28 @@ def _period_fraction_device(day, ms_of_day, period: str):
             + rest / (float(ms_unit) * per_period))
 
 
-class DateToUnitCircleModel(TransformerModel):
+class DateToUnitCircleModel(ColumnWired, TransformerModel):
     out_kind = OPVector
     is_device_op = False  # int64 host split into the int32 wire, then device
     supports_staging = True
 
-    def transform_staged(self, batch: ColumnBatch):
-        """Host prologue: every date as (day, millisecond of the day) int32
-        and its null bits packed.  Device body: the periods' phases, sin and
-        cos, zeros where null, the null column."""
+    def column_wire(self, i: int, col: Column):
+        """``day{i}``, ``ms{i}``: input ``i`` as (day, millisecond of the
+        day) int32; ``null{i}``: its null bits packed, where it has a mask.
+        Nothing fitted is read."""
+        wire = dict(zip((f"day{i}", f"ms{i}"), _day_and_ms(col.values)))
+        if col.mask is not None:
+            wire[f"null{i}"] = pack_bits(~np.asarray(col.mask))
+        return wire
+
+    def transform_staged(self, batch: ColumnBatch, parts=None):
+        """Host prologue: every date's ``column_wire`` (or ``parts`` made
+        by it already).  Device body: the periods' phases, sin and cos,
+        zeros where null, the null column."""
         periods = list(self.get("periods"))
         track_nulls = self.get("track_nulls", True)
-        wire = {}
-        for i, f in enumerate(self.input_features):
-            col = batch[f.name]
-            wire[f"day{i}"], wire[f"ms{i}"] = _day_and_ms(col.values)
-            if col.mask is not None:
-                wire[f"null{i}"] = pack_bits(~np.asarray(col.mask))
+        parts = self.column_wires(batch) if parts is None else parts
+        wire = {k: v for part in parts for k, v in part.items()}
         count = len(self.input_features)
         meta = self.fitted["meta"]
 

@@ -12,7 +12,7 @@ import numpy as np
 from jax import lax
 
 from ..columns import Column, ColumnBatch, pack_bits, unpack_bits_device
-from ..stages.base import Estimator, TransformerModel
+from ..stages.base import ColumnWired, Estimator, TransformerModel
 from ..types import OPVector
 from ..vector_meta import NULL_INDICATOR, VectorColumnMeta, VectorMeta
 
@@ -37,27 +37,36 @@ def _geo_arrays(col) -> tuple:
 _PARTS = ("lat", "lon", "accuracy")
 
 
-class GeolocationVectorizerModel(TransformerModel):
+class GeolocationVectorizerModel(ColumnWired, TransformerModel):
     out_kind = OPVector
     is_device_op = False  # host gather of the triples, then device fill
     supports_staging = True
 
-    def transform_staged(self, batch: ColumnBatch):
-        """Host prologue: every column's latitudes, longitudes and accuracies
-        as three float32 vectors (bfloat16 on an accelerator's link, as
-        every real value; a vector each, so that the device slices no
-        ``[N, 3]`` operand along its lanes), its null bits packed, and the
-        fitted fills as their bit patterns (an operand, not a constant: the
-        program is the same for every fit; the bits, because a float32 wire
-        is rounded on the link).  Device body: the fill, the null column."""
+    def column_wire(self, i: int, col: Column):
+        """Input ``i``'s latitudes, longitudes and accuracies as three
+        float32 vectors (``lat{i}``, ``lon{i}``, ``accuracy{i}``) and its
+        null bits packed (``null{i}``).  Nothing fitted is read: the fills
+        are an entry of their own."""
+        arr, mask = _geo_arrays(col)
+        wire = {f"{part}{i}": np.ascontiguousarray(arr[:, k])
+                for k, part in enumerate(_PARTS)}
+        wire[f"null{i}"] = pack_bits(~mask)
+        return wire
+
+    def transform_staged(self, batch: ColumnBatch, parts=None):
+        """Host prologue: every column's ``column_wire`` (or ``parts`` made
+        by it already) — three float32 vectors (bfloat16 on an
+        accelerator's link, as every real value; a vector each, so that the
+        device slices no ``[N, 3]`` operand along its lanes) and packed null
+        bits — and the fitted fills as their bit patterns (an operand, not a
+        constant: the program is the same for every fit; the bits, because a
+        float32 wire is rounded on the link).  Device body: the fill, the
+        null column."""
         track_nulls = self.get("track_nulls", True)
         fills = np.asarray(self.fitted["fills"], np.float32)
+        parts = self.column_wires(batch) if parts is None else parts
         wire = {"fills": fills.view(np.int32)}
-        for i, f in enumerate(self.input_features):
-            arr, mask = _geo_arrays(batch[f.name])
-            for k, part in enumerate(_PARTS):
-                wire[f"{part}{i}"] = np.ascontiguousarray(arr[:, k])
-            wire[f"null{i}"] = pack_bits(~mask)
+        wire.update((k, v) for part in parts for k, v in part.items())
         count = len(fills)
         meta = self.fitted["meta"]
 

@@ -230,6 +230,83 @@ class TransformerModel(Transformer):
         self.metadata: Dict[str, Any] = {}
 
 
+class ColumnWired:
+    """A staged model whose host prologue is made input column by input
+    column: ``column_wire(i, col)`` gives the wire entries of input ``i``
+    from that column alone (numpy, no jax), and ``transform_staged(batch,
+    parts)`` builds its wire from them.  So a train's prologue pool (an
+    ``ops.text_profile.HostPool``) can make each column's wire as a job from
+    the moment the model is fitted (``start_wires``) and the flush that
+    needs them take the jobs' results (``take_wires``); everywhere else —
+    scoring, serving, a small train — ``transform_staged(batch)`` makes them
+    here, one after another, by the same function."""
+
+    # (pool, the input Columns the jobs read, a future a column) while jobs
+    # are out; never saved, and dropped when taken
+    _wire_jobs = None
+
+    def column_wire(self, i: int, col: Column) -> Optional[Dict[str, Any]]:
+        """Input ``i``'s wire entries from ``col``, or None where the staged
+        form does not apply to such a column."""
+        raise NotImplementedError
+
+    def column_wires(self, batch: ColumnBatch) -> Optional[List[Dict]]:
+        """Every input's ``column_wire`` in order; None at the first that
+        has none."""
+        parts = []
+        for i, f in enumerate(self.input_features):
+            part = self.column_wire(i, batch[f.name])
+            if part is None:
+                return None
+            parts.append(part)
+        return parts
+
+    def start_wires(self, batch: ColumnBatch, pool) -> None:
+        """One job on ``pool`` an input column of ``batch``, each under the
+        span ``transform.stage_wires.<class>`` on the worker that runs it.
+        Nothing is started unless every input is in ``batch``."""
+        from functools import partial
+
+        cols = [batch.get(f.name) for f in self.input_features]
+        if any(c is None for c in cols):
+            return
+        self._wire_jobs = (pool, cols, [
+            pool.submit(partial(self._column_job, i, c))
+            for i, c in enumerate(cols)])
+
+    def _column_job(self, i: int, col: Column) -> Optional[Dict[str, Any]]:
+        from ..telemetry import span
+        with span("transform.stage_wires." + type(self).__name__,
+                  column=self.input_features[i].name, rows=len(col)) as sp:
+            part = self.column_wire(i, col)
+            if sp is not None and part is not None:
+                sp.attrs["wire_bytes"] = int(sum(
+                    getattr(v, "nbytes", 0) for v in part.values()))
+            return part
+
+    def take_wires(self, batch: ColumnBatch) -> Optional[List]:
+        """What the jobs ``start_wires`` left made of ``batch``'s inputs, in
+        the inputs' order (joined through the pool, so that the waits count
+        in ``prologue.wait_s``; what a job raised is raised here), and the
+        jobs dropped from the model.  None where none were started or
+        ``batch`` holds other columns under those names: the caller then
+        makes the wire itself."""
+        from ..telemetry import REGISTRY
+        jobs, self._wire_jobs = self._wire_jobs, None
+        if jobs is None:
+            return None
+        pool, cols, futures = jobs
+        if any(batch.get(f.name) is not c
+               for f, c in zip(self.input_features, cols)):
+            for future in futures:
+                future.cancel()
+            return None
+        parts = [pool.join(future) for future in futures]
+        REGISTRY.counter("transform.wires_ahead").inc(
+            sum(p is not None for p in parts))
+        return parts
+
+
 class Estimator(PipelineStage):
     """Fits on a batch to produce a TransformerModel (≙ OpEstimator).
 

@@ -23,7 +23,8 @@ from .columns import Column, ColumnBatch
 from .dag import apply_dag, compute_dag, cut_dag, dag_stages, fit_dag, fit_layer
 from .features import Feature
 from .readers.base import DataReader, Reader
-from .stages.base import Estimator, PipelineStage, Transformer, TransformerModel
+from .stages.base import (ColumnWired, Estimator, PipelineStage, Transformer,
+                          TransformerModel)
 from .stages.generator import FeatureGeneratorStage
 from .stages.serialization import (feature_to_json, kind_by_name,
                                    stage_fitted_arrays, stage_from_json,
@@ -315,8 +316,10 @@ class Workflow(_WorkflowCore):
         rff_results = None
         # one pool for the prologue's host work over rows: the string walks
         # and RawFeatureFilter's distributions start on it side by side, and
-        # the filter joins its own where its rules first need them.  Large
-        # batches only: a tiny one is done before a thread has started.
+        # the filter joins its own where its rules first need them; open
+        # through the fit, where a staged model's column wires start as it
+        # is fitted.  Large batches only: a tiny one is done before a thread
+        # has started.
         with (host_pool(len(self.raw_features))
               if len(batch) >= PREFETCH_MIN_ROWS
               else contextlib.nullcontext()) as pool:
@@ -329,16 +332,17 @@ class Workflow(_WorkflowCore):
                             batch, self.raw_features)
                     self.blacklisted = dropped
                     self._apply_blacklist()
-        dag = compute_dag(self.result_features)
-        if self._sanitizers.get("serialization"):
-            audit_stage_serialization(dag_stages(dag))
-        raw_batch = batch if self._sanitizers.get("purity") else None
-        with nan_guard(self._sanitizers.get("nan", False)):
-            if self._workflow_cv:
-                batch, fitted_dag = self._fit_with_workflow_cv(batch, dag,
-                                                               timer)
-            else:
-                batch, fitted_dag = self._fit_plain(batch, dag, timer)
+            dag = compute_dag(self.result_features)
+            if self._sanitizers.get("serialization"):
+                audit_stage_serialization(dag_stages(dag))
+            raw_batch = batch if self._sanitizers.get("purity") else None
+            with nan_guard(self._sanitizers.get("nan", False)):
+                if self._workflow_cv:
+                    batch, fitted_dag = self._fit_with_workflow_cv(
+                        batch, dag, timer)
+                else:
+                    batch, fitted_dag = self._fit_plain(batch, dag, timer,
+                                                        pool)
         if raw_batch is not None:
             audit_dag_purity(fitted_dag, raw_batch)
         model = WorkflowModel(
@@ -454,7 +458,7 @@ class Workflow(_WorkflowCore):
                     (f.name, (col, cap, int(st.get("num_hashes") or 0))))
         return columns
 
-    def _fit_plain(self, batch, dag, timer=None):
+    def _fit_plain(self, batch, dag, timer=None, pool=None):
         """Fit the DAG with DEFERRED transform application: estimators fit
         layer-by-layer as before, but fitted transforms apply lazily — each
         run of pending transforms compiles into ONE fused XLA program
@@ -462,7 +466,12 @@ class Workflow(_WorkflowCore):
         needs their outputs.  The whole vectorizer layer + combiner becomes
         a single program instead of one dispatch/compile per stage — the fit
         path's analog of the reference's single bulk row map
-        (FitStagesUtil.scala:96)."""
+        (FitStagesUtil.scala:96).
+
+        With the train's prologue ``pool``, a fitted model whose host
+        prologue is made column by column (``ColumnWired``) starts one job
+        an input column on it at once, behind the fits that follow; the
+        flush that applies the model joins them."""
         import itertools
 
         from .compiled import ScoreProgram
@@ -517,6 +526,8 @@ class Workflow(_WorkflowCore):
                                 new_layer[j:],
                                 (s for l in dag[i + 1:] for s in l)))
                         m = _fit_stage(st, batch)
+                        if pool is not None and isinstance(m, ColumnWired):
+                            m.start_wires(batch, pool)
                     elif isinstance(st, Transformer):
                         m = st
                     else:

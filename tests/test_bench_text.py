@@ -620,15 +620,17 @@ def test_readers_and_appended_cells_say_what_benchmark_json_says():
             m["layer"], m["unit"], m["source"], m["moves"])
         # the cell's own readers; ISSUE 36 appended its cell to the one of
         # them that reads something there
-        assert m["workloads"] == [CELL] + ["typed_sweep"] * (
-            name == "text_profile_s") and m["better"] == "lower"
+        assert m["workloads"] == [CELL] + ["typed_sweep", "maps_sweep"] * (
+            name == "text_profile_s") + ["maps_sweep"] * (
+            name != "text_profile_s") and m["better"] == "lower"
         assert m["layer"] in {e["layer"] for e in MANIFEST["per_layer"][:18]}
     for name in ("prologue_s", "selector_s", "host_link_MB",
                  "window_compiles", "setup_compile_s", "device_idle_share",
                  "sweep_mfu", "peak_hbm_GiB", "prologue_idle_s",
                  "transform_s", "sanity_s", "refit_s", "train_jit_s"):
-        # appended last by ISSUE 34, and the next cell after it by ISSUE 36
-        assert entries[name]["workloads"][-2:] == [CELL, "typed_sweep"], name
+        # appended with this cell, then the next cells after it
+        assert entries[name]["workloads"][-3:] == [CELL, "typed_sweep",
+                                                   "maps_sweep"], name
     for name in ("mesh_devices", "place_s", "relayout_MB", "sweep_mfu_x4",
                  "chip_rows_per_s"):
         assert CELL not in entries[name]["workloads"], name

@@ -814,14 +814,16 @@ def test_readers_and_appended_cells_say_what_benchmark_json_says():
         mod, m = reader(name), entries[name]
         assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
             m["layer"], m["unit"], m["source"], m["moves"])
-        assert m["workloads"] == [CELL] and m["better"] == "lower"
+        # the cell's own readers, and the next cell after it
+        assert m["workloads"] == [CELL, "maps_sweep"] \
+            and m["better"] == "lower"
         assert m["layer"] in {e["layer"] for e in MANIFEST["per_layer"][:21]}
     joined = ("prologue_s", "selector_s", "host_link_MB", "window_compiles",
               "setup_compile_s", "device_idle_share", "sweep_mfu",
               "peak_hbm_GiB", "prologue_idle_s", "transform_s", "sanity_s",
               "refit_s", "train_jit_s", "text_profile_s")
     for name in joined:
-        assert entries[name]["workloads"][-1] == CELL, name
+        assert entries[name]["workloads"][-2:] == [CELL, "maps_sweep"], name
     # what ISSUE 39 appended after them reads something in every cell
     every_cell = ("prologue_wait_s", "prologue_queue_s", "stage_fit_s")
     for name in set(entries) - set(joined) - set(NEW_READERS) - set(
@@ -829,9 +831,9 @@ def test_readers_and_appended_cells_say_what_benchmark_json_says():
         assert CELL not in entries[name]["workloads"], name
     assert [m["name"] for m in MANIFEST["per_layer"][21:24]] == list(
         NEW_READERS)
-    assert [w["name"] for w in MANIFEST["workloads"]] == [
+    assert [w["name"] for w in MANIFEST["workloads"]][:4] == [
         "mixed_sweep", "mixed_sweep_x4", "text_sweep", CELL]
-    assert [c["name"] for c in MANIFEST["configs"]][-1] == "nyc_taxi_typed"
+    assert [c["name"] for c in MANIFEST["configs"]][3] == "nyc_taxi_typed"
 
 
 # (h) ----------------------------------------------------------------------
@@ -981,6 +983,7 @@ def test_selftest_runs_the_cell(trace):
 
 
 def test_selftest_has_four_cells():
-    assert sorted(run.cpu_cells()) == ["mixed_sweep", "mixed_sweep_x4",
-                                       "text_sweep", CELL]
+    """The four cells up to this one, and ``maps_sweep`` after it."""
+    assert sorted(run.cpu_cells()) == ["maps_sweep", "mixed_sweep",
+                                       "mixed_sweep_x4", "text_sweep", CELL]
     assert {w["name"] for w in MANIFEST["workloads"]} == set(run.cpu_cells())

@@ -392,6 +392,7 @@ def test_a_reader_says_what_benchmark_json_says(name):
     assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
         entry["layer"], entry["unit"], entry["source"], entry["moves"])
     assert entry["workloads"] == ["mixed_sweep", "mixed_sweep_x4",
-                                  "text_sweep", "typed_sweep"]
+                                  "text_sweep", "typed_sweep", "maps_sweep"]
     assert entry["better"] == "lower"
-    assert [m["name"] for m in manifest["per_layer"][-3:]] == list(READERS)
+    # these three, and the map walks' reader after them
+    assert [m["name"] for m in manifest["per_layer"][-4:-1]] == list(READERS)

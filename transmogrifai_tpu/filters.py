@@ -413,11 +413,43 @@ def _value_presence(col: Column) -> np.ndarray:
             # the vectorizers reuse, so RFF costs no extra column walk
             from .ops.text_profile import column_profile
             return column_profile(col).presence
-        return np.array([v is not None and v != "" and v != [] and v != {}
-                         for v in col.values])
+        exp = _expansion(col)
+        if exp is not None:
+            return exp.nonempty
+        # None, and anything of length 0 ("", [], {}, an empty set), is null
+        return np.array([v is not None and not (
+            hasattr(v, "__len__") and len(v) == 0) for v in col.values],
+            dtype=bool)
     if col.mask is not None:
         return np.asarray(col.mask)
     return np.ones(len(col), dtype=bool)
+
+
+def _expansion(col: Column):
+    """The cached one-walk expansion of a string-valued map or a set column
+    (``ops.map_profile``) where it says what the row walk would, else
+    None: RawFeatureFilter then walks the rows."""
+    from .ops.map_profile import (is_set_kind, is_string_map, set_expansion,
+                                  string_map_expansion)
+    if not col.is_host_object():
+        return None
+    if is_string_map(col.kind):
+        exp = string_map_expansion(col)
+    elif is_set_kind(col.kind):
+        exp = set_expansion(col)
+    else:
+        return None
+    return exp if exp.inexact == 0 else None
+
+
+def _value_bins(uniq: Sequence[str], weights: np.ndarray,
+                text_bins: int) -> np.ndarray:
+    """The hashed-value histogram of the row walk's text branch from each
+    distinct value, binned once and weighted by its count."""
+    bins = np.fromiter((_stable_text_bin(u, text_bins) for u in uniq),
+                       np.int64, count=len(uniq))
+    return np.bincount(bins, weights=np.asarray(weights, np.float64),
+                       minlength=text_bins)
 
 
 def numeric_ranges(feature: Feature, col: Column
@@ -516,6 +548,22 @@ def compute_distribution(feature: Feature, col: Column, bins: int,
                 out.append(FeatureDistribution(feature.name, count=n, nulls=n,
                                                distribution=np.zeros(bins)))
             return out
+        exp = _expansion(col)
+        if exp is not None:
+            # a string-valued map from its one expansion: a key's values
+            # binned once each, weighted by their rows
+            idx = exp.key_index()
+            for k in sorted(exp.keys):
+                j = idx[k]
+                out.append(FeatureDistribution(
+                    feature.name, key=k, count=n,
+                    nulls=n - int(exp.counts[j].sum()),
+                    distribution=_value_bins(exp.uniq[j], exp.counts[j],
+                                             text_bins)))
+            if not exp.keys:
+                out.append(FeatureDistribution(feature.name, count=n, nulls=n,
+                                               distribution=np.zeros(bins)))
+            return out
         keys = sorted({k for m in col.values if m for k in m})
         for k in keys:
             vals = [m.get(k) if m else None for m in col.values]
@@ -537,6 +585,9 @@ def compute_distribution(feature: Feature, col: Column, bins: int,
         dist = column_profile(col).crc_hist(text_bins)
     elif _list_held_as_array(col):
         dist = _array_item_bins(np.asarray(col.values), present, text_bins)
+    elif (exp := _expansion(col)) is not None:
+        # a set column from its one expansion: every item, duplicates too
+        dist = _value_bins(exp.uniq, exp.items, text_bins)
     else:
         dist = _histogram_of(list(np.asarray(col.values, dtype=object))
                              if col.is_host_object() else np.asarray(col.values),
@@ -650,11 +701,15 @@ def _passes_without_gil(f: Feature, col: Column) -> bool:
     """Whether ``col``'s distribution holds no GIL while it passes over
     rows, so that it may run beside other host work: an array (a numeric
     column's native range and histogram, a list-valued array's
-    ``np.unique``) or strings (the native walk, or its cached profile).
-    Maps and other Python objects are walked in Python
-    (``rff.python_rows``) and stay with the thread that filters."""
+    ``np.unique``), strings (the native walk, or its cached profile), a
+    string-valued map or a set column (the native walk of
+    ``ops.map_profile``, or its cached expansion).  Numeric maps and other
+    Python objects are walked in Python (``rff.python_rows``) and stay with
+    the thread that filters."""
+    from .ops.map_profile import is_set_kind, is_string_map
     if col.is_host_object():
-        return is_text_kind(f.kind)
+        return (is_text_kind(f.kind) or is_string_map(f.kind)
+                or is_set_kind(f.kind))
     return not isinstance(col.values, dict)
 
 
@@ -720,14 +775,15 @@ class RawFeatureFilter:
         ``filter_batch(batch, raw_features)`` will join: the score batch
         read, and one job a predictor whose distribution passes over rows
         with no GIL held (``_passes_without_gil``).  A column named in
-        ``walked`` is a string column the caller profiles itself: its job
-        waits until the caller says ``walked(name)`` on what is returned,
-        so that the column is walked once.  Every other predictor — and a
+        ``walked`` is a string, map or set column the caller walks itself:
+        its job waits until the caller says ``walked(name)`` on what is
+        returned, so that the column is walked once.  Every other predictor
+        — and a
         ``walked`` one never released — is computed by ``filter_batch``
         itself, as it is when nothing was started."""
         from .native import load
         from .parallel.mesh import maybe_data_mesh
-        for module in ("numdist", "textprof"):
+        for module in ("mapprof", "numdist", "textprof"):
             load(module)        # built and imported once, before the threads
         score_batch = self._score_batch(raw_features)
         started = _StartedDistributions(batch, score_batch, pool)

@@ -20,8 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .categorical import top_values_by_count
+from .map_profile import key_order, string_map_expansion
 from ..columns import Column, ColumnBatch, indicator_2d
-from ..stages.base import Estimator, TransformerModel
+from ..stages.base import ColumnWired, Estimator, TransformerModel
 from ..types import OPVector
 from ..vector_meta import (NULL_INDICATOR, OTHER_INDICATOR, VectorColumnMeta,
                            VectorMeta)
@@ -38,9 +39,17 @@ def _discover_keys(maps: List[Dict[str, Any]], max_keys: int,
     counts: Counter = Counter()
     for m in maps:
         counts.update(m.keys())
+    return _cut_keys(counts, max_keys, allow_list, block_list)
+
+
+def _cut_keys(in_dict: Dict[str, int], max_keys: int, allow_list=None,
+              block_list=None) -> List[str]:
+    """The first ``max_keys`` keys of ``map_profile.key_order`` (rows that
+    hold the key, descending, then the key), laid out by key."""
     block = set(block_list or ())
-    return sorted(k for k, _ in counts.most_common(max_keys)
-                  if (allow_list is None or k in allow_list) and k not in block)
+    return sorted(k for k in key_order(in_dict)[:max_keys]
+                  if (allow_list is None or k in allow_list)
+                  and k not in block)
 
 
 class TextMapStats:
@@ -195,35 +204,78 @@ class SmartTextMapVectorizer(Estimator):
 # TextMapPivotVectorizer
 # ---------------------------------------------------------------------------
 
-class TextMapPivotVectorizerModel(TransformerModel):
+class TextMapPivotVectorizerModel(ColumnWired, TransformerModel):
     out_kind = OPVector
-    is_device_op = False
+    is_device_op = False     # host ids from the map's expansion, then device
+    supports_staging = True  # one-hots fused into the transform program
+
+    def column_wire(self, i: int, col: Column):
+        """``ids{i}``: uint8 ``[N, K]`` (int32 past 255 slots) of input
+        ``i``'s K fitted keys, a key's vocabulary position, OTHER or null
+        in every row, read from the column's cached expansion
+        (``map_profile.string_map_expansion``); None for a column not held
+        as objects."""
+        if not col.is_host_object():
+            return None
+        f = self.input_features[i]
+        per_key = self.fitted["per_feature"][f.name]
+        exp = string_map_expansion(col, f.name)
+        at = exp.key_index()
+        widest = max([len(v) + 2 for v in per_key["vocabs"].values()] + [0])
+        dtype = np.uint8 if widest <= 256 else np.int32
+        ids = np.empty((len(col), len(per_key["keys"])), dtype)
+        for j, k in enumerate(per_key["keys"]):
+            vocab = per_key["vocabs"][k]
+            other = len(vocab)
+            if k not in at:
+                ids[:, j] = other + 1
+                continue
+            # a distinct value's slot, then null last: a row's id -1 takes it
+            slot = np.fromiter(
+                (vocab.get(u, other) for u in exp.uniq[at[k]]), dtype,
+                count=len(exp.uniq[at[k]]))
+            ids[:, j] = np.append(slot, dtype(other + 1))[exp.ids[at[k]]]
+        return {f"ids{i}": ids}
+
+    def transform_staged(self, batch: ColumnBatch, parts=None):
+        """Host prologue: every input's ``column_wire`` (or ``parts`` made
+        by it already).  Device body: each key's one-hot over its vocabulary,
+        OTHER and null, concatenated in the fitted order."""
+        parts = self.column_wires(batch) if parts is None else parts
+        if parts is None or None in parts:
+            return None
+        wire = {k: v for part in parts for k, v in part.items()}
+        plan = []
+        for i, f in enumerate(self.input_features):
+            per_key = self.fitted["per_feature"][f.name]
+            plan.append((f"ids{i}", [len(per_key["vocabs"][k]) + 2
+                                     for k in per_key["keys"]]))
+        n = len(batch)
+        meta = self.fitted["meta"]
+
+        def body(w):
+            outs = []
+            for name, widths in plan:
+                ids = jnp.asarray(w[name]).astype(jnp.int32)
+                for j, width in enumerate(widths):
+                    outs.append((ids[:, j][:, None] == jnp.arange(
+                        width, dtype=jnp.int32)[None, :]).astype(jnp.float32))
+            return Column(OPVector, jnp.concatenate(outs, axis=1) if outs
+                          else jnp.zeros((n, 0), jnp.float32), meta=meta)
+
+        return wire, body
 
     def transform(self, batch: ColumnBatch) -> Column:
-        n = len(batch)
-        blocks: List[np.ndarray] = []
-        for f in self.input_features:
-            maps = _map_values(batch[f.name])
-            per_key = self.fitted["per_feature"][f.name]
-            for k in per_key["keys"]:
-                vocab = per_key["vocabs"][k]
-                width = len(vocab) + 2
-                col = np.zeros((n, width), np.float32)
-                for i, m in enumerate(maps):
-                    v = m.get(k)
-                    if v is None:
-                        col[i, width - 1] = 1.0
-                    else:
-                        col[i, vocab.get(str(v), len(vocab))] = 1.0
-                blocks.append(col)
-        arr = (np.concatenate(blocks, axis=1) if blocks
-               else np.zeros((n, 0), np.float32))
-        return Column(OPVector, jnp.asarray(arr), meta=self.fitted["meta"])
+        wire, body = self.transform_staged(batch)
+        return body({k: jnp.asarray(v) for k, v in wire.items()})
 
 
 class TextMapPivotVectorizer(Estimator):
     """Always-pivot per-key text-map vectorizer (≙ TextMapPivotVectorizer.scala):
-    every key gets top-K one-hot + OTHER + null, no hashing fallback."""
+    every key gets top-K one-hot + OTHER + null, no hashing fallback.  Keys
+    and value counts come from the column's one expansion
+    (``map_profile.string_map_expansion``), which the model's wire reads
+    too."""
 
     out_kind = OPVector
 
@@ -236,13 +288,15 @@ class TextMapPivotVectorizer(Estimator):
         cols_meta: List[VectorColumnMeta] = []
         per_feature: Dict[str, Dict[str, Any]] = {}
         for f in self.input_features:
-            maps = _map_values(batch[f.name])
-            keys = _discover_keys(maps, self.get("max_keys", 100))
+            exp = string_map_expansion(batch[f.name], f.name)
+            at = exp.key_index()
+            keys = _cut_keys(dict(zip(exp.keys, exp.in_dict.tolist())),
+                             self.get("max_keys", 100))
             vocabs: Dict[str, Dict[str, int]] = {}
             kindname = f.kind.__name__
             for k in keys:
-                cnt = Counter(str(m[k]) for m in maps if m.get(k) is not None)
-                top = top_values_by_count(cnt, self.get("top_k"),
+                top = top_values_by_count(exp.value_counts(at[k]),
+                                          self.get("top_k"),
                                           self.get("min_support"))
                 vocab = {v: i for i, v in enumerate(top)}
                 vocabs[k] = vocab

@@ -15,6 +15,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -365,11 +366,15 @@ class Workflow(_WorkflowCore):
         nothing.
 
         On every backend, where the train has a ``pool``
-        (``ops.text_profile.host_pool``) and a RawFeatureFilter: the
-        filter's distributions (``RawFeatureFilter.start_distributions``),
-        a job a predictor held as an array or as strings, which
-        ``filter_batch`` joins where its rules need them — they hide host
-        work behind host work, not the link.
+        (``ops.text_profile.host_pool``): first every map or set column of a
+        ``TextMapPivotVectorizer`` or ``MultiPickListVectorizer`` walked once
+        as a job (``ops.map_profile``), joined under the span
+        ``prefetch.maps`` at the end of the phase; with a RawFeatureFilter,
+        its distributions (``RawFeatureFilter.start_distributions``), a job a
+        predictor held as an array, as strings, as string-valued maps or as
+        sets, which ``filter_batch`` joins where its rules need them (a map
+        or set column's starts when its walk is whole) — they hide host work
+        behind host work, not the link.
 
         On an accelerator, also: every text column of a hashing vectorizer
         or a pivot profiled ONCE (``ops.text_profile.profile_columns``:
@@ -387,19 +392,53 @@ class Workflow(_WorkflowCore):
         if len(batch) < PREFETCH_MIN_ROWS:
             return
         import jax
-
-        from .columns import to_device_f32
-        from .ops.text_profile import profile_columns
-        from .telemetry import span
         accelerator = jax.default_backend() != "cpu"
         columns = self._profiled_columns(batch) if accelerator else []
+        # the map and set walks first: the longest jobs of the prologue
+        walks = [(name, pool.submit(partial(expand, col, name)))
+                 for name, col, expand in (self._expanded_columns(batch)
+                                           if pool is not None else [])]
         started = None
         if pool is not None and self._raw_feature_filter is not None:
             started = self._raw_feature_filter.start_distributions(
                 batch, self.raw_features, pool,
-                walked=[name for name, _ in columns])
-        if not accelerator:
-            return
+                walked=[name for name, _ in columns]
+                + [name for name, _ in walks])
+        if accelerator:
+            self._prefetch_on_accelerator(batch, pool, columns, started)
+        if walks:
+            self._join_map_walks(batch, pool, walks, started)
+
+    @staticmethod
+    def _join_map_walks(batch, pool, walks, started) -> None:
+        """The map and set walks joined in the features' order (their waits
+        in ``prologue.wait_s``), the filter's job for such a column let go
+        as its walk is whole; the span ``prefetch.maps``."""
+        from .telemetry import span
+        with span("prefetch.maps", columns=len(walks), rows=len(batch)
+                  ) as sp:
+            keys = 0
+            for name, walk in walks:
+                try:
+                    exp = pool.join(walk)
+                except Exception as e:  # noqa: BLE001 — the fit walks the
+                    # column itself and says what is wrong there
+                    from .resilience import record_failure
+                    record_failure("workflow.prefetch", "swallowed", e,
+                                   point="workflow.prefetch")
+                    continue
+                keys += len(exp.keys) if hasattr(exp, "keys") else 1
+                if started is not None:
+                    started.walked(name)
+            if sp is not None:
+                sp.attrs["keys"] = keys
+
+    def _prefetch_on_accelerator(self, batch, pool, columns, started) -> None:
+        """The string walks and the link transfers of
+        ``_prefetch_text_profiles`` on an accelerator."""
+        from .columns import to_device_f32
+        from .ops.text_profile import profile_columns
+        from .telemetry import span
         try:
             with span("prefetch.text_profiles", rows=len(batch),
                       columns=len(columns)):
@@ -431,6 +470,30 @@ class Workflow(_WorkflowCore):
             from .resilience import record_failure
             record_failure("workflow.prefetch", "swallowed", e,
                            point="workflow.prefetch")
+
+    def _expanded_columns(self, batch):
+        """[(feature name, column, its expansion's function)] of the map and
+        set columns a ``TextMapPivotVectorizer`` or ``MultiPickListVectorizer``
+        of this workflow reads, once each: what the prefetch walks ahead of
+        RawFeatureFilter and their fits (``ops.map_profile``)."""
+        from .ops.collections import MultiPickListVectorizer
+        from .ops.map_profile import set_expansion, string_map_expansion
+        from .ops.map_vectorizers import TextMapPivotVectorizer
+        out, seen = [], set()
+        for st in dag_stages(compute_dag(self.result_features)):
+            if isinstance(st, TextMapPivotVectorizer):
+                expand = string_map_expansion
+            elif isinstance(st, MultiPickListVectorizer):
+                expand = set_expansion
+            else:
+                continue
+            for f in st.input_features:
+                col = batch.get(f.name)
+                if col is None or not col.is_host_object() or f.name in seen:
+                    continue
+                seen.add(f.name)
+                out.append((f.name, col, expand))
+        return out
 
     def _profiled_columns(self, batch):
         """[(feature name, (column, cap, num_hashes))] of the string columns

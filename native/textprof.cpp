@@ -43,6 +43,13 @@
 //     ops/text.py TextStats.of_column): once the table holds cap+1 distinct
 //     values ALL counting stops; lengths elsewhere keep accumulating.
 //
+//   lookup(values, table) -> int32[N]
+//     Each of `values` (a list or tuple of str or None, read in place: a
+//     column's distinct values, as intern gives them) as its index in
+//     `table` (a few distinct str, a pivot's vocabulary), -2 where it is not
+//     there, -1 for None: the walk of a frozen table, with its phase two
+//     out of the GIL.  The values must not be resized during the call.
+//
 //   pack_ids3(hashes, num_hashes, offset, out, carry, last) -> words written
 //     The packed token wire of the hashing trick (ops/text.py _pack_ids3 is
 //     the definition): token t of a column goes, modulo num_hashes (< 1024),
@@ -333,11 +340,9 @@ PyObject* array_of(const std::vector<T>& v, int type) {
     return a;
 }
 
-// Walks `arr` into `w`'s outputs.  False with a Python error set on failure.
-bool walk_column(PyArrayObject* arr, Walk& w) {
-    const Py_ssize_t n = PyArray_DIM(arr, 0);
-    const char* base = PyArray_BYTES(arr);
-    const npy_intp stride = PyArray_STRIDE(arr, 0);
+// Walks the `n` rows whose PyObject* lie `stride` bytes apart from `base`
+// into `w`'s outputs.  False with a Python error set on failure.
+bool walk_rows(const char* base, npy_intp stride, Py_ssize_t n, Walk& w) {
     const Py_ssize_t rows = std::min(n, BLOCK_ROWS);
     std::vector<const char*> ptr;
     std::vector<Py_ssize_t> blen;
@@ -401,6 +406,11 @@ bool walk_column(PyArrayObject* arr, Walk& w) {
             break;
     }
     return true;
+}
+
+bool walk_column(PyArrayObject* arr, Walk& w) {
+    return walk_rows(PyArray_BYTES(arr), PyArray_STRIDE(arr, 0),
+                     PyArray_DIM(arr, 0), w);
 }
 
 // Rebuilds a frozen table from its values, in order, so that a later range
@@ -559,6 +569,35 @@ PyObject* intern_values(PyObject*, PyObject* args) {
     return out;
 }
 
+PyObject* lookup(PyObject*, PyObject* args) {
+    PyObject *values_obj, *table_obj;
+    if (!PyArg_ParseTuple(args, "OO", &values_obj, &table_obj))
+        return nullptr;
+    // a list or tuple is read in place: its items are contiguous
+    if (!PyList_Check(values_obj) && !PyTuple_Check(values_obj)) {
+        PyErr_SetString(PyExc_TypeError,
+                        "textprof: lookup wants a list or tuple of values");
+        return nullptr;
+    }
+    PyObject* table = PySequence_Tuple(table_obj);
+    PyObject* codes = nullptr;
+    Walk w;
+    w.intern = true;
+    Py_INCREF(values_obj);
+    if (table && seed_frozen(table, w)) {
+        const Py_ssize_t n = PySequence_Fast_GET_SIZE(values_obj);
+        codes = new_rows(n, NPY_INT32, reinterpret_cast<void**>(&w.codes));
+        if (codes &&
+            !walk_rows(reinterpret_cast<const char*>(
+                           PySequence_Fast_ITEMS(values_obj)),
+                       sizeof(PyObject*), n, w))
+            Py_CLEAR(codes);
+    }
+    Py_XDECREF(table);
+    Py_DECREF(values_obj);
+    return codes;
+}
+
 // `obj` as a C-contiguous 1-D array of `type`, or nullptr with TypeError set.
 PyArrayObject* flat_array(PyObject* obj, int type, const char* what) {
     if (!PyArray_Check(obj) ||
@@ -647,6 +686,10 @@ PyMethodDef methods[] = {
      "intern(arr, cap=-1) -> (uniq, counts int64[U], codes int32[N]) of a "
      "1-D object ndarray, read in place; cap>=0 applies the TextStats "
      "freeze semantics"},
+    {"lookup", lookup, METH_VARARGS,
+     "lookup(values, table) -> int32[N]: each of a list or tuple of str or "
+     "None as its index in table (distinct str), -2 where it is not there, "
+     "-1 for None"},
     {nullptr, nullptr, 0, nullptr},
 };
 

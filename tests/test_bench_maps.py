@@ -35,7 +35,7 @@ MANIFEST = run.load_json("BENCHMARK.json")
 TINY = run.cpu_cells()[CELL]
 STATED = plain.Precision.stated("cpu")
 COUNTERS = ("transform.host_stages", "rff.python_rows", "map_profile.columns",
-            "map_profile.keys", "transform.wires_ahead")
+            "map_profile.keys", "transform.wires_ahead", "pivot.python_values")
 
 
 def reader(name):
@@ -242,6 +242,8 @@ def test_spans_and_counters_of_a_pooled_train(cell, traced):
     assert traced["counted"]["map_profile.keys"] == joins.attrs["keys"]
     assert traced["counted"]["transform.host_stages"] == 0
     assert traced["counted"]["rff.python_rows"] == 0
+    # the ID, place and pick-list pivots read their interning as arrays
+    assert traced["counted"]["pivot.python_values"] == 0
     # the six pivots, the two dates, the coordinate, the two maps and the
     # two sets: their wires made by the pool's jobs
     assert traced["counted"]["transform.wires_ahead"] == 13
@@ -264,6 +266,7 @@ def test_without_a_pool_the_fit_walks_and_nothing_is_joined(cell, data):
     assert counts["map_profile.columns"] == 4
     assert counts["transform.host_stages"] == 0
     assert counts["rff.python_rows"] == 0
+    assert counts["pivot.python_values"] == 0
 
 
 def test_map_and_set_scopes_are_in_the_fused_program(cell, data):

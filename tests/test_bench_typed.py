@@ -36,7 +36,8 @@ MANIFEST = run.load_json("BENCHMARK.json")
 TINY = run.cpu_cells()[CELL]
 STATED = plain.Precision.stated("cpu")
 COUNTERS = ("transform.host_stages", "rff.python_rows",
-            "sanity.groups_dropped", "selector.family_rounds")
+            "sanity.groups_dropped", "selector.family_rounds",
+            "pivot.python_values")
 PERIODS = ("HourOfDay", "DayOfWeek", "DayOfMonth", "DayOfYear")
 ULP = float(np.finfo(np.float32).eps)
 
@@ -707,7 +708,8 @@ def test_spans_and_counters_of_a_traced_train(cell, traced):
     assert traced["counted"] == {"transform.host_stages": 0,
                                  "rff.python_rows": 0,
                                  "sanity.groups_dropped": 1,
-                                 "selector.family_rounds": 4}
+                                 "selector.family_rounds": 4,
+                                 "pivot.python_values": 0}
     profile = traced["profile"]
     # inline, as every train under 100,000 rows is: the flush's span holds
     # its stages' prologues (with a pool they are workers' jobs, started at
@@ -733,7 +735,7 @@ def test_no_host_stage_and_no_python_row_in_the_other_programs(other):
     jax.clear_caches()
     assert not rec["why_failed"], rec["why_failed"]
     for name in ("transform.host_stages", "rff.python_rows",
-                 "sanity.groups_dropped"):
+                 "sanity.groups_dropped", "pivot.python_values"):
         assert after[name] is not None and after[name] == before[name], name
 
 

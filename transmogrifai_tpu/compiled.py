@@ -342,12 +342,14 @@ class ScoreProgram:
     # -- execution ----------------------------------------------------------
     def __call__(self, batch: ColumnBatch, keep_intermediate: bool = False
                  ) -> ColumnBatch:
-        # stages run outside a compiled segment, and input columns whose
-        # wire a train's prologue pool made ahead (``ColumnWired``); created
+        # stages run outside a compiled segment, input columns whose wire a
+        # train's prologue pool made ahead (``ColumnWired``), and values a
+        # pivot handled one by one in Python (``ops.categorical``); created
         # at 0 by a flush that counts none, so that a reader can tell 0 from
         # no counter
         host_stages = REGISTRY.counter("transform.host_stages")
         REGISTRY.counter("transform.wires_ahead")
+        REGISTRY.counter("pivot.python_values")
         for _attempt in range(len(self.stages) + 1):
             segments = self._partition(batch)
             b = batch

@@ -35,14 +35,40 @@ def _col_strings(col: Column) -> np.ndarray:
     return vals.astype(object)
 
 
-def top_values_by_count(counts, top_k: int, min_support: int):
+def top_value_indices(values: Sequence, counts, top_k: int,
+                      min_support: int) -> List[int]:
     """Reference top-value selection (SmartTextVectorizer.scala:97-100,
-    OpOneHotVectorizer): drop values below ``min_support``, order by
-    (count desc, value asc), take ``top_k``.  The returned ORDER is the
-    pivot column layout — most frequent value first."""
-    eligible = [(v, c) for v, c in counts.items() if c >= min_support]
-    eligible.sort(key=lambda vc: (-vc[1], vc[0]))
-    return [v for v, _ in eligible[:top_k]]
+    OpOneHotVectorizer) as indices into ``values`` (distinct) and their
+    ``counts``: drop values below ``min_support``, order by (count desc,
+    value asc), take ``top_k``.  The ORDER is the pivot column layout —
+    most frequent value first.  Only the values counted at least as often
+    as the ``top_k``-th eligible one, ties included, are sorted in Python,
+    so a million distinct member ids cost numpy passes over ``counts``."""
+    counts = np.asarray(counts)
+    eligible = np.flatnonzero(counts >= min_support)
+    if 0 < top_k < eligible.size:
+        held = counts[eligible]
+        kth = np.partition(held, held.size - top_k)[held.size - top_k]
+        eligible = eligible[held >= kth]
+    order = eligible.tolist()
+    order.sort(key=lambda i: (-counts[i], values[i]))
+    return order[:top_k]
+
+
+def top_values_by_count(counts: Dict, top_k: int, min_support: int):
+    """``top_value_indices`` over a dict of value → count (a Counter)."""
+    values = list(counts)
+    at = top_value_indices(values, np.asarray(list(counts.values())), top_k,
+                           min_support)
+    return [values[i] for i in at]
+
+
+def _python_values():
+    """Distinct values a pivot's fit or id wire handled one by one in
+    Python (a row each where the column is not held as strings): 0 where
+    both read the column's interning as arrays."""
+    from ..telemetry import REGISTRY
+    return REGISTRY.counter("pivot.python_values")
 
 
 def encode_with_vocab(values: np.ndarray, vocab: Dict[str, int], other_id: int) -> np.ndarray:
@@ -57,18 +83,37 @@ def encode_with_vocab(values: np.ndarray, vocab: Dict[str, int], other_id: int) 
     return out
 
 
+def _vocab_table(uniq: List[str], vocab: Dict[str, int],
+                 other_id: int) -> np.ndarray:
+    """int32[U]: each distinct value's vocabulary position, ``other_id``
+    where it has none.  One native pass of ``uniq`` against a table of the
+    vocabulary's few values (native/textprof.cpp ``lookup``, its probes out
+    of the GIL); a ``dict.get`` a value where the helper is missing."""
+    from ..native import load
+    native = load("textprof")
+    if native is None:
+        _python_values().inc(len(uniq))
+        return np.fromiter((vocab.get(v, other_id) for v in uniq), np.int32,
+                           count=len(uniq))
+    at = native.lookup(uniq, list(vocab))
+    position = np.append(np.fromiter(vocab.values(), np.int32, len(vocab)),
+                         np.int32(other_id))
+    return position[np.where(at >= 0, at, len(vocab))]
+
+
 def encode_column(col: Column, vocab: Dict[str, int], other_id: int) -> np.ndarray:
     """``encode_with_vocab`` through the cached one-pass column profile:
     the per-row dict probe collapses to one small table lookup over the
     interned codes (native/textprof.cpp)."""
     if not col.is_host_object():
-        return encode_with_vocab(_col_strings(col), vocab, other_id)
+        values = _col_strings(col)
+        _python_values().inc(len(values))
+        return encode_with_vocab(values, vocab, other_id)
     from .text_profile import column_profile
     iv = column_profile(col).values(-1)
     if not iv.uniq:    # all-null column
         return np.full(len(iv.codes), other_id + 1, np.int32)
-    table = np.fromiter((vocab.get(v, other_id) for v in iv.uniq), np.int32,
-                        count=len(iv.uniq))
+    table = _vocab_table(iv.uniq, vocab, other_id)
     return np.where(iv.codes < 0, np.int32(other_id + 1),
                     table[np.maximum(iv.codes, 0)]).astype(np.int32)
 
@@ -177,11 +222,14 @@ class OneHotEstimator(Estimator):
             col = batch[f.name]
             if col.is_host_object():
                 from .text_profile import column_profile
-                counts = column_profile(col).values(-1).value_counts()
+                iv = column_profile(col).values(-1)
+                top = [iv.uniq[i] for i in top_value_indices(
+                    iv.uniq, iv.counts, top_k, min_support)]
             else:
                 counts = Counter(
                     v for v in _col_strings(col) if v is not None)
-            top = top_values_by_count(counts, top_k, min_support)
+                _python_values().inc(len(counts))
+                top = top_values_by_count(counts, top_k, min_support)
             vocab = {v: i for i, v in enumerate(top)}
             vocabs[f.name] = vocab
             for v in top:

@@ -622,15 +622,17 @@ def test_readers_and_appended_cells_say_what_benchmark_json_says():
         # them that reads something there
         assert m["workloads"] == [CELL] + ["typed_sweep", "maps_sweep"] * (
             name == "text_profile_s") + ["maps_sweep"] * (
-            name != "text_profile_s") and m["better"] == "lower"
+            name != "text_profile_s") + ["lists_sweep"] \
+            and m["better"] == "lower"
         assert m["layer"] in {e["layer"] for e in MANIFEST["per_layer"][:18]}
     for name in ("prologue_s", "selector_s", "host_link_MB",
                  "window_compiles", "setup_compile_s", "device_idle_share",
                  "sweep_mfu", "peak_hbm_GiB", "prologue_idle_s",
                  "transform_s", "sanity_s", "refit_s", "train_jit_s"):
         # appended with this cell, then the next cells after it
-        assert entries[name]["workloads"][-3:] == [CELL, "typed_sweep",
-                                                   "maps_sweep"], name
+        assert entries[name]["workloads"][-4:] == [CELL, "typed_sweep",
+                                                   "maps_sweep",
+                                                   "lists_sweep"], name
     for name in ("mesh_devices", "place_s", "relayout_MB", "sweep_mfu_x4",
                  "chip_rows_per_s"):
         assert CELL not in entries[name]["workloads"], name

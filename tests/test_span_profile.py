@@ -634,7 +634,7 @@ def test_readers_say_what_benchmark_json_says():
             entry["layer"], entry["unit"], entry["source"], entry["moves"])
         assert entry["workloads"] == ["mixed_sweep", "mixed_sweep_x4",
                                       "text_sweep", "typed_sweep",
-                                      "maps_sweep"]
+                                      "maps_sweep", "lists_sweep"]
         assert entry["better"] == "lower"
 
 

@@ -197,6 +197,100 @@ def to_device_f32(values, exact: bool = False) -> Any:
     return dev
 
 
+class RaggedList:
+    """A column of lists of int64 (a ``DateList``'s epoch milliseconds) held
+    as two arrays, the form a join produces: ``values`` every row's items in
+    row order, ``offsets`` [N + 1] where each row's start (row i is
+    ``values[offsets[i]:offsets[i + 1]]``).  A row without a list is an
+    empty one, as ``OPList`` reads None.  Read as a sequence it gives each
+    row as a Python list; ``np.asarray`` of it is the object array of lists
+    that the row-by-row consumers read."""
+
+    __slots__ = ("values", "offsets", "__weakref__")
+
+    def __init__(self, values, offsets):
+        self.values = np.ascontiguousarray(values, dtype=np.int64)
+        self.offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+        if (self.offsets.ndim != 1 or self.offsets.size == 0
+                or self.offsets[0] != 0
+                or self.offsets[-1] != self.values.size
+                or (np.diff(self.offsets) < 0).any()):
+            raise ValueError("offsets must rise from 0 to len(values)")
+
+    @classmethod
+    def from_lists(cls, lists) -> "RaggedList":
+        """The object-held form (lists, tuples, arrays, ``OPList``s or
+        None, a row each) in one pass over its rows."""
+        rows = [getattr(v, "value", v) if isinstance(v, FeatureType) else v
+                for v in lists]
+        lengths = np.fromiter((0 if v is None else len(v) for v in rows),
+                              np.int64, len(rows))
+        offsets = np.zeros(len(rows) + 1, np.int64)
+        np.cumsum(lengths, out=offsets[1:])
+        values = np.fromiter(
+            (x for v in rows if v is not None for x in v), np.int64,
+            int(offsets[-1]))
+        return cls(values, offsets)
+
+    def __len__(self) -> int:
+        return self.offsets.size - 1
+
+    def lengths(self) -> np.ndarray:
+        return np.diff(self.offsets)
+
+    def __getitem__(self, i):
+        if isinstance(i, (int, np.integer)):
+            i = int(i) + (len(self) if i < 0 else 0)
+            return self.values[self.offsets[i]:self.offsets[i + 1]].tolist()
+        return self.take(np.arange(len(self))[i])
+
+    def __iter__(self):
+        o = self.offsets.tolist()
+        for a, b in zip(o[:-1], o[1:]):
+            yield self.values[a:b].tolist()
+
+    def take(self, idx) -> "RaggedList":
+        """The rows ``idx`` (integers or a boolean mask), in that order."""
+        idx = np.asarray(idx)
+        if idx.dtype == bool:
+            idx = np.flatnonzero(idx)
+        starts = self.offsets[:-1][idx]
+        lengths = self.offsets[1:][idx] - starts
+        offsets = np.zeros(len(idx) + 1, np.int64)
+        np.cumsum(lengths, out=offsets[1:])
+        at = np.arange(offsets[-1], dtype=np.int64) + np.repeat(
+            starts - offsets[:-1], lengths)
+        return RaggedList(self.values[at], offsets)
+
+    def __array__(self, dtype=None, copy=None):
+        out = np.empty(len(self), dtype=object)
+        out[:] = list(self)
+        return out if dtype is None else out.astype(dtype)
+
+    def __eq__(self, other) -> bool:
+        return (isinstance(other, RaggedList)
+                and np.array_equal(self.offsets, other.offsets)
+                and np.array_equal(self.values, other.values))
+
+    __hash__ = None
+
+
+def ragged_of(col: "Column") -> RaggedList:
+    """``col``'s lists as a ``RaggedList``: its values where it is held so,
+    else its object-held lists converted once and kept on the Column (which
+    is immutable throughout the framework)."""
+    if isinstance(col.values, RaggedList):
+        return col.values
+    held = getattr(col, "_ragged", None)
+    if held is None:
+        held = RaggedList.from_lists(col.values)
+        try:
+            object.__setattr__(col, "_ragged", held)
+        except Exception:  # pragma: no cover — exotic column subtype
+            pass
+    return held
+
+
 @dataclass
 class Column:
     """A typed column of N rows.
@@ -207,7 +301,8 @@ class Column:
       * text kinds      — ``values``: numpy object array [N] of str | None (host).
       * OPVector        — ``values``: float32 array [N, D]; ``meta``: VectorMeta.
       * Geolocation     — ``values``: float32 [N, 3]; ``mask``: bool [N].
-      * lists/sets      — ``values``: numpy object array [N] of list/set (host).
+      * lists/sets      — ``values``: numpy object array [N] of list/set (host);
+                        a DateList's may be a ``RaggedList`` instead.
       * maps            — ``values``: numpy object array [N] of dict (host).
       * Prediction      — ``values``: dict with 'prediction' [N] and optionally
                         'probability' [N, C], 'rawPrediction' [N, C] arrays.
@@ -228,6 +323,8 @@ class Column:
         """True if values are dense arrays usable inside jit."""
         if isinstance(self.values, dict):
             return True
+        if isinstance(self.values, RaggedList):
+            return False
         return not (isinstance(self.values, np.ndarray) and self.values.dtype == object)
 
     def row_value(self, i: int) -> FeatureType:
@@ -250,7 +347,7 @@ class Column:
             if self.mask is not None and not bool(np.asarray(self.mask)[i]):
                 return Geolocation()
             return Geolocation(list(np.asarray(self.values)[i].tolist()))
-        if self.is_host_object():
+        if self.is_host_object() or isinstance(self.values, RaggedList):
             return k(self.values[i])
         v = np.asarray(self.values)[i]
         if self.mask is not None and not bool(np.asarray(self.mask)[i]):
@@ -353,6 +450,8 @@ def column_from_values(kind: Type[FeatureType], values: Iterable) -> Column:
                 continue  # missing vector → zero row (lenient, like fills)
             arr[i, :] = np.asarray(data, dtype=np.float32)
         return Column(OPVector, arr)
+    if issubclass(kind, DateList):
+        return Column(kind, RaggedList.from_lists(values))
     return object_column(kind, values)
 
 
@@ -412,6 +511,8 @@ class ColumnBatch:
                 vals = {k: np.asarray(v)[idx] for k, v in c.values.items()}
             elif isinstance(c.values, SparseMatrix):
                 vals = c.values.take_rows(idx)   # stays sparse end-to-end
+            elif isinstance(c.values, RaggedList):
+                vals = c.values.take(idx)
             else:
                 vals = np.asarray(c.values)[idx]
             mask = None if c.mask is None else np.asarray(c.mask)[idx]

@@ -22,10 +22,10 @@ import numpy as np
 
 import zlib
 
-from .columns import Column, ColumnBatch
+from .columns import Column, ColumnBatch, RaggedList, ragged_of
 from .features import Feature
 from .telemetry import REGISTRY, span
-from .types import is_map_kind, is_numeric_kind, is_text_kind
+from .types import DateList, is_map_kind, is_numeric_kind, is_text_kind
 
 
 @dataclass
@@ -182,6 +182,11 @@ def compute_sketches(raw_features: Sequence[Feature], batch: ColumnBatch,
                 f.name, None, n,
                 int(sum(1 for m in col.values if not m)),
                 text_counts=np.zeros(text_bins))
+            continue
+        if _is_date_list(col):
+            out[(f.name, None)] = FeatureSketch(
+                f.name, None, n, int((~_value_presence(col)).sum()),
+                text_counts=_date_list_bins(col, text_bins, f.name))
             continue
         if _list_held_as_array(col):
             present = _value_presence(col)
@@ -396,7 +401,21 @@ def _array_item_bins(values: np.ndarray, present: np.ndarray,
 def _list_held_as_array(col: Column) -> bool:
     """A column of lists (a Geolocation's triples) stored as one array."""
     return not col.is_host_object() and not is_numeric_kind(col.kind) \
-        and not isinstance(col.values, dict)
+        and not isinstance(col.values, (dict, RaggedList))
+
+
+def _is_date_list(col: Column) -> bool:
+    """A date-list column, held ragged or as lists of dates."""
+    return isinstance(col.values, RaggedList) or (
+        col.is_host_object() and issubclass(col.kind, DateList))
+
+
+def _date_list_bins(col: Column, text_bins: int, name=None) -> np.ndarray:
+    """The row walk's text branch over a date-list column (every date of
+    every row by the crc32 of its decimal string), read from the column's
+    one walk (``ops.list_profile.date_profile``)."""
+    from .ops.list_profile import date_profile
+    return date_profile(col, text_bins, name).hist.copy()
 
 
 def _stable_text_bin(item, text_bins: int) -> int:
@@ -407,6 +426,9 @@ def _stable_text_bin(item, text_bins: int) -> int:
 
 
 def _value_presence(col: Column) -> np.ndarray:
+    if _is_date_list(col):
+        # an empty list, or none, is null
+        return ragged_of(col).lengths() > 0
     if col.is_host_object():
         if is_text_kind(col.kind):
             # cached one-pass profile (ops/text_profile.py) — the same scan
@@ -583,6 +605,8 @@ def compute_distribution(feature: Feature, col: Column, bins: int,
         # hashed whole-value bins straight from the cached column profile
         from .ops.text_profile import column_profile
         dist = column_profile(col).crc_hist(text_bins)
+    elif _is_date_list(col):
+        dist = _date_list_bins(col, text_bins, feature.name)
     elif _list_held_as_array(col):
         dist = _array_item_bins(np.asarray(col.values), present, text_bins)
     elif (exp := _expansion(col)) is not None:
@@ -703,9 +727,10 @@ def _passes_without_gil(f: Feature, col: Column) -> bool:
     column's native range and histogram, a list-valued array's
     ``np.unique``), strings (the native walk, or its cached profile), a
     string-valued map or a set column (the native walk of
-    ``ops.map_profile``, or its cached expansion).  Numeric maps and other
-    Python objects are walked in Python (``rff.python_rows``) and stay with
-    the thread that filters."""
+    ``ops.map_profile``, or its cached expansion), a ragged date list (the
+    native walk of ``ops.list_profile``).  Numeric maps and other Python
+    objects are walked in Python (``rff.python_rows``) and stay with the
+    thread that filters."""
     from .ops.map_profile import is_set_kind, is_string_map
     if col.is_host_object():
         return (is_text_kind(f.kind) or is_string_map(f.kind)

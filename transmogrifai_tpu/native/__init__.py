@@ -26,8 +26,8 @@ import threading
 import warnings
 from typing import Any, Dict, Optional
 
-MODULES = ("datewire", "fastcsv", "fasttok", "locofmt", "mapprof", "numdist",
-           "textprof")
+MODULES = ("datewire", "fastcsv", "fasttok", "listprof", "locofmt", "mapprof",
+           "numdist", "textprof")
 
 _CACHE: dict = {}
 _REASONS: Dict[str, str] = {}

@@ -101,16 +101,30 @@ def _vocab_table(uniq: List[str], vocab: Dict[str, int],
     return position[np.where(at >= 0, at, len(vocab))]
 
 
-def encode_column(col: Column, vocab: Dict[str, int], other_id: int) -> np.ndarray:
-    """``encode_with_vocab`` through the cached one-pass column profile:
-    the per-row dict probe collapses to one small table lookup over the
-    interned codes (native/textprof.cpp)."""
-    if not col.is_host_object():
+def pivot_values(col: Column, kind=None, name: Optional[str] = None):
+    """The ``InternedValues`` a pivot of ``col`` counts: for a URL or Email
+    ``kind``, the interned domains of one walk of the column
+    (``ops.list_profile.domain_values``), else the column's own values from
+    its cached profile."""
+    if kind is not None:
+        from .list_profile import domain_kind, domain_values
+        if domain_kind(kind) is not None:
+            return domain_values(col, kind, name)
+    from .text_profile import column_profile
+    return column_profile(col).values(-1)
+
+
+def encode_column(col: Column, vocab: Dict[str, int], other_id: int,
+                  iv=None) -> np.ndarray:
+    """``encode_with_vocab`` through the cached one-pass column profile
+    (or the interning ``iv`` given): the per-row dict probe collapses to one
+    small table lookup over the interned codes (native/textprof.cpp)."""
+    if iv is None and not col.is_host_object():
         values = _col_strings(col)
         _python_values().inc(len(values))
         return encode_with_vocab(values, vocab, other_id)
-    from .text_profile import column_profile
-    iv = column_profile(col).values(-1)
+    if iv is None:
+        iv = pivot_values(col)
     if not iv.uniq:    # all-null column
         return np.full(len(iv.codes), other_id + 1, np.int32)
     table = _vocab_table(iv.uniq, vocab, other_id)
@@ -123,15 +137,20 @@ class OneHotModel(ColumnWired, TransformerModel):
     is_device_op = False  # host vocab lookup, then device one-hot
     supports_staging = True
 
+    def _interned(self, i: int, col: Column):
+        """What input ``i``'s pivot counts (``pivot_values``)."""
+        f = self.input_features[i]
+        return pivot_values(col, f.kind, f.name)
+
     def column_wire(self, i: int, col: Column):
         """``ids{i}``: input ``i`` vocab-encoded through its cached column
-        profile (uint8 where the ids fit); None for a column not held as
-        strings."""
+        profile, or its domains' interning (uint8 where the ids fit); None
+        for a column not held as strings."""
         if not col.is_host_object():
             return None
         vocab: Dict[str, int] = self.fitted["vocabs"][
             self.input_features[i].name]
-        ids = encode_column(col, vocab, len(vocab))
+        ids = encode_column(col, vocab, len(vocab), self._interned(i, col))
         return {f"ids{i}": ids.astype(np.uint8) if len(vocab) + 1 < 256
                 else ids}
 
@@ -176,10 +195,13 @@ class OneHotModel(ColumnWired, TransformerModel):
         outs = []
         track_other = self.get("track_other", True)
         track_nulls = self.get("track_nulls", True)
-        for f in self.input_features:
+        for i, f in enumerate(self.input_features):
             vocab: Dict[str, int] = self.fitted["vocabs"][f.name]
             other_id = len(vocab)
-            ids = encode_column(batch[f.name], vocab, other_id)
+            col = batch[f.name]
+            ids = encode_column(col, vocab, other_id,
+                                self._interned(i, col)
+                                if col.is_host_object() else None)
             # full encoding always has [vocab..., OTHER, NULL]; select only the
             # slots this model tracks so columns stay aligned with the meta
             cols = list(range(other_id))
@@ -203,7 +225,10 @@ class OneHotModel(ColumnWired, TransformerModel):
 
 class OneHotEstimator(Estimator):
     """Pivot top-K categorical values into indicator columns with OTHER and
-    null slots (≙ OpOneHotVectorizer/OneHotEstimator)."""
+    null slots (≙ OpOneHotVectorizer/OneHotEstimator).  A URL input pivots
+    its valid url's host and an Email input its domain (≙ transmogrify's
+    url.toDomain / email.toDomain before the pivot), read from one walk of
+    the column (``ops.list_profile``)."""
 
     out_kind = OPVector
 
@@ -221,8 +246,7 @@ class OneHotEstimator(Estimator):
         for f in self.input_features:
             col = batch[f.name]
             if col.is_host_object():
-                from .text_profile import column_profile
-                iv = column_profile(col).values(-1)
+                iv = pivot_values(col, f.kind, f.name)
                 top = [iv.uniq[i] for i in top_value_indices(
                     iv.uniq, iv.counts, top_k, min_support)]
             else:

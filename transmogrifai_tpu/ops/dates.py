@@ -204,16 +204,110 @@ class TimePeriodTransformer(Transformer):
         return Column(Integral, out, mask=col.mask)
 
 
-class DateListVectorizerModel(TransformerModel):
+# DateListVectorizer's mode pivots: pivot -> (period, width)
+_MODES = {"ModeDay": ("DayOfWeek", 7), "ModeMonth": ("MonthOfYear", 12),
+          "ModeHour": ("HourOfDay", 24)}
+
+
+def _period_index(ms: np.ndarray, period: str) -> np.ndarray:
+    """int64 epoch milliseconds -> the period's index as the eager
+    transform counts it (floor arithmetic, as Python's)."""
+    if period == "DayOfWeek":
+        return ((ms + _EPOCH_DOW_SHIFT) % _MS_WEEK) // _MS_DAY
+    if period == "MonthOfYear":
+        return ((ms % _MS_YEAR) // int(30.44 * _MS_DAY)) % 12
+    return (ms % _MS_DAY) // _MS_HOUR
+
+
+def _mode_index(ragged, pivot: str) -> np.ndarray:
+    """uint8[N]: each row's most frequent period index, a tie going to the
+    index first seen in the row (``Counter.most_common``'s order), 255 for
+    an empty row."""
+    lengths = ragged.lengths()
+    out = np.full(len(lengths), 255, np.uint8)
+    if not ragged.values.size:
+        return out
+    period, width = _MODES[pivot]
+    rows = np.repeat(np.arange(len(lengths), dtype=np.int64), lengths)
+    keys = rows * width + _period_index(ragged.values, period)
+    uniq, first, counts = np.unique(keys, return_index=True,
+                                    return_counts=True)
+    # by row, then count descending, then first occurrence
+    order = np.lexsort((first, -counts, uniq // width))
+    uniq = uniq[order]
+    lead = np.r_[True, uniq[1:] // width != uniq[:-1] // width]
+    out[uniq[lead] // width] = (uniq[lead] % width).astype(np.uint8)
+    return out
+
+
+class DateListVectorizerModel(ColumnWired, TransformerModel):
     out_kind = OPVector
-    is_device_op = False
+    is_device_op = False  # host walk of the lists, then device
+    supports_staging = True
+
+    def column_wire(self, i: int, col: Column):
+        """Input ``i``'s wire from one walk of its lists
+        (``ops.list_profile``): for ``SinceFirst`` / ``SinceLast``,
+        ``days{i}`` the float32 days from ``reference_ms`` to the row's
+        first or last date (0 for an empty row), exactly the eager
+        transform's float64 quotient rounded once, and ``null{i}`` its
+        packed null bits; for a mode pivot, ``mode{i}`` the uint8 index of
+        the row's most frequent period (255 for none).  Nothing fitted is
+        read."""
+        from .list_profile import date_profile
+        name = self.input_features[i].name
+        pivot = self.get("pivot")
+        if pivot in ("SinceFirst", "SinceLast"):
+            prof = date_profile(col, name=name)
+            pick = prof.lo if pivot == "SinceFirst" else prof.hi
+            days = ((np.int64(self.get("reference_ms")) - pick)
+                    / _MS_DAY).astype(np.float32)
+            days[~prof.present] = 0.0
+            wire = {f"days{i}": days}
+            if self.get("track_nulls", True):
+                wire[f"null{i}"] = pack_bits(~prof.present)
+            return wire
+        from ..columns import ragged_of
+        return {f"mode{i}": _mode_index(ragged_of(col), pivot)}
+
+    def transform_staged(self, batch: ColumnBatch, parts=None):
+        """Host prologue: every input's ``column_wire`` (or ``parts`` made
+        by it already).  Device body: the days and null column, or the
+        mode's one-hot, input by input."""
+        pivot = self.get("pivot")
+        track_nulls = self.get("track_nulls", True)
+        parts = self.column_wires(batch) if parts is None else parts
+        wire = {k: v for part in parts for k, v in part.items()}
+        count = len(self.input_features)
+        meta = self.fitted["meta"]
+
+        def body(w):
+            outs = []
+            for i in range(count):
+                if pivot in ("SinceFirst", "SinceLast"):
+                    days = w[f"days{i}"]
+                    outs.append(days[:, None])
+                    if track_nulls:
+                        outs.append(unpack_bits_device(
+                            w[f"null{i}"], days.shape[0])[:, None])
+                else:
+                    width = _MODES[pivot][1]
+                    mode = w[f"mode{i}"].astype(jnp.int32)
+                    outs.append((mode[:, None] == jnp.arange(width)[None, :]
+                                 ).astype(jnp.float32))
+            return Column(OPVector, jnp.concatenate(outs, axis=1), meta=meta)
+
+        return wire, body
 
     def transform(self, batch: ColumnBatch) -> Column:
+        """The unstaged form, row by row over each row's list."""
         pivot = self.get("pivot")
         ref = self.get("reference_ms")
         outs = []
         for f in self.input_features:
-            lists = batch[f.name].values
+            col = batch[f.name]
+            lists = (col.values if col.is_host_object()
+                     else list(col.values))
             if pivot in ("SinceFirst", "SinceLast"):
                 pick = min if pivot == "SinceFirst" else max
                 vals, mask = [], []
@@ -228,9 +322,7 @@ class DateListVectorizerModel(TransformerModel):
                 if self.get("track_nulls", True):
                     outs.append((~np.asarray(mask, bool)).astype(np.float32)[:, None])
             else:  # ModeDay / ModeMonth / ModeHour pivots one-hot the mode
-                period = {"ModeDay": ("DayOfWeek", 7), "ModeMonth": ("MonthOfYear", 12),
-                          "ModeHour": ("HourOfDay", 24)}[pivot]
-                name, width = period
+                name, width = _MODES[pivot]
                 block = np.zeros((len(lists), width), np.float32)
                 for i, lst in enumerate(lists):
                     if not lst:
@@ -273,7 +365,7 @@ class DateListVectorizer(Estimator):
                     cols_meta.append(VectorColumnMeta(
                         f.name, f.kind.__name__, indicator_value=NULL_INDICATOR))
             else:
-                width = {"ModeDay": 7, "ModeMonth": 12, "ModeHour": 24}[pivot]
+                width = _MODES[pivot][1]
                 for j in range(width):
                     cols_meta.append(VectorColumnMeta(
                         f.name, f.kind.__name__,

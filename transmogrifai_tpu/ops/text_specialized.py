@@ -96,13 +96,11 @@ class EmailToPickListTransformer(Transformer):
     is_device_op = False
 
     def transform(self, batch: ColumnBatch) -> Column:
+        """The domains of one walk of the column (``ops.list_profile``)."""
+        from .list_profile import domain_column, domain_values
         (f,) = self.input_features
-        strings = _col_strings(batch[f.name])
-        out = np.empty(len(strings), object)
-        for i, s in enumerate(strings):
-            _, d = email_parts(s)
-            out[i] = d
-        return Column(PickList, out)
+        return domain_column(domain_values(batch[f.name], Email, f.name),
+                             PickList)
 
 
 class UrlToPickListTransformer(Transformer):
@@ -114,12 +112,11 @@ class UrlToPickListTransformer(Transformer):
     is_device_op = False
 
     def transform(self, batch: ColumnBatch) -> Column:
+        """The hosts of one walk of the column (``ops.list_profile``)."""
+        from .list_profile import domain_column, domain_values
         (f,) = self.input_features
-        strings = _col_strings(batch[f.name])
-        out = np.empty(len(strings), object)
-        for i, s in enumerate(strings):
-            out[i] = url_domain(s)
-        return Column(PickList, out)
+        return domain_column(domain_values(batch[f.name], URL, f.name),
+                             PickList)
 
 
 class EmailMapToPickListMapTransformer(Transformer):

@@ -95,13 +95,17 @@ def transmogrify(features: Sequence[Feature],
 
     # specialized text kinds route through their validators/extractors first
     # (≙ TextTransmogrify cases, Transmogrifier.scala:116-180: email/url →
-    # domain picklist, base64 → mime-type picklist, phone → isValid binary)
-    from .text_specialized import (EmailToPickListTransformer,
-                                   IsValidPhoneDefaultCountry,
-                                   MimeTypeDetector, UrlToPickListTransformer)
+    # domain picklist, base64 → mime-type picklist, phone → isValid binary).
+    # An email's or a url's domain is pivoted by the categorical pivot itself
+    # (``OneHotEstimator``) from one native walk of the column,
+    # so that no stage between the raw column and the pivot runs outside the
+    # fused program
+    from .text_specialized import IsValidPhoneDefaultCountry, MimeTypeDetector
+    for group in ("email", "url"):
+        groups.setdefault("categorical", []).extend(groups.pop(group, []))
+    if not groups["categorical"]:
+        del groups["categorical"]
     specialized_routes = [
-        ("email", EmailToPickListTransformer, "categorical"),
-        ("url", UrlToPickListTransformer, "categorical"),
         ("base64", MimeTypeDetector, "categorical"),
         ("phone", IsValidPhoneDefaultCountry, "binary"),
     ]

@@ -394,20 +394,29 @@ class Workflow(_WorkflowCore):
         import jax
         accelerator = jax.default_backend() != "cpu"
         columns = self._profiled_columns(batch) if accelerator else []
-        # the map and set walks first: the longest jobs of the prologue
+        # the map and set walks first: the longest jobs of the prologue;
+        # then the date-list and domain walks
         walks = [(name, pool.submit(partial(expand, col, name)))
                  for name, col, expand in (self._expanded_columns(batch)
                                            if pool is not None else [])]
+        lists = [(name, col, pool.submit(walk))
+                 for name, col, walk in (self._list_columns(batch)
+                                         if pool is not None else [])]
         started = None
         if pool is not None and self._raw_feature_filter is not None:
+            from .types import DateList
             started = self._raw_feature_filter.start_distributions(
                 batch, self.raw_features, pool,
                 walked=[name for name, _ in columns]
-                + [name for name, _ in walks])
+                + [name for name, _ in walks]
+                + [name for name, col, _ in lists
+                   if issubclass(col.kind, DateList)])
         if accelerator:
             self._prefetch_on_accelerator(batch, pool, columns, started)
         if walks:
             self._join_map_walks(batch, pool, walks, started)
+        if lists:
+            self._join_list_walks(batch, pool, lists, started)
 
     @staticmethod
     def _join_map_walks(batch, pool, walks, started) -> None:
@@ -432,6 +441,66 @@ class Workflow(_WorkflowCore):
                     started.walked(name)
             if sp is not None:
                 sp.attrs["keys"] = keys
+
+    @staticmethod
+    def _join_list_walks(batch, pool, lists, started) -> None:
+        """The date-list and domain walks joined in the features' order
+        (their waits in ``prologue.wait_s``), the filter's job for a date
+        list let go as its walk is whole; the span ``prefetch.lists``."""
+        from .telemetry import span
+        with span("prefetch.lists", columns=len(lists), rows=len(batch)
+                  ) as sp:
+            values = 0
+            for name, col, walk in lists:
+                try:
+                    done = pool.join(walk)
+                except Exception as e:  # noqa: BLE001 — the consumer walks
+                    # the column itself and says what is wrong there
+                    from .resilience import record_failure
+                    record_failure("workflow.prefetch", "swallowed", e,
+                                   point="workflow.prefetch")
+                    continue
+                values += (int(done.lengths.sum()) if hasattr(done, "lengths")
+                           else int((done.codes >= 0).sum()))
+                if started is not None:
+                    started.walked(name)
+            if sp is not None:
+                sp.attrs["values"] = values
+
+    def _list_columns(self, batch):
+        """[(feature name, column, its walk)] of the date-list columns a
+        ``DateListVectorizer`` reads and the URL and e-mail columns whose
+        domains a pivot or an extractor reads, once each: what the prefetch
+        walks ahead of RawFeatureFilter and the fits
+        (``ops.list_profile``).  A date list's walk takes the filter's
+        histogram too."""
+        from .ops.categorical import OneHotEstimator
+        from .ops.dates import DateListVectorizer
+        from .ops.list_profile import date_profile, domain_kind, domain_values
+        from .ops.text_specialized import (EmailToPickListTransformer,
+                                           UrlToPickListTransformer)
+        rff = self._raw_feature_filter
+        bins = rff.text_bins if rff is not None else 0
+        out, seen = [], set()
+        for st in dag_stages(compute_dag(self.result_features)):
+            dates = isinstance(st, DateListVectorizer)
+            if not (dates or isinstance(st, (UrlToPickListTransformer,
+                                             EmailToPickListTransformer,
+                                             OneHotEstimator))):
+                continue
+            for f in st.input_features:
+                col = batch.get(f.name)
+                if col is None or f.name in seen:
+                    continue
+                if dates:
+                    walk = partial(date_profile, col, bins, f.name)
+                elif domain_kind(f.kind) is not None:
+                    walk = partial(domain_values, col, f.kind, f.name)
+                else:
+                    continue
+                seen.add(f.name)
+                out.append((f.name, col, walk))
+        return out
 
     def _prefetch_on_accelerator(self, batch, pool, columns, started) -> None:
         """The string walks and the link transfers of
@@ -500,10 +569,12 @@ class Workflow(_WorkflowCore):
         a hashing vectorizer or a pivot of this workflow reads: what
         ``profile_columns`` walks ahead of their fits."""
         from .ops.categorical import OneHotEstimator
+        from .ops.list_profile import domain_kind
         from .ops.text import HashingVectorizer, SmartTextVectorizer
         columns = []
         for st in dag_stages(compute_dag(self.result_features)):
-            if isinstance(st, OneHotEstimator):
+            pivot = isinstance(st, OneHotEstimator)
+            if pivot:
                 cap = -1        # a pivot counts every value
             elif isinstance(st, (SmartTextVectorizer, HashingVectorizer)):
                 cap = st.get("max_cardinality")  # None: no interning
@@ -517,8 +588,12 @@ class Workflow(_WorkflowCore):
                 if len(vals) and not isinstance(
                         next((v for v in vals if v is not None), ""), str):
                     continue    # token lists take the legacy path
-                columns.append(
-                    (f.name, (col, cap, int(st.get("num_hashes") or 0))))
+                # a pivot of a URL's or an e-mail's domains counts the
+                # domains' walk (``_list_columns``): the whole strings are
+                # profiled for RawFeatureFilter alone, and not interned
+                columns.append((f.name, (
+                    col, None if pivot and domain_kind(f.kind) is not None
+                    else cap, int(st.get("num_hashes") or 0))))
         return columns
 
     def _fit_plain(self, batch, dag, timer=None, pool=None):

@@ -557,6 +557,41 @@ def test_packed_null_bits_unpack_cheaply_on_the_chip(one_chip):
     assert m.temp_size_in_bytes < 48 * rows, m
 
 
+# the kept matrices of the one-chip cells: rows after the sweep's padding,
+# columns SanityChecker keeps, and the first stage's folds x grid points
+ONE_PASS_SHAPES = {"mixed_sweep": (196608, 8815, 2, 4, "logistic"),
+                   "text_sweep": (1835008, 1025, 2, 2, "squared_hinge"),
+                   "maps_sweep": (1572864, 1169, 2, 3, "logistic"),
+                   "typed_sweep": (6291456, 81, 2, 3, "squared_hinge")}
+
+
+@pytest.mark.parametrize("cell", sorted(ONE_PASS_SHAPES))
+def test_one_pass_grid_fit_compiles_for_the_chip_without_a_copy(cell,
+                                                                one_chip):
+    """The grid program with its gradients through the one-pass kernel,
+    compiled for a v5e chip at a cell's matrix.  The chip lays such a
+    matrix out column by column (no width here is a multiple of 128) and
+    the kernel reads it so: a row-major read would make XLA copy the whole
+    matrix first (3.5 GB in ``mixed_sweep``).  Here with this file's other
+    compiles: one worker loads the TPU's compiler."""
+    import jax.numpy as jnp
+    from transmogrifai_tpu.models import solvers
+    rows, cols, folds, grid, loss = ONE_PASS_SHAPES[cell]
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    with no_compile_cache():
+        compiled = solvers.linear_grid_fit.lower(
+            shape(rows, cols, dtype=jnp.bfloat16), shape(rows),
+            shape(folds, rows), shape(grid), shape(grid), loss=loss,
+            max_iter=50, one_pass=True).compile()
+    assert compiled.input_formats[0][0].layout.major_to_minor == (1, 0)
+    text = compiled.as_text()
+    assert "linear_onepass" in text
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < rows * cols // 8, m
+
+
 # (g) ----------------------------------------------------------------------
 
 NEW_READERS = ("text_pack_s", "text_profile_s", "token_pad_share")

@@ -20,6 +20,7 @@ import numpy as np
 
 from ..columns import device_matrix, to_device_f32
 from ..sparse.matrix import SparseMatrix
+from . import onepass
 from .base import PredictionModel, PredictorEstimator
 from .solvers import (FitResult, fista_fit, linear_grid_fit, naive_bayes_fit,
                       ridge_fit, ridge_grid_fit, sparse_fista_fit,
@@ -35,6 +36,27 @@ def _n_classes(y) -> int:
         # column over the host link vs one d2h scalar here
         return int(jnp.max(y)) + 1
     return int(np.max(y)) + 1
+
+
+def _count_program(one_pass: bool, n_iter) -> None:
+    """A dense FISTA program dispatched: through the one-pass kernel or the
+    two XLA products (``onepass.engages``), and the iterations of its
+    longest lane.  All three counters exist from the first program on."""
+    from ..telemetry import REGISTRY
+    names = ("linear.onepass_programs", "linear.twopass_programs")
+    counters = [REGISTRY.counter(name) for name in names]
+    counters[0 if one_pass else 1].inc()
+    REGISTRY.counter("linear.loop_iterations").inc(int(np.max(n_iter)))
+
+
+def _dense_fista_fit(X, y, w, l2, l1, *, loss: str, n_classes: int,
+                     **kw) -> FitResult:
+    """``fista_fit`` of one dense matrix, counted as a program."""
+    one = onepass.engages(X, loss, n_classes)
+    res = fista_fit(X, y, w, jnp.float32(l2), jnp.float32(l1), loss=loss,
+                    n_classes=n_classes, one_pass=one, **kw)
+    _count_program(one, res.n_iter)
+    return res
 
 
 def _grouped_grid_fit(est, X, y, fold_weights, grids, *, loss: str,
@@ -100,7 +122,8 @@ def _grouped_grid_fit(est, X, y, fold_weights, grids, *, loss: str,
             g_args = (Xj, yj, Wj, l2s, l1s)
             g_statics = dict(loss=loss, fit_intercept=fit_intercept,
                              standardization=standardization,
-                             max_iter=max_iter, tol=tol, n_classes=nc)
+                             max_iter=max_iter, tol=tol, n_classes=nc,
+                             one_pass=onepass.engages(Xj, loss, nc))
         if pretrace_mode():
             # background pre-trace: registry hit → deserialize the
             # executable now (the real fit below dispatches it directly);
@@ -111,6 +134,8 @@ def _grouped_grid_fit(est, X, y, fold_weights, grids, *, loss: str,
         coef = np.asarray(res.coef)
         inter = np.asarray(res.intercept)
         n_it = np.asarray(res.n_iter)
+        if g_fn is linear_grid_fit:
+            _count_program(g_statics["one_pass"], n_it)
         for j, gi in enumerate(gidx):
             for k in range(K):
                 out[k][gi] = {"coef": coef[k, j], "intercept": inter[k, j],
@@ -273,10 +298,11 @@ class OpLogisticRegression(PredictorEstimator):
             Xs, mean, scale = standardize(Xj, w, center=self.get("fit_intercept", True))
         else:
             Xs, mean, scale = Xj, jnp.zeros(d), jnp.ones(d)
-        res = fista_fit(Xs, jnp.asarray(y), w, jnp.float32(l2), jnp.float32(l1),
-                        loss=loss, fit_intercept=self.get("fit_intercept", True),
-                        max_iter=int(self.get("max_iter", 100)),
-                        tol=float(self.get("tol", 1e-6)), n_classes=nc)
+        res = _dense_fista_fit(Xs, jnp.asarray(y), w, l2, l1, loss=loss,
+                               n_classes=nc,
+                               fit_intercept=self.get("fit_intercept", True),
+                               max_iter=int(self.get("max_iter", 100)),
+                               tol=float(self.get("tol", 1e-6)))
         res = unscale_params(res, mean, scale, nc)
         return {"coef": np.asarray(res.coef), "intercept": np.asarray(res.intercept),
                 "kind": "binary" if C <= 2 else "multinomial",
@@ -329,12 +355,12 @@ class OpLinearSVC(PredictorEstimator):
             Xs, mean, scale = standardize(Xj, w, center=self.get("fit_intercept", True))
         else:
             Xs, mean, scale = Xj, jnp.zeros(d), jnp.ones(d)
-        res = fista_fit(Xs, jnp.asarray(y), w,
-                        jnp.float32(self.get("reg_param", 0.0)), jnp.float32(0.0),
-                        loss="squared_hinge",
-                        fit_intercept=self.get("fit_intercept", True),
-                        max_iter=int(self.get("max_iter", 100)),
-                        tol=float(self.get("tol", 1e-6)))
+        res = _dense_fista_fit(Xs, jnp.asarray(y), w,
+                               float(self.get("reg_param", 0.0)), 0.0,
+                               loss="squared_hinge", n_classes=1,
+                               fit_intercept=self.get("fit_intercept", True),
+                               max_iter=int(self.get("max_iter", 100)),
+                               tol=float(self.get("tol", 1e-6)))
         res = unscale_params(res, mean, scale, 1)
         return {"coef": np.asarray(res.coef), "intercept": np.asarray(res.intercept),
                 "kind": "svc", "n_classes": 2, "n_iter": int(res.n_iter)}
@@ -386,11 +412,12 @@ class OpLinearRegression(PredictorEstimator):
             res = ridge_fit(Xs, yj, w, jnp.float32(l2),
                             fit_intercept=self.get("fit_intercept", True))
         else:
-            res = fista_fit(Xs, yj, w, jnp.float32(l2), jnp.float32(l1),
-                            loss="squared",
-                            fit_intercept=self.get("fit_intercept", True),
-                            max_iter=int(self.get("max_iter", 100)),
-                            tol=float(self.get("tol", 1e-6)))
+            res = _dense_fista_fit(Xs, yj, w, l2, l1, loss="squared",
+                                   n_classes=1,
+                                   fit_intercept=self.get("fit_intercept",
+                                                          True),
+                                   max_iter=int(self.get("max_iter", 100)),
+                                   tol=float(self.get("tol", 1e-6)))
         res = unscale_params(res, mean, scale, 1)
         return {"coef": np.asarray(res.coef), "intercept": np.asarray(res.intercept),
                 "kind": "regression", "n_iter": int(res.n_iter)}
@@ -440,11 +467,11 @@ class OpGeneralizedLinearRegression(PredictorEstimator):
                     "n_iter": int(res.n_iter)}
         Xj, yj = jnp.asarray(X), jnp.asarray(y)
         Xs, mean, scale = standardize(Xj, w, center=self.get("fit_intercept", True))
-        res = fista_fit(Xs, yj, w, jnp.float32(self.get("reg_param", 0.0)),
-                        jnp.float32(0.0), loss=loss,
-                        fit_intercept=self.get("fit_intercept", True),
-                        max_iter=int(self.get("max_iter", 50)),
-                        tol=float(self.get("tol", 1e-6)))
+        res = _dense_fista_fit(Xs, yj, w, float(self.get("reg_param", 0.0)),
+                               0.0, loss=loss, n_classes=1,
+                               fit_intercept=self.get("fit_intercept", True),
+                               max_iter=int(self.get("max_iter", 50)),
+                               tol=float(self.get("tol", 1e-6)))
         res = unscale_params(res, mean, scale, 1)
         return {"coef": np.asarray(res.coef), "intercept": np.asarray(res.intercept),
                 "kind": "glm", "family": family, "n_iter": int(res.n_iter)}

@@ -25,6 +25,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from transmogrifai_tpu.models import onepass
 from transmogrifai_tpu.sparse.matrix import (sp_matmat, sp_matvec,
                                              sp_rmatmat, sp_rmatvec)
 
@@ -37,9 +38,9 @@ def _logistic_loss_grad(logits: jnp.ndarray, y01: jnp.ndarray, w: jnp.ndarray):
     """Binary logistic.  logits [N], y01 [N] in {0,1}, w [N] sample weights.
     Returns (mean loss, dloss/dlogits [N])."""
     ls = jax.nn.softplus(jnp.where(y01 > 0.5, -logits, logits))
-    p = jax.nn.sigmoid(logits)
     wsum = jnp.sum(w)
-    return jnp.sum(w * ls) / wsum, w * (p - y01) / wsum
+    return (jnp.sum(w * ls) / wsum,
+            w * onepass.POINTWISE["logistic"](logits, y01) / wsum)
 
 
 def _softmax_loss_grad(logits: jnp.ndarray, yoh: jnp.ndarray, w: jnp.ndarray):
@@ -61,7 +62,8 @@ def _squared_hinge_loss_grad(margin: jnp.ndarray, ypm: jnp.ndarray, w: jnp.ndarr
     """Squared hinge for linear SVC.  ypm [N] in {-1,+1}."""
     viol = jnp.maximum(0.0, 1.0 - ypm * margin)
     wsum = jnp.sum(w)
-    return jnp.sum(w * viol * viol) / wsum, w * (-2.0 * viol * ypm) / wsum
+    return (jnp.sum(w * viol * viol) / wsum,
+            w * onepass.POINTWISE["squared_hinge"](margin, ypm) / wsum)
 
 
 def _poisson_loss_grad(eta: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray):
@@ -118,42 +120,131 @@ def _exact_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
-def _about(X: jnp.ndarray, mean: jnp.ndarray,
-           pivot: Optional[jnp.ndarray]):
-    """(a function that gives X about the pivot, the mean about the pivot).
-    The subtraction is written where a product reads it, inside the loops'
-    bodies, so that it fuses into the product's operand as the storage
-    dtype's conversion does; hoisted, it would be a float32 copy of X."""
-    if pivot is None:
-        return (lambda: X), mean
-    return (lambda: X - pivot), mean - pivot
+class _Folds(NamedTuple):
+    """Per-fold operands of the dense products, each [F, ...]: row weights,
+    the same over their sum, the standardisation moments and the mean about
+    the products' pivot."""
+    w: jnp.ndarray
+    wn: jnp.ndarray
+    mean: jnp.ndarray
+    off: jnp.ndarray
+    scale: jnp.ndarray
+
+
+def _folds(fold_weights: jnp.ndarray, mean: jnp.ndarray, scale: jnp.ndarray,
+           pivot: Optional[jnp.ndarray]) -> _Folds:
+    w = fold_weights.astype(jnp.float32)
+    off = mean if pivot is None else mean - pivot
+    return _Folds(w, w / jnp.sum(w, axis=1, keepdims=True), mean, off, scale)
+
+
+def _over_lanes(fn: Callable, folds, grid_points: int) -> Callable:
+    """``fn(fold, *lane_args)`` for every lane at once, lane ``l`` being grid
+    point ``l % grid_points`` of fold ``l // grid_points``.  Mapped as
+    [F] x [G], as the per-lane fits were, so that XLA batches the lanes'
+    products into one product per pass as it did."""
+    def call(*lane_args):
+        n_folds = jax.tree_util.tree_leaves(folds)[0].shape[0]
+        split = jax.tree_util.tree_map(
+            lambda a: a.reshape((n_folds, grid_points) + a.shape[1:]),
+            lane_args)
+        out = jax.vmap(lambda fold, args: jax.vmap(
+            lambda *a: fn(fold, *a))(*args))(folds, split)
+        return jax.tree_util.tree_map(
+            lambda o: o.reshape((n_folds * grid_points,) + o.shape[2:]), out)
+    return call
+
+
+def _lane_fns(mv: Callable, tmv: Callable, target: jnp.ndarray,
+              loss: str) -> Tuple[Callable, Callable]:
+    """(grad, value) of one lane from its products: ``grad(fold, z_c, z_i)``
+    is (Xs^T g, the sum of g) for g the loss's derivative at Xs z_c + z_i,
+    ``value(fold, c, i)`` the loss at Xs c + i.  ``mv(coef, fold)`` and
+    ``tmv(g, fold)`` are Xs coef and Xs^T g."""
+    loss_fn = LOSSES[loss]
+
+    def grad(fold, z_c, z_i):
+        _, glin = loss_fn(mv(z_c, fold) + z_i, target, fold.w)
+        return tmv(glin, fold), jnp.sum(glin, axis=0)
+
+    def value(fold, coef, intercept):
+        return loss_fn(mv(coef, fold) + intercept, target, fold.w)[0]
+
+    return grad, value
+
+
+def _dense_lanes(X: jnp.ndarray, pivot: Optional[jnp.ndarray],
+                 target: jnp.ndarray, folds: _Folds, *, loss: str,
+                 grid_points: int, one_pass: bool):
+    """(grad, value, power) of the implicitly standardized dense matrix
+    Xs = (X - mean)/scale for every lane of a stage, each taking and giving
+    lane-stacked arrays: ``grad(z_c, z_i)`` -> (Xs^T g, Σ g), ``value(c, i)``
+    -> the loss, ``power(v)`` [F, D] -> Xs^T diag(wn) Xs v, a fold a lane.
+
+    ``one_pass`` (``onepass.engages``): ``grad`` and ``power`` make one
+    pass over X for every lane (``onepass.grad``); otherwise two XLA
+    products, X v and X^T g.  ``value`` is always the XLA product: it runs
+    for the final objective, and in the backtracking losses, which the
+    kernel does not compute."""
+    # the subtraction is written where a product reads it, inside the
+    # loops' bodies, so that it fuses into the product's operand as the
+    # storage dtype's conversion does; hoisted, it would be a float32 copy
+    about = (lambda: X) if pivot is None else (lambda: X - pivot)
+
+    def mv(coef, fold):
+        """Xs @ coef without materializing Xs ([N] or [N, C])."""
+        v = coef / (fold.scale[:, None] if coef.ndim == 2 else fold.scale)
+        return about() @ v - _exact_dot(fold.off, v)
+
+    def tmv(glin, fold):
+        """Xs^T @ glin ([D] or [D, C])."""
+        if glin.ndim == 2:
+            sg = jnp.sum(glin, axis=0)
+            num = about().T @ glin - fold.off[:, None] * sg[None, :]
+            return num / fold.scale[:, None]
+        return (about().T @ glin - fold.off * jnp.sum(glin)) / fold.scale
+
+    def power_lane(fold, v):
+        vs = v / fold.scale
+        u = fold.wn * (about() @ vs - _exact_dot(fold.off, vs))   # Xs @ v [N]
+        return (about().T @ u - fold.off * jnp.sum(u)) / fold.scale
+
+    lane_grad, lane_value = _lane_fns(mv, tmv, target, loss)
+    value = _over_lanes(lane_value, folds, grid_points)
+    if not one_pass:
+        return (_over_lanes(lane_grad, folds, grid_points), value,
+                _over_lanes(power_lane, folds, 1))
+    wsum = jnp.sum(folds.w, axis=1)
+
+    def kernel(v, b, form, g_pts):
+        off = jnp.repeat(folds.off, g_pts, axis=0)
+        scale = jnp.repeat(folds.scale, g_pts, axis=0)
+        vs = v / scale
+        c = jax.vmap(_exact_dot)(off, vs)
+        acc, gsum = onepass.grad(X, vs, c, b, pivot, target, folds.w, wsum,
+                                 form=form, grid_points=g_pts)
+        return (acc - off * gsum[:, None]) / scale, gsum
+
+    def grad(z_c, z_i):
+        return kernel(z_c, z_i, loss, grid_points)
+
+    def power(v):
+        return kernel(v, jnp.zeros(v.shape[:1], jnp.float32), onepass.POWER,
+                      1)[0]
+
+    return grad, value, power
 
 
 @jax.named_scope("linear.lipschitz")
-def _spectral_norm_sq_weighted(X: jnp.ndarray, wn: jnp.ndarray,
-                               mean: jnp.ndarray, scale: jnp.ndarray,
-                               iters: int = 16,
-                               pivot: Optional[jnp.ndarray] = None
-                               ) -> jnp.ndarray:
-    """λ_max of Xs^T diag(wn) Xs for the IMPLICITLY standardized matrix
-    Xs = (X - mean)/scale, never materializing Xs or the weighted product —
-    one shared HBM-resident X serves every (fold × grid) lane.  ``pivot``:
-    see ``fista_fit``."""
-    d = X.shape[1]
-    v = jnp.full((d,), 1.0 / jnp.sqrt(d), jnp.float32)
-    about, off = _about(X, mean, pivot)
-
-    def mv(v):
-        vs = v / scale
-        u = wn * (about() @ vs - _exact_dot(off, vs))    # Xs @ v  [N]
-        return (about().T @ u - off * jnp.sum(u)) / scale   # Xs^T u  [D]
-
-    def body(_, v):
-        u = mv(v)
-        return u / (jnp.linalg.norm(u) + 1e-12)
-
-    v = jax.lax.fori_loop(0, iters, body, v)
-    return jnp.vdot(v, mv(v))
+def _spectral_norm_sq(power: Callable, folds: int, d: int,
+                      iters: int = 16) -> jnp.ndarray:
+    """[F] λ_max of each fold's Xs^T diag(wn) Xs by power iteration, every
+    fold a lane of one ``power`` ([F, D] -> [F, D]): one shared
+    HBM-resident X serves every lane, and Xs is never materialized."""
+    v = jnp.full((folds, d), 1.0 / jnp.sqrt(d), jnp.float32)
+    unit = jax.vmap(lambda u: u / (jnp.linalg.norm(u) + 1e-12))
+    v = jax.lax.fori_loop(0, iters, lambda _, v: unit(power(v)), v)
+    return jax.vmap(jnp.vdot)(v, power(v))
 
 
 def _loss_target(loss: str, y: jnp.ndarray, n_classes: int) -> jnp.ndarray:
@@ -166,103 +257,119 @@ def _loss_target(loss: str, y: jnp.ndarray, n_classes: int) -> jnp.ndarray:
 
 
 @jax.named_scope("linear.fista")
-def _fista_loop(xs_mv: Callable, xs_tmv: Callable, target: jnp.ndarray,
-                w: jnp.ndarray, l2: jnp.ndarray, l1: jnp.ndarray, *,
-                loss: str, d: int, n_classes: int, fit_intercept: bool,
-                max_iter: int, tol: float, sigma_sq: jnp.ndarray) -> FitResult:
-    """The FISTA iteration shared by the dense and sparse fitters: the data
-    matrix enters ONLY through the ``xs_mv``/``xs_tmv`` closures, so the same
-    loop serves both the implicit-standardized dense matmuls and the
-    take+segment_sum flat-COO matvecs."""
+def _fista_loop(grad: Callable, value: Callable, l2: jnp.ndarray,
+                l1: jnp.ndarray, *, loss: str, d: int, n_classes: int,
+                fit_intercept: bool, max_iter: int, tol: float,
+                sigma_sq: jnp.ndarray) -> FitResult:
+    """The FISTA iteration of every lane of a stage at once, shared by the
+    dense and sparse fitters.  ``l2``, ``l1`` and ``sigma_sq`` are [L]; the
+    data matrix enters ONLY through ``grad(z_c, z_i)`` -> (Xs^T g, Σ g) and
+    ``value(c, i)`` -> the mean loss, each over lane-stacked arrays, so
+    that one call can serve every lane in one pass over X.
+
+    The loop runs while any lane is active; an inactive lane's state is
+    held, as ``vmap`` of a ``while_loop`` holds it, so every lane makes the
+    iterations it made fitted alone.  Returns [L, ...]-stacked leaves."""
     C = n_classes
-    loss_fn = LOSSES[loss]
+    lanes = l2.shape[0]
     L = _LOSS_CURVATURE[loss] * sigma_sq + l2
     step0 = 1.0 / jnp.maximum(L, 1e-12)
     backtrack = loss in _BACKTRACK_LOSSES
 
-    shape = (d, C) if C > 1 else (d,)
-    b_shape = (C,) if C > 1 else ()
+    shape = (lanes, d, C) if C > 1 else (lanes, d)
+    b_shape = (lanes, C) if C > 1 else (lanes,)
 
-    def smooth_grad(coef, intercept):
-        """Value and gradient of the smooth part (loss + l2 ridge)."""
-        lin = xs_mv(coef) + intercept
-        lval, glin = loss_fn(lin, target, w)
-        gcoef = xs_tmv(glin) + l2 * coef
-        gint = (jnp.sum(glin, axis=0) if C > 1 else jnp.sum(glin))
-        return lval + 0.5 * l2 * jnp.sum(coef * coef), gcoef, gint
+    def per(x, like):
+        """A lane's scalar against an array of that lane."""
+        return x.reshape(x.shape + (1,) * (like.ndim - 1))
+
+    def total(x):
+        return jnp.sum(x, axis=tuple(range(1, x.ndim)))
+
+    def peak(x):
+        return jnp.max(jnp.abs(x), axis=tuple(range(1, x.ndim)))
 
     def smooth_val(coef, intercept):
-        lin = xs_mv(coef) + intercept
-        lval, _ = loss_fn(lin, target, w)
-        return lval + 0.5 * l2 * jnp.sum(coef * coef)
+        """Value of the smooth part (loss + l2 ridge)."""
+        return value(coef, intercept) + 0.5 * l2 * total(coef * coef)
 
     def prox(u, s):
-        return jnp.sign(u) * jnp.maximum(jnp.abs(u) - s * l1, 0.0)
+        return jnp.sign(u) * jnp.maximum(jnp.abs(u) - per(s * l1, u), 0.0)
+
+    def active(k, delta):
+        return jnp.logical_and(k < max_iter, delta > tol)
 
     def cond(state):
         k, _, _, _, _, _, _, delta = state
-        return jnp.logical_and(k < max_iter, delta > tol)
+        return jnp.any(active(k, delta))
 
     def body(state):
-        k, coef, intercept, z_c, z_i, t, step, _ = state
-        f_z, g_c, g_i = smooth_grad(z_c, z_i)
+        k, coef, intercept, z_c, z_i, t, step, delta = state
+        g_c, g_i = grad(z_c, z_i)
+        g_c = g_c + per(l2, z_c) * z_c
 
         def attempt(s):
-            nc = prox(z_c - s * g_c, s)
-            ni = z_i - s * g_i if fit_intercept else z_i
+            nc = prox(z_c - per(s, g_c) * g_c, s)
+            ni = z_i - per(s, g_i) * g_i if fit_intercept else z_i
             return nc, ni
 
         if backtrack:
             # Beck–Teboulle backtracking: shrink the step until the smooth
             # part is majorized by its quadratic model at z (exp-link losses
             # have unbounded curvature, so the fixed bound is unreliable)
+            f_z = smooth_val(z_c, z_i)
+
             def sufficient(s):
                 nc, ni = attempt(s)
                 dc = nc - z_c
-                di = jnp.atleast_1d(ni - z_i)
-                quad = (f_z + jnp.sum(dc * g_c)
-                        + jnp.sum(di * jnp.atleast_1d(g_i))
-                        + (jnp.sum(dc * dc) + jnp.sum(di * di)) / (2.0 * s))
+                di = ni - z_i
+                quad = (f_z + total(dc * g_c) + total(di * g_i)
+                        + (total(dc * dc) + total(di * di)) / (2.0 * s))
                 return smooth_val(nc, ni) <= quad + 1e-12
 
-            def bt_cond(bs):
-                s, ok, it = bs
+            def shrinking(bs):
+                _, ok, it = bs
                 return jnp.logical_and(~ok, it < 30)
 
             def bt_body(bs):
-                s, _, it = bs
-                s = s * 0.5
-                return s, sufficient(s), it + 1
+                s, ok, it = bs
+                go = shrinking(bs)
+                s = jnp.where(go, s * 0.5, s)
+                return (s, jnp.where(go, sufficient(s), ok),
+                        jnp.where(go, it + 1, it))
 
             step, _, _ = jax.lax.while_loop(
-                bt_cond, bt_body,
-                (step, sufficient(step), jnp.zeros((), jnp.int32)))
+                lambda bs: jnp.any(shrinking(bs)), bt_body,
+                (step, sufficient(step), jnp.zeros((lanes,), jnp.int32)))
 
         new_c, new_i = attempt(step)
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
         beta = (t - 1.0) / t_new
         # adaptive restart on non-descent direction
-        restart = jnp.sum((z_c - new_c) * (new_c - coef)) > 0.0
+        restart = total((z_c - new_c) * (new_c - coef)) > 0.0
         beta = jnp.where(restart, 0.0, beta)
         t_new = jnp.where(restart, 1.0, t_new)
-        zc_next = new_c + beta * (new_c - coef)
-        zi_next = new_i + beta * (new_i - intercept)
-        delta = jnp.max(jnp.abs(new_c - coef)) + jnp.max(
-            jnp.abs(jnp.atleast_1d(new_i - intercept)))
-        return k + 1, new_c, new_i, zc_next, zi_next, t_new, step, delta
+        zc_next = new_c + per(beta, new_c) * (new_c - coef)
+        zi_next = new_i + per(beta, new_i) * (new_i - intercept)
+        delta_new = peak(new_c - coef) + peak(new_i - intercept)
+        new = (k + 1, new_c, new_i, zc_next, zi_next, t_new, step, delta_new)
+        held = active(k, delta)
+        return tuple(jnp.where(per(held, n), n, o) for n, o in zip(new, state))
 
-    init = (jnp.zeros((), jnp.int32), jnp.zeros(shape, jnp.float32),
+    init = (jnp.zeros((lanes,), jnp.int32), jnp.zeros(shape, jnp.float32),
             jnp.zeros(b_shape, jnp.float32), jnp.zeros(shape, jnp.float32),
-            jnp.zeros(b_shape, jnp.float32), jnp.ones((), jnp.float32),
-            step0.astype(jnp.float32), jnp.full((), jnp.inf, jnp.float32))
+            jnp.zeros(b_shape, jnp.float32), jnp.ones((lanes,), jnp.float32),
+            step0.astype(jnp.float32),
+            jnp.full((lanes,), jnp.inf, jnp.float32))
     k, coef, intercept, *_ = jax.lax.while_loop(cond, body, init)
-    obj = smooth_val(coef, intercept) + l1 * jnp.sum(jnp.abs(coef))
-    return FitResult(coef, jnp.atleast_1d(intercept), k, obj)
+    obj = smooth_val(coef, intercept) + l1 * total(jnp.abs(coef))
+    return FitResult(coef, intercept.reshape(lanes, -1), k, obj)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("loss", "fit_intercept", "max_iter", "n_classes"))
+    static_argnames=("loss", "fit_intercept", "max_iter", "n_classes",
+                     "one_pass"))
 def fista_fit(X: jnp.ndarray, y: jnp.ndarray, sample_weight: jnp.ndarray,
               l2: jnp.ndarray, l1: jnp.ndarray, *, loss: str = "logistic",
               fit_intercept: bool = True, max_iter: int = 100,
@@ -270,23 +377,21 @@ def fista_fit(X: jnp.ndarray, y: jnp.ndarray, sample_weight: jnp.ndarray,
               mean: Optional[jnp.ndarray] = None,
               scale: Optional[jnp.ndarray] = None,
               sigma_sq: Optional[jnp.ndarray] = None,
-              pivot: Optional[jnp.ndarray] = None) -> FitResult:
+              pivot: Optional[jnp.ndarray] = None,
+              one_pass: bool = False) -> FitResult:
     """Accelerated proximal gradient with adaptive restart.
 
     minimises  mean_loss(Xs w + b) + l2/2 ||w||² + l1 ||w||₁  (no penalty on b)
     where Xs = (X - mean)/scale is the IMPLICITLY standardized matrix when
     ``mean``/``scale`` are given — the standardized copy is never
-    materialized, so every (fold × grid) vmap lane shares the single
-    HBM-resident ``X`` and XLA batches the lanes' matvecs into one matmul.
-    The returned coefficients live in the standardized basis (caller
-    un-scales, matching Spark ML's internal-standardization contract).
+    materialized.  The returned coefficients live in the standardized basis
+    (caller un-scales, matching Spark ML's internal-standardization
+    contract).  One fit: the one-lane case of ``linear_grid_fit``'s loop.
 
-    ``l2``/``l1`` may be traced scalars → vmap over a regularisation grid.
-    ``sigma_sq`` (λ_max of the weighted Gram) may be shared across grid
-    lanes; computed here when absent.  A fit that computes its own is a
-    single fit, not a grid lane — the winner's refit, a candidate fitted
-    alone — and its operations carry the scope ``linear.refit`` in the
-    device trace.
+    ``sigma_sq`` (λ_max of the weighted Gram) is computed here when absent.
+    A fit that computes its own is a single fit, not a grid lane — a
+    candidate fitted alone — and its operations carry the scope
+    ``linear.refit`` in the device trace.
 
     ``pivot`` [D] (``column_pivot``; with ``mean``) has every product read X
     about it: Xs = ((X - pivot) - (mean - pivot))/scale, the same matrix.  A
@@ -294,42 +399,31 @@ def fista_fit(X: jnp.ndarray, y: jnp.ndarray, sample_weight: jnp.ndarray,
     0.03) otherwise loses its standardized values in the rounding of
     40.75 * v - mean * v, in float32 and, the coefficient rounded to
     bfloat16, far sooner on the chip.
+
+    ``one_pass`` is what ``onepass.engages`` says of ``X`` (the caller asks
+    it where X is placed): one pass of X an iteration instead of two.
     """
     n, d = X.shape
     C = n_classes
-    w = sample_weight.astype(jnp.float32)
     target = _loss_target(loss, y, C)
 
     std = scale is not None
     mu = mean if std else jnp.zeros((d,), jnp.float32)
     sc = scale if std else jnp.ones((d,), jnp.float32)
-
     pivot = pivot if std else None
-    about, off = _about(X, mu, pivot)
-
-    def xs_mv(coef):
-        """Xs @ coef without materializing Xs ([N] or [N, C])."""
-        v = coef / (sc[:, None] if coef.ndim == 2 else sc)
-        return about() @ v - _exact_dot(off, v)
-
-    def xs_tmv(glin):
-        """Xs^T @ glin ([D] or [D, C])."""
-        if glin.ndim == 2:
-            sg = jnp.sum(glin, axis=0)
-            num = about().T @ glin - off[:, None] * sg[None, :]
-            return num / sc[:, None]
-        return (about().T @ glin - off * jnp.sum(glin)) / sc
-
-    # step size from Lipschitz bound: c * sigma_max(Xs_w)^2 (+ l2)
-    wn = w / jnp.sum(w)
+    folds = _folds(sample_weight[None], mu[None], sc[None], pivot)
+    grad, value, power = _dense_lanes(X, pivot, target, folds, loss=loss,
+                                      grid_points=1, one_pass=one_pass)
+    lane = lambda a: jnp.asarray(a, jnp.float32).reshape(1)   # noqa: E731
     with (jax.named_scope("linear.refit") if sigma_sq is None
           else contextlib.nullcontext()):
         if sigma_sq is None:
-            sigma_sq = _spectral_norm_sq_weighted(X, wn, mu, sc,
-                                                  pivot=pivot)
-        return _fista_loop(xs_mv, xs_tmv, target, w, l2, l1, loss=loss, d=d,
-                           n_classes=C, fit_intercept=fit_intercept,
-                           max_iter=max_iter, tol=tol, sigma_sq=sigma_sq)
+            sigma_sq = _spectral_norm_sq(power, 1, d)
+        res = _fista_loop(grad, value, lane(l2), lane(l1), loss=loss, d=d,
+                          n_classes=C, fit_intercept=fit_intercept,
+                          max_iter=max_iter, tol=tol,
+                          sigma_sq=lane(sigma_sq))
+    return FitResult(*(leaf[0] for leaf in res))
 
 
 @functools.partial(jax.jit, static_argnames=("fit_intercept",))
@@ -377,12 +471,13 @@ def naive_bayes_fit(X: jnp.ndarray, y: jnp.ndarray, sample_weight: jnp.ndarray,
 @functools.partial(
     jax.jit,
     static_argnames=("loss", "fit_intercept", "standardization", "max_iter",
-                     "n_classes"))
+                     "n_classes", "one_pass"))
 def linear_grid_fit(X: jnp.ndarray, y: jnp.ndarray, fold_weights: jnp.ndarray,
                     l2s: jnp.ndarray, l1s: jnp.ndarray, *,
                     loss: str = "logistic", fit_intercept: bool = True,
                     standardization: bool = True, max_iter: int = 100,
-                    tol: float = 1e-6, n_classes: int = 1) -> FitResult:
+                    tol: float = 1e-6, n_classes: int = 1,
+                    one_pass: bool = False) -> FitResult:
     """The whole (fold × grid-point) CV matrix as ONE XLA program.
 
     ``fold_weights`` [F, N] are per-fold row weights (weight 0 == row held
@@ -392,38 +487,43 @@ def linear_grid_fit(X: jnp.ndarray, y: jnp.ndarray, fold_weights: jnp.ndarray,
     grid.  Standardisation moments are computed once per fold and shared by
     the grid points.  Returns a FitResult with [F, G, ...]-stacked leaves.
 
+    The F·G fits are the lanes of one loop (``_fista_loop``), lane ``l``
+    grid point ``l % G`` of fold ``l // G``, so that one pass over X can
+    serve them all: with ``one_pass`` (what ``onepass.engages`` says of X)
+    an iteration reads X once for every lane, else XLA batches the lanes'
+    products into two passes.
+
     ≙ the reference's thread-pool fan-out of k×Σ|grid| Spark jobs
-    (OpValidator.scala:320-349), re-expressed as nested vmap (SURVEY §2.6 P3).
+    (OpValidator.scala:320-349), re-expressed as lanes (SURVEY §2.6 P3).
     """
     d = X.shape[1]
+    n_folds, grid_points = fold_weights.shape[0], l2s.shape[0]
     pivot = column_pivot(X) if standardization else None
     # the products read X about it only where the columns are centred: a
     # shift is neutral there and nowhere else
     product_pivot = pivot if fit_intercept else None
-
-    def one_fold(w):
-        if standardization:
-            mean, scale = standardize_moments(X, w, center=fit_intercept,
-                                              pivot=pivot)
-        else:
-            mean, scale = (jnp.zeros((d,), jnp.float32), jnp.ones((d,), jnp.float32))
-        # λ_max of the fold's weighted Gram is grid-independent: compute it
-        # once per fold and share it across the vmapped grid lanes
-        wn = w / jnp.sum(w)
-        sigma_sq = _spectral_norm_sq_weighted(X, wn, mean, scale,
-                                              pivot=product_pivot)
-
-        def one_pt(l2, l1):
-            res = fista_fit(X, y, w, l2, l1, loss=loss,
-                            fit_intercept=fit_intercept, max_iter=max_iter,
-                            tol=tol, n_classes=n_classes,
-                            mean=mean, scale=scale, sigma_sq=sigma_sq,
-                            pivot=product_pivot)
-            return unscale_params(res, mean, scale, n_classes)
-
-        return jax.vmap(one_pt)(l2s, l1s)
-
-    return jax.vmap(one_fold)(fold_weights)
+    if standardization:
+        mean, scale = jax.vmap(lambda w: standardize_moments(
+            X, w, center=fit_intercept, pivot=pivot))(fold_weights)
+    else:
+        mean = jnp.zeros((n_folds, d), jnp.float32)
+        scale = jnp.ones((n_folds, d), jnp.float32)
+    folds = _folds(fold_weights, mean, scale, product_pivot)
+    grad, value, power = _dense_lanes(
+        X, product_pivot, _loss_target(loss, y, n_classes), folds, loss=loss,
+        grid_points=grid_points, one_pass=one_pass)
+    # λ_max of a fold's weighted Gram is grid-independent: computed once a
+    # fold and shared by its grid points
+    sigma_sq = _spectral_norm_sq(power, n_folds, d)
+    lanes = lambda a: jnp.tile(a.astype(jnp.float32), n_folds)  # noqa: E731
+    res = _fista_loop(grad, value, lanes(l2s), lanes(l1s), loss=loss, d=d,
+                      n_classes=n_classes, fit_intercept=fit_intercept,
+                      max_iter=max_iter, tol=tol,
+                      sigma_sq=jnp.repeat(sigma_sq, grid_points))
+    res = _over_lanes(lambda fold, r: unscale_params(
+        r, fold.mean, fold.scale, n_classes), folds, grid_points)(res)
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((n_folds, grid_points) + a.shape[1:]), res)
 
 
 @functools.partial(
@@ -600,41 +700,44 @@ def sparse_linear_grid_fit(values, indices, row_ids, y, fold_weights,
     C = n_classes
     target = _loss_target(loss, y, C)
     zeros_d = jnp.zeros((n_cols,), jnp.float32)
+    n_folds, grid_points = fold_weights.shape[0], l2s.shape[0]
+    w = fold_weights.astype(jnp.float32)
+    wn = w / jnp.sum(w, axis=1, keepdims=True)
+    if standardization:
+        scale = jax.vmap(lambda wn: _sp_col_scale(values, indices, row_ids,
+                                                  wn, n_cols))(wn)
+    else:
+        scale = jnp.ones((n_folds, n_cols), jnp.float32)
+    sigma_sq = jax.vmap(lambda wn, scale: _sp_spectral_norm_sq(
+        values, indices, row_ids, wn, scale, n_rows, n_cols))(wn, scale)
+    zeros = jnp.zeros((n_folds, n_cols), jnp.float32)
+    folds = _Folds(w, wn, zeros, zeros, scale)
 
-    def one_fold(w):
-        w = w.astype(jnp.float32)
-        wn = w / jnp.sum(w)
-        if standardization:
-            scale = _sp_col_scale(values, indices, row_ids, wn, n_cols)
-        else:
-            scale = jnp.ones((n_cols,), jnp.float32)
-        sigma_sq = _sp_spectral_norm_sq(values, indices, row_ids, wn, scale,
-                                        n_rows, n_cols)
+    def xs_mv(coef, fold):
+        if coef.ndim == 2:
+            return sp_matmat(values, indices, row_ids,
+                             coef / fold.scale[:, None], n_rows=n_rows)
+        return sp_matvec(values, indices, row_ids, coef / fold.scale,
+                         n_rows=n_rows)
 
-        def xs_mv(coef):
-            if coef.ndim == 2:
-                return sp_matmat(values, indices, row_ids,
-                                 coef / scale[:, None], n_rows=n_rows)
-            return sp_matvec(values, indices, row_ids, coef / scale,
-                             n_rows=n_rows)
+    def xs_tmv(glin, fold):
+        if glin.ndim == 2:
+            return sp_rmatmat(values, indices, row_ids, glin,
+                              n_cols=n_cols) / fold.scale[:, None]
+        return sp_rmatvec(values, indices, row_ids, glin,
+                          n_cols=n_cols) / fold.scale
 
-        def xs_tmv(glin):
-            if glin.ndim == 2:
-                return sp_rmatmat(values, indices, row_ids, glin,
-                                  n_cols=n_cols) / scale[:, None]
-            return sp_rmatvec(values, indices, row_ids, glin,
-                              n_cols=n_cols) / scale
-
-        def one_pt(l2, l1):
-            res = _fista_loop(xs_mv, xs_tmv, target, w, l2, l1, loss=loss,
-                              d=n_cols, n_classes=C,
-                              fit_intercept=fit_intercept, max_iter=max_iter,
-                              tol=tol, sigma_sq=sigma_sq)
-            return unscale_params(res, zeros_d, scale, C)
-
-        return jax.vmap(one_pt)(l2s, l1s)
-
-    return jax.vmap(one_fold)(fold_weights)
+    grad, value = (_over_lanes(fn, folds, grid_points)
+                   for fn in _lane_fns(xs_mv, xs_tmv, target, loss))
+    lanes = lambda a: jnp.tile(a.astype(jnp.float32), n_folds)  # noqa: E731
+    res = _fista_loop(grad, value, lanes(l2s), lanes(l1s), loss=loss,
+                      d=n_cols, n_classes=C, fit_intercept=fit_intercept,
+                      max_iter=max_iter, tol=tol,
+                      sigma_sq=jnp.repeat(sigma_sq, grid_points))
+    res = _over_lanes(lambda fold, r: unscale_params(
+        r, zeros_d, fold.scale, C), folds, grid_points)(res)
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((n_folds, grid_points) + a.shape[1:]), res)
 
 
 def sparse_fista_fit(sm, y, sample_weight, l2: float, l1: float, *,

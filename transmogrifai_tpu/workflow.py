@@ -304,6 +304,8 @@ class Workflow(_WorkflowCore):
         # maxQuarantineFraction, training aborts with DataQualityError —
         # never silently fits on a fraction of the data.
         from .quality import QualityConfig, screen_batch, use_quality
+        from .models import onepass
+        onepass.warm()
         qcfg = QualityConfig.resolve(self.parameters.get("quality"))
         with timer.phase("read"):
             if qcfg.enabled:

@@ -494,6 +494,32 @@ def test_hash_scatter_compiles_for_the_chip_at_the_cells_size(one_chip):
     assert held < 12 * 2 ** 30, m
 
 
+def test_hash_kernel_compiles_for_the_chip_at_the_cells_size(one_chip):
+    """The same counts through the Pallas kernel (``ops/hash_kernel.py``),
+    compiled for a v5e chip at the cell's rows and wire: Mosaic takes the
+    kernel, its block bounds fit the scalar memory, and the program holds
+    the counts twice at most (the kernel's ``[512, rows]`` and the
+    ``[rows, 512]`` it is read as), with no float32 ``[rows + 1, 513]``
+    scatter target and no sort.  Here with this file's other compiles: one
+    worker loads the TPU's compiler."""
+    import jax.numpy as jnp
+    from transmogrifai_tpu.ops import text
+    cfg = run.load_json("benchmark", "configs", "amazon_polarity_text.json")
+    rows = cfg["rows"]
+    tokens = rows * cfg["generator"]["text"]["mean_tokens"]
+    words = text._size_class(-(-tokens // 3))
+    with no_compile_cache():
+        compiled = text._scatter_counts_packed.lower(
+            jax.ShapeDtypeStruct((words,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((rows + 1,), jnp.int32, sharding=one_chip),
+            rows, 512, False, True).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "sort(" not in hlo
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes == rows * 512 * 4
+    assert m.temp_size_in_bytes < 1.1 * rows * 512 * 4, m
+
+
 def test_round_b_panel_compiles_for_the_chip_at_the_typed_cells_size(
         one_chip):
     """The metric panel of two folds by three survivors (every cell's

@@ -343,13 +343,16 @@ class ScoreProgram:
     def __call__(self, batch: ColumnBatch, keep_intermediate: bool = False
                  ) -> ColumnBatch:
         # stages run outside a compiled segment, input columns whose wire a
-        # train's prologue pool made ahead (``ColumnWired``), and values a
-        # pivot handled one by one in Python (``ops.categorical``); created
-        # at 0 by a flush that counts none, so that a reader can tell 0 from
-        # no counter
+        # train's prologue pool made ahead (``ColumnWired``), values a pivot
+        # handled one by one in Python (``ops.categorical``), and hashed
+        # text columns whose counts the Pallas kernel or XLA's scatter built
+        # (``ops.text``); created at 0 by a flush that counts none, so that
+        # a reader can tell 0 from no counter
         host_stages = REGISTRY.counter("transform.host_stages")
         REGISTRY.counter("transform.wires_ahead")
         REGISTRY.counter("pivot.python_values")
+        REGISTRY.counter("text.hash_kernel_columns")
+        REGISTRY.counter("text.hash_scatter_columns")
         for _attempt in range(len(self.stages) + 1):
             segments = self._partition(batch)
             b = batch

@@ -150,6 +150,37 @@ def _scatter_counts_device(ids, lens_padded, n, num_hashes, binary):
 _STACKED_UNPACK_BYTES = 1 << 28
 
 
+def _platform() -> str:
+    return jax.default_backend()
+
+
+def _one_tpu(rows: Optional[int] = None) -> bool:
+    """Whether a count program runs on ONE TPU device: the platform is the
+    TPU and the fused transform lays no mesh over its ``rows`` rows (the
+    rule ``compiled.ScoreProgram._wire`` shards by); ``None``: the arrays
+    stay on the default device."""
+    if _platform() != "tpu":
+        return False
+    if rows is None:
+        return True
+    from ..parallel.mesh import maybe_data_mesh
+    return maybe_data_mesh(rows) is None
+
+
+def _kernel_engages(cap: int, num_hashes: int, one_tpu: bool) -> bool:
+    """The rule that builds a wire's count matrix with the Pallas kernel of
+    ``ops/hash_kernel.py`` instead of XLA's scatter-add: the wire is long
+    enough to unpack lane by lane (``_STACKED_UNPACK_BYTES``, from its
+    static length), the program runs on one TPU device (``_one_tpu``) and
+    the ids fit the packed wire's 10 bits.  Everything else keeps the
+    scatter: the CPU, a mesh, and the short stacked wires, whose scatter
+    costs milliseconds (the Criteo cells' one-token columns: 65,536 words a
+    column) where a kernel each would add its lowering to every train's
+    first call of the fused program."""
+    return (one_tpu and num_hashes < 1024
+            and cap * 512 > _STACKED_UNPACK_BYTES)
+
+
 def _unpack_ids3(words, lens_padded):
     """Packed words [cap] + tokens a row [n + 1] (the last entry is the
     padding's) → (rows, ids) of every token slot, both flat [3 * cap].  The
@@ -183,17 +214,39 @@ def _unpack_ids3(words, lens_padded):
             jnp.concatenate(lanes))
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
-def _scatter_counts_packed(words, lens_padded, n, num_hashes, binary):
+def _count_form(kernel: bool) -> None:
+    """One hashed column of a flush, on the side its count program took."""
+    from ..telemetry import REGISTRY
+    REGISTRY.counter("text.hash_kernel_columns" if kernel
+                     else "text.hash_scatter_columns").inc()
+
+
+@jax.named_scope("text.hash_counts")
+def _hash_counts(words, lens_padded, num_hashes: int, binary: bool, dtype,
+                 kernel: bool):
+    """``[n, num_hashes]`` counts (``binary``: their indicators) in
+    ``dtype`` of a packed wire, ``n = len(lens_padded) - 1``: through the
+    kernel or XLA's scatter-add, as ``_kernel_engages`` chose.  The two
+    give the same matrix to the bit."""
+    if kernel:
+        from . import hash_kernel
+        return hash_kernel.counts(words, lens_padded, num_hashes, binary,
+                                  dtype)
+    n = lens_padded.shape[0] - 1
+    rows, ids = _unpack_ids3(words, lens_padded)
+    counts = jnp.zeros((n + 1, num_hashes + 1), jnp.float32)
+    counts = counts.at[rows, ids].add(1.0)[:n, :num_hashes]
+    return ((counts > 0) if binary else counts).astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def _scatter_counts_packed(words, lens_padded, n, num_hashes, binary,
+                           kernel=False):
     """Packed-wire variant: each int32 word carries THREE 10-bit bucket ids
     (token order preserved), tripling the effective host-link bandwidth of
     the hashing trick — the ids unpack with two shifts on device."""
-    rows, ids = _unpack_ids3(words, lens_padded)
-    counts = jnp.zeros((n + 1, num_hashes + 1), jnp.float32)
-    with jax.named_scope("text.hash_counts"):
-        counts = counts.at[rows, ids].add(1.0)
-    counts = counts[:n, :num_hashes]
-    return (counts > 0).astype(jnp.float32) if binary else counts
+    return _hash_counts(words, lens_padded, num_hashes, binary, jnp.float32,
+                        kernel)
 
 
 def _size_class(n: int, floor: int = 1024) -> int:
@@ -251,13 +304,16 @@ def device_counts_from_flat(lens: np.ndarray, flat: np.ndarray,
             device_ids = jnp.asarray(words_p)
         cap = int(device_ids.shape[0])
         lens_p = np.append(lens, np.int32(3 * cap - total)).astype(np.int32)
+        kernel = _kernel_engages(cap, num_hashes, _one_tpu())
+        _count_form(kernel)
         out = _scatter_counts_packed(device_ids, jnp.asarray(lens_p),
-                                     n, num_hashes, bool(binary))
+                                     n, num_hashes, bool(binary), kernel)
     else:
         cap = 1 << max(10, int(np.ceil(np.log2(max(total, 1)))))
         ids_p = np.full(cap, num_hashes, dtype=np.int32)     # sentinel bin
         ids_p[:total] = flat
         lens_p = np.append(lens, np.int32(cap - total)).astype(np.int32)
+        _count_form(False)
         out = _scatter_counts_device(jnp.asarray(ids_p), jnp.asarray(lens_p),
                                      n, num_hashes, bool(binary))
     return out if dtype is None or out.dtype == dtype else out.astype(dtype)
@@ -467,6 +523,7 @@ class SmartTextVectorizerModel(TransformerModel):
         dtype = feature_matrix_dtype(n * est_width)
         wire: Dict[str, Any] = {}
         plan: List[Tuple[str, Any, Tuple[Optional[str], ...]]] = []
+        one_tpu = _one_tpu(n)
         for i, f in enumerate(self.input_features):
             col = batch[f.name]
             if not col.is_host_object():
@@ -495,7 +552,10 @@ class SmartTextVectorizerModel(TransformerModel):
                 if track_nulls:
                     nk = f"null{i}"
                     wire[nk] = pack_bits(prof.null)
-                plan.append(("hash", num_hashes, (f"words{i}", f"lens{i}", nk)))
+                kernel = _kernel_engages(cap, num_hashes, one_tpu)
+                _count_form(kernel)
+                plan.append(("hash", (num_hashes, kernel),
+                             (f"words{i}", f"lens{i}", nk)))
         meta = self.fitted["meta"]
 
         def body(w):
@@ -510,13 +570,10 @@ class SmartTextVectorizerModel(TransformerModel):
                         w[keys[0]], n)[:, None].astype(dtype))
                 else:
                     words, lens_p = w[keys[0]], w[keys[1]]
-                    h = info
-                    rows, ids = _unpack_ids3(words, lens_p)
                     nr = lens_p.shape[0] - 1
-                    counts = jnp.zeros((nr + 1, h + 1), jnp.float32)
-                    with jax.named_scope("text.hash_counts"):
-                        counts = counts.at[rows, ids].add(1.0)
-                    counts = counts[:nr, :h].astype(dtype)
+                    h, kernel = info
+                    counts = _hash_counts(words, lens_p, h, False, dtype,
+                                          kernel)
                     if keys[2] is not None:
                         counts = jnp.concatenate(
                             [counts,
